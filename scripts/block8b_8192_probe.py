@@ -1,7 +1,7 @@
-"""Standalone probe for the block8b seq-8192 compile-helper failure
-(BENCH_r5_watch*.json: HTTP 500 at every batch). Runs the exact bench
-tier config at batch 1 and lets the full compile error reach stderr,
-which the bench's 400-char truncation cuts off."""
+"""Standalone probe for the block8b seq-8192 compile failure (r5: out
+of memory at every batch on the naive attention path). Runs the exact
+bench tier config at batch 1 and lets the full compile error reach
+stderr."""
 import dataclasses
 import sys
 

@@ -3,7 +3,7 @@
 Starts the CPU HTTP server (llama3_tiny, random init — weight values
 don't matter for scheduling behavior), then overlaps three requests:
 
-- LONG:   max_new 60 — submitted first, holds a slot the whole run;
+- LONG:   max_new 600 — submitted first, holds a slot the whole run;
 - SHORT:  max_new 4  — submitted after the long one has started;
 - STREAM: max_new 16 — SSE, sharing decode chunks with both.
 
@@ -52,12 +52,15 @@ os.environ.setdefault(
     "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
 )
 os.environ.setdefault("TPUFW_MODEL", "llama3_tiny")
+# Room for a LONG request that is still decoding when the others arrive:
+# the tiny model on one CPU device emits a 60-token answer in ~0.1 s.
+os.environ.setdefault("TPUFW_MAX_SEQ_LEN", "1024")
 os.environ.setdefault("TPUFW_SERVE_CHUNK", "2")
 os.environ.setdefault("TPUFW_SERVE_PAGE", "16")
 os.environ.setdefault("TPUFW_SERVE_SPEC_K", "4")
 os.environ.setdefault("TPUFW_SERVE_PREFILL_CHUNK", "1")
 
-LONG_NEW, SHORT_NEW, STREAM_NEW = 60, 4, 16
+LONG_NEW, SHORT_NEW, STREAM_NEW = 600, 4, 16
 
 
 def main() -> int:
@@ -117,7 +120,14 @@ def main() -> int:
         args=("long", {"prompts": [[1, 2, 3]], "max_new_tokens": LONG_NEW}),
     )
     long_t.start()
-    time.sleep(0.3)  # let the long request occupy its slot first
+    # Let the long request occupy its slot first: wait until the
+    # scheduler reports it, not for a guessed interval.
+    deadline = time.time() + 30
+    while time.time() < deadline:
+        with urllib.request.urlopen(base + "/metrics", timeout=30) as resp:
+            if b"tpufw_serve_slots_occupied 1" in resp.read():
+                break
+        time.sleep(0.01)
     short_t = threading.Thread(
         target=post,
         args=("short", {"prompts": [[4, 5]], "max_new_tokens": SHORT_NEW}),

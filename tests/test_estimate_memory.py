@@ -59,8 +59,8 @@ def test_decode_cache_len_scales_kv():
 
 
 def test_cli_emits_json_without_backend():
-    """The CLI must answer from the static chip table — a wedged
-    accelerator backend (jax.devices() hanging) must not block it."""
+    """The CLI answers from the static chip table: no backend is
+    asked, so it works where there is no accelerator at all."""
     proc = subprocess.run(
         [
             sys.executable, "-m", "tpufw.tools.estimate_memory",

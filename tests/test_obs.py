@@ -420,6 +420,57 @@ def test_meter_without_registry_unchanged():
     assert sm.step == 1 and meter.registry is None
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def test_detect_chip_knows_the_chip_and_refuses_to_guess():
+    from tpufw.utils.hardware import CHIP_SPECS, detect_chip
+
+    # "TPU v5 lite" is what jax 0.9.0 / libtpu 0.0.34 report for a v5e
+    # (chip_smoke.py's device line on the chip tool's machine, PR 21).
+    for kind in ("TPU v5 lite", "TPU v5e", "TPU v5litepod"):
+        assert detect_chip(_FakeDevice("tpu", kind)) is CHIP_SPECS["v5e"]
+    assert detect_chip(_FakeDevice("tpu", "TPU v4")) is CHIP_SPECS["v4"]
+    # An accelerator the table does not know is an error, not a v5e.
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        detect_chip(_FakeDevice("tpu", "TPU v9 mega"))
+    with pytest.raises(ValueError, match="unknown accelerator"):
+        detect_chip(_FakeDevice("gpu", "NVIDIA H100"))
+    # A CPU has no row at all — no invented peak.
+    assert detect_chip(_FakeDevice("cpu", "cpu")) is None
+    assert "cpu" not in CHIP_SPECS
+
+
+def test_cpu_meter_emits_no_mfu():
+    from tpufw.train.metrics import Meter
+    from tpufw.utils.hardware import CHIP_SPECS
+
+    reg = Registry()
+    meter = Meter(
+        tokens_per_step=1000, flops_per_token=6e9, n_chips=1, registry=reg
+    )  # the suite runs on CPU devices: no chip detected
+    assert meter.chip is None
+    meter.start()
+    sm = meter.stop(1, 2.5)
+    assert sm.mfu is None
+    assert "mfu" not in sm.as_dict() and "mfu" not in sm.event_fields()
+    assert sm.tokens_per_sec_per_chip > 0
+    assert "tpufw_train_mfu" not in reg.render()
+    # With a chip there is a peak to divide by, and the MFU appears.
+    reg = Registry()
+    meter = Meter(
+        tokens_per_step=1000, flops_per_token=6e9, n_chips=1,
+        chip=CHIP_SPECS["v5e"], registry=reg,
+    )
+    meter.start()
+    sm = meter.stop(1, 2.5)
+    assert sm.mfu > 0 and "mfu" in sm.as_dict()
+    assert "tpufw_train_mfu " in reg.render()
+
+
 # ------------------------------------------------- disabled-overhead budget
 
 
@@ -520,7 +571,9 @@ def test_live_scrape_has_step_mfu_data_wait(telemetry_run):
     _, _, _, scraped = telemetry_run
     text = scraped["text"]
     assert "# TYPE tpufw_train_steps_total counter" in text
-    assert "tpufw_train_mfu " in text
+    assert "tpufw_train_tokens_per_sec_per_chip " in text
+    # A CPU run has no peak to take a utilization against.
+    assert "tpufw_train_mfu" not in text
     # Run identity published at startup: every scrape is joinable to a
     # build/backend/mesh/model, not just the final snapshot.
     info_lines = [

@@ -142,9 +142,22 @@ def test_roofline_classify_and_attainable():
     assert attainable_flops_per_s(1e6, peaks) == 1e12
 
 
-def test_detect_peaks_survives_without_backend():
+def test_detect_peaks_on_cpu_has_no_peaks():
+    # No table row for a CPU: every peak reads 0 = unknown, and the
+    # observatory leaves MFU, bound and headroom out instead of
+    # dividing by an invented chip.
     peaks = detect_peaks()
-    assert peaks.flops_per_s > 0 and peaks.hbm_bytes > 0
+    assert (peaks.flops_per_s, peaks.hbm_bw_bytes_per_s, peaks.hbm_bytes) == (
+        0.0, 0.0, 0
+    )
+    obs = PerfObservatory(registry=Registry())
+    obs.record_costs(
+        "p", flops=2e9, bytes_accessed=1e9,
+        memory={"argument_bytes": 4_000_000_000},
+    )
+    assert obs.record_wall("p", 0.004) is None
+    assert obs.attrib("p") == {"program": "p"}
+    assert obs.snapshot()["p"]["wall_s"] == 0.004
 
 
 # ------------------------------------------------------ programs.json

@@ -40,7 +40,7 @@ def test_trains_and_meters(devices8):
     assert len(hist) == 8
     assert hist[-1].loss < hist[0].loss
     assert hist[-1].tokens_per_sec_per_chip > 0
-    assert np.isfinite(hist[-1].mfu)
+    assert hist[-1].mfu is None  # CPU mesh: no peak, no MFU
 
 
 def test_stage_params_sharded_on_pipe(devices8):

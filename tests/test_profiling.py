@@ -8,7 +8,73 @@ import jax.numpy as jnp
 from tpufw.utils.profiling import StepProfiler, enable_compile_cache
 
 
-def test_compile_cache_enable(tmp_path):
+_CACHE_PROBE = """
+import json, sys
+import jax
+from tpufw.utils.profiling import enable_compile_cache
+updates = []
+real = jax.config.update
+def spy(name, value):
+    updates.append(name)
+    return real(name, value)
+jax.config.update = spy
+got = enable_compile_cache()
+print(json.dumps({
+    "returned": got,
+    "config": jax.config.jax_compilation_cache_dir,
+    "dir_set_in_code": "jax_compilation_cache_dir" in updates,
+    "min_compile_secs": jax.config.jax_persistent_cache_min_compile_time_secs,
+}))
+"""
+
+
+def _probe_compile_cache(env_dir):
+    """enable_compile_cache() in a fresh process: jax reads
+    JAX_COMPILATION_CACHE_DIR at import, so only a child shows what an
+    entry point really gets."""
+    import json
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE],
+        env=env, cwd=repo, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_dir_from_environment_is_left_alone(tmp_path):
+    placed = str(tmp_path / "placed-from-outside")
+    got = _probe_compile_cache(placed)
+    # Exactly the directory that was set: nothing appended, and the
+    # helper never re-points jax's config — only the thresholds move.
+    assert got["returned"] == placed
+    assert got["config"] == placed
+    assert got["dir_set_in_code"] is False
+    assert got["min_compile_secs"] == 0.0
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    first = _probe_compile_cache(None)
+    second = _probe_compile_cache(None)
+    # The path is part of what a later process must reproduce to hit:
+    # the same across calls and processes, inside the checkout.
+    assert first == second
+    assert first["returned"] == os.path.join(repo, ".xla-cache")
+    assert first["config"] == first["returned"]
+    assert _dir_of_two_calls_in_this_process() == first["returned"]
+
+
+def _dir_of_two_calls_in_this_process():
+    """Two calls in THIS process agree (config restored afterwards; the
+    suite keeps jax's cache master switch off, so nothing is written)."""
     prev = {
         n: getattr(jax.config, n)
         for n in (
@@ -17,30 +83,13 @@ def test_compile_cache_enable(tmp_path):
             "jax_persistent_cache_min_entry_size_bytes",
         )
     }
-    cache = tmp_path / "xla-cache"
     try:
-        from tpufw.utils.profiling import machine_fingerprint
-
-        got = enable_compile_cache(str(cache))
-        # Per-machine keying: a shared dir cannot serve executables
-        # compiled for another host's CPU features (BENCH_r02 SIGILL
-        # warning); identical machines map to the same subdir.
-        assert got == str(cache / machine_fingerprint())
-        assert os.path.isdir(got)
-        assert enable_compile_cache(str(cache), per_machine=False) == str(
-            cache
-        )
-        got = enable_compile_cache(str(cache))
-        # A fresh compile must leave a persisted entry behind.
-        jax.jit(lambda x: x * 2 + 1)(jnp.arange(128.0)).block_until_ready()
-        assert any(os.listdir(got))
+        a, b = enable_compile_cache(), enable_compile_cache()
+        assert a == b == jax.config.jax_compilation_cache_dir
+        return a
     finally:
         for name, value in prev.items():
             jax.config.update(name, value)
-        # Re-BIND the persistent cache, not just the config: the cache
-        # object latches onto whatever dir it initialized with, and the
-        # suite-wide conftest cache must survive this test (otherwise
-        # every later test persists compiles into this tmp_path).
         from jax.experimental.compilation_cache import (
             compilation_cache as _cc,
         )
@@ -48,9 +97,31 @@ def test_compile_cache_enable(tmp_path):
         _cc.reset_cache()
 
 
-def test_compile_cache_noop_without_config(monkeypatch):
-    monkeypatch.delenv("TPUFW_COMPILE_CACHE_DIR", raising=False)
-    assert enable_compile_cache() is None
+def test_compile_cache_persists_a_fresh_compile(tmp_path):
+    """With the directory placed from outside and jax's switch on, a
+    compile leaves an entry behind — in a child, so the suite's own
+    no-cache choice (conftest) is untouched."""
+    import subprocess
+    import sys
+
+    cache = tmp_path / "xla-cache"
+    env = dict(os.environ)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "1"
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [
+            sys.executable, "-c",
+            "import jax, jax.numpy as jnp\n"
+            "from tpufw.utils.profiling import enable_compile_cache\n"
+            "enable_compile_cache()\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.arange(128.0))"
+            ".block_until_ready()\n",
+        ],
+        env=env, cwd=repo, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert any(os.listdir(cache))
 
 
 def test_step_profiler_inactive_is_free():

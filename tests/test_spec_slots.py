@@ -40,7 +40,16 @@ PAGE = 16
 N_SLOTS = 4
 K = 3
 
-PROMPTS = [[1, 5, 9], [2, 7], [3]]
+# Greedy continuations with room between the top two logits at every
+# step. The accept-all floor below needs the draft's t=1 program and the
+# target's t=k+1 verify program to pick the SAME argmax; they are
+# different XLA programs, a bf16 rounding apart, and a prompt whose
+# continuation crosses a near-tie loses accepts to that rounding, not to
+# the algorithm ([3] -> ... 137 and [2, 7] -> ... 134|86 sit at gaps of
+# 0.012 and 0.004, under a bf16 ulp of the logit; jax 0.9.0's XLA:CPU
+# lands on the other side of the first). Chosen by measuring the gaps
+# over nine greedy steps: [90] keeps >= 0.32, [59] >= 0.21.
+PROMPTS = [[1, 5, 9], [90], [59]]
 
 
 @pytest.fixture(scope="module")

@@ -1,8 +1,8 @@
 """sync_every > 1: windowed host syncs across all three trainer loops.
 
 One host sync (block_until_ready on the loss) per window of dispatched
-steps — on a remote/tunneled PJRT backend every sync is a round trip
-that serializes against short steps (bench r3: the ResNet tier). The
+steps — every sync is a host<->device round trip that serializes
+against short steps (bench r3: the ResNet tier). The
 cadence contract: always sync after the FIRST step (compile boundary,
 so cold-start timing survives) and the LAST; metrics entries carry
 window averages in ``StepMetrics.window_steps``.

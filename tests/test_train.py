@@ -51,7 +51,8 @@ def test_metrics_populated(trained):
     _, history = trained
     m = history[-1]
     assert m.tokens_per_sec_per_chip > 0
-    assert 0 <= m.mfu  # CPU mesh: no meaningful bound, just well-formed.
+    # A CPU device has no peak FLOP/s to divide by: no MFU is invented.
+    assert m.mfu is None and "mfu" not in m.as_dict()
     assert m.step_time_s > 0
 
 
@@ -136,6 +137,44 @@ def test_packed_data_through_flash_backend(devices8):
         losses["flash"], losses["xla"], rtol=2e-4,
         err_msg="flash-vs-xla packed loss diverged",
     )
+
+
+@pytest.mark.parametrize(
+    "mesh_cfg",
+    [MeshConfig(), MeshConfig(fsdp=4, tensor=2)],
+    ids=["fsdp8", "fsdp4_tensor2"],
+)
+def test_flash_train_step_lowers_for_tpu_over_a_mesh(
+    devices8, monkeypatch, mesh_cfg
+):
+    """The blocker PR 21 found by reading, caught from a CPU host.
+
+    Mosaic kernels cannot be partitioned by GSPMD: under the trainer's
+    jit over more than one device the flash kernel must sit inside a
+    fully-manual ``shard_map``, or lowering raises ``NotImplementedError:
+    Mosaic kernels cannot be automatically partitioned``. The Pallas
+    interpreter — what every CPU run uses — lowers to plain HLO and never
+    meets that rule, so this forces the compiled side (interpret=False)
+    and LOWERS (no compile, no run) the train step for the TPU platform
+    over the 8-device virtual mesh. Cross-platform lowering runs jax's
+    TPU lowering rules, Mosaic's included, without a TPU backend; what
+    it cannot reach is the Mosaic compiler itself (chip_smoke.py does)."""
+    import dataclasses
+
+    monkeypatch.setattr(
+        "tpufw.ops.flash.default_interpret", lambda platform: False
+    )
+    cfg = dataclasses.replace(
+        TINY, attention_backend="flash", head_dim=128, max_seq_len=256
+    )
+    trainer = Trainer(
+        Llama(cfg),
+        TrainerConfig(batch_size=8, seq_len=129, loss_chunk_size=64),
+        mesh_cfg,
+    )
+    text = trainer.lower_step(lowering_platforms=("tpu",)).as_text()
+    # Forward, dq and dkv kernels, each a Mosaic custom call.
+    assert text.count("tpu_custom_call") >= 3
 
 
 def test_data_wait_is_measured(devices8):

@@ -39,7 +39,8 @@ def test_train_llama_main_env_config(capsys, monkeypatch):
     ]
     metrics = [m for m in lines if "loss" in m]
     assert len(metrics) == 3
-    assert {"loss", "tokens_per_sec_per_chip", "mfu"} <= metrics[0].keys()
+    assert {"loss", "tokens_per_sec_per_chip"} <= metrics[0].keys()
+    assert "mfu" not in metrics[0]  # a CPU run has no peak to divide by
     # Cold-start→first-step (BASELINE.md metric 2) precedes the metrics.
     cold = [m for m in lines if "cold_start_to_first_step_s" in m]
     assert len(cold) == 1
@@ -283,7 +284,7 @@ def test_embed_workload_main(capsys, monkeypatch, tmp_path):
         json.loads(line) for line in out.splitlines()
         if line.startswith("{") and "loss" in line
     ]
-    assert metrics and "mfu" in metrics[0]
+    assert metrics and "step_time_s" in metrics[0]
 
 
 def test_embed_workload_requires_data(monkeypatch):

@@ -2,9 +2,9 @@
 
 Two scopes, one rule: code that runs under ``jax.jit``/``shard_map``
 tracing must never touch the host (``.item()``, ``np.asarray``,
-``jax.device_get``, ``block_until_ready``, I/O) — on 0.4.x some of
-these are trace-time errors, others silently insert a device->host
-round trip per step; and the *host-side step loop* (any function
+``jax.device_get``, ``block_until_ready``, I/O) — some of these are
+trace-time errors, others silently insert a device->host round trip
+per step; and the *host-side step loop* (any function
 driving batches through a compiled step via ``timed_batches``) must
 keep its per-step path free of the same sync primitives, because one
 stray ``.item()`` serializes the async dispatch pipeline and the MFU

@@ -135,15 +135,8 @@ def initialize_cluster(
     config = config or resolve_cluster_env()
     if not config.is_distributed:
         return config
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:  # jax >= 0.5
-        if is_init():
-            return config
-    else:  # jax 0.4.x: the client handle is the only initialized signal
-        from jax._src import distributed as _dist
-
-        if getattr(_dist.global_state, "client", None) is not None:
-            return config
+    if jax.distributed.is_initialized():
+        return config
     if config.process_id >= config.num_processes or config.process_id < 0:
         raise ValueError(
             f"process_id {config.process_id} out of range for "

@@ -134,10 +134,14 @@ def paged_pool_cache(model, params, n_slots: int):
     return {"cache": tree}
 
 
-def _row_zeros_tree(row_model, params):
+def _row_zeros_tree(row_model, params, home):
     """Zeroed B=1 CONTIGUOUS row cache for ``row_model`` (the paged
     model's contiguous twin) — the shape ``prefill_row`` hands back,
-    used as the canvas ``prefill_shared`` gathers shared pages into."""
+    used as the canvas ``prefill_shared`` gathers shared pages into.
+    Born committed at ``home`` like the pool's own state
+    (SlotPool.__post_init__): the chunk programs see this canvas first
+    and their own donated output after, and the two must be the same
+    argument to jit."""
 
     def init(p):
         toks = jnp.zeros((1, 1), jnp.int32)
@@ -154,7 +158,7 @@ def _row_zeros_tree(row_model, params):
     # path alignment against the pool tree lines up leaf-for-leaf.
     return {
         "cache": jax.tree_util.tree_map(
-            lambda l: jnp.zeros(l.shape, l.dtype), shapes
+            lambda l: jnp.zeros(l.shape, l.dtype, device=home), shapes
         )
     }
 
@@ -1029,7 +1033,7 @@ class PagedSlotPool(SlotPool):
         leaves (their memory becomes the attached cache), so a cached
         tree would hand already-deleted buffers to the second prefix
         hit. The zeros alloc is trivia next to the prefill."""
-        row_tree = _row_zeros_tree(self.row_model, self.params)
+        row_tree = _row_zeros_tree(self.row_model, self.params, self.home)
         if not len(shared_ids):
             return row_tree
         paths, names, leaves, _ = self._pool_flat()

@@ -36,6 +36,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from tpufw.infer.generate import _model_apply, _stream_prefill
 from tpufw.infer.sampling import SamplingConfig, sample_token
@@ -254,6 +255,20 @@ class SlotPool:
     done: jax.Array
     remaining: jax.Array
     seen: Any
+
+    def __post_init__(self):
+        # Pool state is born committed, where the weights live. To jit a
+        # fresh uncommitted array and the committed output of an earlier
+        # call are different arguments: left as created, every pool
+        # program compiles a second time on its second call — after
+        # warm-up, in the first live request (seen on the compile
+        # counter, PR 21; TRACE_COUNTS cannot see it, nothing retraces).
+        home = jax.tree_util.tree_leaves(self.params)[0].sharding
+        if isinstance(home, NamedSharding):
+            home = NamedSharding(home.mesh, PartitionSpec())
+        self.home = home
+        for name in ("cache", "token", "pos", "done", "remaining", "seen"):
+            setattr(self, name, jax.device_put(getattr(self, name), home))
 
     @classmethod
     def create(

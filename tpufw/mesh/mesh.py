@@ -125,8 +125,8 @@ def build_mesh(
     """Build the named device mesh for a MeshConfig.
 
     Uses ``mesh_utils.create_device_mesh`` when the devices are real TPUs so
-    the physical ICI topology is respected; falls back to a plain reshape for
-    CPU/virtual meshes (tests, dryrun_multichip).
+    the physical ICI topology is respected; CPU/virtual meshes (tests,
+    dryrun_multichip) have no topology and are a plain reshape.
     """
     config = config or MeshConfig()
     if devices is None:
@@ -159,10 +159,9 @@ def build_mesh(
     sizes = config.sizes(len(devices))
     shape = tuple(sizes[a] for a in MESH_AXES)
     if devices[0].platform == "tpu":
-        try:
-            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except (ValueError, NotImplementedError):
-            dev_array = np.array(devices).reshape(shape)
+        # A shape the ICI topology cannot serve raises here — a plain
+        # reshape would run, with collectives on links that do not exist.
+        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     else:
         dev_array = np.array(devices).reshape(shape)
     return Mesh(dev_array, MESH_AXES)

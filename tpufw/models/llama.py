@@ -227,7 +227,10 @@ LLAMA_CONFIGS: dict[str, LlamaConfig] = {
     "llama3_8b": LlamaConfig(attention_backend="flash"),
     # Llama-3.1-8B: same shape as 3.0, llama3 rope transform (Meta's
     # published scaling params are RopeScaling's defaults), 128k
-    # context window.
+    # context window. The flash kernel holds whole-sequence slabs in
+    # VMEM and stops compiling between 8k and 16k positions per shard
+    # (tpufw.ops.flash._check_slabs_fit): training at the full window
+    # needs the sequence sharded (attention_backend "ring"/"ulysses").
     "llama31_8b": LlamaConfig(
         max_seq_len=131_072,
         rope_scaling=RopeScaling(),

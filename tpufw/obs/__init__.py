@@ -375,11 +375,11 @@ def _emit_compile_cache_event(events) -> None:
         return
     if not cache_dir:
         return
-    try:
-        warm = bool(os.listdir(cache_dir))
-    except OSError:
-        warm = False
-    events.emit("compile_cache", dir=cache_dir, warm=warm)
+    from tpufw.utils.profiling import compile_cache_is_warm
+
+    events.emit(
+        "compile_cache", dir=cache_dir, warm=compile_cache_is_warm(cache_dir)
+    )
 
 
 # Shared disabled singleton: null events/tracer, no registry. close()

@@ -40,16 +40,13 @@ PROGRAMS_FILENAME = "programs.json"
 
 
 def _cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to one flat dict.
-    Older jax returns a one-element list of dicts, newer a dict;
-    both may be empty on backends without an HLO cost model."""
+    """``Compiled.cost_analysis()`` as a plain dict — empty on
+    backends without an HLO cost model."""
     try:
         ca = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 — unimplemented on some backends
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if isinstance(ca, dict) else {}
+    return dict(ca or {})
 
 
 def _memory_dict(compiled) -> dict:
@@ -275,7 +272,11 @@ class PerfObservatory:
                 for p in self._programs.values()
                 if p.get("peak_hbm_bytes")
             ]
-        if not peaks_seen or self._registry is None:
+        if (
+            not peaks_seen
+            or self._registry is None
+            or not self.peaks.hbm_bytes
+        ):
             return
         self._registry.gauge(
             "tpufw_hbm_headroom_bytes",
@@ -295,10 +296,12 @@ class PerfObservatory:
             entry = self._programs.get(name)
             if entry is None or not entry.get("flops"):
                 return None
-            mfu = entry["flops"] / (wall_s * self.peaks.flops_per_s)
             entry["wall_s"] = wall_s
-            entry["mfu"] = mfu
             entry["calls"] = entry.get("calls", 0) + 1
+            if not self.peaks.flops_per_s:
+                return None  # no peak for this device (CPU): no MFU
+            mfu = entry["flops"] / (wall_s * self.peaks.flops_per_s)
+            entry["mfu"] = mfu
         if self._registry is not None:
             self._registry.gauge(
                 "tpufw_program_mfu",
@@ -336,7 +339,7 @@ class PerfObservatory:
             for q in self.snapshot().values()
             if q.get("peak_hbm_bytes")
         ]
-        if hbm_peaks:
+        if hbm_peaks and self.peaks.hbm_bytes:
             out["hbm_headroom_bytes"] = int(
                 self.peaks.hbm_bytes - max(hbm_peaks)
             )

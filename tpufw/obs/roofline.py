@@ -7,9 +7,8 @@ puts it on one side of the machine balance point
 peak FLOPs no matter how good the schedule (memory-bound), above it
 the HBM is not the wall (compute-bound). The peaks come from the
 per-generation chip table (tpufw.utils.hardware) with env overrides
-— ``TPUFW_PEAK_FLOPS`` / ``TPUFW_PEAK_HBM_BW`` — for hardware the
-table does not know or for what-if analysis against a different
-roofline (docs/PERF.md).
+— ``TPUFW_PEAK_FLOPS`` / ``TPUFW_PEAK_HBM_BW`` — for what-if
+analysis against a different roofline (docs/PERF.md).
 
 Kept jax-free: the one jax call (device-kind detection) is behind
 ``detect_peaks(device=...)``'s default and callers (tests,
@@ -58,15 +57,15 @@ def peaks_from_spec(spec: ChipSpec) -> PeakSpec:
 
 
 def detect_peaks(device=None) -> PeakSpec:
-    """Peaks for the running backend's chip (default device). Falls
-    back to the CPU table row when no backend is reachable, so the
-    observatory never crashes a run over a roofline lookup."""
-    try:
-        spec = detect_chip(device)
-    except Exception:  # noqa: BLE001 — uninitialized backend etc.
-        from tpufw.utils.hardware import CHIP_SPECS
-
-        spec = CHIP_SPECS["cpu"]
+    """Peaks for the running backend's chip (default device). A CPU
+    device has no table row: every peak is 0 = unknown, and consumers
+    leave MFU, bound and headroom out instead of inventing them. An
+    accelerator the table does not know raises (tpufw.utils.hardware)."""
+    spec = detect_chip(device)
+    if spec is None:
+        return PeakSpec(
+            chip="cpu", flops_per_s=0.0, hbm_bw_bytes_per_s=0.0, hbm_bytes=0
+        )
     return peaks_from_spec(spec)
 
 

@@ -18,11 +18,23 @@ dividing H (GQA: each kv head serves H//K query heads).
 
 from __future__ import annotations
 
+import functools
+import logging
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+
+@functools.lru_cache(maxsize=None)
+def announce_once(choice: str) -> None:
+    """Say once per process which implementation a platform-dependent
+    default resolved to (Mosaic or the Pallas interpreter; the flash
+    ring or its einsum reference), so a run's output shows whether the
+    compiled side or the test side ran."""
+    logging.getLogger("tpufw.attention").warning(choice)
 
 
 def tanh_soft_cap(x: jax.Array, cap: float) -> jax.Array:
@@ -113,6 +125,55 @@ def xla_attention(
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
+def _flash_over_mesh(q, k, v, *, segment_ids, **kwargs) -> jax.Array:
+    """The Pallas flash kernel, placed where Mosaic can compile it.
+
+    Mosaic kernels cannot be partitioned by GSPMD: under a jit whose
+    computation spans more than one device the call must sit inside a
+    ``shard_map`` with EVERY mesh axis manual. The mesh is the one the
+    trainer registers (``tpufw.parallel.context.use_mesh``) and the
+    layout is the one ring/ulysses use — batch over (data, fsdp), heads
+    over tensor; attention is independent per batch row and per
+    kv-head group, so the per-shard kernel needs no collective. Axes
+    the spec does not name (sequence, expert, pipe) see replicated
+    operands. Without a registered mesh, on a one-device mesh, or when
+    the caller is already inside a manual region (pipeline stages,
+    ulysses), the kernel is called as is.
+    """
+    from tpufw.mesh.mesh import AXIS_DATA, AXIS_FSDP, AXIS_TENSOR
+    from tpufw.ops.flash import flash_attention
+    from tpufw.parallel.context import current_mesh
+
+    kernel = functools.partial(flash_attention, **kwargs)
+    mesh = current_mesh()
+    if (
+        mesh is None
+        or mesh.size == 1
+        or jax.sharding.get_abstract_mesh().manual_axes
+    ):
+        return kernel(q, k, v, segment_ids=segment_ids)
+    dp = mesh.shape[AXIS_DATA] * mesh.shape[AXIS_FSDP]
+    tp = mesh.shape[AXIS_TENSOR]
+    if q.shape[0] % dp or q.shape[2] % tp or k.shape[2] % tp:
+        raise ValueError(
+            f"flash attention over mesh {dict(mesh.shape)}: batch "
+            f"{q.shape[0]} must divide over data x fsdp = {dp}, and q/kv "
+            f"heads {q.shape[2]}/{k.shape[2]} over tensor = {tp}"
+        )
+    spec = P((AXIS_DATA, AXIS_FSDP), None, AXIS_TENSOR, None)
+    seg = () if segment_ids is None else (segment_ids,)
+    return jax.shard_map(
+        lambda q, k, v, *seg: kernel(
+            q, k, v, segment_ids=seg[0] if seg else None
+        ),
+        mesh=mesh,
+        in_specs=(spec, spec, spec)
+        + (P((AXIS_DATA, AXIS_FSDP), None),) * len(seg),
+        out_specs=spec,
+        check_vma=False,
+    )(q, k, v, *seg)
+
+
 def multi_head_attention(
     q: jax.Array,
     k: jax.Array,
@@ -145,9 +206,7 @@ def multi_head_attention(
             f"backend='xla', got {backend!r}"
         )
     if backend == "flash":
-        from tpufw.ops.flash import flash_attention
-
-        return flash_attention(
+        return _flash_over_mesh(
             q, k, v, causal=causal, segment_ids=segment_ids,
             logits_soft_cap=logits_soft_cap,
             sliding_window=sliding_window,

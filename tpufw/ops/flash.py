@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpufw.ops.attention import tanh_soft_cap
+from tpufw.ops.attention import announce_once, tanh_soft_cap
 
 NEG_INF = -1e30
 
@@ -66,6 +66,32 @@ def _seg_mask(qseg_block: jax.Array, kseg_row: jax.Array) -> jax.Array:
     """[bq, LANES] lanes-broadcast q ids x [1, bkv] kv ids -> [bq, bkv]."""
     bkv = kseg_row.shape[-1]
     return jnp.tile(qseg_block, (1, bkv // _LANES)) == kseg_row
+
+
+# Scoped VMEM one kernel may take by default on a v5e. The forward and dq
+# kernels keep the whole K and V sequence resident per grid step, the dkv
+# kernel the whole Q and dO, and the pipeline double-buffers each:
+# 4 * S * D * itemsize bytes. Measured on the chip (PR 21,
+# scripts/flash_chip_check.py, bf16, D=128): S=8192 (8 MiB) compiles and
+# runs, forward and backward, with and without segment ids; S=16384 asks
+# for 16.04 MiB and Mosaic refuses it.
+_VMEM_SCOPED_BYTES = 16 * 2**20
+
+
+def _check_slabs_fit(n_pad: int, d: int, dtype) -> None:
+    """Refuse at trace time a sequence whose resident slabs Mosaic would
+    refuse at compile time, with a message that says what to do."""
+    need = 4 * n_pad * d * jnp.dtype(dtype).itemsize
+    if need >= _VMEM_SCOPED_BYTES:
+        raise ValueError(
+            f"flash attention keeps whole-sequence K/V (Q/dO in backward) "
+            f"slabs in VMEM: {n_pad} positions x head_dim {d} need "
+            f"{need / 2**20:.0f} MiB double-buffered, the kernel may take "
+            f"{_VMEM_SCOPED_BYTES / 2**20:.0f} MiB. Shard the sequence "
+            "(attention_backend='ring' or 'ulysses' over the `sequence` "
+            "mesh axis) so each shard is shorter; a kernel that streams "
+            "K/V blocks from HBM is not written yet."
+        )
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -125,8 +151,9 @@ def _fwd_kernel(
 
     def body(j, carry):
         m_prev, l_prev, acc = carry
-        k = k_ref[0, 0, pl.ds(j * bkv, bkv), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * bkv, bkv), :].astype(jnp.float32)
+        start = pl.multiple_of(j * bkv, bkv)
+        k = k_ref[0, 0, pl.ds(start, bkv), :].astype(jnp.float32)
+        v = v_ref[0, 0, pl.ds(start, bkv), :].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q,
             k,
@@ -145,7 +172,7 @@ def _fwd_kernel(
         if window is not None:
             mask = mask & _window_mask(i, j, bq, bkv, offset, window)
         if has_seg:
-            kseg = kseg_ref[0, :1, pl.ds(j * bkv, bkv)]  # [1, bkv]
+            kseg = kseg_ref[0, :1, pl.ds(start, bkv)]  # [1, bkv]
             mask = mask & _seg_mask(qseg, kseg)
         logits = jnp.where(mask, logits, NEG_INF)
         m_cur = jnp.max(logits, axis=1, keepdims=True)
@@ -196,8 +223,9 @@ def _dq_kernel(
     n_kv = k_ref.shape[2] // bkv
 
     def body(j, dq):
-        k = k_ref[0, 0, pl.ds(j * bkv, bkv), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * bkv, bkv), :].astype(jnp.float32)
+        start = pl.multiple_of(j * bkv, bkv)
+        k = k_ref[0, 0, pl.ds(start, bkv), :].astype(jnp.float32)
+        v = v_ref[0, 0, pl.ds(start, bkv), :].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -209,7 +237,7 @@ def _dq_kernel(
         if window is not None:
             mask = mask & _window_mask(i, j, bq, bkv, offset, window)
         if has_seg:
-            kseg = kseg_ref[0, :1, pl.ds(j * bkv, bkv)]
+            kseg = kseg_ref[0, :1, pl.ds(start, bkv)]
             mask = mask & _seg_mask(qseg, kseg)
         if soft_cap is not None:
             capped = tanh_soft_cap(logits, soft_cap)
@@ -258,10 +286,11 @@ def _dkv_kernel(
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, 0, pl.ds(i * bq, bq), :].astype(jnp.float32) * scale
-        do = do_ref[0, 0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0, pl.ds(i * bq, bq)][:, None]
-        delta = delta_ref[0, 0, 0, pl.ds(i * bq, bq)][:, None]
+        start = pl.multiple_of(i * bq, bq)
+        q = q_ref[0, 0, pl.ds(start, bq), :].astype(jnp.float32) * scale
+        do = do_ref[0, 0, pl.ds(start, bq), :].astype(jnp.float32)
+        lse = lse_ref[0, 0, 0, pl.ds(start, bq)][:, None]
+        delta = delta_ref[0, 0, 0, pl.ds(start, bq)][:, None]
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -273,7 +302,7 @@ def _dkv_kernel(
         if window is not None:
             mask = mask & _window_mask(i, j, bq, bkv, offset, window)
         if has_seg:
-            qseg = qseg_ref[0, pl.ds(i * bq, bq), :]  # [bq, LANES]
+            qseg = qseg_ref[0, pl.ds(start, bq), :]  # [bq, LANES]
             mask = mask & _seg_mask(qseg, kseg)
         if soft_cap is not None:
             capped = tanh_soft_cap(logits, soft_cap)
@@ -419,6 +448,8 @@ def _flash_fwd_impl(
     kh_ = _pad_to(kh_, 2, t_pad_mult)
     vh = _pad_to(vh, 2, t_pad_mult)
     t_p, s_p = qh.shape[2], kh_.shape[2]
+    if not interpret:
+        _check_slabs_fit(max(t_p, s_p), d, q.dtype)
     bq, bkv = _block_sizes(t_p, s_p, block_sizes)
 
     grid = (b, h, t_p // bq)
@@ -643,6 +674,16 @@ def _flash_bwd_rule(
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def default_interpret(platform: str) -> bool:
+    """Pallas interpreter on CPU (tests, dryruns), Mosaic anywhere else."""
+    interpret = platform == "cpu"
+    announce_once(
+        f"pallas flash kernels on platform={platform}: "
+        + ("INTERPRETED (not Mosaic)" if interpret else "compiled by Mosaic")
+    )
+    return interpret
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -692,7 +733,7 @@ def flash_attention(
             f"(self-attention); got T={q.shape[1]}, S={k.shape[1]}"
         )
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = default_interpret(jax.devices()[0].platform)
     cap = None if logits_soft_cap is None else float(logits_soft_cap)
     win = None if sliding_window is None else int(sliding_window)
     blocks = None if block_sizes is None else tuple(block_sizes)

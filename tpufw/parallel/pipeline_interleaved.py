@@ -64,7 +64,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from tpufw.parallel.compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpufw.mesh import AXIS_DATA, AXIS_FSDP, AXIS_PIPE, AXIS_TENSOR

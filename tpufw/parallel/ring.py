@@ -20,11 +20,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from tpufw.parallel.compat import shard_map
 
 from tpufw.mesh.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQUENCE, AXIS_TENSOR
-from tpufw.ops.attention import _repeat_kv, tanh_soft_cap
+from tpufw.ops.attention import _repeat_kv, announce_once, tanh_soft_cap
 from tpufw.parallel.context import current_mesh
 
 NEG_INF = -1e30
@@ -190,8 +190,12 @@ def ring_attention(
             f"sliding_window must be >= 1, got {sliding_window}"
         )
     if impl is None:
-        on_tpu = mesh.devices.flatten()[0].platform == "tpu"
-        impl = "flash" if (causal and on_tpu) else "einsum"
+        platform = mesh.devices.flatten()[0].platform
+        impl = "flash" if (causal and platform == "tpu") else "einsum"
+        announce_once(
+            f"ring attention on platform={platform}, causal={causal}: "
+            f"impl={impl!r}"
+        )
     if impl == "flash":
         # sliding_window runs in-kernel: the per-step chunk distance is
         # static on the unrolled ring, so window masks see global

@@ -37,8 +37,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from tpufw.parallel.compat import shard_map
 
 from tpufw.mesh.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQUENCE, AXIS_TENSOR
 from tpufw.ops import flash as F
@@ -281,7 +281,7 @@ def ring_flash_attention(
         )
     n = mesh.shape[axis_name]
     if interpret is None:
-        interpret = mesh.devices.flatten()[0].platform == "cpu"
+        interpret = F.default_interpret(mesh.devices.flatten()[0].platform)
     has_seg = segment_ids is not None
     cap = None if logits_soft_cap is None else float(logits_soft_cap)
     win = None if sliding_window is None else int(sliding_window)

@@ -30,11 +30,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from tpufw.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpufw.mesh.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQUENCE, AXIS_TENSOR
-from tpufw.ops.attention import _repeat_kv, multi_head_attention
+from tpufw.ops.attention import (
+    _repeat_kv,
+    announce_once,
+    multi_head_attention,
+)
 from tpufw.parallel.context import current_mesh
 
 
@@ -120,8 +124,12 @@ def ulysses_attention(
             f"!= S={k.shape[1]}"
         )
     if backend is None:
-        on_tpu = mesh.devices.flatten()[0].platform == "tpu"
-        backend = "flash" if (causal and on_tpu) else "xla"
+        platform = mesh.devices.flatten()[0].platform
+        backend = "flash" if (causal and platform == "tpu") else "xla"
+        announce_once(
+            f"ulysses attention on platform={platform}, causal={causal}: "
+            f"local backend={backend!r}"
+        )
     if backend not in ("xla", "flash"):
         raise ValueError(
             f"ulysses local backend must be 'xla' or 'flash', "

@@ -1525,6 +1525,9 @@ def main_role(role: str) -> int:
         from tpufw.serve.router import main_router
 
         return main_router()
+    from tpufw.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     engine, restored = _build_engine(role)
     port = env_int("serve_peer_port", DEFAULT_PEER_PORT)
     if role == "prefill":

@@ -278,7 +278,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--chip", default="v5e",
         help="chip spec to compare against (static table; 'auto' "
-        "queries the live backend, which can block on a wedged one)",
+        "asks the live backend, which must be an accelerator)",
     )
     args = ap.parse_args(argv)
     if args.model not in presets:
@@ -315,6 +315,8 @@ def main(argv=None) -> int:
     chip = (
         detect_chip() if args.chip == "auto" else CHIP_SPECS[args.chip]
     )
+    if chip is None:
+        ap.error("--chip auto found a CPU backend; name a chip instead")
     out = {
         "model": args.model,
         "mode": "decode" if args.decode else "train",

@@ -476,18 +476,7 @@ class PipelineTrainer:
                     py_step, loss,
                     data_wait_s=window_wait, n_steps=window_n,
                 )
-                tel.events.emit(
-                    "step",
-                    step=sm.step,
-                    loss=round(sm.loss, 6),
-                    step_time_s=round(sm.step_time_s, 6),
-                    data_wait_s=round(sm.data_wait_s, 6),
-                    mfu=round(sm.mfu, 5),
-                    tokens_per_sec_per_chip=round(
-                        sm.tokens_per_sec_per_chip, 1
-                    ),
-                    window_steps=sm.window_steps,
-                )
+                tel.events.emit("step", **sm.event_fields())
                 if tel.skew is not None:
                     tel.skew.record(
                         sm.step,

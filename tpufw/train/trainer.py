@@ -434,8 +434,8 @@ class TrainerConfig:
     preemption_sync_every: int = 1
     # Steps between host syncs of the loss (block_until_ready). 1 = the
     # classic per-step sync. >1 dispatches a WINDOW of steps and syncs
-    # once: on a remote/tunneled backend every sync costs a host<->device
-    # round trip, which serializes against short steps. The loop always
+    # once: every sync costs a host<->device round trip, which
+    # serializes against short steps. The loop always
     # syncs after the first step (compile boundary / first-step latency)
     # and the last; metrics entries then carry window averages
     # (StepMetrics.window_steps), and checkpoint saves, in-loop eval,
@@ -758,6 +758,26 @@ class Trainer:
             )
         return self._compiled[key]
 
+    def lower_step(self, lowering_platforms=None):
+        """Trace and lower the train step on an abstract state and
+        batch: nothing is allocated, compiled or run. For looking at
+        what the step lowers to — that the flash kernel is a Mosaic
+        custom call, that it lowers over a multi-device mesh at all
+        (``lowering_platforms=("tpu",)`` reaches the TPU rules from a
+        CPU host)."""
+        _, boxed = self._abstract_state(jax.random.key(0))
+        self.state_sharding = meta.unbox(state_shardings(boxed, self.mesh))
+        batch = {
+            "tokens": jax.ShapeDtypeStruct(
+                (self.cfg.batch_size, self.cfg.seq_len), jnp.int32
+            )
+        }
+        with use_mesh(self.mesh):
+            traced = self.compiled_step(batch).trace(
+                meta.unbox(boxed), batch
+            )
+            return traced.lower(lowering_platforms=lowering_platforms)
+
     def compiled_eval_step(self, batch: dict):
         """Jitted forward-only step (no donation: state survives)."""
         key = ("eval", *sorted(batch.keys()))
@@ -915,18 +935,7 @@ class Trainer:
                     py_step, loss,
                     data_wait_s=window_wait, n_steps=window_n,
                 )
-                tel.events.emit(
-                    "step",
-                    step=sm.step,
-                    loss=round(sm.loss, 6),
-                    step_time_s=round(sm.step_time_s, 6),
-                    data_wait_s=round(sm.data_wait_s, 6),
-                    mfu=round(sm.mfu, 5),
-                    tokens_per_sec_per_chip=round(
-                        sm.tokens_per_sec_per_chip, 1
-                    ),
-                    window_steps=sm.window_steps,
-                )
+                tel.events.emit("step", **sm.event_fields())
                 if tel.skew is not None:
                     tel.skew.record(
                         sm.step,
@@ -966,8 +975,7 @@ class Trainer:
                             window_wait += wait
                             # state.step advances by exactly 1 per
                             # step_fn: tracking it host-side avoids a
-                            # device fetch (= a round trip on tunneled
-                            # backends) per step.
+                            # device fetch per step.
                             py_step = start_step + i + 1
                             # Sync at step 1 (compile boundary), then
                             # at steps that are MULTIPLES of sync_every

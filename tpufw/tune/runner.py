@@ -539,8 +539,7 @@ def apply_autotune(
     from tpufw.utils.hardware import detect_chip
 
     on_tpu = jax.devices()[0].platform == "tpu"
-    # HBM pruning only means something against a real chip's HBM; the
-    # CPU table entry is a placeholder and would mis-prune.
+    # HBM pruning only means something against a real chip's HBM.
     hbm = detect_chip().hbm_bytes if on_tpu else None
     mcfg = _trainer_model_cfg(trainer)
     dp = trainer.mesh.shape["data"] * trainer.mesh.shape["fsdp"]

@@ -2,5 +2,4 @@ from tpufw.utils.hardware import (  # noqa: F401
     ChipSpec,
     CHIP_SPECS,
     detect_chip,
-    peak_flops_per_chip,
 )

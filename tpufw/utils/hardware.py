@@ -55,41 +55,39 @@ CHIP_SPECS: dict[str, ChipSpec] = {
         "v6e", 918e12, 32 * 2**30,
         hbm_bw_bytes_per_s=1.64e12, chips_per_host=8,
     ),
-    # CPU fallback so MFU accounting degrades gracefully in tests / dryruns.
-    # ~100 GFLOP/s and ~50 GB/s are nominal single-socket figures; tests
-    # never assert on them.
-    "cpu": ChipSpec(
-        "cpu", 100e9, 16 * 2**30,
-        ici_links=0, hbm_bw_bytes_per_s=5e10, chips_per_host=1,
-    ),
 }
 
+# ``device.device_kind`` patterns. A v5e chip reports "TPU v5 lite"
+# (what the chip tool's machine printed, PR 21).
 _KIND_PATTERNS: list[tuple[str, str]] = [
     (r"v6e|v6 ?lite|trillium", "v6e"),
     (r"v5p", "v5p"),
     (r"v5 ?lite|v5e|v5litepod", "v5e"),
     (r"v4", "v4"),
-    (r"cpu", "cpu"),
 ]
 
 
-def detect_chip(device=None) -> ChipSpec:
+def detect_chip(device=None) -> ChipSpec | None:
     """Map a jax device (default: ``jax.devices()[0]``) to its ChipSpec.
 
     Works off ``device.device_kind`` strings like "TPU v5 lite" / "TPU v5e".
-    Unknown accelerators fall back to v5e (the BASELINE target hardware)
-    rather than raising — benchmarks should run, and report, not crash.
+    A CPU device has no row — None, so MFU and roofline figures are absent
+    from a CPU run instead of invented. An accelerator the table does not
+    know is an error: a peak borrowed from another chip makes every
+    utilization figure wrong without saying so.
     """
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "cpu").lower()
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind.lower()
     for pattern, name in _KIND_PATTERNS:
         if re.search(pattern, kind):
             return CHIP_SPECS[name]
-    return CHIP_SPECS["v5e"]
-
-
-def peak_flops_per_chip(device=None) -> float:
-    return detect_chip(device).peak_bf16_flops
+    raise ValueError(
+        f"unknown accelerator device_kind {device.device_kind!r} "
+        f"(platform {device.platform!r}): add its peaks to "
+        "tpufw.utils.hardware.CHIP_SPECS"
+    )

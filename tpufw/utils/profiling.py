@@ -19,23 +19,25 @@ profiler to port. The TPU-native build gets two real mechanisms:
 from __future__ import annotations
 
 import os
+import pathlib
 from typing import Optional
 
 import jax
 
-# Env var consumed by workload entry points (set in deploy/ manifests).
-COMPILE_CACHE_ENV = "TPUFW_COMPILE_CACHE_DIR"
+# Where the cache lives when JAX_COMPILATION_CACHE_DIR does not say: one
+# fixed directory in the checkout. The path is part of what a later
+# process must reproduce to hit, so it never carries a pid, a time or a
+# temp name.
+_DEFAULT_COMPILE_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[2] / ".xla-cache"
+)
 
 
 def machine_fingerprint() -> str:
-    """Short stable id of this host's CPU architecture + feature flags.
-
-    XLA CPU executables are compiled for the build host's exact feature
-    set; reusing a cache dir across heterogeneous machines can SIGILL
-    (observed as a warning spray in BENCH_r02). Keying cache dirs by
-    this fingerprint gives each machine class its own namespace while
-    identical pods still share.
-    """
+    """Short stable id of this host's CPU architecture + feature flags —
+    the machine half of the autotuner's winner-cache key
+    (tpufw.tune.cache): a tuned config is only valid on the machine
+    class that measured it."""
     import hashlib
     import platform
 
@@ -53,38 +55,41 @@ def machine_fingerprint() -> str:
     return hashlib.sha256(" ".join(bits).encode()).hexdigest()[:10]
 
 
-def enable_compile_cache(
-    path: Optional[str] = None, per_machine: bool = True
-) -> Optional[str]:
-    """Turn on XLA's persistent compilation cache at ``path``.
+def enable_compile_cache() -> str:
+    """Turn on XLA's persistent compilation cache and return its directory.
 
-    ``path`` defaults to ``$TPUFW_COMPILE_CACHE_DIR``; no-op (returning
-    None) when neither is set, so workloads can call this unconditionally.
-    With ``per_machine`` (default) the cache lives in a
-    ``machine_fingerprint()`` subdir, so a dir shared across machine
-    types (PV, checked-in cache) cannot serve an executable compiled
-    for another host's CPU features.
+    The directory is placed from outside: where ``JAX_COMPILATION_CACHE_DIR``
+    is set jax already holds it and this function sets none (a PV mount
+    in the deploy manifests, the chip machine's own cache). Otherwise the
+    cache goes to ``<checkout>/.xla-cache``. Every workload entry point
+    calls this before its first compile; it is the one place the cache
+    is configured.
     """
-    path = path or os.environ.get(COMPILE_CACHE_ENV)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
-        return None
-    if per_machine:
-        path = os.path.join(path, machine_fingerprint())
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+        path = _DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+        # jax binds the persistent cache to the first directory it
+        # initializes with; a compile before this call would have bound
+        # "none", and re-pointing the config alone would not rebind it.
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc,
+        )
+
+        _cc.reset_cache()
     # Cache everything: tiny compiles are still worth skipping on restart.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax binds the persistent cache to the FIRST dir it initializes
-    # with; re-pointing the config alone would silently keep writing to
-    # the old dir. Reset unconditionally — re-init is lazy and cheap,
-    # and conditional resets invite stale-binding bugs.
-    from jax.experimental.compilation_cache import (
-        compilation_cache as _cc,
-    )
-
-    _cc.reset_cache()
     return path
+
+
+def compile_cache_is_warm(path: str) -> bool:
+    """True when ``path`` already holds cache entries: the bit that
+    decides most of a run's start-to-first-step time."""
+    try:
+        return bool(os.listdir(path))
+    except OSError:
+        return False
 
 
 class StepProfiler:

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import json
 import time
-from typing import Callable, Optional
+from typing import Callable
 
 from tpufw.train.metrics import StepMetrics
 
@@ -26,7 +26,7 @@ def check_global_batch(batch_size: int, n_processes: int) -> int:
 
 
 def metrics_printer(
-    t0: float, compile_cache: Optional[str]
+    t0: float, compile_cache: str
 ) -> Callable[[StepMetrics], None]:
     """on_metrics callback: first call emits the cold-start->first-step
     record (BASELINE.md metric 2), every call emits the step JSON line."""
@@ -41,7 +41,7 @@ def metrics_printer(
                         "cold_start_to_first_step_s": round(
                             first_step["t"] - t0, 1
                         ),
-                        "compile_cache": compile_cache or None,
+                        "compile_cache": compile_cache,
                     }
                 ),
                 flush=True,
@@ -116,6 +116,12 @@ def print_summary(history: list[StepMetrics]) -> None:
     last = history[-1]
     print(
         f"TRAIN OK: {len(history)} steps, final loss {last.loss:.4f}, "
-        f"{last.tokens_per_sec_per_chip:.0f} tok/s/chip, "
-        f"MFU {last.mfu:.1%}"
+        f"{last.tokens_per_sec_per_chip:.0f} tok/s/chip"
+        + mfu_suffix(last)
     )
+
+
+def mfu_suffix(m: StepMetrics) -> str:
+    """", MFU x%" for a summary line — empty where the device has no
+    peak (a CPU run), so no utilization is printed that was not measured."""
+    return "" if m.mfu is None else f", MFU {m.mfu:.1%}"

@@ -92,7 +92,7 @@ def main() -> int:
         f"mesh={dict(trainer.mesh.shape)} params={model_cfg.n_params():,}"
         f" pooling={trainer.contrastive.pooling}"
         f" causal={getattr(model_cfg, 'causal', True)}"
-        + (f" compile_cache={cache}" if cache else "")
+        f" compile_cache={cache}"
     )
 
     resumed = trainer.maybe_restore()

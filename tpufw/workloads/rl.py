@@ -161,7 +161,7 @@ def main() -> int:
     print(
         f"tpufw rl: devices={len(jax.devices())} "
         f"mesh={dict(trainer.mesh.shape)} params={model_cfg.n_params():,}"
-        + (f" compile_cache={cache}" if cache else "")
+        f" compile_cache={cache}"
     )
 
     init_from = env_str("init_from", "")
@@ -207,7 +207,7 @@ def main() -> int:
                     "cold_start_to_first_step_s": round(
                         first["t"] - _T0, 1
                     ),
-                    "compile_cache": cache or None,
+                    "compile_cache": cache,
                 }),
                 flush=True,
             )

@@ -42,15 +42,18 @@ from tpufw.workloads.env import env_bool, env_float, env_int, env_str
 _T0 = time.time()
 
 
-def _backend_name() -> str:
-    """jax backend for the run_info gauge; 'unknown' when jax is not
-    initialized enough to ask (run_info must never crash serving)."""
-    try:
-        import jax
+def _replica_mesh():
+    """One replica, one device. The server does not shard: its slot
+    pools, page arena and decode programs are single-device, so weights
+    must not come out FSDP-split over every chip the host shows (each
+    decode step would all-gather them). Further chips are for further
+    replicas (ROADMAP R5 widens one replica)."""
+    import jax
 
-        return str(jax.default_backend())
-    except Exception:  # noqa: BLE001
-        return "unknown"
+    from tpufw.mesh import MeshConfig, build_mesh
+
+    return build_mesh(MeshConfig(fsdp=1), devices=jax.local_devices()[:1])
+
 
 DEMO_PROMPTS = [[1, 42, 7, 99], [1, 5], [1, 1000, 2000, 3000, 17]]
 
@@ -62,7 +65,6 @@ def build_generator():
     import jax
 
     from tpufw.configs import bench_model_config
-    from tpufw.mesh import MeshConfig
     from tpufw.models import (
         DEEPSEEK_CONFIGS,
         Deepseek,
@@ -84,9 +86,8 @@ def build_generator():
         # FIRST and TPUFW_MODEL is genuinely ignored (stale manifest
         # values can't break it). Params load onto the default device in
         # the activation dtype (bf16 — serving keeps no fp32 master
-        # copy); for models larger than one chip, convert once via
-        # `python -m tpufw.tools.import_hf` and use the Orbax path,
-        # which restores sharded over the mesh.
+        # copy). A model larger than one chip cannot be served yet:
+        # one replica is one device (ROADMAP R5).
         from tpufw.models.gemma import GemmaConfig
         from tpufw.models.mixtral import MixtralConfig
         from tpufw.tools.import_hf import config_from_hf, from_hf
@@ -136,8 +137,7 @@ def build_generator():
     if params_dir:
         # Bare-params Orbax checkpoint (tpufw.tools.import_hf CLI
         # output) — TPUFW_MODEL still names the architecture. Restored
-        # SHARDED onto the mesh (no throwaway init materializes), so
-        # multi-chip models load split, not on device 0.
+        # straight onto the replica's device (no throwaway init).
         params = _restore_bare_params(model_cfg, params_dir)
         model_cfg, params = _maybe_quantize(model_cfg, params)
         model_cfg, params = _maybe_unroll(model_cfg, params)
@@ -154,7 +154,7 @@ def build_generator():
             total_steps=1,
             checkpoint_dir=env_str("checkpoint_dir", "") or None,
         ),
-        MeshConfig(),
+        mesh=_replica_mesh(),
     )
     restored = trainer.maybe_restore()
     if not restored:
@@ -385,10 +385,9 @@ def build_draft_generator(sampling):
 
 def _restore_bare_params(model_cfg, params_dir: str):
     """Bare-params Orbax restore via the trainer's abstract-tree helper
-    — sharded onto the mesh, no throwaway init. ONE copy for the target
-    (TPUFW_PARAMS_CHECKPOINT) and draft (TPUFW_DRAFT_PARAMS_CHECKPOINT)
-    paths."""
-    from tpufw.mesh import MeshConfig
+    — onto the replica's device, no throwaway init. ONE copy for the
+    target (TPUFW_PARAMS_CHECKPOINT) and draft
+    (TPUFW_DRAFT_PARAMS_CHECKPOINT) paths."""
     from tpufw.models import model_for_config
     from tpufw.train import Trainer, TrainerConfig
 
@@ -397,7 +396,7 @@ def _restore_bare_params(model_cfg, params_dir: str):
         TrainerConfig(
             batch_size=1, seq_len=min(32, model_cfg.max_seq_len)
         ),
-        MeshConfig(),
+        mesh=_replica_mesh(),
     )
     params, _ = shape_trainer.restore_params(params_dir)
     return params
@@ -2546,7 +2545,7 @@ class _Server:
                 trace_max_events=100_000,
             )
             self._tel.set_run_info(
-                backend=_backend_name(),
+                backend=jax.default_backend(),
                 model=type(self.model).__name__,
                 mesh="serve",
             )
@@ -2638,8 +2637,6 @@ class _Server:
         warmup is invisible to seed replay and metrics — safe because
         the listener is not up yet, so nothing can scrape or enqueue
         during the window. Disable entirely with TPUFW_WARMUP=0."""
-        import sys
-
         run_new = _pow2_ceil(self.default_new)
         if isinstance(self._batcher, _SlotScheduler):
             # Slot mode: the pool batch is ALWAYS n_slots, so there is
@@ -2649,10 +2646,11 @@ class _Server:
             # and leaves the default pool warm. The counters it moved
             # and the rng-stream indices are restored so warmup stays
             # invisible to scrapes and to seed replay.
+            # A warm-up that fails is the server's first real failure
+            # (on the chip, usually a program that does not compile):
+            # it propagates and the listener never binds.
             try:
                 self._batcher.submit([[1]], self.default_new, None)
-            except Exception as e:  # noqa: BLE001
-                print(f"serve: warmup skipped: {e}", file=sys.stderr)
             finally:
                 self._batcher.reset_after_warmup()
                 self.metrics.reset(
@@ -2699,8 +2697,6 @@ class _Server:
             return
         tick0 = self._tick_index
         try:
-            # Parse inside the try: a malformed env value must degrade
-            # to a warning, not keep the server from binding its port.
             # Buckets clamp to the batcher's row cap — a bigger program
             # would compile but never be hit by live coalescing.
             max_rows = env_int("batch_max_rows", 64)
@@ -2711,9 +2707,6 @@ class _Server:
             })
             for rows in buckets:
                 self._run_tick([[1]] * rows, run_new, None)
-        except Exception as e:  # noqa: BLE001
-            # Warmup is an optimization; never block serving on it.
-            print(f"serve: warmup skipped: {e}", file=sys.stderr)
         finally:
             self._tick_index = tick0
             if self._draft is not None:
@@ -3218,6 +3211,16 @@ class _Server:
         httpd = ThreadingHTTPServer(("0.0.0.0", self.port), Handler)
         self.port = httpd.server_address[1]  # resolve port 0 -> actual
         self.httpd = httpd
+        import jax
+
+        held = sorted(
+            {
+                d
+                for leaf in jax.tree_util.tree_leaves(self.params)
+                for d in leaf.devices()
+            },
+            key=lambda d: d.id,
+        )
         print(
             json.dumps(
                 {
@@ -3226,6 +3229,12 @@ class _Server:
                     "model_params": self.cfg.n_params(),
                     "restored_checkpoint": self.restored,
                     "startup_s": round(time.time() - _T0, 1),
+                    # One replica, one device (_replica_mesh): say which
+                    # one holds the weights and how many the host shows.
+                    "platform": held[0].platform,
+                    "device_kind": held[0].device_kind,
+                    "replica_devices": [str(d) for d in held],
+                    "devices_visible": len(jax.devices()),
                 }
             ),
             flush=True,

@@ -273,11 +273,14 @@ def main() -> int:
     from tpufw.train import synthetic_batches
 
     trainer, model_cfg = build_trainer()
+    dev = jax.devices()[0]
     print(
         f"tpufw train_llama: process {cluster.process_id}/"
-        f"{cluster.num_processes} devices={len(jax.devices())} "
-        f"mesh={dict(trainer.mesh.shape)} params={model_cfg.n_params():,}"
-        + (f" compile_cache={cache}" if cache else "")
+        f"{cluster.num_processes} platform={dev.platform} "
+        f"device_kind={dev.device_kind!r} devices={len(jax.devices())} "
+        f"mesh={dict(trainer.mesh.shape)} params={model_cfg.n_params():,} "
+        f"attention={getattr(model_cfg, 'attention_backend', None)} "
+        f"compile_cache={cache}"
     )
 
     from tpufw.train import DPOTrainer as _DPOT

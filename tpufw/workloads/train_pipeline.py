@@ -143,7 +143,7 @@ def main() -> int:
         f"microbatches={trainer.pipe.n_microbatches} "
         f"bubble={trainer.pipe.bubble_fraction():.1%} "
         f"params={model_cfg.n_params():,}"
-        + (f" compile_cache={cache}" if cache else "")
+        f" compile_cache={cache}"
     )
 
     resumed = trainer.maybe_restore()

@@ -64,7 +64,7 @@ def main() -> int:
         flops_per_image=flops,
         on_metrics=lambda m: print(json.dumps(m.as_dict()), flush=True),
     )
-    from tpufw.workloads._common import report_preemption
+    from tpufw.workloads._common import mfu_suffix, report_preemption
 
     report_preemption(trainer)
     if history:
@@ -72,7 +72,7 @@ def main() -> int:
         imgs_per_sec = last.tokens_per_sec_per_chip  # tokens == images
         print(
             f"TRAIN OK: {len(history)} steps, final loss {last.loss:.4f}, "
-            f"{imgs_per_sec:.1f} images/s/chip, MFU {last.mfu:.1%}"
+            f"{imgs_per_sec:.1f} images/s/chip" + mfu_suffix(last)
         )
     return 0
 

@@ -77,7 +77,7 @@ def main() -> int:
         flops_per_image=mcfg.flops_per_image(),
         on_metrics=lambda m: print(json.dumps(m.as_dict()), flush=True),
     )
-    from tpufw.workloads._common import report_preemption
+    from tpufw.workloads._common import mfu_suffix, report_preemption
 
     report_preemption(trainer)
     if history:
@@ -85,7 +85,7 @@ def main() -> int:
         print(
             f"TRAIN OK: {len(history)} windows, final loss "
             f"{last.loss:.4f}, {last.tokens_per_sec_per_chip:.1f} "
-            f"images/s/chip, MFU {last.mfu:.1%}"
+            f"images/s/chip" + mfu_suffix(last)
         )
     return 0
 
