@@ -1,0 +1,20 @@
+"""Kernels: the least time the chip could take for one decode step (the
+bytes it has to read — ``costs.decode_step_bytes`` at the rows and cache
+tokens live at mid-window — over the chip's published bandwidth) as a share
+of the decode step's measured device time. Memory-bound: a decode step of
+tens of rows is far below the compute roof."""
+
+from benchmarks import costs, harness
+from benchmarks.metrics import _steps
+
+
+def read(obs: dict):
+    if obs["trace"] is None:
+        return None
+    step_ms = _steps.decode_step_ms(obs["trace"])
+    if not step_ms:
+        return None
+    rows, tokens = _steps.live_rows_and_tokens(obs)
+    need = costs.decode_step_bytes(obs["family"], obs["config"], rows, tokens)
+    floor_ms = need / harness.peaks(obs["device"]["kind"])["hbm_bytes_per_s"] * 1e3
+    return 100.0 * floor_ms / step_ms
