@@ -22,6 +22,11 @@ Contracts, all on CPU with the tiny model:
   scheduler, including under concurrent submission.
 - NO HOL: a 1-page prompt submitted AFTER a 10-page prompt streams
   its first token before the long prompt finishes prefilling.
+- ON THE RECORD: the scheduler's leaf phases partition its thread's
+  time (self seconds, in the trace and on
+  ``tpufw_serve_phase_seconds_total`` alike), the row allocation is
+  recorded once per admission, and a request's ``req_queue`` /
+  ``req_prefill`` spans and ``serve_request`` event share its ``rid``.
 """
 
 import dataclasses
@@ -297,3 +302,119 @@ def test_long_prompt_no_hol(tiny_sched_model):
     (short_first, short_kind) = out["s"]
     assert long_kind == "done" and short_kind == "done"
     assert short_first < long_first
+
+
+# ------------------------------------- the scheduler's pass on the record
+
+def _wait_idle(tracer, timeout=30.0):
+    """Until the scheduler thread is back in ``serve_wait`` and nothing
+    else is open: every span of the work before it has been recorded."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        stacks = list(tracer.live_spans().values())
+        if [[n for n, _ in st] for st in stacks] == [["serve_wait"]]:
+            return
+        time.sleep(0.005)
+    raise AssertionError(f"scheduler never went idle: {tracer.live_spans()}")
+
+
+def test_scheduler_phases_and_request_chain(tiny_sched_model, tmp_path):
+    """Paged chunked prefill with the pass on the record: the leaf
+    phases partition the scheduler thread's time (their self seconds sum
+    to >= 95% of it, in the trace and on the counter alike), the row
+    allocation is recorded once per admission, the request-chain
+    histograms each take one observation per request, and one request's
+    ``req_queue`` / ``req_prefill`` spans and ``serve_request`` event
+    share its ``rid``."""
+    from tpufw.obs import events as events_mod
+    from tpufw.obs import trace as trace_mod
+    from tpufw.workloads import serve as serve_mod
+
+    model, params = tiny_sched_model
+    tracer = trace_mod.Tracer(str(tmp_path / "trace-serve.json"))
+    events = events_mod.EventLog(str(tmp_path / "events.jsonl"))
+    metrics = serve_mod._Metrics()
+    sched = serve_mod._SlotScheduler(
+        model, params, eos_id=None, default_sampling=GREEDY, seed_base=0,
+        page=PAGE, arena_pages=None, prefix_cache=True,
+        prefill_chunk_pages=1, metrics=metrics, events=events,
+        tracer=tracer,
+    )
+    phases = set(serve_mod.SCHED_PHASES)
+
+    def batch(first_tokens):
+        # 40 tokens = chunks of 16, 16 and a padded 8; distinct first
+        # tokens, so no prompt shares a page with another.
+        prompts = [[t, 5, 9, 2, 6] * 8 for t in first_tokens]
+        threads = [
+            threading.Thread(target=sched.submit, args=([p], 8))
+            for p in prompts
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+            assert not t.is_alive()
+        _wait_idle(tracer)
+
+    batch([1, 2, 3])  # builds the pool and every program
+    n0 = len(tracer._events)
+    batch([4, 5, 6])
+    warm = [e for e in tracer._events[n0:] if e["ph"] == "X"]
+    sched_evs = [e for e in warm if e["name"] in phases]
+
+    # The phases partition the thread's time over the warm batch.
+    lo = min(e["ts"] for e in sched_evs)
+    hi = max(e["ts"] + e["dur"] for e in sched_evs)
+    covered = sum(e["self_dur"] for e in sched_evs)
+    assert covered <= (hi - lo) * (1 + 1e-6)
+    assert covered >= 0.95 * (hi - lo), (covered, hi - lo)
+    by_phase = {}
+    for e in sched_evs:
+        by_phase[e["name"]] = by_phase.get(e["name"], 0) + 1
+    for name in ("serve_wait", "serve_admit", "serve_prefill_chunk",
+                 "serve_decode_chunk", "serve_decode_dispatch",
+                 "serve_device_wait", "serve_emit"):
+        assert by_phase.get(name), f"{name} never recorded: {by_phase}"
+    # Once per admission, inside the request's first chunk.
+    assert by_phase["serve_row_alloc"] == 3
+    assert by_phase["serve_prefill_chunk"] == 9
+    chunk_args = [e["args"] for e in sched_evs if e["name"] == "serve_prefill_chunk"]
+    assert {a["width"] for a in chunk_args} == {PAGE}
+    assert sum(a["final"] for a in chunk_args) == 3
+    admitted = [e["args"]["admitted"] for e in sched_evs if e["name"] == "serve_admit"]
+    assert sum(admitted) == 3
+
+    # The counter carries the same self seconds as the trace (all six
+    # requests: nothing here resets it).
+    reg = metrics.registry
+    counter = reg.counter("tpufw_serve_phase_seconds_total")
+    traced = {}
+    for e in tracer._events:
+        if e["ph"] == "X" and e["name"] in phases:
+            traced[e["name"]] = traced.get(e["name"], 0.0) + e["self_dur"] / 1e6
+    for name in serve_mod.SCHED_PHASES:
+        assert counter.value(phase=name) == pytest.approx(
+            traced.get(name, 0.0), abs=1e-4
+        ), name
+    text = reg.render()
+    for name in serve_mod.SCHED_PHASES:  # exposed even where still 0
+        assert f'tpufw_serve_phase_seconds_total{{phase="{name}"}} ' in text
+
+    # The request chain: one observation per request in each histogram.
+    for hist in ("join_latency", "queue_wait", "prefill"):
+        assert reg.histogram(f"tpufw_serve_{hist}_seconds").value() == 6, hist
+
+    # One identifier through a request's records.
+    events.close()
+    done = [e for e in events_mod.read_events(events.path) if e["kind"] == "serve_request"]
+    queued = {e["args"]["rid"]: e for e in warm if e["name"] == "req_queue"}
+    prefilled = {e["args"]["rid"]: e for e in warm if e["name"] == "req_prefill"}
+    assert set(queued) == set(prefilled) == {4, 5, 6}
+    assert {e["rid"] for e in done} == {1, 2, 3, 4, 5, 6}
+    for rid, ev in prefilled.items():
+        assert ev["args"]["prompt"] == queued[rid]["args"]["prompt"] == 40
+        assert ev["args"]["chunks"] == 3
+        assert ev["args"]["passes"] >= 3
+        # Request-level records cross passes: no phase, no counter.
+        assert ev["name"] not in phases

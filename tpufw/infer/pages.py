@@ -53,6 +53,7 @@ from tpufw.infer.generate import _model_apply, split_prefill_keys
 from tpufw.infer.prefix import PrefixCache
 from tpufw.infer.sampling import sample_token
 from tpufw.infer.slots import SlotPool, _retire_jit, _track_seen
+from tpufw.obs import trace as obs_trace
 from tpufw.ops.quant import dequantize_kv, quantize_kv
 
 # Trace-time counters, same contract as tpufw.infer.slots.TRACE_COUNTS:
@@ -679,6 +680,10 @@ class PagedSlotPool(SlotPool):
     #: kept copy would go stale the moment decode appends).
     trie_spill: Any = None
     trie_restore: Any = None
+    #: Span sink for the host work done in here (``serve_row_alloc``,
+    #: the final chunk's ``serve_device_wait``); the serve scheduler
+    #: mounts its own tracer after building the pool.
+    tracer: Any = obs_trace.NULL
     # Admission-outcome counters for signals()/bench: requests whose
     # trie match (incl. spill restores) covered >= 1 page vs not, and
     # pages moved across the HBM <-> spill boundary.
@@ -1032,29 +1037,37 @@ class PagedSlotPool(SlotPool):
         A fresh template every call: the attach jit DONATES the row
         leaves (their memory becomes the attached cache), so a cached
         tree would hand already-deleted buffers to the second prefix
-        hit. The zeros alloc is trivia next to the prefill."""
-        row_tree = _row_zeros_tree(self.row_model, self.params, self.home)
-        if not len(shared_ids):
-            return row_tree
-        paths, names, leaves, _ = self._pool_flat()
-        row_paths, _, row_leaves, row_treedef = _flatten_with_names(
-            row_tree
-        )
-        row_map = dict(zip(row_paths, row_leaves))
-        aligned = [row_map.get(p) for p in paths]
-        quant = self.model.cfg.kv_quant == "int8"
-        src = self._scale_src(paths, names)
-        scale_of = tuple(
-            src.index(i) if i in src else -1 for i in range(len(paths))
-        )
-        attached = _attach_shared_jit(
-            tuple(aligned), tuple(leaves),
-            jnp.asarray(np.asarray(shared_ids, np.int32)),
-            names=names, scale_of=scale_of, page=self.page, quant=quant,
-        )
-        return jax.tree_util.tree_unflatten(
-            row_treedef, [a for a in attached if a is not None]
-        )
+        hit. Not trivia: ``_row_zeros_tree`` re-traces the row model on
+        the host, every admission."""
+        with self.tracer.span(
+            "serve_row_alloc", shared_pages=len(shared_ids)
+        ):
+            row_tree = _row_zeros_tree(
+                self.row_model, self.params, self.home
+            )
+            if not len(shared_ids):
+                return row_tree
+            paths, names, leaves, _ = self._pool_flat()
+            row_paths, _, row_leaves, row_treedef = _flatten_with_names(
+                row_tree
+            )
+            row_map = dict(zip(row_paths, row_leaves))
+            aligned = [row_map.get(p) for p in paths]
+            quant = self.model.cfg.kv_quant == "int8"
+            src = self._scale_src(paths, names)
+            scale_of = tuple(
+                src.index(i) if i in src else -1
+                for i in range(len(paths))
+            )
+            attached = _attach_shared_jit(
+                tuple(aligned), tuple(leaves),
+                jnp.asarray(np.asarray(shared_ids, np.int32)),
+                names=names, scale_of=scale_of, page=self.page,
+                quant=quant,
+            )
+            return jax.tree_util.tree_unflatten(
+                row_treedef, [a for a in attached if a is not None]
+            )
 
     def prefill_shared(self, prompt: Sequence[int], shared_ids, rng):
         """Prefix-hit admission: attach ``shared_ids``' pages to a
@@ -1142,6 +1155,13 @@ class PagedSlotPool(SlotPool):
             raise
         return cp
 
+    def chunk_extent(self, cp: ChunkedPrefill) -> Tuple[int, int, bool]:
+        """(padded width, real tokens, is it the final chunk) of the
+        chunk ``chunk_step`` would run next for ``cp``."""
+        left = len(cp.prompt) - cp.cursor
+        width = min(cp.chunk_pages, -(-left // self.page)) * self.page
+        return width, min(left, width), left <= width
+
     def chunk_step(
         self, cp: ChunkedPrefill, unlocked=None
     ) -> str:
@@ -1167,12 +1187,8 @@ class PagedSlotPool(SlotPool):
         # transferred into cp.page_ids before it can return or raise,
         # so the CALLER holds nothing — cp's owner discharges via
         # finalize_chunked / abandon_chunked.
-        p = len(cp.prompt)
         start = cp.cursor
-        left = p - start
-        width = min(cp.chunk_pages, -(-left // self.page)) * self.page
-        n_real = min(left, width)
-        is_final = left <= width
+        width, n_real, is_final = self.chunk_extent(cp)
         # The final chunk acquires the full remaining page need —
         # including the decode-budget tail — BEFORE compute, so a
         # finished prefill can always finalize.
@@ -1245,8 +1261,11 @@ class PagedSlotPool(SlotPool):
             self.allocator.hold(adopted)
         if is_final:
             cp.first = first
-            cp.first_int = int(np.asarray(first)[0])
-            cp.done0 = bool(np.asarray(done0)[0])
+            # The one read of a chunked prefill that blocks: it waits
+            # for every program queued before it, this chunk's last.
+            with self.tracer.span("serve_device_wait"):
+                cp.first_int = int(np.asarray(first)[0])
+                cp.done0 = bool(np.asarray(done0)[0])
             return "done"
         return "ran"
 

@@ -185,6 +185,9 @@ class Telemetry:
                 pid=proc,
                 process_name=f"{role}:p{proc}/{nprocs}",
                 max_events=trace_max_events,
+                # Every caller is a process with a device (the
+                # trainers, the server): its spans belong in an xplane.
+                annotate=trace_mod.jax_annotation(),
             )
             tracer.listeners.append(ledger.on_span)
             events.listeners.append(ledger.on_event)

@@ -42,7 +42,11 @@ SCHEMA: Dict[str, FrozenSet[str]] = {
     "straggler_detected": frozenset(
         {"step", "straggler_hosts", "median_s", "factor"}
     ),
-    "serve_request": frozenset({"rows", "new_tokens", "latency_s"}),
+    # ``rid``: the scheduler's request id, shared with the request's
+    # ``req_queue`` / ``req_prefill`` spans in trace-serve.json.
+    "serve_request": frozenset(
+        {"rows", "new_tokens", "latency_s", "rid"}
+    ),
     "serve_pool_switch": frozenset({"cache_len", "slots"}),
     "serve_prefix": frozenset({"hit", "shared_pages", "prompt_tokens"}),
     "serve_migration": frozenset({"pages", "bytes", "wall_s"}),

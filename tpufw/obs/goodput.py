@@ -64,7 +64,8 @@ TRAIN_SPAN_CATEGORIES: Dict[str, str] = {
 # double-book the prefill seconds. ``serve_decode_chunk`` is also
 # absent — the scheduler splits each chunk into busy/wasted_slot
 # explicitly via ``add()`` using the live-token fraction, which a
-# name->category table cannot express.
+# name->category table cannot express. Admission's own host time is
+# booked by self time on ``tpufw_serve_phase_seconds_total`` instead.
 SERVE_SPAN_CATEGORIES: Dict[str, str] = {
     "serve_pool_build": "compile",
     "serve_prefill": "busy",
@@ -151,8 +152,12 @@ class GoodputLedger:
                 self._seconds.get(category, 0.0) + seconds
             )
 
-    def on_span(self, name: str, dur_s: float, args=None) -> None:
-        """Tracer listener: span completion -> category."""
+    def on_span(
+        self, name: str, dur_s: float, args=None, self_s=None
+    ) -> None:
+        """Tracer listener: span completion -> category. Books the
+        span's whole duration (``self_s`` unused): the tables below
+        name no span that nests another one they name."""
         cat = self._span_cats.get(name)
         if cat is None:
             return
@@ -279,7 +284,9 @@ class NullGoodputLedger:
     def add(self, category: str, seconds: float) -> None:
         pass
 
-    def on_span(self, name: str, dur_s: float, args=None) -> None:
+    def on_span(
+        self, name: str, dur_s: float, args=None, self_s=None
+    ) -> None:
         pass
 
     def on_event(self, event: dict) -> None:

@@ -9,10 +9,23 @@ like a flame chart. This is the microscope for WHERE a window's time
 went; XProf (``utils/profiling.py``) stays the microscope for what
 the devices did inside the step.
 
+One span, three sinks. Besides the JSON buffer a span (1) enters a
+``jax.profiler.TraceAnnotation`` of its name, so that while a profiler
+session runs the program's spans are host events in the same xplane,
+on the same clock, as the device operations (outside a session the
+annotation is a flag test); and (2) is handed to ``listeners`` with its
+duration and its SELF time (duration minus what its child spans
+covered), which is what counters sum without double-booking a nested
+span. The annotation factory is the caller's to hand in (``annotate=
+jax_annotation()``): obs imports no jax, and a role without a device
+pays nothing for it. ``Tracer(None)`` is the live-but-unbuffered form:
+it annotates and feeds listeners, keeps no events and writes no file.
+
 Disabled tracing must be free enough to leave the instrumentation
 in the loop unconditionally: ``NullTracer.span`` returns one shared
 no-op context manager — no allocation, no clock read (the <1%
-per-step overhead budget is asserted in tests/test_obs.py).
+per-step overhead budget is asserted in tests/test_obs.py, for the
+unbuffered tracer too).
 """
 
 from __future__ import annotations
@@ -24,11 +37,26 @@ import time
 from typing import List, Optional
 
 
+def jax_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is not
+    installed: what a process whose spans can reach a device xplane
+    (the serve scheduler, the trainer) hands a ``Tracer`` as
+    ``annotate``. Never called by obs itself, so stdlib-only roles
+    (the router, the load tools) trace without importing jax."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except Exception:  # noqa: BLE001 — no jax, or a jax without it
+        return None
+    return TraceAnnotation
+
+
 class _Span:
     """Reusable-shape span context manager; one allocation per enter
-    (cheap relative to the phases traced, which are >=100us)."""
+    (cheap relative to the phases traced, which are >=100us). ``args``
+    may be added to until exit (an outcome known only at the end); the
+    profiler annotation carries them as they were on entry."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_frame", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -36,13 +64,18 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
-        self._tracer._push(self.name)
+        ann = self._tracer._annotate
+        if ann is not None:
+            ann = ann(self.name, **self.args)
+            ann.__enter__()
+        self._ann = ann
+        self._frame = self._tracer._push(self.name)
         return self
 
     def __exit__(self, *exc):
-        self._tracer._pop(self.name)
-        self._tracer._record(self.name, self._t0, time.perf_counter(), self.args)
+        self._tracer._pop(self._frame, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -64,21 +97,29 @@ class Tracer:
     JSON. Timestamps are microseconds on the process-local
     ``perf_counter`` clock (Chrome trace epochs are arbitrary); the
     wall-clock anchor is recorded in ``otherData`` for cross-host
-    alignment."""
+    alignment. ``path=None`` buffers no event and writes no file; the
+    profiler annotations and the listeners work the same. ``annotate``
+    is a context-manager factory ``(name, **args)`` entered around every
+    span, ``jax_annotation()`` in a process with a device; ``clock`` is
+    for tests that set the time by hand."""
 
     def __init__(
         self,
-        path: str,
+        path: Optional[str],
         pid: int = 0,
         process_name: str = "",
         max_events: Optional[int] = None,
+        clock=time.perf_counter,
+        annotate=None,
     ):
         self.path = path
         self.pid = pid
         self._name = process_name
+        self._clock = clock
+        self._annotate = annotate
         self._lock = threading.Lock()
         self._events: List[dict] = []
-        self._t0 = time.perf_counter()
+        self._t0 = clock()
         self._wall0 = time.time()
         self._closed = False
         # Long-running processes (the serving scheduler) trace hot
@@ -88,13 +129,15 @@ class Tracer:
         # counts what it dropped.
         self._max = max_events
         self._dropped = 0
-        # Observers called (name, dur_s, args) after each complete
-        # span — the goodput ledger rides these instead of re-timing
-        # the loop. Wiring-time mutation only.
+        # Observers called (name, dur_s, args, self_s) after each
+        # complete span — the goodput ledger and the serve scheduler's
+        # phase counter ride these instead of re-timing the loop.
+        # Wiring-time mutation only.
         self.listeners: List = []
-        # Open spans per thread, for the hang watchdog's "where was
-        # the run wedged" dump. perf_counter start kept so the dump
-        # can say how long each frame has been open.
+        # Open spans per thread, innermost last, as [name, start,
+        # seconds its closed children covered]: the hang watchdog's
+        # "where was the run wedged" dump, and what self time is
+        # computed from.
         self._live: dict = {}
 
     enabled = True
@@ -102,57 +145,72 @@ class Tracer:
     def _ts(self, t: float) -> float:
         return round((t - self._t0) * 1e6, 3)
 
-    def _push(self, name: str) -> None:
+    def _push(self, name: str) -> list:
+        frame = [name, self._clock(), 0.0]
         tid = threading.get_ident()
         with self._lock:
-            self._live.setdefault(tid, []).append((name, time.perf_counter()))
+            self._live.setdefault(tid, []).append(frame)
+        return frame
 
-    def _pop(self, name: str) -> None:
+    def _pop(self, frame: list, args: Optional[dict]) -> None:
+        name, t0, child_s = frame
+        t1 = self._clock()
         tid = threading.get_ident()
         with self._lock:
             stack = self._live.get(tid)
-            if stack and stack[-1][0] == name:
+            if stack and stack[-1] is frame:
                 stack.pop()
-            if not stack:
-                self._live.pop(tid, None)
+                if stack:
+                    stack[-1][2] += t1 - t0
+                else:
+                    del self._live[tid]
+            if self._closed:
+                return
+            self._buffer_complete(name, t0, t1, args, t1 - t0 - child_s)
+        self._notify(name, t1 - t0, args, t1 - t0 - child_s)
 
     def live_spans(self) -> dict:
         """Snapshot of currently-open spans: thread ident ->
         [(name, open_for_s), ...] innermost last. The watchdog dumps
         this so a hang report names the wedged phase, not just the
         wedged line."""
-        now = time.perf_counter()
+        now = self._clock()
         with self._lock:
             return {
-                tid: [(name, round(now - t0, 3)) for name, t0 in stack]
+                tid: [(name, round(now - t0, 3)) for name, t0, _ in stack]
                 for tid, stack in self._live.items()
             }
 
-    def _record(
-        self, name: str, t0: float, t1: float, args: Optional[dict]
+    def _buffer_complete(
+        self, name: str, t0: float, t1: float, args, self_s: float
     ) -> None:
+        """Append one complete event; the caller holds the lock."""
+        if self.path is None:
+            return
+        if self._max is not None and len(self._events) >= self._max:
+            self._dropped += 1
+            return
         ev = {
             "name": name,
             "ph": "X",
             "ts": self._ts(t0),
             "dur": round((t1 - t0) * 1e6, 3),
+            # Not a trace-event field (viewers ignore it): the span's
+            # duration less what its child spans covered, microseconds.
+            "self_dur": round(self_s * 1e6, 3),
             "pid": self.pid,
             "tid": threading.get_ident() & 0xFFFF,
         }
         if args:
             ev["args"] = args
-        with self._lock:
-            if self._closed:
-                return
-            if self._max is not None and len(self._events) >= self._max:
-                self._dropped += 1
-            else:
-                self._events.append(ev)
+        self._events.append(ev)
+
+    def _notify(self, name: str, dur_s: float, args, self_s: float) -> None:
         # Listeners fire even past the buffer cap (ledger accounting
         # must not stop when the trace fills) and outside the lock.
         for fn in tuple(self.listeners):
             try:
-                fn(name, t1 - t0, args)
+                fn(name, dur_s, args or None, self_s)
             except Exception:
                 pass  # observability must never take down the run
 
@@ -163,16 +221,23 @@ class Tracer:
         """Record a span that just ENDED, ``dur_s`` long — for phases
         whose duration is measured elsewhere (e.g. ``timed_batches``
         already times the data wait; re-timing it would double-count
-        the clock reads)."""
-        t1 = time.perf_counter()
-        self._record(name, t1 - dur_s, t1, args or None)
+        the clock reads). It is no child of whatever span is open:
+        what it covers may have begun before that span did."""
+        t1 = self._clock()
+        with self._lock:
+            if self._closed:
+                return
+            self._buffer_complete(name, t1 - dur_s, t1, args, dur_s)
+        self._notify(name, dur_s, args, dur_s)
 
     def instant(self, name: str, **args) -> None:
+        if self.path is None:
+            return  # a marker has no duration: nothing for a listener
         ev = {
             "name": name,
             "ph": "i",
             "s": "p",
-            "ts": self._ts(time.perf_counter()),
+            "ts": self._ts(self._clock()),
             "pid": self.pid,
             "tid": threading.get_ident() & 0xFFFF,
         }
@@ -192,6 +257,8 @@ class Tracer:
                 return
             self._closed = True
             events = self._events
+        if self.path is None:
+            return
         if self._name:
             events = [
                 {

@@ -852,7 +852,7 @@ class _SlotJob:
     its own EOS/max_new."""
 
     __slots__ = ("req", "prompt", "p_bucket", "max_new", "cache_len",
-                 "tokens", "unflushed", "cp")
+                 "tokens", "unflushed", "cp", "t_grant", "pass0")
 
     def __init__(self, req, prompt, p_bucket, max_new, cache_len):
         self.req = req
@@ -866,6 +866,10 @@ class _SlotJob:
         # row occupies a slot as a PREFILLING citizen; None once the
         # first token lands (or always, in monolithic admission mode).
         self.cp = None
+        # Slot grant (perf_counter) and the scheduler pass it fell in:
+        # where ``req_prefill`` / tpufw_serve_prefill_seconds start.
+        self.t_grant = 0.0
+        self.pass0 = 0
 
 
 class _SlotReq:
@@ -874,7 +878,7 @@ class _SlotReq:
 
     __slots__ = ("pend", "sampling", "jobs", "next_job", "rows_left",
                  "cache_len", "t_submit", "started", "error",
-                 "batched_with", "overtaken")
+                 "batched_with", "overtaken", "rid")
 
     def __init__(self, pend, sampling, jobs):
         self.pend = pend
@@ -890,6 +894,42 @@ class _SlotReq:
         self.error: Exception | None = None
         self.batched_with = 1
         self.overtaken = 0  # admission rounds later arrivals ran ahead
+        # Per-scheduler request id (set at enqueue): the one identifier
+        # req_queue, req_prefill and the serve_request event share.
+        self.rid = 0
+
+
+#: The spans that partition the scheduler thread's pass: each one's
+#: SELF seconds feed ``tpufw_serve_phase_seconds_total{phase=<name>}``,
+#: so over any interval they sum to the thread's wall time less what no
+#: span covers. ``serve_device_wait`` is the thread blocked on the
+#: device, ``serve_wait`` the thread with nothing queued or running,
+#: ``serve_prefill_chunk`` / ``serve_decode_dispatch`` time to ENQUEUE a
+#: program (dispatch is asynchronous); the rest is host work. Request-
+#: level records (``req_queue``, ``req_prefill``) cross passes and stay
+#: out.
+SCHED_PHASES = (
+    "serve_wait",
+    "serve_pool_build",
+    "serve_admit",
+    "serve_row_alloc",
+    "serve_prefill",
+    "serve_prefill_chunk",
+    "serve_decode_chunk",
+    "serve_spec_chunk",
+    "serve_decode_dispatch",
+    "serve_device_wait",
+    "serve_emit",
+)
+
+#: Buckets of the request-chain histograms: queue waits of a fraction
+#: of a second and chunked prefills of several seconds both need finer
+#: steps than the registry's default ladder has there.
+_CHAIN_BUCKETS = (
+    0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.15, 0.2, 0.25, 0.3,
+    0.35, 0.4, 0.5, 0.6, 0.75, 1.0, 1.5, 2.0, 2.5, 3.0, 3.5, 4.0, 5.0,
+    6.0, 8.0, 10.0, 15.0, 20.0, 30.0, 45.0, 60.0, 120.0,
+)
 
 
 class _SlotScheduler:
@@ -972,7 +1012,16 @@ class _SlotScheduler:
         self._metrics = metrics
         self._seed_base = seed_base
         self._events = events if events is not None else obs_events.NULL
-        self._tracer = tracer if tracer is not None else obs_trace.NULL
+        # Always a live tracer: without a telemetry dir it buffers
+        # nothing and writes no file, but its spans still show in a
+        # profiler capture and still feed the phase counter below.
+        self._tracer = (
+            tracer
+            if tracer is not None and tracer.enabled
+            else obs_trace.Tracer(
+                None, annotate=obs_trace.jax_annotation()
+            )
+        )
         self._goodput = goodput if goodput is not None else obs_goodput.NULL
         self._watchdog = watchdog if watchdog is not None else NULL_WATCHDOG
         self._perf = perf if perf is not None else obs_perf.NULL
@@ -980,11 +1029,6 @@ class _SlotScheduler:
         # every naturally-completing paged row, where ``state`` is the
         # slot's export_slot() dict taken BEFORE the slot is retired.
         self._page_export = page_export
-        # Join-latency component split (queue_wait + prefill). Gated
-        # OFF by default: registering the histograms adds scrape lines,
-        # and the legacy exposition must stay byte-identical unless the
-        # operator opts in.
-        self.latency_breakdown = env_bool("serve_latency_breakdown", False)
         self.n_slots = max(1, env_int("serve_slots", 8))
         self.chunk = max(
             1, env_int("serve_chunk", 0) or env_int("stream_chunk", 16)
@@ -1184,22 +1228,36 @@ class _SlotScheduler:
                 )
                 metrics.registry.gauge("tpufw_spec_accept_rate")
                 metrics.registry.gauge("tpufw_spec_fallback_slots")
+            # The request chain: join = queue_wait + admission, and
+            # ttft = queue_wait + prefill (plus the stream's flush).
             metrics.registry.histogram(
                 "tpufw_serve_join_latency_seconds",
                 "Request submit-to-first-slot-insert latency",
             )
-            if self.latency_breakdown:
-                # Component split of the join latency: time queued
-                # behind other requests vs. time inside the prefill
-                # program itself.
-                metrics.registry.histogram(
-                    "tpufw_serve_queue_wait_seconds",
-                    "Request submit-to-admission-start latency",
-                )
-                metrics.registry.histogram(
-                    "tpufw_serve_prefill_seconds",
-                    "Per-row prefill wall-clock",
-                )
+            metrics.registry.histogram(
+                "tpufw_serve_queue_wait_seconds",
+                "Request submit-to-admission-start latency",
+                buckets=_CHAIN_BUCKETS,
+            )
+            metrics.registry.histogram(
+                "tpufw_serve_prefill_seconds",
+                "Per-row slot grant to first token sampled",
+                buckets=_CHAIN_BUCKETS,
+            )
+            # Where the scheduler thread's time went, by span self
+            # time; every phase exposed at 0 before it first runs.
+            phase_s = metrics.registry.counter(
+                "tpufw_serve_phase_seconds_total",
+                "Scheduler-thread self seconds by phase (span name)",
+            )
+            for phase in SCHED_PHASES:
+                phase_s.inc(0.0, phase=phase)
+
+            def on_span(name, dur_s, args, self_s):
+                if name in SCHED_PHASES:
+                    phase_s.inc(max(0.0, self_s), phase=name)
+
+            self._tracer.listeners.append(on_span)
         self._pool = None  # tpufw.infer.slots.SlotPool (lazy, keyed)
         self._pool_key: Optional[tuple] = None
         self._slots: list[Optional[_SlotJob]] = [None] * self.n_slots
@@ -1210,6 +1268,8 @@ class _SlotScheduler:
         # invisible to seed replay.
         self._job_index = 0
         self._chunk_index = 0
+        self._rid = 0  # request ids handed out (rid of the newest)
+        self._pass = 0  # scheduler passes begun (req_prefill counts them)
         self._queue: list[_SlotReq] = []
         self._cv = threading.Condition()
         self._thread = threading.Thread(
@@ -1280,6 +1340,8 @@ class _SlotScheduler:
     def _enqueue(self, pend: _Pending) -> None:
         req = self._make_req(pend)  # raises ValueError -> HTTP 400
         with self._cv:
+            self._rid += 1
+            req.rid = self._rid
             self._queue.append(req)
             self._cv.notify()
 
@@ -1346,17 +1408,24 @@ class _SlotScheduler:
     # ---- worker loop ----
 
     def _loop(self) -> None:
+        # Every stretch of a pass lies in a leaf span (SCHED_PHASES).
+        # No span encloses the pass: in a profiler capture it would
+        # cover every idle gap of the device and hide the phase.
         while True:
             with self._cv:
-                while not self._queue and not self._n_active:
-                    self._cv.wait()
+                if not self._queue and not self._n_active:
+                    with self._tracer.span("serve_wait"):
+                        while not self._queue and not self._n_active:
+                            self._cv.wait()
                 idle = self._n_active == 0
             if idle and self.wait_s > 0:
                 # Coalescing window: near-simultaneous arrivals land
                 # in the same first admission round. Never slept while
                 # the pool is running — joins happen at chunk
                 # boundaries, which are the natural cadence.
-                time.sleep(self.wait_s)
+                with self._tracer.span("serve_wait"):
+                    time.sleep(self.wait_s)
+            self._pass += 1
             # Watchdog window: one admit + one chunk. Both are a
             # bounded amount of device work (prefill / k decode
             # steps); if either wedges past TPUFW_HANG_TIMEOUT_S the
@@ -1523,6 +1592,8 @@ class _SlotScheduler:
                     else None
                 ),
             )
+        if self.page:
+            self._pool.tracer = self._tracer
         if self._perf.enabled:
             # Mount the cost observatory on the pool (dynamic attr:
             # SlotPool/PagedSlotPool read it via getattr) so insert /
@@ -1611,7 +1682,8 @@ class _SlotScheduler:
         free = [i for i, j in enumerate(self._slots) if j is None]
         budget_closed = False
         blocked: Optional[_SlotReq] = None
-        with self._tracer.span("serve_admit", queued=len(queue)):
+        with self._tracer.span("serve_admit", queued=len(queue)) as sp:
+            n_free0 = len(free)
             for req in queue:
                 if req.error is not None:
                     continue
@@ -1648,12 +1720,16 @@ class _SlotScheduler:
                     for r in self._queue
                     if r.error is None and r.next_job < len(r.jobs)
                 ]
-        # batched_with: how many distinct requests share the pool now.
-        reqs = {
-            id(j.req): j.req for j in self._slots if j is not None
-        }
-        for req in reqs.values():
-            req.batched_with = max(req.batched_with, len(reqs))
+            # batched_with: how many distinct requests share the pool
+            # now.
+            reqs = {
+                id(j.req): j.req for j in self._slots if j is not None
+            }
+            for req in reqs.values():
+                req.batched_with = max(req.batched_with, len(reqs))
+            # Known only now, so the JSON trace and the listeners see
+            # it and the profiler annotation does not.
+            sp.args["admitted"] = n_free0 - len(free)
 
     def _admit_req(self, req: _SlotReq, free: list[int]) -> bool:
         """Admit as many of ``req``'s remaining rows as fit; returns
@@ -1714,14 +1790,20 @@ class _SlotScheduler:
                 free.pop(0)
         if admitted and not req.started:
             req.started = True
+            queue_s = max(0.0, t_admit0 - req.t_submit)
+            self._tracer.complete(
+                "req_queue",
+                queue_s,
+                rid=req.rid,
+                prompt=sum(len(j.prompt) for j in req.jobs),
+            )
             if self._metrics is not None:
                 self._metrics.registry.histogram(
                     "tpufw_serve_join_latency_seconds"
                 ).observe(time.time() - req.t_submit)
-                if self.latency_breakdown:
-                    self._metrics.registry.histogram(
-                        "tpufw_serve_queue_wait_seconds"
-                    ).observe(max(0.0, t_admit0 - req.t_submit))
+                self._metrics.registry.histogram(
+                    "tpufw_serve_queue_wait_seconds"
+                ).observe(queue_s)
         if admitted and req.pend.stream_q is not None:
             # First tokens reach the stream at admission, not a chunk
             # later — and every flush stays <= chunk-size tokens/row.
@@ -1804,6 +1886,8 @@ class _SlotScheduler:
             self._free_pages(self._pool.abandon_chunked(cp))
             raise
         job.cp = cp  # resource: transfers pages
+        job.t_grant = time.perf_counter()
+        job.pass0 = self._pass
         self._slots[slot] = job
         self._n_active += 1
         self._set_prefill_inflight()
@@ -1856,7 +1940,8 @@ class _SlotScheduler:
                     shared_pages=shared_n,
                     prompt_tokens=len(job.prompt),
                 )
-        prefill_t0 = time.perf_counter()
+        job.t_grant = time.perf_counter()
+        job.pass0 = self._pass
         with self._tracer.span(
             "serve_prefill", prompt=len(job.prompt), width=job.p_bucket
         ):
@@ -1884,10 +1969,7 @@ class _SlotScheduler:
                         prefill_chunk_size=self.prefill_chunk,
                     )
                 )
-        if self.latency_breakdown and self._metrics is not None:
-            self._metrics.registry.histogram(
-                "tpufw_serve_prefill_seconds"
-            ).observe(time.perf_counter() - prefill_t0)
+        self._first_token(job, chunks=1)
         job.tokens.append(first_int)
         job.unflushed.append(first_int)
         if self._metrics is not None:
@@ -1935,6 +2017,24 @@ class _SlotScheduler:
         self._slots[slot] = job
         self._n_active += 1
         return True
+
+    def _first_token(self, job: _SlotJob, chunks: int) -> None:
+        """``job``'s first token was just sampled: close the request
+        chain's prefill link, slot grant -> first token, on both
+        admission paths."""
+        prefill_s = time.perf_counter() - job.t_grant
+        self._tracer.complete(
+            "req_prefill",
+            prefill_s,
+            rid=job.req.rid,
+            prompt=len(job.prompt),
+            chunks=chunks,
+            passes=self._pass - job.pass0 + 1,
+        )
+        if self._metrics is not None:
+            self._metrics.registry.histogram(
+                "tpufw_serve_prefill_seconds"
+            ).observe(prefill_s)
 
     def _admit_draft(self, job: _SlotJob, slot: int, rng) -> None:
         """Prefill ``job``'s prompt through the draft model into the
@@ -2096,32 +2196,39 @@ class _SlotScheduler:
         with self._tracer.span(
             "serve_spec_chunk", k=k, rows=len(active)
         ):
-            if self._draft_pool is not None:
-                out, n_emit, accept = self._pool.spec_draft_steps(
-                    self._draft_pool, key, k
-                )
-            else:
-                props = self._np.zeros(
-                    (self.n_slots, k), self._np.int32
-                )
-                for slot, job in active:
-                    props[slot] = self._spec_mod.ngram_propose(
-                        list(job.prompt) + job.tokens, k
+            with self._tracer.span("serve_decode_dispatch"):
+                if self._draft_pool is not None:
+                    out, n_emit, accept = self._pool.spec_draft_steps(
+                        self._draft_pool, key, k
                     )
-                # tpulint: disable=TPU003 — exclusive if/else arms:
-                # exactly ONE of spec_draft_steps/spec_steps consumes
-                # this chunk's key.
-                out, n_emit, accept = self._pool.spec_steps(props, key)
-            out = self._np.asarray(out)
-            n_emit = self._np.asarray(n_emit)
-            accept = self._np.asarray(accept)
+                else:
+                    props = self._np.zeros(
+                        (self.n_slots, k), self._np.int32
+                    )
+                    for slot, job in active:
+                        props[slot] = self._spec_mod.ngram_propose(
+                            list(job.prompt) + job.tokens, k
+                        )
+                    # tpulint: disable=TPU003 — exclusive if/else arms:
+                    # exactly ONE of spec_draft_steps/spec_steps
+                    # consumes this chunk's key.
+                    out, n_emit, accept = self._pool.spec_steps(
+                        props, key
+                    )
+            with self._tracer.span("serve_device_wait"):
+                out = self._np.asarray(out)
+                n_emit = self._np.asarray(n_emit)
+                accept = self._np.asarray(accept)
         chunk_s = time.perf_counter() - chunk_t0
-        self._perf.record_wall(
-            f"serve_spec_draft_k{k}"
-            if self._draft_pool is not None
-            else f"serve_spec_k{k}",
-            chunk_s,
-        )
+        with self._tracer.span("serve_emit", rows=len(active)):
+            self._emit_spec(active, k, out, n_emit, accept, chunk_s,
+                            page_snap)
+
+    def _emit_spec(
+        self, active, k, out, n_emit, accept, chunk_s, page_snap
+    ) -> None:
+        """Host post-processing of one speculative pass: per-slot
+        accept bookkeeping, retires, stream flushes, completions."""
         live_tokens = 0
         flush: list[_SlotReq] = []
         finished: list[_SlotReq] = []
@@ -2218,12 +2325,23 @@ class _SlotScheduler:
             if j is not None and j.cp is not None
         ]:
             cp = job.cp
+            # The extent is for the span's arguments only; whether the
+            # prefill ended is the pool's to say (``status`` below).
+            width, _, final = self._pool.chunk_extent(cp)
             t0 = time.perf_counter()
+            # DISPATCH time: the chunk program is enqueued, not waited
+            # for, and its device time is paid by whoever blocks next
+            # (serve_device_wait: in the final chunk's own read of the
+            # first token, else in this pass's decode chunk). A
+            # request's first chunk also allocates its row
+            # (serve_row_alloc, nested).
             with self._tracer.span(
                 "serve_prefill_chunk",
                 slot=slot,
                 cursor=cp.cursor,
                 prompt=len(job.prompt),
+                width=width,
+                final=final,
             ):
                 status = self._pool.chunk_step(cp)
             if status == "stalled":
@@ -2232,20 +2350,22 @@ class _SlotScheduler:
                 # admission reservation guarantees eventual progress).
                 continue
             progressed = True
-            if self._metrics is not None:
-                self._metrics.registry.counter(
-                    "tpufw_prefill_chunks_total"
-                ).inc()
-            self._events.emit(
-                "serve_prefill_chunk",
-                prompt_tokens=len(job.prompt),
-                cursor=cp.cursor,
-                chunk_s=round(time.perf_counter() - t0, 6),
-                final=status == "done",
-                slot=slot,
-            )
-            if status == "done":
-                self._finalize_chunked(slot, job)
+            with self._tracer.span("serve_emit", slot=slot):
+                if self._metrics is not None:
+                    self._metrics.registry.counter(
+                        "tpufw_prefill_chunks_total"
+                    ).inc()
+                self._events.emit(
+                    "serve_prefill_chunk",
+                    prompt_tokens=len(job.prompt),
+                    cursor=cp.cursor,
+                    dispatch_s=round(time.perf_counter() - t0, 6),
+                    final=status == "done",
+                    slot=slot,
+                )
+                if status == "done":
+                    self._first_token(job, chunks=cp.n_chunks)
+                    self._finalize_chunked(slot, job)
         self._set_prefill_inflight()
         return progressed
 
@@ -2306,15 +2426,6 @@ class _SlotScheduler:
         # per-value.
         max_left = max(j.max_new - len(j.tokens) for _, j in active)
         k = min(self.chunk, _pow2_ceil(max_left))
-        with self._cv:
-            # Reset from the caller side in reset_after_warmup; bump
-            # under the monitor so neither side loses an update.
-            chunk_index = self._chunk_index
-            self._chunk_index += 1
-        key = self._jax.random.fold_in(
-            self._jax.random.key(self._seed_base + 1), chunk_index
-        )
-        keys = self._jax.random.split(key, k)
         # Chunk-boundary page-table snapshot for the export hook: a
         # row that finishes mid-chunk keeps absorbing the junk-sink
         # (page 0) writes for the chunk's remaining steps, and once it
@@ -2328,16 +2439,37 @@ class _SlotScheduler:
                 slot: list(self._pool.slot_pages[slot])
                 for slot, _ in active
             }
-        chunk_t0 = time.perf_counter()
+        # An asynchronous pass, recorded as it is: the time to enqueue
+        # the step keys and the decode program, then the time blocked
+        # until the device has run everything queued before the read —
+        # prefill chunks dispatched earlier in this pass included, so
+        # the wait is NOT the decode program's own device time.
         with self._tracer.span(
             "serve_decode_chunk", k=k, rows=len(active)
         ):
-            out = self._np.asarray(self._pool.decode_steps(keys))
+            with self._tracer.span("serve_decode_dispatch"):
+                with self._cv:
+                    # Reset from the caller side in reset_after_warmup;
+                    # bump under the monitor so neither side loses an
+                    # update.
+                    chunk_index = self._chunk_index
+                    self._chunk_index += 1
+                key = self._jax.random.fold_in(
+                    self._jax.random.key(self._seed_base + 1),
+                    chunk_index,
+                )
+                keys = self._jax.random.split(key, k)
+                chunk_t0 = time.perf_counter()
+                out = self._pool.decode_steps(keys)
+            with self._tracer.span("serve_device_wait"):
+                out = self._np.asarray(out)
         chunk_s = time.perf_counter() - chunk_t0
-        # Publishes tpufw_program_mfu{program="serve_decode_k<k>"}
-        # from the chunk's wall-clock + harvested FLOPs (no-op on the
-        # null observatory / before the program's cost harvest).
-        self._perf.record_wall(f"serve_decode_k{k}", chunk_s)
+        with self._tracer.span("serve_emit", rows=len(active)):
+            self._emit_chunk(active, k, out, chunk_s, page_snap)
+
+    def _emit_chunk(self, active, k, out, chunk_s, page_snap) -> None:
+        """Host post-processing of one decode chunk: token
+        bookkeeping, retires, stream flushes, completions."""
         if self._metrics is not None:
             self._metrics.inc("ticks_total")
             self._metrics.inc("tick_rows_total", len(active))
@@ -2419,6 +2551,7 @@ class _SlotScheduler:
             rows=len(req.jobs),
             new_tokens=n_tokens,
             latency_s=round(time.time() - req.t_submit, 6),
+            rid=req.rid,
         )
         if pend.stream_q is not None:
             self._flush_stream(req)
@@ -2681,19 +2814,17 @@ class _Server:
                     self._batcher._spec_accept_rows = 0
                     reg.gauge("tpufw_spec_accept_rate").set(0.0)
                     reg.gauge("tpufw_spec_fallback_slots").set(0.0)
-                self.metrics.registry.histogram(
-                    "tpufw_serve_join_latency_seconds"
-                ).reset()
-                if self._batcher.latency_breakdown:
-                    # Gated like the registration: reset() would CREATE
-                    # the histograms, leaking the breakdown series into
-                    # the legacy scrape when the gate is off.
-                    self.metrics.registry.histogram(
-                        "tpufw_serve_queue_wait_seconds"
-                    ).reset()
-                    self.metrics.registry.histogram(
-                        "tpufw_serve_prefill_seconds"
-                    ).reset()
+                for chain in (
+                    "tpufw_serve_join_latency_seconds",
+                    "tpufw_serve_queue_wait_seconds",
+                    "tpufw_serve_prefill_seconds",
+                ):
+                    self.metrics.registry.histogram(chain).reset()
+                phase_s = self.metrics.registry.counter(
+                    "tpufw_serve_phase_seconds_total"
+                )
+                for phase in SCHED_PHASES:
+                    phase_s.reset(phase=phase)
             return
         tick0 = self._tick_index
         try:
