@@ -8,6 +8,7 @@ priority, same drops, same aux statistics, same gradients.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,7 @@ import numpy as np
 import pytest
 
 from tpufw.models import Mixtral, MixtralConfig
+from tpufw.models.mixtral import MoEMLP
 from tpufw.ops.moe import (
     expert_capacity,
     route_topk_capacity,
@@ -51,9 +53,10 @@ def _sorted_out(logits, x, k, cap, valid=None, norm_topk=True,
         norm_topk=norm_topk, group_limit=group_limit,
     )
     xs = x[token]
-    scale = jnp.concatenate(
-        [jnp.arange(1.0, e + 1.0), jnp.zeros((1,))]
-    )
+    # group_sizes has E entries: sentinel (invalid-token) rows ride in
+    # expert E-1's group and are zeroed by their gate alone.
+    assert group_sizes.shape == (e,)
+    scale = jnp.arange(1.0, e + 1.0)
     eid = jnp.searchsorted(
         jnp.cumsum(group_sizes),
         jnp.arange(token.shape[0]),
@@ -179,6 +182,19 @@ def test_sorted_rejects_unknown_mode():
         jax.jit(Mixtral(cfg).init)(jax.random.key(0), tokens)
 
 
+def _live_lora_b(params):
+    """lora_b zero-inits; perturb it so the LoRA term is actually live."""
+    return jax.tree_util.tree_map_with_path(
+        lambda p, leaf: (
+            jax.random.normal(jax.random.key(3), leaf.shape, leaf.dtype)
+            * 0.1
+            if "lora_b" in jax.tree_util.keystr(p)
+            else leaf
+        ),
+        params,
+    )
+
+
 def test_mixtral_model_sorted_matches_einsum_with_lora():
     """The sorted path's grouped LoRA branch (ragged_dot over the
     lora_a/lora_b stacks) must match the einsum LoRA path from the
@@ -189,17 +205,154 @@ def test_mixtral_model_sorted_matches_einsum_with_lora():
     cfg1 = dataclasses.replace(_tiny("sorted"), lora_rank=4)
     m0, m1 = Mixtral(cfg0), Mixtral(cfg1)
     params = jax.jit(m0.init)(jax.random.key(1), tokens)["params"]
-    # lora_b zero-inits; perturb it so the LoRA term is actually live.
-    params = jax.tree_util.tree_map_with_path(
-        lambda p, leaf: (
-            jax.random.normal(jax.random.key(3), leaf.shape, leaf.dtype)
-            * 0.1
-            if "lora_b" in jax.tree_util.keystr(p)
-            else leaf
-        ),
-        params,
-    )
+    params = _live_lora_b(params)
     logits0, aux0 = m0.apply({"params": params}, tokens)
     logits1, aux1 = m1.apply({"params": params}, tokens)
     np.testing.assert_allclose(logits0, logits1, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(aux0, aux1, rtol=1e-5, atol=1e-6)
+
+
+def _valid_mask(g, seed=5, p=0.7):
+    return jax.random.bernoulli(jax.random.key(seed), p, (g,))
+
+
+@pytest.mark.parametrize("with_valid", [False, True])
+@pytest.mark.parametrize("cap_factor", [4.0, 0.6])
+def test_group_sizes_cover_every_row(with_valid, cap_factor):
+    """ragged_dot's contract: E groups (the stacks as stored) whose
+    sizes sum to the k*G sorted rows, so no output row is undefined —
+    with the sentinel rows of invalid tokens folded into group E-1."""
+    g, e, k = 48, 4, 2
+    logits = _logits(g, e, seed=3)
+    valid = _valid_mask(g) if with_valid else None
+    cap = expert_capacity(g, k, e, cap_factor)
+    token, group_sizes, gates, _, _ = route_topk_sorted(
+        logits, k, cap, valid=valid, dtype=F32
+    )
+    assert group_sizes.shape == (e,)
+    assert token.shape == gates.shape == (k * g,)
+    assert int(jnp.sum(group_sizes)) == k * g
+    # Real assignments per expert, from the selection itself.
+    _, topk_idx = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
+    keep = np.ones(g, bool) if valid is None else np.asarray(valid)
+    n_sentinel = k * int((~keep).sum())
+    want = np.bincount(np.asarray(topk_idx)[keep].ravel(), minlength=e)
+    want[e - 1] += n_sentinel
+    np.testing.assert_array_equal(np.asarray(group_sizes), want)
+    # Sentinel rows sort last and carry a zero gate.
+    if n_sentinel:
+        tail = np.asarray(token)[-n_sentinel:]
+        assert not keep[tail].any()
+        assert np.all(np.asarray(gates)[-n_sentinel:] == 0.0)
+        assert keep[np.asarray(token)[:-n_sentinel]].all()
+
+
+@pytest.mark.parametrize("cap_factor", [4.0, 0.6])
+def test_last_expert_serves_real_and_sentinel_rows(cap_factor):
+    """Expert E-1 is every valid token's first choice AND hosts the
+    sentinel rows: its real assignments keep their ranks and drops
+    (y equals the einsum path), the sentinel rows add exactly 0."""
+    g, e, k, d = 48, 4, 2, 8
+    logits = _logits(g, e, seed=11).at[:, e - 1].add(6.0)
+    x = jax.random.normal(jax.random.key(12), (g, d), F32)
+    valid = _valid_mask(g, seed=13, p=0.6)
+    cap = expert_capacity(g, k, e, cap_factor)
+    _, group_sizes, _, _, _ = route_topk_sorted(
+        logits, k, cap, valid=valid, dtype=F32
+    )
+    n_valid = int(jnp.sum(valid))
+    assert 0 < n_valid < g
+    # Every valid token picked E-1, plus k sentinel rows per invalid.
+    assert int(group_sizes[e - 1]) == n_valid + k * (g - n_valid)
+    y0, aux0, z0 = _einsum_out(logits, x, k, cap, valid=valid)
+    y1, aux1, z1 = _sorted_out(logits, x, k, cap, valid=valid)
+    np.testing.assert_allclose(y0, y1, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(aux0, aux1, rtol=1e-6)
+    np.testing.assert_allclose(z0, z1, rtol=1e-6)
+    assert np.all(np.asarray(y1)[~np.asarray(valid)] == 0.0)
+    assert np.any(np.asarray(y1)[np.asarray(valid)] != 0.0)
+
+
+def _moe_layers(lora_rank):
+    """The (einsum, sorted) MoE layers of ``_tiny``, same param tree."""
+    return tuple(
+        MoEMLP(dataclasses.replace(_tiny(mode), lora_rank=lora_rank))
+        for mode in ("einsum", "sorted")
+    )
+
+
+@pytest.mark.parametrize("lora_rank", [0, 4])
+def test_moe_layer_grads_match_einsum_under_valid_mask(lora_rank):
+    """d(loss)/d(stack) through ragged_dot with the stacks as stored:
+    the sentinel rows multiply against expert E-1 but reach the loss
+    through a zero gate, so expert E-1's gradient (and every other
+    stack's) equals the einsum path's."""
+    m0, m1 = _moe_layers(lora_rank)
+    b, t, d = 2, 16, 32
+    x = jax.random.normal(jax.random.key(20), (b, t, d), F32)
+    valid = _valid_mask(b * t, seed=21, p=0.6).reshape(b, t)
+    params = jax.jit(m0.init)(jax.random.key(22), x)["params"]
+    params = _live_lora_b(params)
+
+    def loss(model):
+        def f(p, xin):
+            y, aux = model.apply({"params": p}, xin, valid)
+            return jnp.sum(jnp.square(y)) + aux, y
+
+        return f
+
+    (l0, y0), g0 = jax.value_and_grad(loss(m0), (0, 1), has_aux=True)(
+        params, x
+    )
+    (l1, y1), g1 = jax.value_and_grad(loss(m1), (0, 1), has_aux=True)(
+        params, x
+    )
+    np.testing.assert_allclose(l0, l1, rtol=1e-5)
+    np.testing.assert_allclose(y0, y1, rtol=2e-4, atol=2e-5)
+    assert np.all(np.asarray(y1)[~np.asarray(valid)] == 0.0)
+    flat1 = dict(jax.tree_util.tree_leaves_with_path(g1))
+    stacks = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(g0):
+        assert np.all(np.isfinite(flat1[path]))
+        np.testing.assert_allclose(
+            leaf, flat1[path], rtol=5e-4, atol=5e-5,
+            err_msg=jax.tree_util.keystr(path),
+        )
+        name = jax.tree_util.keystr(path)
+        if "w_" in name and leaf.ndim == 3:
+            stacks += 1
+            assert leaf.shape[0] == 4
+            assert np.any(np.asarray(leaf)[-1] != 0.0), name
+    assert stacks == (9 if lora_rank else 3)
+
+
+@pytest.mark.parametrize("lora_rank", [0, 4])
+@pytest.mark.parametrize("with_valid", [False, True])
+def test_sorted_lowering_has_no_expert_stack_copy(lora_rank, with_valid):
+    """The copy cannot come back unnoticed: the lowered sorted layer
+    holds no [E+1, ...] stack-shaped tensor (the zero expert the
+    sentinel group used to need), so no concatenate/pad builds one
+    and ragged_dot reads the [E, in, out] parameters themselves."""
+    _, m1 = _moe_layers(lora_rank)
+    e = m1.cfg.n_experts
+    x = jnp.zeros((2, 16, 32), F32)
+    valid = jnp.ones((2, 16), bool) if with_valid else None
+    params = jax.eval_shape(m1.init, jax.random.key(0), x)["params"]
+    # Lowered for the chip (no chip needed): there ragged_dot stays one
+    # op, chlo.ragged_dot; XLA:CPU expands it into masked dense dots.
+    text = (
+        jax.jit(lambda p, xin, v: m1.apply({"params": p}, xin, v))
+        .trace(params, x, valid)
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+    grown = re.findall(rf"tensor<{e + 1}x\d+x\d+x\w+>", text)
+    assert not grown, sorted(set(grown))
+    # Every ragged_dot reads an [E, in, out] stack and E group sizes.
+    calls = re.findall(
+        r"chlo\.ragged_dot.*: \(tensor<\d+x\d+x\w+>, "
+        r"tensor<(\d+)x\d+x\d+x\w+>, tensor<(\d+)xi32>\)",
+        text,
+    )
+    assert len(calls) == (9 if lora_rank else 3)
+    assert set(calls) == {(str(e), str(e))}, calls

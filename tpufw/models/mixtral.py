@@ -252,6 +252,11 @@ class MoEMLP(nn.Module):
         aux losses) are pinned identical to the einsum path by
         ``tests/test_moe_sorted.py``.
 
+        The stacks go to ragged_dot as they are stored, [E, in, out]:
+        invalid-token rows sort last, ride in expert E-1's group and
+        carry a zero gate (``route_topk_sorted``), so no zero expert
+        is appended and no copy the size of a stack is made per call.
+
         Single-device / data-sharded only: the expert weight stacks
         stay whole. Sharding the ``expert`` mesh axis needs the einsum
         path, whose dispatch tensors ARE the all-to-all (module doc of
@@ -269,24 +274,15 @@ class MoEMLP(nn.Module):
         )
         xs = x.reshape(g, d).astype(cfg.dtype)[token]  # [k*G, d]
 
-        def pad(stack):
-            # Sentinel group E (invalid-token assignments) multiplies
-            # against one zero expert; ragged_dot needs sum(group
-            # sizes) == rows, so the group must exist.
-            return jnp.concatenate(
-                [
-                    stack.astype(cfg.dtype),
-                    jnp.zeros((1, *stack.shape[1:]), cfg.dtype),
-                ]
-            )
-
         def grouped(name, shape, names, inp):
             w, a, bw = self._expert_weights(name, shape, names)
-            y = jax.lax.ragged_dot(inp, pad(w), group_sizes)
+            y = jax.lax.ragged_dot(inp, w.astype(cfg.dtype), group_sizes)
             if a is not None:
-                lo = jax.lax.ragged_dot(inp, pad(a), group_sizes)
+                lo = jax.lax.ragged_dot(
+                    inp, a.astype(cfg.dtype), group_sizes
+                )
                 y = y + jax.lax.ragged_dot(
-                    lo, pad(bw), group_sizes
+                    lo, bw.astype(cfg.dtype), group_sizes
                 ) * (
                     getattr(cfg, "lora_alpha", 16.0)
                     / getattr(cfg, "lora_rank", 0)

@@ -100,16 +100,20 @@ def route_topk_sorted(
     of every token before slot 1, earlier tokens first) keep their
     sorted position but get a ZERO combine weight, so they contribute
     nothing (the residual stream carries the token), at the cost of
-    computing the dropped rows. Invalid tokens (``valid`` False) route
-    to a sentinel group E with zero weight.
+    computing the dropped rows. Invalid tokens (``valid`` False) sort
+    LAST under a sentinel id E with zero weight, and ride in the last
+    real group: their count is folded into ``group_sizes[E-1]``, so
+    they multiply against expert E-1 (finite x a zero gate, exactly
+    like dropped rows) and the expert weight stacks go to ragged_dot
+    as they are stored. Expert E-1's real assignments sort before the
+    sentinel rows, so their ranks and capacity drops are untouched.
 
-    Returns (token [k*G], group_sizes [E+1], gates [k*G], aux_lb,
-    z): ``token[i]`` is the source token id of the
-    i-th SORTED assignment (gather ``x[token]`` to build the grouped
-    input), ``group_sizes`` counts sorted assignments per expert with
-    the sentinel group last (pad the expert weight stacks with one
-    zero expert for ragged_dot), ``gates`` is the combine weight per
-    sorted assignment.
+    Returns (token [k*G], group_sizes [E], gates [k*G], aux_lb, z):
+    ``token[i]`` is the source token id of the i-th SORTED assignment
+    (gather ``x[token]`` to build the grouped input), ``group_sizes``
+    counts sorted rows per expert and always sums to k*G (every row
+    of ragged_dot's output is defined), ``gates`` is the combine
+    weight per sorted assignment.
     """
     g, e = router_logits.shape
     probs, topk_probs, topk_idx = _topk_select(
@@ -130,12 +134,14 @@ def route_topk_sorted(
 
     order = jnp.argsort(eids, stable=True)
     sorted_eids = eids[order]
-    group_sizes = jnp.bincount(eids, length=e + 1).astype(jnp.int32)
-    starts = jnp.cumsum(group_sizes) - group_sizes  # [E+1]
+    counts = jnp.bincount(eids, length=e + 1).astype(jnp.int32)
+    starts = jnp.cumsum(counts) - counts  # [E+1], sentinel last
     rank = jnp.arange(k * g, dtype=jnp.int32) - starts[sorted_eids]
     gates = jnp.where(
         (rank < capacity) & (sorted_eids < e), gates_flat[order], 0.0
     ).astype(dtype)
+    # The sentinel rows ride in the last real group (zero gate).
+    group_sizes = counts[:e].at[e - 1].add(counts[e])
 
     # Aux statistics: identical formulas to route_topk_capacity, on
     # the same valid-masked top-1 assignment mask.
