@@ -3,8 +3,9 @@ connection per request, each sent when it is due whatever the server is
 doing (open loop) and timed on this clock from the moment it was due.
 
 The measured window's load comes from a process of its own (``main``
-below, started by the serve phase), so that the generator never waits for
-the interpreter lock of the server it is loading. Times are wall-clock
+below, started by the serve phase, and killed by the kernel should that
+phase die), so that the generator never waits for the interpreter lock of
+the server it is loading. Times are wall-clock
 seconds, shared with the serve phase's scrapes and profiler."""
 
 from __future__ import annotations
@@ -123,8 +124,9 @@ def main(argv=None) -> int:
     drives it, and writes the records to ``--out``."""
     import argparse
 
-    from benchmarks import harness, traffic
+    from benchmarks import harness, procs, traffic
 
+    procs.die_with_parent()
     ap = argparse.ArgumentParser()
     ap.add_argument("--port", type=int, required=True)
     ap.add_argument("--workload", required=True)

@@ -4,8 +4,11 @@ Nothing here imports jax, so the launcher stays off the chip."""
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
 import os
+
+from benchmarks import costs
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmarks")
@@ -71,6 +74,34 @@ def family_modules(family: str):
     )
 
 
+def rehearse_path(family: str) -> str:
+    return os.path.join("benchmarks", "configs", "rehearse", family + ".json")
+
+
+def missing_parts(bench: dict, cell: dict, config: dict) -> list:
+    """What this cell is made of and lacks, a line each naming the file:
+    the family's plain reference, adapter, tiny rehearsal widths and cost
+    functions, the traffic mix, and the reader of each per-layer metric
+    the cell reports. Looks modules up without importing them (the
+    references import jax); the cost functions, which are plain
+    arithmetic, it imports."""
+    family, out = config["family"], []
+    for pkg in ("reference", "adapters"):
+        if importlib.util.find_spec(f"benchmarks.{pkg}.{family}") is None:
+            out.append(f"benchmarks/{pkg}/{family}.py is missing")
+    for rel in (rehearse_path(family), traffic_path(cell["traffic"])):
+        if not os.path.exists(os.path.join(ROOT, rel)):
+            out.append(f"{rel} is missing")
+    try:
+        costs.of(family)
+    except KeyError as e:
+        out.append(e.args[0])
+    for m in metrics_of(bench, cell["name"], "per_layer"):
+        if importlib.util.find_spec(f"benchmarks.metrics.{m['name']}") is None:
+            out.append(f"benchmarks/metrics/{m['name']}.py, the reader of per-layer metric {m['name']}, is missing")
+    return out
+
+
 def model_keys(config: dict) -> dict:
     """The configuration's published keys, without the harness's own."""
     own = {"family", "runner", "source", "reduced", "assumed", "deployment", "memory", "note", "check"}
@@ -84,5 +115,5 @@ def cell_inputs(cell: dict, config: dict, rehearse: bool):
     mix = load_json(traffic_path(cell["traffic"]))
     if not rehearse:
         return mix, model_keys(config), config["check"]
-    tiny = load_json(f"benchmarks/configs/rehearse/{config['family']}.json")
+    tiny = load_json(rehearse_path(config["family"]))
     return {**mix, **mix["rehearse"]}, model_keys(tiny), tiny["check"]

@@ -22,16 +22,21 @@ def decode_step_ms(trace: dict):
     return statistics.median(prog["step_ms"])
 
 
-def live_rows_and_tokens(obs: dict):
-    """Rows decoding and cache tokens live at the window's midpoint, from
-    the client's records."""
+def live_row_tokens(obs: dict) -> list:
+    """Cache tokens (prompt + generated so far) of each row decoding at
+    the window's midpoint, from the client's records."""
     mid = obs["t0"] + obs["seconds"] / 2.0
-    rows = tokens = 0
+    out = []
     for r in obs["records"]:
         if not r["chunks"] or r["chunks"][0][0] > mid:
             continue
         if r["done"] is not None and r["done"] <= mid:
             continue
-        rows += 1
-        tokens += r["n_prompt"] + sum(n for t, n in r["chunks"] if t <= mid)
-    return rows, tokens
+        out.append(r["n_prompt"] + sum(n for t, n in r["chunks"] if t <= mid))
+    return out
+
+
+def live_rows_and_tokens(obs: dict):
+    """Rows decoding and cache tokens live at the window's midpoint."""
+    per_row = live_row_tokens(obs)
+    return len(per_row), sum(per_row)

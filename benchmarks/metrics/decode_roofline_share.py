@@ -1,8 +1,9 @@
 """Kernels: the least time the chip could take for one decode step (the
-bytes it has to read — ``costs.decode_step_bytes`` at the rows and cache
-tokens live at mid-window — over the chip's published bandwidth) as a share
-of the decode step's measured device time. Memory-bound: a decode step of
-tens of rows is far below the compute roof."""
+bytes it has to move — ``costs.decode_step_bytes`` at each live row's
+cache tokens at mid-window, so a family's per-row state counts where it has
+one — over the chip's published bandwidth) as a share of the decode step's
+measured device time. Memory-bound: a decode step of tens of rows is far
+below the compute roof."""
 
 from benchmarks import costs, harness
 from benchmarks.metrics import _steps
@@ -14,7 +15,7 @@ def read(obs: dict):
     step_ms = _steps.decode_step_ms(obs["trace"])
     if not step_ms:
         return None
-    rows, tokens = _steps.live_rows_and_tokens(obs)
-    need = costs.decode_step_bytes(obs["family"], obs["config"], rows, tokens)
+    per_row = _steps.live_row_tokens(obs)
+    need = costs.decode_step_bytes(obs["family"], obs["config"], len(per_row), per_row)
     floor_ms = need / harness.peaks(obs["device"]["kind"])["hbm_bytes_per_s"] * 1e3
     return 100.0 * floor_ms / step_ms

@@ -12,6 +12,10 @@ touches jax (a chip belongs to one process at a time):
 2. ``check``: the plain reference, alone on the chip, over that sample:
    how far below the reference's best logit each served token lies.
 
+Each phase is a process group of its own that ends with the launcher,
+however the launcher ends, and leaves by ``os._exit`` on every path
+(``benchmarks/procs.py``).
+
 The one substitution in the program: ``serve.build_generator`` is rebound
 to hand the server the benchmark's weights (after the program's own
 ``_maybe_quantize`` and ``_maybe_unroll``). See PERF.md, "What must change
@@ -28,7 +32,7 @@ import subprocess
 import sys
 import time
 
-from benchmarks import harness, stats, traffic
+from benchmarks import harness, procs, stats, traffic
 
 TRACE_SECONDS = 6.0
 
@@ -47,7 +51,7 @@ def scratch_dir(args) -> str:
     return d
 
 
-def _child(args, phase: str) -> int:
+def _phase_cmd(args, phase: str) -> list:
     cmd = [
         sys.executable, os.path.join(harness.HERE, "run.py"),
         "--workload", args.workload, "--seed", str(args.seed),
@@ -60,26 +64,26 @@ def _child(args, phase: str) -> int:
         cmd += ["--control", args.control]
     if args.broken:
         cmd += ["--break", args.broken]
-    return subprocess.run(cmd, cwd=harness.ROOT).returncode
+    return cmd
 
 
 def main(args, bench: dict, cell: dict, config: dict) -> int:
-    if args.phase == "serve":
-        return serve_phase(args, bench, cell, config)
-    if args.phase == "check":
-        return check_phase(args, bench, cell, config)
+    phases = {"serve": serve_phase, "check": check_phase}
+    if args.phase:
+        # A phase holds the device, and after the serve phase's window the
+        # scheduler's thread never ends and still drives it: every way out
+        # leaves without the interpreter's teardown, which would race it.
+        procs.exit_after(lambda: phases[args.phase](args, bench, cell, config))
     out = scratch_dir(args)
     for name in ("serve.json", "check.json", "served.json"):
         if os.path.exists(os.path.join(out, name)):
             os.remove(os.path.join(out, name))
-    rc = _child(args, "serve")
-    if rc != 0:
-        print(f"bench: the serve phase exited {rc}; no result", file=sys.stderr)
-        return rc
-    rc = _child(args, "check")
-    if rc != 0:
-        print(f"bench: the check phase exited {rc}; no result", file=sys.stderr)
-        return rc
+    with procs.Launcher(harness.SCRATCH) as launcher:
+        for phase in phases:
+            rc = launcher.run(_phase_cmd(args, phase), harness.ROOT)
+            if rc != 0:
+                print(f"bench: the {phase} phase exited {rc}; no result", file=sys.stderr)
+                return rc
     with open(os.path.join(out, "serve.json")) as f:
         served = json.load(f)
     with open(os.path.join(out, "check.json")) as f:
@@ -99,7 +103,13 @@ def main(args, bench: dict, cell: dict, config: dict) -> int:
         result["breakdown"] = served["breakdown"]
     if args.rehearse_cpu:
         result["rehearsal"] = "cpu, tiny widths: no number here is a measurement"
+    # Each number that decided `correct` beside its limit: last in the line,
+    # and the last lines on standard error.
+    result["compared"] = {**served["compared"], **checked["compared"]}
     print(json.dumps(result), flush=True)
+    for name, c in result["compared"].items():
+        print(f"bench: compare {name}={c['value']} limit={c['limit']}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 
@@ -208,7 +218,7 @@ def serve_phase(args, bench: dict, cell: dict, config: dict) -> int:
             # The program's own quantization, traced as one program over a
             # donated tree: run leaf by leaf it holds the bfloat16 tree, the
             # int8 tree and float32 temporaries of the largest leaf at once,
-            # more than the chip has for Mixtral.
+            # more than the chip has for the cell with the widest experts.
             made = {}
 
             def quantize(p):
@@ -268,11 +278,7 @@ def serve_phase(args, bench: dict, cell: dict, config: dict) -> int:
 
     _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
             compiles, setup_s, out_dir, client, jax)
-    # The scheduler's thread never ends and still holds the device: leave
-    # without the interpreter's teardown, which would race it.
-    sys.stdout.flush()
-    sys.stderr.flush()
-    os._exit(0)
+    return 0
 
 
 def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
@@ -297,7 +303,7 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
            "--drain", str(drain), "--out", rec_path]
     if rehearse:
         cmd.append("--rehearse-cpu")
-    gen = subprocess.Popen(cmd, cwd=harness.ROOT, stdout=subprocess.PIPE, text=True)
+    gen = subprocess.Popen(cmd, cwd=harness.ROOT, env=procs.child_env(), stdout=subprocess.PIPE, text=True)
     try:
         t0 = json.loads(gen.stdout.readline())["t0"]
 
@@ -329,6 +335,9 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
         if gen.poll() is None:
             gen.kill()
             gen.wait()
+    if args.broken == "raise":
+        # Test only: the phase fails with the server up and the device held.
+        raise RuntimeError("--break raise: the serve phase fails once its window has closed")
     with open(rec_path) as f:
         run = json.load(f)
     records, cutoff = run["records"], run["cutoff"]
@@ -364,10 +373,11 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
         f"{cached} tokens in their caches; backlog {ws['backlog_start']} -> {ws['backlog_end']}")
     counts = ("attempted", "failed", "tokens_in_window", "n_ttft", "n_tpot", "backlog_start", "backlog_end")
     say("window " + json.dumps({k: v for k, v in ws.items() if not rehearse or k in counts}))
-    say(f"compare requests_failed={ws['failed']} limit=0")
-    say(f"compare replies_malformed={bad} limit=0")
-    say(f"compare compiled_in_window={compiled_in_window} limit=0"
-        + (f" {[n for n, _ in compiles.programs[c0:c1]]}" if compiled_in_window else ""))
+    compared = {"requests_failed": ws["failed"], "replies_malformed": bad, "compiled_in_window": compiled_in_window}
+    for name, value in compared.items():
+        say(f"compare {name}={value} limit=0")
+    if compiled_in_window:
+        say(f"programs built in the window: {[n for n, _ in compiles.programs[c0:c1]]}")
 
     e2e = {}
     for m in harness.metrics_of(bench, cell["name"], "end_to_end"):
@@ -382,6 +392,12 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
         "device": device, "family": config["family"], "config": keys,
         "trace": None, "rehearse": rehearse,
     }
+    from benchmarks.metrics import _phases
+
+    phases = _phases.deltas(obs)
+    if phases:
+        say(f"scheduler thread, self seconds by phase between the window's two scrapes ({seconds:.0f} s): "
+            + json.dumps(dict(sorted(phases.items(), key=lambda kv: -kv[1]))))
     breakdown = None
     if traced:
         from benchmarks import trace_reduce
@@ -430,6 +446,7 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
             "attempted": ws["attempted"], "failed": ws["failed"], "metrics": metrics,
             "device": device, "breakdown": breakdown, "replies_ok": bad == 0 and ws["attempted"] > 0,
             "compiled_in_window": compiled_in_window, "digest": digest,
+            "compared": {k: {"value": v, "limit": 0} for k, v in compared.items()},
         }, f)
 
 
@@ -474,7 +491,7 @@ def check_phase(args, bench: dict, cell: dict, config: dict) -> int:
     if not served["sample"]:
         say("no request was served two tokens: nothing to compare with the reference, so not correct")
         with open(os.path.join(out_dir, "check.json"), "w") as f:
-            json.dump({"within_limits": False}, f)
+            json.dump({"within_limits": False, "compared": {"sequences_to_check": {"value": 0, "limit": "1 or more"}}}, f)
         return 0
     gaps, margins, top2 = [], [], []
     for s in served["sample"]:
@@ -499,12 +516,13 @@ def check_phase(args, bench: dict, cell: dict, config: dict) -> int:
         f"{all_gaps.size} served tokens in {time.time() - t:.1f} s: logit_noise over all of them, the gaps over "
         f"{got['tokens']} ({got['left_out']} routed within {check['routing_margin']} of a tie are left out, "
         f"{100 * got['moved_share']:.2f}% of the rest are not the reference's first choice)")
-    say(f"compare logit_noise={got['logit_noise']:.6f} limit={check['logit_noise']}")
-    say(f"compare gap_max={got['gap_max']:.6f} limit={check['gap_max']}")
-    say(f"compare gap_mean={got['gap_mean']:.6f} limit={check['gap_mean']}")
-    within = all(got[k] <= check[k] for k in ("logit_noise", "gap_max", "gap_mean"))
+    numbers = ("logit_noise", "gap_max", "gap_mean")
+    for k in numbers:
+        say(f"compare {k}={got[k]:.6f} limit={check[k]}")
+    within = all(got[k] <= check[k] for k in numbers)
     with open(os.path.join(out_dir, "check.json"), "w") as f:
         json.dump({"within_limits": bool(within), **got,
+                   "compared": {k: {"value": got[k], "limit": check[k]} for k in numbers},
                    # Per position, for whoever sets the limits anew: the served token's gap,
                    # the reference's own top-two margin and its routing margin.
                    "positions": {"gap": all_gaps.round(5).tolist(), "top2": top2.round(5).tolist(),

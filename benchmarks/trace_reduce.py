@@ -96,15 +96,20 @@ def host_events(planes: dict) -> list:
 
 
 def attribute_gap(gap, host) -> str:
-    """What the host was doing in an idle gap: the host event that covers
-    most of it, else "waiting for a request"."""
+    """What the host was doing in an idle gap. Spans nest, and an outer one
+    covers whatever its inner ones do, so the answer is the innermost that
+    still accounts for the gap: of the host events that cover more than
+    half of it, the shortest. Where none does, the event that covers most
+    of it; with no event at all, "waiting for a request"."""
     gs, gd = gap
-    best, best_cover = "waiting for a request", 0.0
+    best, best_cover, inner, inner_dur = "waiting for a request", 0.0, None, 0.0
     for name, s, d, _ in host:
         cover = min(gs + gd, s + d) - max(gs, s)
         if cover > best_cover:
             best, best_cover = name, cover
-    return best
+        if cover > gd / 2.0 and (inner is None or d < inner_dur):
+            inner, inner_dur = name, d
+    return best if inner is None else inner
 
 
 def loop_steps(ops, start: float, dur: float) -> int:

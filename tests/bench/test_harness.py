@@ -8,7 +8,7 @@ import re
 
 import pytest
 
-from benchmarks import harness
+from benchmarks import costs, harness
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -73,7 +73,7 @@ def test_every_file_resolves_by_name(bench):
         config = harness.load_json(c["file"])
         importlib.import_module(f"benchmarks.runners.{config['runner']}")
         ref, adapter = harness.family_modules(config["family"])
-        assert ref.FAMILY == adapter.FAMILY == config["family"]
+        assert ref.FAMILY == adapter.FAMILY == costs.of(config["family"]).FAMILY == config["family"]
         assert sorted(c["reduced"]) == sorted(config["reduced"])
         assert len(c["reduced"]) <= 16
         for key in c["reduced"]:
@@ -86,6 +86,8 @@ def test_every_file_resolves_by_name(bench):
             os.path.join(harness.ROOT, "benchmarks", "configs", "rehearse", config["family"] + ".json")
         )
     for w in bench["workloads"]:
+        config = harness.load_json(harness.config_entry(bench, w["config"])["file"])
+        assert harness.missing_parts(bench, w, config) == [], "what the launcher checks before it starts a process"
         mix = harness.load_json(harness.traffic_path(w["traffic"]))
         assert {"arrivals", "prompt", "output", "server_env", "rehearse", "ramp_s", "drain_s", "shape_seed"} <= set(mix)
     for m in bench["per_layer"]:

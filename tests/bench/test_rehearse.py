@@ -32,7 +32,7 @@ def test_rehearsal_of_each_cell(cell):
     assert proc.returncode == 0, proc.stderr[-2000:]
     result = json.loads(lines[-1])
     assert CONTRACT_KEYS <= set(result)
-    assert set(result) <= CONTRACT_KEYS | {"breakdown", "rehearsal"}
+    assert set(result) <= CONTRACT_KEYS | {"breakdown", "rehearsal", "compared"}
     assert result["device"]["platform"] == "cpu"
     assert result["metrics"] == {}, "a CPU run prints no metric under a device metric's name"
     assert "rehearsal" in result and "REHEARSAL" in proc.stdout
@@ -40,6 +40,12 @@ def test_rehearsal_of_each_cell(cell):
     shown = {ln.split()[2].split("=")[0] for ln in lines if ln.startswith("bench: compare ")}
     assert shown == {"requests_failed", "replies_malformed", "compiled_in_window", "logit_noise",
                      "gap_max", "gap_mean"}
+    # Each number compared beside its limit: the result line's last key, and standard error's last lines.
+    assert list(result)[-1] == "compared" and set(result["compared"]) == shown
+    assert all(c["value"] <= c["limit"] for c in result["compared"].values())
+    last = [ln for ln in proc.stderr.splitlines() if ln.strip()][-len(shown):]
+    assert [ln.split()[2].split("=")[0] for ln in last] == list(result["compared"])
+    assert "self seconds by phase" in proc.stdout and '"serve_wait"' in proc.stdout
     for name in ("tokens_per_s_per_chip", "tpot_p50_ms", "ttft_p50_ms", "setup_s "):
         assert f'"{name}' not in proc.stdout.replace("setup_s ", "")
 

@@ -44,6 +44,27 @@ def test_reduce(planes):
     assert trace_reduce.attribute_gap((20000, 100), []) == "waiting for a request"
 
 
+def test_gap_goes_to_the_innermost_span_that_accounts_for_it():
+    """Spans nest: a gap inside ``serve_prefill_chunk`` > ``serve_row_alloc``
+    reads the inner one, whichever the trace lists first, and still does
+    where the gap begins a little before the inner span (the device runs
+    dry while the outer one is still scanning its queue: what the chip
+    showed, 0.72 s of a 0.83 s gap). A span that covers less than half of
+    the gap does not account for it."""
+    outer, inner = ("serve_prefill_chunk", 1000.0, 9000.0, {}), ("serve_row_alloc", 2000.0, 6000.0, {})
+    thread = ("serve_loop", 0.0, 50000.0, {})
+    for host in ([outer, inner, thread], [thread, inner, outer]):
+        assert trace_reduce.attribute_gap((3000.0, 4000.0), host) == "serve_row_alloc"
+        assert trace_reduce.attribute_gap((1500.0, 6000.0), host) == "serve_row_alloc"  # 5500 of 6000
+        assert trace_reduce.attribute_gap((3000.0, 6500.0), host) == "serve_row_alloc"  # 5000 of 6500
+        assert trace_reduce.attribute_gap((6000.0, 3900.0), host) == "serve_row_alloc"  # 2000 of 3900
+        assert trace_reduce.attribute_gap((6000.0, 4000.0), host) == "serve_prefill_chunk"  # 2000 of 4000: not more than half
+        assert trace_reduce.attribute_gap((9000.0, 8000.0), host) == "serve_loop"
+    # No event covers more than half: the one that covers most, as before.
+    assert trace_reduce.attribute_gap((7000.0, 10000.0), [inner, outer]) == "serve_prefill_chunk"
+    assert trace_reduce.attribute_gap((60000.0, 100.0), [outer, inner, thread]) == "waiting for a request"
+
+
 def test_traced_stretch_and_chunk_widths(planes):
     """The stretch is the host clock's between the profiler's start and
     stop where that is longer than first-to-last device operation: idle
