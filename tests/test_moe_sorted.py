@@ -356,3 +356,161 @@ def test_sorted_lowering_has_no_expert_stack_copy(lora_rank, with_valid):
     )
     assert len(calls) == (9 if lora_rank else 3)
     assert set(calls) == {(str(e), str(e))}, calls
+
+
+# ------------------------------ held experts and sigmoid scoring (PR 28)
+
+def _moe_layer(family, dispatch, held=None):
+    """One expert layer at test widths: Mixtral's MoEMLP, or DeepSeek's
+    routed + shared experts."""
+    from tpufw.models.deepseek import DeepseekConfig, DeepseekMoE
+
+    if family == "mixtral":
+        cfg = MixtralConfig(
+            vocab_size=256, d_model=64, n_layers=1, n_heads=4, n_kv_heads=2,
+            head_dim=16, d_ff=128, n_experts=8, experts_per_token=2,
+            capacity_factor=4.0, moe_dispatch=dispatch, remat=False,
+            dtype=F32, param_dtype=F32,
+        )
+        return MoEMLP(cfg, held=held)
+    return DeepseekMoE(DeepseekConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=4, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, d_ff=128,
+        n_routed_experts=8, experts_per_token=2, moe_d_ff=48,
+        n_shared_experts=2, capacity_factor=4.0, moe_dispatch=dispatch,
+        remat=False, scan_layers=False, dtype=F32, param_dtype=F32,
+        experts_held=held,
+    ))
+
+
+@pytest.mark.parametrize("dispatch", ["sorted", "einsum"])
+@pytest.mark.parametrize("family", ["mixtral", "deepseek"])
+def test_all_experts_held_is_the_same_program(family, dispatch):
+    """``held`` naming every expert, with softmax scoring, lowers to the
+    text the layer lowers to without the option — the program the two
+    benchmark families ran before the option existed (compared with the
+    parent commit's text by hand in PR 28: byte-identical)."""
+    x = jnp.zeros((2, 16, 64), F32)
+    valid = jnp.ones((2, 16), bool)
+
+    def text(mod):
+        p = jax.eval_shape(
+            lambda a, b: mod.init(jax.random.key(0), a, valid=b), x, valid
+        )
+        return jax.jit(
+            lambda p, a, b: mod.apply(p, a, valid=b)
+        ).lower(p, x, valid).as_text()
+
+    assert text(_moe_layer(family, dispatch, held=(0, 8))) == text(
+        _moe_layer(family, dispatch)
+    )
+
+
+def _reference_sigmoid_gates(logits, bias, k, norm):
+    """Dense [G, E] gates of sigmoid scoring with a selection bias, in
+    numpy: chosen by score + bias, weighed by the score alone."""
+    s = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+    idx = np.argsort(-(s + np.asarray(bias, np.float64)), axis=-1)[:, :k]
+    w = np.take_along_axis(s, idx, axis=-1)
+    if norm:
+        w = w / w.sum(-1, keepdims=True)
+    gates = np.zeros_like(s)
+    np.put_along_axis(gates, idx, w, axis=-1)
+    return gates, idx
+
+
+@pytest.mark.parametrize("norm_topk", [True, False])
+def test_sigmoid_scoring_with_a_bias_matches_the_reference(norm_topk):
+    g, e, k = 96, 16, 4
+    logits = _logits(g, e, seed=3)
+    bias = jax.random.normal(jax.random.key(4), (e,), F32) * 0.3
+    want, idx = _reference_sigmoid_gates(logits, bias, k, norm_topk)
+    _, idx0 = _reference_sigmoid_gates(logits, jnp.zeros((e,)), k, norm_topk)
+    assert (np.sort(idx, -1) != np.sort(idx0, -1)).any(), "the bias decides some choices"
+    cap = g  # dropless
+    dispatch, combine, _, _ = route_topk_capacity(
+        logits, k, cap, dtype=F32, norm_topk=norm_topk,
+        scoring="sigmoid", select_bias=bias,
+    )
+    np.testing.assert_allclose(np.asarray(combine.sum(-1)), want, atol=1e-6)
+    token, sizes, gates, _, _ = route_topk_sorted(
+        logits, k, cap, dtype=F32, norm_topk=norm_topk,
+        scoring="sigmoid", select_bias=bias,
+    )
+    eid = np.searchsorted(np.cumsum(np.asarray(sizes)), np.arange(k * g), side="right")
+    dense = np.zeros((g, e))
+    np.add.at(dense, (np.asarray(token), eid), np.asarray(gates))
+    np.testing.assert_allclose(dense, want, atol=1e-6)
+
+
+@pytest.mark.parametrize("with_valid", [False, True])
+def test_held_experts_take_their_share_and_nothing_else(with_valid):
+    """With ``held = (first, n)`` both routings return the columns
+    [first, first + n) of the whole layer's gates: the same selection
+    and weights over all E, assignments to experts held elsewhere in
+    the sentinel group with a zero gate; the shares add up."""
+    g, e, k, n = 64, 16, 4, 4
+    logits = _logits(g, e, seed=5)
+    bias = jax.random.normal(jax.random.key(6), (e,), F32) * 0.2
+    valid = (jnp.arange(g) % 5 != 0) if with_valid else None
+    kw = dict(dtype=F32, scoring="sigmoid", select_bias=bias, valid=valid)
+    whole = np.asarray(route_topk_capacity(logits, k, g, **kw)[1].sum(-1))
+    total = np.zeros_like(whole)
+    for first in range(0, e, n):
+        d, c, _, _ = route_topk_capacity(logits, k, g, held=(first, n), **kw)
+        assert d.shape == (g, n, g)
+        part = np.asarray(c.sum(-1))
+        np.testing.assert_allclose(part, whole[:, first:first + n], atol=1e-6)
+        token, sizes, gates, _, _ = route_topk_sorted(logits, k, g, held=(first, n), **kw)
+        assert sizes.shape == (n,) and int(sizes.sum()) == k * g
+        eid = np.searchsorted(np.cumsum(np.asarray(sizes)), np.arange(k * g), side="right")
+        dense = np.zeros((g, n))
+        np.add.at(dense, (np.asarray(token), eid), np.asarray(gates))
+        np.testing.assert_allclose(dense, part, atol=1e-6)
+        total[:, first:first + n] = part
+    np.testing.assert_allclose(total, whole, atol=1e-6)
+
+
+@pytest.mark.parametrize("dispatch", ["sorted", "einsum"])
+def test_the_eight_shares_add_up_to_the_uncut_layer(dispatch):
+    """The guide's share test on the program's layer: the routed parts
+    that the eight shares of an expert layer give, plus the shared
+    expert once, are the uncut layer's output."""
+    from tpufw.models.solar_open2 import SOLAR_OPEN2_CONFIGS
+    from tpufw.models.deepseek import DeepseekMoE
+
+    base = dataclasses.replace(
+        SOLAR_OPEN2_CONFIGS["solar_open2_tiny"], dtype=F32, param_dtype=F32,
+        moe_dispatch=dispatch, experts_held=None,
+    )
+    x = jax.random.normal(jax.random.key(0), (2, 24, base.d_model), F32)
+    whole = DeepseekMoE(base)
+    from flax.linen import meta
+
+    params = meta.unbox(whole.init(jax.random.key(1), x)["params"])
+    params["routed"]["router_bias"] = (
+        jax.random.normal(jax.random.key(2), (16,), F32) * 0.05
+    )
+    want, _ = whole.apply({"params": params}, x)
+    total = jnp.zeros_like(want)
+    for i in range(8):
+        part = dataclasses.replace(base, experts_held=(2 * i, 2))
+        p = {
+            "shared": params["shared"],
+            "routed": {
+                **params["routed"],
+                **{n: params["routed"][n][2 * i:2 * i + 2]
+                   for n in ("w_gate", "w_up", "w_down")},
+            },
+        }
+        y, _ = DeepseekMoE(part).apply({"params": p}, x)
+        total = total + y
+    # Every share added the shared expert: count it once.
+    from tpufw.models.llama import MLP
+
+    shared = MLP(base, d_ff=base.moe_d_ff * base.n_shared_experts).apply(
+        {"params": params["shared"]}, x
+    )
+    np.testing.assert_allclose(
+        np.asarray(total - 7 * shared), np.asarray(want), atol=2e-5
+    )

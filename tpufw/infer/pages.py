@@ -52,7 +52,14 @@ import numpy as np
 from tpufw.infer.generate import _model_apply, split_prefill_keys
 from tpufw.infer.prefix import PrefixCache
 from tpufw.infer.sampling import sample_token
-from tpufw.infer.slots import SlotPool, _retire_jit, _track_seen
+from tpufw.infer.slots import (
+    STATE_LEAVES,
+    SlotPool,
+    _retire_jit,
+    _track_seen,
+    reject_state,
+    state_leaf_bytes,
+)
 from tpufw.obs import trace as obs_trace
 from tpufw.ops.quant import dequantize_kv, quantize_kv
 
@@ -72,12 +79,20 @@ _ARENA_RANK = {
     "cached_key": 4, "cached_value": 4,  # llama-family K/V heads
     "cached_ckv": 3, "cached_kpe": 3,    # deepseek MLA latents
 }
+# The roles a paged cache leaf can have: an arena page (_ARENA_RANK,
+# its ``*_scale`` and ``cached_segment_ids``), a table or cursor
+# (``page_table``, ``cache_index``), or per-slot state (STATE_LEAVES,
+# named once in tpufw.infer.slots): [*stack, n_slots, *feat] in the
+# pool, [*stack, 1, *feat] in the row twin, where it is carried from
+# prefill chunk to prefill chunk.
 
 
 def _export_rank(name: str) -> Optional[int]:
     """Collapse rank of a leaf that travels in a page bundle (arena KV,
     page-structured scales, segment ids); None for per-slot leaves
-    (page_table, cache_index) the importer rebuilds locally."""
+    (page_table, cache_index) the importer rebuilds locally, and for
+    per-slot STATE, which no bundle carries yet: ``export_slot`` and
+    ``splice_slot`` refuse a pool that has any before they get here."""
     if name in _ARENA_RANK:
         return _ARENA_RANK[name]
     if name.endswith("_scale") or name == "cached_segment_ids":
@@ -116,7 +131,8 @@ def paged_pool_cache(model, params, n_slots: int):
     directly, so — unlike the contiguous ``pool_cache`` — no axis
     probing or trailing-slot-axis surgery is needed: the model's own
     init shapes ARE the pool shapes. Zeros are safe initial state
-    (page 0 reserved, segment 0 everywhere)."""
+    (page 0 reserved, segment 0 everywhere; per-slot state leaves come
+    [n_slots, *feat] and zero is a row's empty past)."""
 
     def init(p):
         toks = jnp.zeros((n_slots, 1), jnp.int32)
@@ -142,7 +158,8 @@ def _row_zeros_tree(row_model, params, home):
     Born committed at ``home`` like the pool's own state
     (SlotPool.__post_init__): the chunk programs see this canvas first
     and their own donated output after, and the two must be the same
-    argument to jit."""
+    argument to jit. Per-slot state leaves are in it at B=1, zero: the
+    row's own state from its first token on, never a slot's."""
 
     def init(p):
         toks = jnp.zeros((1, 1), jnp.int32)
@@ -298,6 +315,15 @@ def _paged_insert_jit(
             vals = _collapse_row(row_leaves[i], 2).astype(leaf.dtype)
             a = _collapse_arena(leaf, 2)
             out.append(a.at[:, phys, off].set(vals).reshape(leaf.shape))
+        elif name in STATE_LEAVES:
+            # Per-slot state: the row's, whole — nothing of the slot's
+            # previous occupant survives the insert.
+            rank = STATE_LEAVES[name]
+            a = _collapse_arena(leaf, rank)  # [stacks, n_slots, *feat]
+            row = _collapse_arena(row_leaves[i], rank)[:, 0]
+            out.append(
+                a.at[:, slot].set(row.astype(leaf.dtype)).reshape(leaf.shape)
+            )
         else:
             raise ValueError(
                 f"unknown paged cache leaf {name!r}: the paged insert "
@@ -367,6 +393,8 @@ def _attach_shared_jit(
                 rr.at[:, :length].set(g.astype(rr.dtype)).reshape(row.shape)
             )
         else:
+            # Per-slot state lands here too: shared pages do not
+            # determine it (PagedSlotPool._match_prefix declines first).
             raise ValueError(f"unknown row cache leaf {name!r}")
     return tuple(out)
 
@@ -586,8 +614,10 @@ def _prefill_chunk_jit(
                 quantized[i] = quantize_kv(win, n_feat=rank - 2)
     out = []
     for i, (name, leaf) in enumerate(zip(names, leaves)):
-        if name in ("page_table", "cache_index"):
-            out.append(leaf)  # finalize owns the pool-side cursors
+        if name in ("page_table", "cache_index") or name in STATE_LEAVES:
+            # finalize owns the pool-side cursors; per-slot state stays
+            # in the row twin until then (nothing to scatter).
+            out.append(leaf)
         elif name.endswith("_scale"):
             scales = quantized[scale_src[i]][1]
             a = _collapse_arena(leaf, 2)
@@ -691,6 +721,10 @@ class PagedSlotPool(SlotPool):
     prefix_misses: int = 0
     spill_pages_out: int = 0
     spill_pages_in: int = 0
+    #: Why this pool has no prefix trie although one was asked for
+    #: ("state_layers": the model keeps per-slot state). The scheduler
+    #: counts the admissions declined for it, by this reason.
+    prefix_decline: str = ""
 
     @classmethod
     def create_paged(
@@ -719,6 +753,9 @@ class PagedSlotPool(SlotPool):
                 f"shared allocator covers {allocator.n_pages} pages but "
                 f"cfg.kv_pages={cfg.kv_pages}"
             )
+        # Shared pages are K/V alone: a row attached to them would start
+        # its state layers from zero, silently wrong. No trie, counted.
+        stateful = prefix_cache and state_leaf_bytes(cache) > 0
         return cls(
             model=model,
             params=params,
@@ -739,7 +776,11 @@ class PagedSlotPool(SlotPool):
                 PageAllocator(int(cfg.kv_pages))
                 if allocator is None else allocator
             ),
-            prefix=PrefixCache(int(cfg.kv_page)) if prefix_cache else None,
+            prefix=(
+                PrefixCache(int(cfg.kv_page))
+                if prefix_cache and not stateful else None
+            ),
+            prefix_decline="state_layers" if stateful else "",
             slot_pages=[[] for _ in range(n_slots)],
         )
 
@@ -754,6 +795,16 @@ class PagedSlotPool(SlotPool):
         max_new - 1: a live row's cursor never passes its budget)."""
         return -(-need // self.page)
 
+    def _match_prefix(self, prompt: Sequence[int]) -> List[int]:
+        """Resident pages of the trie that ``prompt`` may attach: its
+        match, capped so >= 1 suffix token always remains (the first
+        output token's logits need a real forward pass). None of them
+        where the pool has no trie (``prefix_decline`` says why)."""
+        p = len(prompt)
+        if p <= 1 or self.prefix is None:
+            return []
+        return self.prefix.match(prompt)[: (p - 1) // self.page]
+
     def acquire_pages(
         self, prompt: Sequence[int], need: int
     ) -> Optional[Tuple[List[int], int]]:
@@ -765,11 +816,7 @@ class PagedSlotPool(SlotPool):
         retries after the next retire)."""
         p = len(prompt)
         n_total = self.n_pages_for(need)
-        shared: List[int] = []
-        if self.prefix is not None and p > 1:
-            # Cap so >= 1 suffix token always remains: the first output
-            # token's logits need a real forward pass.
-            shared = self.prefix.match(prompt)[: (p - 1) // self.page]
+        shared = self._match_prefix(prompt)
         # resource: acquires pages
         # Reference the shared pages FIRST so eviction below can't free
         # them out from under us (match() alone leaves refcount at 0
@@ -1040,7 +1087,8 @@ class PagedSlotPool(SlotPool):
         hit. Not trivia: ``_row_zeros_tree`` re-traces the row model on
         the host, every admission."""
         with self.tracer.span(
-            "serve_row_alloc", shared_pages=len(shared_ids)
+            "serve_row_alloc", shared_pages=len(shared_ids),
+            state_bytes=self.state_bytes // self.n_slots,
         ):
             row_tree = _row_zeros_tree(
                 self.row_model, self.params, self.home
@@ -1107,11 +1155,7 @@ class PagedSlotPool(SlotPool):
         prefill engine exporting prompt-only bundles)."""
         prompt = [int(t) for t in prompt]
         p = len(prompt)
-        shared: List[int] = []
-        if self.prefix is not None and p > 1:
-            # Same cap as acquire_pages: >= 1 suffix token must remain
-            # so the first output token's logits get a real forward.
-            shared = self.prefix.match(prompt)[: (p - 1) // self.page]
+        shared = self._match_prefix(prompt)
         # resource: acquires pages
         # ref() pins the shared pages host-side right now (eviction
         # can't reclaim them); their KV is gathered lazily by the
@@ -1298,7 +1342,9 @@ class PagedSlotPool(SlotPool):
     def release_slot(self, slot: int) -> int:
         """Free ``slot``: freeze its masks, zero its page-table row,
         return its pages to the allocator. Returns pages actually freed
-        (shared/held pages may stay resident)."""
+        (shared/held pages may stay resident). Per-slot state is left
+        as it lies: a freed slot's is read by nobody, and the next
+        ``insert_paged`` overwrites all of it."""
         # resource: releases pages
         # resource: releases slot
         self.done, self.remaining = _retire_jit(
@@ -1341,6 +1387,7 @@ class PagedSlotPool(SlotPool):
         so a row finishing mid-chunk exports the pages it owned when
         the chunk was launched, not whatever the list mutated to."""
         # resource: transfers slot
+        reject_state(self, "export_slot")
         ids = list(
             self.slot_pages[slot] if page_ids is None else page_ids
         )
@@ -1388,6 +1435,7 @@ class PagedSlotPool(SlotPool):
         mismatch — a bundle from a differently-shaped pool must be
         rejected before it scribbles on the arena."""
         # resource: transfers pages
+        reject_state(self, "splice_slot")
         if int(state["page"]) != self.page:
             raise ValueError(
                 f"bundle page size {state['page']} != pool page "

@@ -46,6 +46,39 @@ from tpufw.infer.sampling import SamplingConfig, sample_token
 # not recompile" without reaching into jax internals.
 TRACE_COUNTS: Dict[str, int] = {"insert": 0, "decode_steps": 0, "retire": 0}
 
+#: Cache leaves that are per-slot STATE, by unstacked rank with the
+#: batch axis first ([*stack, B, *feat]): what a row keeps between steps
+#: that is neither a page of keys and values nor a table or cursor —
+#: a linear-attention layer's recurrent state and its convolution's
+#: tail (tpufw.models.solar_open2.KDALayer). The third role a cache
+#: leaf can have; every program that moves rows in or out of a pool
+#: decides what it does with one (tpufw.infer.pages). A prefix of
+#: pages does not determine it, a verify block cannot rewind it and a
+#: page bundle does not carry it, so the prefix trie, speculation and
+#: slot export decline a model that has any (``reject_state``).
+STATE_LEAVES = {"kda_state": 4, "conv_state": 3}
+
+
+def state_leaf_bytes(cache) -> int:
+    """Bytes of per-slot state in a cache pytree (0: keys and values
+    only)."""
+    return sum(
+        int(leaf.nbytes)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+        if str(getattr(path[-1], "key", path[-1])) in STATE_LEAVES
+    )
+
+
+def reject_state(pool, what: str) -> None:
+    """The one refusal of what per-slot state makes wrong today."""
+    if getattr(pool, "state_bytes", 0):
+        raise ValueError(
+            f"{what}: {type(pool.model).__name__} keeps per-slot state "
+            f"({', '.join(STATE_LEAVES)}) beside its pages; snapshots of "
+            "state are not built yet, so this would continue from the "
+            "wrong state"
+        )
+
 
 def _track_seen(sampling: SamplingConfig) -> bool:
     return (
@@ -166,7 +199,9 @@ def _decode_steps_jit(
     a row emits its token THEN burns budget, so the EOS/boundary token
     itself is delivered and the row freezes after. Done rows keep
     stepping (static shapes; masking, not control flow) but feed pad
-    back and emit pad out.
+    back and emit pad out. Per-slot state (STATE_LEAVES) rides in
+    ``cache`` and is updated in place by the model, a done row's too:
+    it is junk from then on and the next insert overwrites all of it.
     """
     TRACE_COUNTS["decode_steps"] += 1
     apply = _model_apply(model, params)
@@ -269,6 +304,8 @@ class SlotPool:
         self.home = home
         for name in ("cache", "token", "pos", "done", "remaining", "seen"):
             setattr(self, name, jax.device_put(getattr(self, name), home))
+        #: Bytes of per-slot state (STATE_LEAVES) this pool holds.
+        self.state_bytes = state_leaf_bytes(self.cache)
 
     @classmethod
     def create(
@@ -371,6 +408,8 @@ class SlotPool:
         path). Returns (out [S, k+1], n_emit [S], accept [S])."""
         from tpufw.infer import speculative as _spec
 
+        reject_state(self, "speculative decoding")
+
         return _spec.spec_verify_steps(self, proposals, key)
 
     def spec_draft_steps(self, draft_pool, key, k: int):
@@ -378,6 +417,9 @@ class SlotPool:
         ``draft_pool`` (same slot count, cursors in lockstep).
         Returns (out [S, k+1], n_emit [S], accept [S])."""
         from tpufw.infer import speculative as _spec
+
+        reject_state(self, "speculative decoding")
+        reject_state(draft_pool, "speculative decoding (draft)")
 
         return _spec.spec_draft_steps(self, draft_pool, key, k)
 

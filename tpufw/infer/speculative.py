@@ -71,6 +71,7 @@ from tpufw.infer.generate import (
     prefill_cache,
 )
 from tpufw.infer.sampling import SamplingConfig, sample_token, transform_logits
+from tpufw.infer.slots import STATE_LEAVES
 
 # Trace-time counters for the CHUNKED slot-pool speculation below —
 # same contract as tpufw.infer.slots.TRACE_COUNTS: bumped once per
@@ -86,6 +87,12 @@ def _rollback(cache: dict, new_cursor: jax.Array) -> dict:
 
     def fix(path, leaf):
         name = getattr(path[-1], "key", None)
+        if name in STATE_LEAVES:
+            raise ValueError(
+                f"speculative decoding: cache leaf {name!r} is per-slot "
+                "state, which a rejected draft has already advanced and "
+                "no cursor can rewind"
+            )
         if name == "cache_index":
             # nn.scan stacks per-layer cursors into [L]; keep the shape.
             return jnp.full(leaf.shape, new_cursor, leaf.dtype)

@@ -22,6 +22,11 @@ from tpufw.models.mixtral import (  # noqa: F401
     MoEMLP,
 )
 from tpufw.models.resnet import ResNet, ResNetConfig, resnet50  # noqa: F401
+from tpufw.models.solar_open2 import (  # noqa: F401
+    SOLAR_OPEN2_CONFIGS,
+    SolarOpen2,
+    SolarOpen2Config,
+)
 from tpufw.models.vit import (  # noqa: F401
     VIT_CONFIGS,
     ViT,
@@ -50,6 +55,8 @@ def model_for_config(cfg):
         )
     if isinstance(cfg, DeepseekConfig):
         return Deepseek(cfg)
+    if isinstance(cfg, SolarOpen2Config):  # a LlamaConfig too: first
+        return SolarOpen2(cfg)
     if isinstance(cfg, MixtralConfig):
         return Mixtral(cfg)
     if isinstance(cfg, GemmaConfig):

@@ -34,9 +34,13 @@ MoEMLP) with the V2 gate conventions: raw softmax top-k mass (no
 renormalization — matching the HF reference's executed behavior) times
 ``routed_scaling_factor``, plus group-limited selection (the 236B/Chat
 ``topk_method="group_limited_greedy"`` — ``n_group``/``topk_group``)
-and yarn long-context rope scaling. Remaining import rejections
-(tools/import_hf.py): other topk_methods (e.g. V3's noaux_tc),
-non-softmax scoring, sparse ``moe_layer_freq``, and attention bias.
+and yarn long-context rope scaling. ``moe_scoring="sigmoid"`` scores
+each expert alone and chooses by score + a selection bias (the V3
+convention, tpufw.ops.moe), and ``experts_held`` tells the layer which
+routed experts this chip holds; tpufw.models.solar_open2 runs both.
+Remaining import rejections (tools/import_hf.py): other topk_methods
+(e.g. V3's noaux_tc group-limited form), a checkpoint's selection bias,
+sparse ``moe_layer_freq``, and attention bias.
 """
 
 from __future__ import annotations
@@ -135,6 +139,12 @@ class DeepseekConfig:
     # n_group=0 disables (plain greedy, the V2-Lite choice).
     n_group: int = 0
     topk_group: int = 0
+    # How the router scores ("softmax": V2; "sigmoid" with a selection
+    # bias: the V3 convention, tpufw.ops.moe._topk_select), and which
+    # routed experts this chip holds of each layer, (first, n); None =
+    # all (tpufw.models.mixtral.MoEMLP.held).
+    moe_scoring: str = "softmax"
+    experts_held: Optional[tuple] = None
     # GShard capacity discipline for the einsum dispatch; imports
     # default to dropless (n_routed_experts) like Mixtral's.
     capacity_factor: float = 1.25
@@ -671,6 +681,8 @@ class DeepseekMoE(nn.Module):
             group_limit=(
                 (cfg.n_group, cfg.topk_group) if cfg.n_group else None
             ),
+            scoring=cfg.moe_scoring,
+            held=cfg.experts_held,
             name="routed",
         )(x, valid=valid)
         y = routed * cfg.routed_scaling_factor

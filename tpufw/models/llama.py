@@ -558,9 +558,11 @@ class Attention(nn.Module):
             cfg, x, (cfg.n_kv_heads, cfg.head_dim), -1,
             ("embed",), ("kv_heads", "head_dim"), "v", use_bias=qkv_bias,
         )
-        rope_scaling = getattr(cfg, "rope_scaling", None)
-        q = apply_rope(q, positions, cfg.rope_theta, rope_scaling)
-        k = apply_rope(k, positions, cfg.rope_theta, rope_scaling)
+        if getattr(cfg, "use_rope", True):
+            rope_scaling = getattr(cfg, "rope_scaling", None)
+            q = apply_rope(q, positions, cfg.rope_theta, rope_scaling)
+            k = apply_rope(k, positions, cfg.rope_theta, rope_scaling)
+        # else NoPE: no position signal; causality alone orders tokens.
         # Non-default query scaling (Gemma's query_pre_attn_scalar):
         # backends scale by head_dim**-0.5 internally, so pre-multiply q
         # by the ratio to the desired qpas**-0.5.
@@ -607,6 +609,15 @@ class Attention(nn.Module):
                 sliding_window=self.window,
                 backend=cfg.attention_backend,
             )
+        if getattr(cfg, "attn_output_gate", False):
+            # Elementwise sigmoid gate on the heads' output, taken from
+            # the layer's input (a flat [d, H*hd] kernel, so the int8
+            # path's table reads it like an MLP's ``gate``).
+            gate = projection(
+                cfg, x, cfg.n_heads * cfg.head_dim, -1,
+                ("embed",), ("heads",), "gate",
+            )
+            out = out * nn.sigmoid(gate).reshape(out.shape)
         return projection(
             cfg, out, cfg.d_model, (-2, -1),
             ("heads", "head_dim"), ("embed",), "o",

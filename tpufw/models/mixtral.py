@@ -161,6 +161,15 @@ class MoEMLP(nn.Module):
     needs the MoE fields (n_experts, experts_per_token,
     capacity_factor, router_*_weight) plus dtypes — DeepseekConfig
     passes a compatible view.
+
+    ``held = (first, n)``: this chip's share of an expert-parallel
+    layer. The router keeps its width ``cfg.n_experts`` and scores and
+    weighs over all of them; the stacks hold the n experts
+    [first, first + n) and the layer returns THEIR part of the result
+    (what the experts held elsewhere add is those chips' to compute;
+    nothing here stands in for them or for the exchange).
+    ``scoring="sigmoid"`` adds the selection bias ``router_bias`` [E]
+    (tpufw.ops.moe._topk_select).
     """
 
     cfg: MixtralConfig
@@ -169,6 +178,37 @@ class MoEMLP(nn.Module):
     # (n_group, topk_group): DeepSeek-236B group-limited selection —
     # passed straight to tpufw.ops.moe.route_topk_capacity.
     group_limit: Optional[tuple] = None
+    scoring: str = "softmax"
+    held: Optional[tuple] = None
+
+    def _held(self) -> Optional[tuple]:
+        """``held``, or None where it names every expert: the whole
+        layer is then the program it is without the option."""
+        if self.held is None or tuple(self.held) == (0, self.cfg.n_experts):
+            return None
+        return tuple(self.held)
+
+    def _n_held(self) -> int:
+        """Length of the expert stacks here."""
+        held = self._held()
+        return self.cfg.n_experts if held is None else held[1]
+
+    def _routing(self) -> dict:
+        """The keyword arguments both routing functions share."""
+        kw = dict(norm_topk=self.norm_topk, group_limit=self.group_limit)
+        if self._held() is not None:
+            kw["held"] = self._held()
+        if self.scoring != "softmax":
+            kw["scoring"] = self.scoring
+            kw["select_bias"] = self.param(
+                "router_bias",
+                nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), ("expert",)
+                ),
+                (self.cfg.n_experts,),
+                jnp.float32,
+            )
+        return kw
 
     def _expert_matmul(
         self, name: str, xe: jax.Array, shape: tuple, names: tuple
@@ -263,14 +303,14 @@ class MoEMLP(nn.Module):
         tpufw.ops.moe)."""
         cfg = self.cfg
         b, t, d = x.shape
-        e, k = cfg.n_experts, cfg.experts_per_token
+        k = cfg.experts_per_token
+        e = self._n_held()
         g = b * t
         token, group_sizes, gates, aux, z = route_topk_sorted(
             router_logits, k, capacity,
             valid=None if valid is None else valid.reshape(g),
             dtype=x.dtype,
-            norm_topk=self.norm_topk,
-            group_limit=self.group_limit,
+            **self._routing(),
         )
         xs = x.reshape(g, d).astype(cfg.dtype)[token]  # [k*G, d]
 
@@ -355,9 +395,9 @@ class MoEMLP(nn.Module):
             router_logits, k, capacity,
             valid=None if valid is None else valid.reshape(g),
             dtype=x.dtype,
-            norm_topk=self.norm_topk,
-            group_limit=self.group_limit,
+            **self._routing(),
         )
+        e = self._n_held()  # the stacks' length; the router keeps E
 
         xf = x.reshape(g, d)
         xe = jnp.einsum("gec,gd->ecd", dispatch, xf)  # [E, C, d]

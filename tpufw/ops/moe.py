@@ -38,12 +38,40 @@ def _topk_select(
     k: int,
     norm_topk: bool,
     group_limit: Optional[tuple[int, int]],
+    scoring: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Shared selection front half of both routing implementations:
-    softmax, optional DeepSeek group-limited masking, top-k, optional
+    scores, optional DeepSeek group-limited masking, top-k, optional
     top-k renormalization. Returns (probs [G,E], topk_probs [G,k],
-    topk_idx [G,k])."""
+    topk_idx [G,k]).
+
+    ``scoring="softmax"`` scores by softmax over the experts;
+    ``"sigmoid"`` scores each expert alone (the DeepSeek-V3 / GLM-4.5
+    convention): the k experts are CHOSEN by score + ``select_bias``
+    [E], and weighed by the score without it."""
     g, e = router_logits.shape
+    if scoring == "sigmoid":
+        if group_limit is not None:
+            raise NotImplementedError(
+                "sigmoid scoring with group-limited selection"
+            )
+        scores = jax.nn.sigmoid(router_logits)  # [G, E]
+        biased = scores if select_bias is None else scores + select_bias
+        _, topk_idx = jax.lax.top_k(biased, k)
+        topk_probs = jnp.take_along_axis(scores, topk_idx, axis=-1)
+        if norm_topk:
+            topk_probs = topk_probs / jnp.sum(
+                topk_probs, axis=-1, keepdims=True
+            )
+        # The aux statistics want a distribution over the experts.
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        return probs, topk_probs, topk_idx
+    if scoring != "softmax" or select_bias is not None:
+        raise ValueError(
+            f"scoring={scoring!r}: choose 'softmax' or 'sigmoid' (a "
+            "selection bias goes with 'sigmoid')"
+        )
     probs = jax.nn.softmax(router_logits, axis=-1)  # [G, E]
 
     sel_probs = probs
@@ -85,6 +113,9 @@ def route_topk_sorted(
     dtype=jnp.bfloat16,
     norm_topk: bool = True,
     group_limit: Optional[tuple[int, int]] = None,
+    scoring: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
+    held: Optional[tuple[int, int]] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Sorted-dispatch twin of ``route_topk_capacity``: identical
     selection, priority, capacity-drop, and aux-statistic semantics,
@@ -108,6 +139,14 @@ def route_topk_sorted(
     as they are stored. Expert E-1's real assignments sort before the
     sentinel rows, so their ranks and capacity drops are untouched.
 
+    ``held = (first, n)``: this chip holds experts [first, first + n)
+    of the E the router scores (its share of an expert-parallel
+    layer). Selection and gate weights are over all E, as published;
+    an assignment to an expert held elsewhere joins the sentinel group
+    (zero gate: its part of the result is the other chip's to add),
+    expert ids become local and ``group_sizes`` has length n, matching
+    stacks of n experts. None = all E are here.
+
     Returns (token [k*G], group_sizes [E], gates [k*G], aux_lb, z):
     ``token[i]`` is the source token id of the i-th SORTED assignment
     (gather ``x[token]`` to build the grouped input), ``group_sizes``
@@ -115,9 +154,9 @@ def route_topk_sorted(
     of ragged_dot's output is defined), ``gates`` is the combine
     weight per sorted assignment.
     """
-    g, e = router_logits.shape
+    g, e_all = router_logits.shape
     probs, topk_probs, topk_idx = _topk_select(
-        router_logits, k, norm_topk, group_limit
+        router_logits, k, norm_topk, group_limit, scoring, select_bias
     )
     validf = None if valid is None else valid.reshape(g).astype(jnp.float32)
 
@@ -127,6 +166,11 @@ def route_topk_sorted(
     eids = topk_idx.T.reshape(k * g)  # [k*G]
     gates_flat = topk_probs.T.reshape(k * g)
     token = jnp.tile(jnp.arange(g, dtype=jnp.int32), k)
+    e = e_all  # experts here; e is also the sentinel's id
+    if held is not None:
+        first, e = held
+        local = eids - first
+        eids = jnp.where((local >= 0) & (local < e), local, e)
     if validf is not None:
         invalid = validf < 0.5
         eids = jnp.where(invalid[token], e, eids)
@@ -145,7 +189,7 @@ def route_topk_sorted(
 
     # Aux statistics: identical formulas to route_topk_capacity, on
     # the same valid-masked top-1 assignment mask.
-    top1_mask = jax.nn.one_hot(topk_idx[:, 0], e, dtype=jnp.float32)
+    top1_mask = jax.nn.one_hot(topk_idx[:, 0], e_all, dtype=jnp.float32)
     if validf is not None:
         top1_mask = top1_mask * validf[:, None]
     aux_lb, z = _router_stats(router_logits, probs, top1_mask, validf, g)
@@ -188,6 +232,9 @@ def route_topk_capacity(
     dtype=jnp.bfloat16,
     norm_topk: bool = True,
     group_limit: Optional[tuple[int, int]] = None,
+    scoring: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
+    held: Optional[tuple[int, int]] = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Route G tokens to top-``k`` of E experts under a per-expert
     ``capacity``.
@@ -214,6 +261,10 @@ def route_topk_capacity(
         reference. Exact float ties between group maxima keep both
         groups (HF's torch.topk breaks such ties arbitrarily;
         measure-zero under real routers).
+      scoring, select_bias, held: one semantics with
+        ``route_topk_sorted``. With ``held = (first, n)`` the returned
+        tensors are [G, n, C] over the experts held here; assignments
+        to experts held elsewhere appear in neither.
 
     Returns:
       (dispatch [G, E, C], combine [G, E, C], aux_lb, z):
@@ -223,11 +274,18 @@ def route_topk_capacity(
       frac_probs)`` over top-1 assignments, ``z`` the mean squared
       router logsumexp — both raw (callers apply their config weights).
     """
-    g, e = router_logits.shape
+    g, e_all = router_logits.shape
     probs, topk_probs, topk_idx = _topk_select(
-        router_logits, k, norm_topk, group_limit
+        router_logits, k, norm_topk, group_limit, scoring, select_bias
     )
     validf = None if valid is None else valid.reshape(g).astype(jnp.float32)
+    top1 = topk_idx[:, 0]
+    e = e_all
+    if held is not None:
+        # Local ids; one_hot of an id outside [0, n) is all zeros, so
+        # an expert held elsewhere gets no slot and no weight.
+        first, e = held
+        topk_idx = topk_idx - first
 
     # Priority order: expert slot 0 of every token beats slot 1, and
     # earlier tokens beat later ones — [k, G, E] cumsum order.
@@ -254,6 +312,11 @@ def route_topk_capacity(
 
     # Switch-transformer load-balance statistic over top-1 fractions,
     # computed over valid tokens only.
-    top1_mask = mask[:, 0, :]  # [G, E] (already zeroed on invalid)
+    if held is None:
+        top1_mask = mask[:, 0, :]  # [G, E] (already zeroed on invalid)
+    else:
+        top1_mask = jax.nn.one_hot(top1, e_all, dtype=jnp.float32)
+        if validf is not None:
+            top1_mask = top1_mask * validf[:, None]
     aux_lb, z = _router_stats(router_logits, probs, top1_mask, validf, g)
     return dispatch, combine, aux_lb, z

@@ -10,7 +10,7 @@ and the scale multiply fuse into the surrounding ops under XLA).
 Scope: the projection kernels per block (attention q/k/v/o, MLA's
 q_a/q_b/kv_a, MLP gate/up/down), the dedicated LM head, and the raw
 expert stacks of Mixtral (``moe`` scope) and DeepSeek (``routed``
-scope) — routers and MLA's small kv_b latent up-projection stay fp. Embeddings stay full precision (a gather, and for tied
+scope; stacks of a chip's held experts alike) — routers and MLA's small kv_b latent up-projection stay fp. Embeddings stay full precision (a gather, and for tied
 heads the two uses want incompatible scale granularities).
 Per-OUTPUT-channel symmetric scales keep the quantization error
 independent per output unit, and scaling AFTER the contraction is
@@ -35,6 +35,11 @@ _PROJ_IN_DIMS = {
     # its halves separately.
     "q_a": 1, "q_b": 1, "kv_a": 1,
     "gate": 1, "up": 1, "down": 1,
+    # Linear attention (tpufw.models.solar_open2 KDALayer): the two
+    # low-rank pairs (decay, output gate) and the write strength; its
+    # q/k/v/o are shaped like softmax attention's. The softmax layer's
+    # output gate is a flat [d, H*hd] "gate".
+    "f_a": 1, "f_b": 1, "g_a": 1, "g_b": 1, "beta": 1,
     # The dedicated LM head ([D, V]) is the largest single matmul a
     # decode step streams; tied (Gemma) embeddings stay fp — the gather
     # and the attend contraction want incompatible scale granularities.
@@ -45,6 +50,7 @@ _PROJ_RANK = {
     "q": 3, "k": 3, "v": 3, "o": 3,
     "q_a": 2, "q_b": 3, "kv_a": 2,
     "gate": 2, "up": 2, "down": 2,
+    "f_a": 2, "f_b": 2, "g_a": 2, "g_b": 2, "beta": 2,
     "lm_head": 2,
 }
 #: Mixtral expert stacks: RAW [E, in, out] arrays (not {kernel} modules)

@@ -168,9 +168,12 @@ def _deepseek_config_from_hf(get):
     Routed experts (DeepSeek MoE FFN), group-limited selection
     (topk_method="group_limited_greedy", n_group/topk_group), and yarn
     rope scaling import directly. Rejects, loudly, what tpufw's MLA
-    blocks don't implement: other topk_methods, non-softmax scoring,
-    sparse moe_layer_freq, and attention bias — importing them would
-    produce silently wrong logits."""
+    blocks don't implement or this importer doesn't map: other
+    topk_methods, non-softmax scoring (tpufw.ops.moe scores by sigmoid
+    with a selection bias too, ``DeepseekConfig.moe_scoring``, but no
+    checkpoint key is mapped onto its ``router_bias`` here yet), sparse
+    moe_layer_freq, and attention bias — importing them would produce
+    silently wrong logits."""
     from tpufw.models.deepseek import DeepseekConfig
 
     bad = {}
@@ -251,9 +254,9 @@ def _deepseek_config_from_hf(get):
         raise NotImplementedError(
             f"DeepseekV2 import: unsupported features {bad}; tpufw's "
             "MLA family implements greedy and group-limited-greedy "
-            "softmax MoE and default+yarn rope (non-softmax scoring, "
-            "sparse moe_layer_freq, and attention bias are the known "
-            "gaps)"
+            "softmax MoE and default+yarn rope (importing sigmoid "
+            "scoring's selection bias, sparse moe_layer_freq, and "
+            "attention bias are the known gaps)"
         )
     moe_kwargs = {}
     if has_moe:

@@ -74,6 +74,8 @@ def build_generator():
         Llama,
         MIXTRAL_CONFIGS,
         Mixtral,
+        SOLAR_OPEN2_CONFIGS,
+        SolarOpen2,
     )
     from tpufw.train import Trainer, TrainerConfig
 
@@ -122,10 +124,12 @@ def build_generator():
         model_cfg, model_cls = GEMMA_CONFIGS[name], Gemma
     elif name in DEEPSEEK_CONFIGS:
         model_cfg, model_cls = DEEPSEEK_CONFIGS[name], Deepseek
+    elif name in SOLAR_OPEN2_CONFIGS:
+        model_cfg, model_cls = SOLAR_OPEN2_CONFIGS[name], SolarOpen2
     else:
         raise ValueError(
             f"unknown TPUFW_MODEL={name!r}; choose from "
-            f"{['llama3_600m_bench', *LLAMA_CONFIGS, *MIXTRAL_CONFIGS, *GEMMA_CONFIGS, *DEEPSEEK_CONFIGS]}"
+            f"{['llama3_600m_bench', *LLAMA_CONFIGS, *MIXTRAL_CONFIGS, *GEMMA_CONFIGS, *DEEPSEEK_CONFIGS, *SOLAR_OPEN2_CONFIGS]}"
         )
     # Serving wants the full sequence budget but no training-only features.
     model_cfg = dataclasses.replace(
@@ -1200,6 +1204,15 @@ class _SlotScheduler:
                     "prefix_misses_total",
                     "pages_freed_total",
                 )
+                # Admissions whose prefix lookup the pool declined
+                # (a model with per-slot state gets no shared pages).
+                metrics.registry.counter(
+                    "tpufw_serve_prefix_declined_total"
+                ).inc(0.0, reason="state_layers")
+            # Per-slot state (linear-attention layers) the pool holds
+            # beside its K/V: 0 for a model that has none.
+            metrics.registry.gauge("tpufw_serve_state_bytes")
+            metrics.registry.gauge("tpufw_serve_state_slots")
             if self.prefill_chunk_pages:
                 # Chunked-prefill series live OUTSIDE the tpufw_serve_
                 # prefix (the disagg PrefillEngine reports the same
@@ -1482,6 +1495,19 @@ class _SlotScheduler:
             )
         )
 
+    def _prefix_declined(self) -> bool:
+        """True where the pool declined this admission's prefix lookup
+        (``PagedSlotPool.prefix_decline``): counted by reason, neither
+        a hit nor a miss."""
+        reason = self._pool.prefix_decline
+        if not reason:
+            return False
+        if self._metrics is not None:
+            self._metrics.registry.counter(
+                "tpufw_serve_prefix_declined_total"
+            ).inc(1.0, reason=reason)
+        return True
+
     def _spec_slack(self, sampling) -> int:
         """Extra KV slots a speculative row needs past max_new - 1 (0
         when speculation is off or ineligible for this sampling)."""
@@ -1594,6 +1620,17 @@ class _SlotScheduler:
             )
         if self.page:
             self._pool.tracer = self._tracer
+        if self.spec_k:
+            self._slots_mod.reject_state(
+                self._pool, f"TPUFW_SERVE_SPEC_K={self.spec_k}"
+            )
+        if self._metrics is not None:
+            state = self._pool.state_bytes
+            reg = self._metrics.registry
+            reg.gauge("tpufw_serve_state_bytes").set(float(state))
+            reg.gauge("tpufw_serve_state_slots").set(
+                float(self.n_slots if state else 0)
+            )
         if self._perf.enabled:
             # Mount the cost observatory on the pool (dynamic attr:
             # SlotPool/PagedSlotPool read it via getattr) so insert /
@@ -1860,7 +1897,7 @@ class _SlotScheduler:
             job.prompt, need, rng, self.prefill_chunk_pages
         )
         try:
-            if self.prefix_enabled:
+            if self.prefix_enabled and not self._prefix_declined():
                 hit = cp.shared_n > 0
                 if self._metrics is not None:
                     self._metrics.inc(
@@ -1926,7 +1963,7 @@ class _SlotScheduler:
         )
         if grant is not None:
             page_ids, shared_n = grant
-            if self.prefix_enabled:
+            if self.prefix_enabled and not self._prefix_declined():
                 hit = shared_n > 0
                 if self._metrics is not None:
                     self._metrics.inc(
@@ -2803,6 +2840,9 @@ class _Server:
                         "prefix_misses_total",
                         "pages_freed_total",
                     )
+                    self.metrics.registry.counter(
+                        "tpufw_serve_prefix_declined_total"
+                    ).reset(reason="state_layers")
                 if self._batcher.spec_k:
                     # Gated like the registration: the warmup request's
                     # speculative passes must stay invisible to scrapes.
