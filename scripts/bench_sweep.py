@@ -44,6 +44,8 @@ from benchmarks import harness, procs, stats, traffic  # noqa: E402
 
 BUCKETS = (512, 2048, 8192)
 QUEUE = "tpufw_serve_queue_depth"
+#: Host traces of the row model: a pool's one, none between two scrapes.
+ROW_TRACES = "tpufw_serve_row_shape_traces_total"
 
 
 def limits_from(records_t0, seconds):
@@ -151,13 +153,15 @@ def sweep_windows(a, rates, shapes, out):
                 rows.append({"rate_rps": rate, "shape_seed": shape, "seed": args.seed + n, "t0": t0,
                              "cutoff": run["cutoff"], "records": run["records"],
                              "queue_start": prom0.get(QUEUE, 0.0), "queue_end": prom1.get(QUEUE, 0.0),
-                             "compiled_in_window": c1 - c0})
+                             "compiled_in_window": c1 - c0,
+                             "row_shape_traces": [prom0.get(ROW_TRACES), prom1.get(ROW_TRACES)]})
                 ws = stats.window_stats(run["records"], t0, seconds, run["cutoff"], {}, cell["chips"])
                 runner.say(f"sweep: window {n} rate {rate} shape {shape}: attempted {ws['attempted']} failed {ws['failed']} "
                            f"tokens/s {ws['tokens_per_s_per_chip']:.2f} tpot_p50 {ws.get('tpot_p50_ms', 0):.2f} "
                            f"ttft_p50 {ws.get('ttft_p50_ms', 0):.0f} queue {rows[-1]['queue_start']:.0f} -> "
                            f"{rows[-1]['queue_end']:.0f} in service {ws['backlog_start']} -> {ws['backlog_end']} "
-                           f"programs built {c1 - c0}; {time.time() - began:.0f} s so far")
+                           f"programs built {c1 - c0} row shape traces {prom0.get(ROW_TRACES)} -> {prom1.get(ROW_TRACES)}; "
+                           f"{time.time() - began:.0f} s so far")
         summarise(a, cell, seconds, setup_s, rows, out)
 
     runner._window = windows
@@ -178,7 +182,8 @@ def summarise(a, cell, seconds, setup_s, rows, out) -> None:
             ws = stats.window_stats(r["records"], r["t0"], seconds, r["cutoff"], limits, cell["chips"])
             ws.pop("late_ms")
             per.append({"shape_seed": r["shape_seed"], "seed": r["seed"], "queue_start": r["queue_start"],
-                        "queue_end": r["queue_end"], "compiled_in_window": r["compiled_in_window"], **ws})
+                        "queue_end": r["queue_end"], "compiled_in_window": r["compiled_in_window"],
+                        "row_shape_traces": r["row_shape_traces"], **ws})
         attempted = sum(w["attempted"] for w in per)
         good = sum(round(w.get("slo_good_share", 0.0) * w["attempted"] / 100.0) for w in per)
         q0, q1 = sum(w["queue_start"] for w in per), sum(w["queue_end"] for w in per)

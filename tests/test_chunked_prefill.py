@@ -16,6 +16,12 @@ Contracts, all on CPU with the tiny model:
 - SHAPE STABILITY: chunk programs key on (width, pool, quant) only —
   chunk-COUNT variation and page churn add zero retraces
   (TRACE_COUNTS["prefill_chunk"] is pinned).
+- ROW CANVAS: a pool traces its row model ONCE, when it is built, for
+  the shapes of the B=1 row cache; an admission only fills fresh
+  zeros from them (never a cached array: the chunk and attach jits
+  donate the row leaves), equal to what the per-admission trace made,
+  and builds no program — for K/V heads, MLA latents and per-slot
+  state leaves alike.
 - FUNGIBILITY: a scheduler admitting prompts chunk-by-chunk inside
   the same passes that advance decoding slots (mixed pools, no
   separate tick) emits byte-identical outputs to the monolithic
@@ -214,6 +220,189 @@ def test_zero_retrace_across_chunk_count(tiny_paged):
     assert pages_mod.TRACE_COUNTS["prefill_chunk"] == before
 
 
+# ------------------------------------------------------ row canvas
+
+def _row_family(kind):
+    """(model class, decode config) of one cache kind the row twin
+    carries: K/V heads, MLA latents, per-slot state beside K/V."""
+    if kind == "gqa":
+        from tpufw.models.mixtral import MIXTRAL_CONFIGS, Mixtral
+
+        return Mixtral, MIXTRAL_CONFIGS["mixtral_tiny"]
+    if kind == "mla":
+        from tpufw.models.deepseek import DEEPSEEK_CONFIGS, Deepseek
+
+        return Deepseek, DEEPSEEK_CONFIGS["deepseek_tiny"]
+    from tpufw.models.solar_open2 import SOLAR_OPEN2_CONFIGS, SolarOpen2
+
+    return SolarOpen2, SOLAR_OPEN2_CONFIGS["solar_open2_tiny"]
+
+
+@pytest.fixture(scope="module", params=["gqa", "mla", "state"])
+def family(request):
+    cls, base = _row_family(request.param)
+    cfg = dataclasses.replace(base.decode_config(), max_seq_len=64)
+    row_model = cls(cfg)
+    params = jax.jit(row_model.init)(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+
+    def pool(n_slots=N_SLOTS):
+        pcfg = dataclasses.replace(
+            cfg, kv_page=PAGE, kv_pages=n_slots * (64 // PAGE) + 1
+        )
+        return pages_mod.PagedSlotPool.create_paged(
+            cls(pcfg), row_model, params, n_slots,
+            sampling=GREEDY, eos_id=None,
+        )
+
+    return request.param, row_model, params, pool
+
+
+def _prompt_of(i, n=36):
+    """36 tokens = 2 full pages + 4; no page shared between two ``i``."""
+    return [(t + 17 * i) % 200 + 1 for t in (PROMPT * 2)[:n]]
+
+
+def _admit_chunked(pool, slot, prompt):
+    cp = pool.start_chunked(
+        prompt, len(prompt) + MAX_NEW - 1, jax.random.key(2), 1
+    )
+    while pool.chunk_step(cp) != "done":
+        pass
+    pool.finalize_chunked(slot, cp, MAX_NEW - 1)
+    return cp
+
+
+def _per_call_row_zeros_tree(row_model, params, home):
+    """What ``_attach_row`` computed on EVERY admission before the
+    shapes were kept (``pages._row_zeros_tree``, verbatim): the oracle
+    for the tree an admission gets now."""
+
+    def init(p):
+        toks = jnp.zeros((1, 1), jnp.int32)
+        pos = jnp.zeros((1, 1), jnp.int32)
+        seg = jnp.ones((1, 1), jnp.int32)
+        _, vars_ = row_model.apply(
+            {"params": p}, toks, positions=pos, segment_ids=seg,
+            mutable=["cache"],
+        )
+        return vars_["cache"]
+
+    shapes = jax.eval_shape(init, params)
+    return {
+        "cache": jax.tree_util.tree_map(
+            lambda l: jnp.zeros(l.shape, l.dtype, device=home), shapes
+        )
+    }
+
+
+def test_admissions_never_trace_the_row_model_again(family, monkeypatch):
+    _, row_model, _, make_pool = family
+    pool = make_pool()
+    assert pool.row_shape_traces == 1
+    _admit_chunked(pool, 0, _prompt_of(0))  # traces the chunk program
+    calls = []
+    real_apply = type(row_model).apply
+
+    def counting_apply(self, *a, **kw):
+        calls.append(self)
+        return real_apply(self, *a, **kw)
+
+    monkeypatch.setattr(type(row_model), "apply", counting_apply)
+    for slot in (1, 2, 3):
+        _admit_chunked(pool, slot, _prompt_of(slot, n=20 + 8 * slot))
+    assert not calls, f"{len(calls)} host traces over 3 admissions"
+    assert pool.row_shape_traces == 1
+
+
+def test_row_canvas_equals_the_per_call_tree(family):
+    kind, row_model, params, make_pool = family
+    pool = make_pool()
+    want = _per_call_row_zeros_tree(row_model, params, pool.home)
+    got = pool._attach_row([])
+    assert (
+        jax.tree_util.tree_structure(got)
+        == jax.tree_util.tree_structure(want)
+        == jax.tree_util.tree_structure(pool.row_shapes)
+    )
+    names = {
+        pages_mod._leaf_name(path)
+        for path, _ in jax.tree_util.tree_flatten_with_path(got)[0]
+    }
+    assert {
+        "gqa": {"cached_key", "cached_value"},
+        "mla": {"cached_ckv", "cached_kpe"},
+        "state": {"cached_key", "cached_value", "kda_state", "conv_state"},
+    }[kind] <= names
+    for g, w in zip(
+        jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
+    ):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+        assert g.sharding == w.sharding == pool.home
+        assert g.committed and w.committed
+        assert not np.asarray(g).any()
+    # Live buffers of its own every call: the first tree's are gone
+    # (as after a donation) and the second is untouched.
+    again = pool._attach_row([])
+    for g in jax.tree_util.tree_leaves(got):
+        g.delete()
+    for g in jax.tree_util.tree_leaves(again):
+        assert not np.asarray(g).any()
+
+
+def test_admissions_in_a_row_get_live_buffers_and_the_same_tokens(family):
+    kind, _, _, make_pool = family
+    pa = _prompt_of(0)
+    pool = make_pool()
+    # Two chunked admissions in a row: the second starts after every
+    # leaf the first was handed has been donated, chunk after chunk.
+    cp_a = _admit_chunked(pool, 0, pa)
+    _admit_chunked(pool, 1, _prompt_of(1))
+    got = _decode_all(pool, {0: cp_a.first_int})[0]
+    ref = make_pool()
+    _, first = _monolithic(ref, pa, jax.random.key(2))
+    assert cp_a.first_int == first
+    assert got == _decode_all(ref, {0: first})[0]
+    if kind == "state":
+        assert pool.prefix is None  # no shared pages beside state
+        return
+    # Two prefix hits in a row (the first admission's pages are in the
+    # trie): each attach donates a canvas of its own.
+    for slot, tail in ((2, [7, 9, 4]), (3, [11, 3])):
+        prompt = pa[:32] + tail
+        cp = _admit_chunked(pool, slot, prompt)
+        assert cp.shared_n == 2 and cp.n_chunks == 1
+        cold = make_pool()
+        _, first = _monolithic(cold, prompt, jax.random.key(2))
+        assert cp.first_int == first
+        assert (
+            _decode_all(pool, {slot: first}, max_new=3)[slot]
+            == _decode_all(cold, {0: first}, max_new=3)[0]
+        )
+
+
+def test_a_second_admission_builds_no_program(family):
+    from benchmarks.compile_log import CompileLog
+
+    _, _, _, make_pool = family
+    log = CompileLog()
+    # A pool size no other test of this file has: its programs are
+    # built here, whatever ran before in this process.
+    pool = make_pool(n_slots=3)
+    assert any("row_zeros" in name for name, _ in log.programs)
+    built = log.n
+    _admit_chunked(pool, 0, _prompt_of(0))  # three chunks
+    first = [name for name, _ in log.programs[built:]]
+    # The canvas and the chunk program's own donated output are the
+    # same argument to jit: one chunk program over the three chunks.
+    assert sum("prefill_chunk" in name for name in first) == 1, first
+    built = log.n
+    _admit_chunked(pool, 1, _prompt_of(1, n=20))
+    _admit_chunked(pool, 2, _prompt_of(2))
+    assert log.n == built, log.programs[built:]
+
+
 # ---------------------------------------------- scheduler fungibility
 
 def _scheduler(model, params, prefill_chunk_pages):
@@ -357,9 +546,13 @@ def test_scheduler_phases_and_request_chain(tiny_sched_model, tmp_path):
             assert not t.is_alive()
         _wait_idle(tracer)
 
+    traces = metrics.registry.counter("tpufw_serve_row_shape_traces_total")
+    assert traces.value() == 0  # exposed before any pool is built
     batch([1, 2, 3])  # builds the pool and every program
+    assert traces.value() == 1  # the one pool built
     n0 = len(tracer._events)
     batch([4, 5, 6])
+    assert traces.value() == 1  # three more admissions, no trace
     warm = [e for e in tracer._events[n0:] if e["ph"] == "X"]
     sched_evs = [e for e in warm if e["name"] in phases]
 
@@ -400,6 +593,7 @@ def test_scheduler_phases_and_request_chain(tiny_sched_model, tmp_path):
     text = reg.render()
     for name in serve_mod.SCHED_PHASES:  # exposed even where still 0
         assert f'tpufw_serve_phase_seconds_total{{phase="{name}"}} ' in text
+    assert "\ntpufw_serve_row_shape_traces_total 1\n" in text
 
     # The request chain: one observation per request in each histogram.
     for hist in ("join_latency", "queue_wait", "prefill"):

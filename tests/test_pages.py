@@ -185,6 +185,49 @@ def test_prefix_share_matches_cold_and_cow(tiny_paged):
     assert isinstance(rows_b[1][-1], int)
 
 
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_two_whole_prompt_prefix_hits_in_a_row(kind, tiny_paged):
+    """``prefill_shared`` twice running: the attach and the suffix
+    prefill donate the row canvas, so each hit needs live buffers of
+    its own from the shapes the pool found once (K/V heads and MLA
+    latents; a model with per-slot state gets no trie)."""
+    if kind == "gqa":
+        cfg, row_model, params = tiny_paged
+        pool = _paged_pool(cfg, row_model, params)
+    else:
+        from tpufw.models.deepseek import DEEPSEEK_CONFIGS, Deepseek
+
+        cfg = dataclasses.replace(
+            DEEPSEEK_CONFIGS["deepseek_tiny"].decode_config(),
+            max_seq_len=64,
+        )
+        row_model = Deepseek(cfg)
+        params = jax.jit(row_model.init)(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+        pcfg = dataclasses.replace(
+            cfg, kv_page=PAGE, kv_pages=N_SLOTS * (64 // PAGE) + 1
+        )
+        pool = pages_mod.PagedSlotPool.create_paged(
+            Deepseek(pcfg), row_model, params, N_SLOTS,
+            sampling=GREEDY, eos_id=None,
+        )
+    shared = list(range(40, 76))  # 36 tokens = 2 full pages + 4
+    prompts = [shared + [7, 9], shared + [11, 3, 5], shared + [2]]
+    want = generate_text(
+        row_model, params, prompts, max_new_tokens=MAX_NEW,
+        sampling=GREEDY,
+    )
+    firsts, hits = {}, []
+    for i, p in enumerate(prompts):
+        firsts[i], shared_n = _admit(pool, i, p, i)
+        hits.append(shared_n)
+    assert hits == [0, 2, 2]
+    assert pool.row_shape_traces == 1
+    rows = _decode_all(pool, firsts)
+    assert [rows[i] for i in range(3)] == want
+
+
 def test_int8_kv_quant_roundtrip_tolerance():
     from tpufw.ops.quant import dequantize_kv, quantize_kv
 

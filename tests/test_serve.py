@@ -791,6 +791,34 @@ def test_warmup_invisible_to_metrics_and_seed_replay(
             assert line.endswith(" 0"), line
 
 
+def test_paged_server_traces_its_row_model_once(tiny_env, monkeypatch):
+    """The paged server's warm-up builds the pool that serves, and
+    with it the row twin's shapes: ``row_shape_traces_total`` reads
+    that one trace on a warm server (it is NOT reset with the traffic
+    counters, whose warm-up stays invisible) and chunked admissions
+    after it add none, nor a pool switch."""
+    from tpufw.workloads import serve as serve_mod
+
+    monkeypatch.setenv("TPUFW_SERVE_PAGE", "16")
+    monkeypatch.setenv("TPUFW_SERVE_PREFILL_CHUNK", "1")
+    srv = serve_mod._Server(port=0, max_new_tokens=4)
+
+    def reading(name):
+        text = srv.metrics.render({})
+        (line,) = [
+            ln for ln in text.splitlines() if ln.split(" ")[0] == name
+        ]
+        return float(line.split(" ")[1])
+
+    assert reading("tpufw_serve_row_shape_traces_total") == 1
+    assert reading("tpufw_serve_pool_switches_total") == 0
+    for first in (3, 4, 5):
+        srv._batcher.submit([[first, 5, 9, 2, 6] * 4], 4, None)
+    assert reading("tpufw_serve_retired_rows_total") == 3
+    assert reading("tpufw_serve_row_shape_traces_total") == 1
+    assert reading("tpufw_serve_pool_switches_total") == 0
+
+
 # ---- _Batcher._take_tick policy (no server, no device work) ----
 
 

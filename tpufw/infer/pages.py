@@ -151,15 +151,12 @@ def paged_pool_cache(model, params, n_slots: int):
     return {"cache": tree}
 
 
-def _row_zeros_tree(row_model, params, home):
-    """Zeroed B=1 CONTIGUOUS row cache for ``row_model`` (the paged
-    model's contiguous twin) — the shape ``prefill_row`` hands back,
-    used as the canvas ``prefill_shared`` gathers shared pages into.
-    Born committed at ``home`` like the pool's own state
-    (SlotPool.__post_init__): the chunk programs see this canvas first
-    and their own donated output after, and the two must be the same
-    argument to jit. Per-slot state leaves are in it at B=1, zero: the
-    row's own state from its first token on, never a slot's."""
+def _row_cache_shapes(row_model, params):
+    """Abstract B=1 CONTIGUOUS row cache of ``row_model`` (the paged
+    model's contiguous twin): the shapes ``prefill_row`` hands back,
+    per-slot state leaves included at B=1. One host trace of the whole
+    row model — seconds at published widths — and the same for a pool's
+    whole life, so a pool asks once (``PagedSlotPool._find_row_shapes``)."""
 
     def init(p):
         toks = jnp.zeros((1, 1), jnp.int32)
@@ -171,14 +168,9 @@ def _row_zeros_tree(row_model, params, home):
         )
         return vars_["cache"]
 
-    shapes = jax.eval_shape(init, params)
     # Wrapped in the same {"cache": ...} form prefill_row returns, so
     # path alignment against the pool tree lines up leaf-for-leaf.
-    return {
-        "cache": jax.tree_util.tree_map(
-            lambda l: jnp.zeros(l.shape, l.dtype, device=home), shapes
-        )
-    }
+    return {"cache": jax.eval_shape(init, params)}
 
 
 class PageAllocator:
@@ -725,6 +717,14 @@ class PagedSlotPool(SlotPool):
     #: ("state_layers": the model keeps per-slot state). The scheduler
     #: counts the admissions declined for it, by this reason.
     prefix_decline: str = ""
+    #: The row twin's B=1 contiguous cache as ShapeDtypeStructs, found
+    #: once by ``_find_row_shapes`` when the pool is built; ``_fresh_row``
+    #: is the program that fills it with zeros at ``home``.
+    row_shapes: Any = None
+    #: Times this pool traced its row model for ``row_shapes``: 1 for
+    #: its whole life (``tpufw_serve_row_shape_traces_total`` adds it).
+    row_shape_traces: int = 0
+    _fresh_row: Any = None
 
     @classmethod
     def create_paged(
@@ -756,7 +756,7 @@ class PagedSlotPool(SlotPool):
         # Shared pages are K/V alone: a row attached to them would start
         # its state layers from zero, silently wrong. No trie, counted.
         stateful = prefix_cache and state_leaf_bytes(cache) > 0
-        return cls(
+        pool = cls(
             model=model,
             params=params,
             n_slots=n_slots,
@@ -782,6 +782,35 @@ class PagedSlotPool(SlotPool):
             ),
             prefix_decline="state_layers" if stateful else "",
             slot_pages=[[] for _ in range(n_slots)],
+        )
+        pool._find_row_shapes()
+        return pool
+
+    def _find_row_shapes(self) -> None:
+        """Trace the row model, once, for the shapes of its B=1 row
+        cache, and build the one program that fills them with zeros.
+
+        Both are the pool's for life (``row_model``, the parameters'
+        structure and ``home`` never change under it), and both belong
+        to pool construction: the trace is host seconds and the program
+        a backend compile, neither of which an admission may pay. The
+        program's output is born committed at ``home`` like the pool's
+        own state (SlotPool.__post_init__): the chunk programs see this
+        canvas first and their own donated output after, and the two
+        must be the same argument to jit. Per-slot state leaves are in
+        it at B=1, zero: the row's own state from its first token on,
+        never a slot's."""
+        self.row_shape_traces += 1
+        self.row_shapes = _row_cache_shapes(self.row_model, self.params)
+        shapes = self.row_shapes
+
+        def row_zeros():
+            return jax.tree_util.tree_map(
+                lambda s: jnp.zeros(s.shape, s.dtype), shapes
+            )
+
+        self._fresh_row = (
+            jax.jit(row_zeros, out_shardings=self.home).lower().compile()
         )
 
     # ---- host-side page bookkeeping -------------------------------
@@ -1081,18 +1110,16 @@ class PagedSlotPool(SlotPool):
         gathered into its first ``len(shared_ids) * page`` slots
         (cursor set accordingly); plain zeros when nothing is shared.
 
-        A fresh template every call: the attach jit DONATES the row
-        leaves (their memory becomes the attached cache), so a cached
-        tree would hand already-deleted buffers to the second prefix
-        hit. Not trivia: ``_row_zeros_tree`` re-traces the row model on
-        the host, every admission."""
+        Fresh BUFFERS every call: the attach and chunk jits DONATE the
+        row leaves (their memory becomes the attached cache), so a
+        cached tree would hand already-deleted buffers to the second
+        admission. Only the shapes are kept (``_find_row_shapes``); one
+        dispatch of ``_fresh_row`` makes the zeros."""
         with self.tracer.span(
             "serve_row_alloc", shared_pages=len(shared_ids),
             state_bytes=self.state_bytes // self.n_slots,
         ):
-            row_tree = _row_zeros_tree(
-                self.row_model, self.params, self.home
-            )
+            row_tree = self._fresh_row()
             if not len(shared_ids):
                 return row_tree
             paths, names, leaves, _ = self._pool_flat()

@@ -1203,6 +1203,12 @@ class _SlotScheduler:
                     "prefix_hits_total",
                     "prefix_misses_total",
                     "pages_freed_total",
+                    # Host traces of a row model for its row cache's
+                    # shapes: one per paged pool built, none per
+                    # admission (PagedSlotPool._find_row_shapes). Not
+                    # reset after warm-up: the pool warm-up built is
+                    # the one that serves, and its 1 is the evidence.
+                    "row_shape_traces_total",
                 )
                 # Admissions whose prefix lookup the pool declined
                 # (a model with per-slot state gets no shared pages).
@@ -1620,6 +1626,7 @@ class _SlotScheduler:
             )
         if self.page:
             self._pool.tracer = self._tracer
+            self._count_row_shape_traces(self._pool)
         if self.spec_k:
             self._slots_mod.reject_state(
                 self._pool, f"TPUFW_SERVE_SPEC_K={self.spec_k}"
@@ -1669,6 +1676,7 @@ class _SlotScheduler:
                                 allocator=self._pool.allocator,
                             )
                         )
+                        self._count_row_shape_traces(self._draft_pool)
                     else:
                         self._draft_pool = self._slots_mod.SlotPool.create(
                             d_pool,
@@ -1695,6 +1703,12 @@ class _SlotScheduler:
         self._events.emit(
             "serve_pool_switch", cache_len=cache_len, slots=self.n_slots
         )
+
+    def _count_row_shape_traces(self, pool) -> None:
+        if self._metrics is not None:
+            self._metrics.inc(
+                "row_shape_traces_total", pool.row_shape_traces
+            )
 
     def _admit(self) -> None:
         with self._cv:
