@@ -361,3 +361,67 @@ def test_deepseek_paged_parity():
         firsts[i], _ = _admit(pool, i, p, i, max_new=max_new)
     rows = _decode_all(pool, firsts, max_new=max_new)
     assert [rows[i] for i in range(len(prompts))] == want
+
+
+def _seam_family(name):
+    if name == "llama":
+        return Llama, LLAMA_CONFIGS["llama3_tiny"].decode_config()
+    if name == "deepseek":
+        from tpufw.models.deepseek import DEEPSEEK_CONFIGS, Deepseek
+
+        return Deepseek, DEEPSEEK_CONFIGS["deepseek_tiny"].decode_config()
+    from benchmarks import harness
+
+    keys = harness.model_keys(
+        harness.load_json("benchmarks/configs/rehearse/solar_open2.json")
+    )
+    cls, cfg = harness.family_modules("solar_open2")[1].program_model(
+        keys, {"moe_dispatch": "sorted"}
+    )
+    return cls, cfg.decode_config()
+
+
+@pytest.mark.parametrize("family", ["llama", "deepseek", "solar_open2"])
+def test_every_cache_leaf_has_a_role_in_the_store(family):
+    """The seam tpufw.ops.kv_store owns: whatever a pool or a row twin
+    holds resolves through ``role()`` (the programs here switch on
+    nothing else), and the models spell no layout of their own."""
+    import pathlib
+
+    from tpufw.ops import kv_store
+
+    cls, cfg = _seam_family(family)
+    cfg = dataclasses.replace(cfg, max_seq_len=64)
+    row_model = cls(cfg)
+    params = jax.eval_shape(
+        row_model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    kinds = set()
+    for quant in ("", "int8"):
+        paged = cls(dataclasses.replace(
+            cfg, kv_page=PAGE, kv_pages=2 * (64 // PAGE) + 1, kv_quant=quant
+        ))
+        trees = (
+            pages_mod.paged_pool_cache(paged, params, 2),
+            pages_mod._row_cache_shapes(row_model, params),
+        )
+        for tree in trees:
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+                r = kv_store.path_role(path)
+                kinds.add(r.kind)
+                if r.kind in (kv_store.PAGE, kv_store.STATE):
+                    # unstacked rank: nn.scan may stack layers in front
+                    assert leaf.ndim >= r.rank, (path, leaf.shape, r)
+    assert {
+        kv_store.PAGE, kv_store.SCALE, kv_store.SEGMENT, kv_store.TABLE,
+        kv_store.CURSOR,
+    } <= kinds
+    assert (kv_store.STATE in kinds) == (family == "solar_open2")
+    models = pathlib.Path(pages_mod.__file__).parents[1] / "models"
+    for source in ("llama.py", "deepseek.py"):
+        text = (models / source).read_text()
+        for spelled in (
+            '"page_table"', '"cache_index"', '"cached_segment_ids"',
+            '"_scale"', "self.variable(",
+        ):
+            assert spelled not in text, (source, spelled)

@@ -12,7 +12,7 @@ framework needs next to the trainer.
 Left-padding is what makes ragged batches one program: every live token
 sits flush against the cache cursor, RoPE positions are slot - pad_len,
 and pad slots carry segment 0 so attention never sees them
-(tpufw.models.llama Attention._cached_attention).
+(tpufw.ops.kv_store).
 """
 
 from __future__ import annotations

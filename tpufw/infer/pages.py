@@ -8,11 +8,12 @@ zero-recompile contract (occupancy, cursors, and now page-table churn
 are all DATA) while storing KV in a global arena of ``kv_pages`` pages
 of ``kv_page`` slots each:
 
-- the MODEL owns the arena + table + gather/scatter reads
-  (``Attention._paged_cached_attention`` in llama/deepseek — the cache
-  leaves just have a different shape, so ``_decode_steps_jit`` is
-  reused verbatim);
-- this module owns moving rows in and out: ``_paged_insert_jit``
+- the STORE (``tpufw.ops.kv_store``, called by every model) owns the
+  arena + table + gather/scatter reads and every leaf's role — the
+  cache leaves just have a different shape, so ``_decode_steps_jit`` is
+  reused verbatim;
+- this module owns moving rows in and out, and the allocator; it asks
+  ``kv_store.role()`` what each leaf is: ``_paged_insert_jit``
   scatters a B=1 contiguous prefilled row into the slot's pages,
   ``PagedSlotPool.release_slot`` zeroes the table row (stale writes
   from a done-but-stepped row then land in reserved page 0, never in a
@@ -33,7 +34,7 @@ pays its prefill-bucket programs), never per decode step.
 
 int8 KV (``cfg.kv_quant == "int8"``): arenas are int8 with per-token
 fp32 scales stored page-structured ``[kv_pages, kv_page]``. Decode
-tokens are quantized inside the model at append; prompt tokens are
+tokens are quantized by the store at append; prompt tokens are
 quantized HERE at insert (prefill itself runs full-precision through
 the contiguous row cache).
 """
@@ -53,7 +54,6 @@ from tpufw.infer.generate import _model_apply, split_prefill_keys
 from tpufw.infer.prefix import PrefixCache
 from tpufw.infer.sampling import sample_token
 from tpufw.infer.slots import (
-    STATE_LEAVES,
     SlotPool,
     _retire_jit,
     _track_seen,
@@ -61,6 +61,10 @@ from tpufw.infer.slots import (
     state_leaf_bytes,
 )
 from tpufw.obs import trace as obs_trace
+from tpufw.ops.kv_store import (
+    CURSOR, PAGE, SCALE, SEGMENT, STATE, TABLE, role,
+)
+from tpufw.ops.kv_store import leaf_name as _leaf_name
 from tpufw.ops.quant import dequantize_kv, quantize_kv
 
 # Trace-time counters, same contract as tpufw.infer.slots.TRACE_COUNTS:
@@ -70,39 +74,6 @@ TRACE_COUNTS: Dict[str, int] = {
     "suffix_prefill": 0, "page_export": 0, "page_splice": 0,
     "prefill_chunk": 0, "page_import": 0,
 }
-
-#: unstacked rank of each KV arena leaf — (n_pages, page, *feat); the
-#: trailing ``rank - 2`` dims are the per-token feature block a single
-#: int8 scale covers. Matching row-cache leaves are (1, W, *feat) at
-#: the same rank.
-_ARENA_RANK = {
-    "cached_key": 4, "cached_value": 4,  # llama-family K/V heads
-    "cached_ckv": 3, "cached_kpe": 3,    # deepseek MLA latents
-}
-# The roles a paged cache leaf can have: an arena page (_ARENA_RANK,
-# its ``*_scale`` and ``cached_segment_ids``), a table or cursor
-# (``page_table``, ``cache_index``), or per-slot state (STATE_LEAVES,
-# named once in tpufw.infer.slots): [*stack, n_slots, *feat] in the
-# pool, [*stack, 1, *feat] in the row twin, where it is carried from
-# prefill chunk to prefill chunk.
-
-
-def _export_rank(name: str) -> Optional[int]:
-    """Collapse rank of a leaf that travels in a page bundle (arena KV,
-    page-structured scales, segment ids); None for per-slot leaves
-    (page_table, cache_index) the importer rebuilds locally, and for
-    per-slot STATE, which no bundle carries yet: ``export_slot`` and
-    ``splice_slot`` refuse a pool that has any before they get here."""
-    if name in _ARENA_RANK:
-        return _ARENA_RANK[name]
-    if name.endswith("_scale") or name == "cached_segment_ids":
-        return 2
-    return None
-
-
-def _leaf_name(path) -> str:
-    last = path[-1]
-    return str(getattr(last, "key", last))
 
 
 def _flatten_with_names(tree):
@@ -280,48 +251,35 @@ def _paged_insert_jit(
     quantized = {}
     if quant:
         for i, name in enumerate(names):
-            if name in _ARENA_RANK:
-                rank = _ARENA_RANK[name]
-                rr = _collapse_row(row_leaves[i], rank)
-                quantized[i] = quantize_kv(rr, n_feat=rank - 2)
+            r = role(name)
+            if r.kind == PAGE:
+                rr = _collapse_row(row_leaves[i], r.rank)
+                quantized[i] = quantize_kv(rr, n_feat=r.rank - 2)
 
     out = []
     for i, (name, leaf) in enumerate(zip(names, leaves)):
-        if name == "page_table":
+        r = role(name)
+        if r.kind == TABLE:
             out.append(leaf.at[..., slot, :].set(table_row))
-        elif name == "cache_index":
+        elif r.kind == CURSOR:
             out.append(leaf.at[..., slot].set(row_leaves[i]))
-        elif name.endswith("_scale"):
-            scales = quantized[scale_src[i]][1]  # [stacks, W] fp32
-            a = _collapse_arena(leaf, 2)
-            out.append(a.at[:, phys, off].set(scales).reshape(leaf.shape))
-        elif name in _ARENA_RANK:
-            rank = _ARENA_RANK[name]
-            if quant:
-                vals = quantized[i][0]
-            else:
-                vals = _collapse_row(row_leaves[i], rank).astype(leaf.dtype)
-            a = _collapse_arena(leaf, rank)
-            out.append(a.at[:, phys, off].set(vals).reshape(leaf.shape))
-        elif name == "cached_segment_ids":
-            vals = _collapse_row(row_leaves[i], 2).astype(leaf.dtype)
-            a = _collapse_arena(leaf, 2)
-            out.append(a.at[:, phys, off].set(vals).reshape(leaf.shape))
-        elif name in STATE_LEAVES:
+        elif r.kind == STATE:
             # Per-slot state: the row's, whole — nothing of the slot's
             # previous occupant survives the insert.
-            rank = STATE_LEAVES[name]
-            a = _collapse_arena(leaf, rank)  # [stacks, n_slots, *feat]
-            row = _collapse_arena(row_leaves[i], rank)[:, 0]
+            a = _collapse_arena(leaf, r.rank)  # [stacks, n_slots, *feat]
+            row = _collapse_arena(row_leaves[i], r.rank)[:, 0]
             out.append(
                 a.at[:, slot].set(row.astype(leaf.dtype)).reshape(leaf.shape)
             )
-        else:
-            raise ValueError(
-                f"unknown paged cache leaf {name!r}: the paged insert "
-                "must know every leaf's role (an untouched leaf would "
-                "leak the previous occupant's state)"
-            )
+        else:  # in the arena: pages, their scales, segment ids
+            if r.kind == SCALE:
+                vals = quantized[scale_src[i]][1]  # [stacks, W] fp32
+            elif quant and r.kind == PAGE:
+                vals = quantized[i][0]
+            else:
+                vals = _collapse_row(row_leaves[i], r.rank).astype(leaf.dtype)
+            a = _collapse_arena(leaf, r.rank)
+            out.append(a.at[:, phys, off].set(vals).reshape(leaf.shape))
     token = token.at[slot].set(first)
     pos = pos.at[slot].set(pos0)
     done = done.at[slot].set(False)
@@ -360,34 +318,26 @@ def _attach_shared_jit(
     length = n * page
     out = []
     for i, name in enumerate(names):
-        row = row_leaves[i]
+        row, r = row_leaves[i], role(name)
         if row is None:
             out.append(None)
-        elif name == "cache_index":
+        elif r.kind == CURSOR:
             out.append(jnp.full(row.shape, length, row.dtype))
-        elif name == "cached_segment_ids":
-            rr = _collapse_row(row, 2)
-            a = _collapse_arena(pool_leaves[i], 2)
-            g = a[:, ids].reshape(a.shape[0], length)
-            out.append(
-                rr.at[:, :length].set(g.astype(rr.dtype)).reshape(row.shape)
-            )
-        elif name in _ARENA_RANK:
-            rank = _ARENA_RANK[name]
-            a = _collapse_arena(pool_leaves[i], rank)
+        elif r.kind in (PAGE, SEGMENT):
+            a = _collapse_arena(pool_leaves[i], r.rank)
             g = a[:, ids]  # [stacks, n, page, *feat]
-            if quant:
+            if quant and r.kind == PAGE:
                 sa = _collapse_arena(pool_leaves[scale_of[i]], 2)
                 g = dequantize_kv(g, sa[:, ids], row.dtype)
             g = g.reshape((g.shape[0], length) + g.shape[3:])
-            rr = _collapse_row(row, rank)
+            rr = _collapse_row(row, r.rank)
             out.append(
                 rr.at[:, :length].set(g.astype(rr.dtype)).reshape(row.shape)
             )
         else:
-            # Per-slot state lands here too: shared pages do not
-            # determine it (PagedSlotPool._match_prefix declines first).
-            raise ValueError(f"unknown row cache leaf {name!r}")
+            # Per-slot state: shared pages do not determine it
+            # (PagedSlotPool._match_prefix declines first).
+            raise ValueError(f"prefix attach: no rule for {name!r}")
     return tuple(out)
 
 
@@ -402,11 +352,10 @@ def _export_pages_jit(leaves, ids, *, names):
     TRACE_COUNTS["page_export"] += 1
     out = []
     for name, leaf in zip(names, leaves):
-        rank = _export_rank(name)
-        if rank is None:
-            continue
-        a = _collapse_arena(leaf, rank)
-        out.append(a[:, ids])  # [stacks, n, page, *feat]
+        r = role(name)
+        if r.in_arena:
+            a = _collapse_arena(leaf, r.rank)
+            out.append(a[:, ids])  # [stacks, n, page, *feat]
     return tuple(out)
 
 
@@ -423,11 +372,11 @@ def _import_pages_jit(leaves, page_arrays, ids, *, names):
     k = 0
     out = []
     for name, leaf in zip(names, leaves):
-        rank = _export_rank(name)
-        if rank is None:
+        r = role(name)
+        if not r.in_arena:
             out.append(leaf)
             continue
-        a = _collapse_arena(leaf, rank)
+        a = _collapse_arena(leaf, r.rank)
         vals = page_arrays[k].astype(leaf.dtype)
         out.append(a.at[:, ids].set(vals).reshape(leaf.shape))
         k += 1
@@ -458,20 +407,20 @@ def _splice_pages_jit(
     k = 0
     out = []
     for name, leaf in zip(names, leaves):
-        if name == "page_table":
+        r = role(name)
+        if r.kind == TABLE:
             out.append(leaf.at[..., slot, :].set(table_row))
             continue
-        if name == "cache_index":
+        if r.kind == CURSOR:
             out.append(leaf.at[..., slot].set(cache_idx))
             continue
-        rank = _export_rank(name)
-        if rank is None:
+        if not r.in_arena:
+            # splice_slot refuses a pool with per-slot state first.
             raise ValueError(
-                f"unknown paged cache leaf {name!r}: the page splice "
-                "must know every leaf's role (an untouched leaf would "
-                "leak the previous occupant's state)"
+                f"page splice: no bundle carries {name!r} ({r.kind}), and "
+                "an untouched leaf would leak the previous occupant's"
             )
-        a = _collapse_arena(leaf, rank)
+        a = _collapse_arena(leaf, r.rank)
         vals = page_arrays[k].astype(leaf.dtype)
         out.append(a.at[:, ids].set(vals).reshape(leaf.shape))
         k += 1
@@ -571,7 +520,7 @@ def _prefill_chunk_jit(
     )
     row_leaves = [
         jnp.full(l.shape, start + n_real, l.dtype)
-        if n == "cache_index" else l
+        if role(n).kind == CURSOR else l
         for n, l in zip(row_names, row_leaves)
     ]
     if seen_row is not None:
@@ -596,48 +545,35 @@ def _prefill_chunk_jit(
     # Padded tail slots scatter into reserved page 0 — the same junk
     # sink unmapped table entries read through.
     phys = jnp.where(valid, chunk_ids[in_win // page], 0)
+
+    def window(i, rank):
+        rr = _collapse_row(aligned[i], rank)
+        return jax.lax.dynamic_slice_in_dim(rr, start, width, axis=1)
+
     quantized = {}
     if quant:
         for i, name in enumerate(names):
-            if name in _ARENA_RANK:
-                rank = _ARENA_RANK[name]
-                rr = _collapse_row(aligned[i], rank)
-                win = jax.lax.dynamic_slice_in_dim(rr, start, width, axis=1)
-                quantized[i] = quantize_kv(win, n_feat=rank - 2)
+            r = role(name)
+            if r.kind == PAGE:
+                quantized[i] = quantize_kv(
+                    window(i, r.rank), n_feat=r.rank - 2
+                )
     out = []
     for i, (name, leaf) in enumerate(zip(names, leaves)):
-        if name in ("page_table", "cache_index") or name in STATE_LEAVES:
-            # finalize owns the pool-side cursors; per-slot state stays
-            # in the row twin until then (nothing to scatter).
+        r = role(name)
+        if not r.in_arena:
+            # finalize owns the pool-side table and cursors; per-slot
+            # state stays in the row twin until then (nothing to scatter).
             out.append(leaf)
-        elif name.endswith("_scale"):
-            scales = quantized[scale_src[i]][1]
-            a = _collapse_arena(leaf, 2)
-            out.append(a.at[:, phys, off].set(scales).reshape(leaf.shape))
-        elif name in _ARENA_RANK:
-            rank = _ARENA_RANK[name]
-            if quant:
-                vals = quantized[i][0]
-            else:
-                rr = _collapse_row(aligned[i], rank)
-                vals = jax.lax.dynamic_slice_in_dim(
-                    rr, start, width, axis=1
-                ).astype(leaf.dtype)
-            a = _collapse_arena(leaf, rank)
-            out.append(a.at[:, phys, off].set(vals).reshape(leaf.shape))
-        elif name == "cached_segment_ids":
-            rr = _collapse_row(aligned[i], 2)
-            vals = jax.lax.dynamic_slice_in_dim(
-                rr, start, width, axis=1
-            ).astype(leaf.dtype)
-            a = _collapse_arena(leaf, 2)
-            out.append(a.at[:, phys, off].set(vals).reshape(leaf.shape))
+            continue
+        if r.kind == SCALE:
+            vals = quantized[scale_src[i]][1]
+        elif quant and r.kind == PAGE:
+            vals = quantized[i][0]
         else:
-            raise ValueError(
-                f"unknown paged cache leaf {name!r}: the chunk scatter "
-                "must know every leaf's role (an untouched leaf would "
-                "leak the previous occupant's state)"
-            )
+            vals = window(i, r.rank).astype(leaf.dtype)
+        a = _collapse_arena(leaf, r.rank)
+        out.append(a.at[:, phys, off].set(vals).reshape(leaf.shape))
     row_out = jax.tree_util.tree_unflatten(row_treedef, row_leaves)
     return tuple(out), row_out, first, done0, seen_row
 
@@ -937,10 +873,7 @@ class PagedSlotPool(SlotPool):
             "page": self.page,
             "kv_quant": self.model.cfg.kv_quant or "",
             "n_pages": len(ids),
-            "paths": [
-                p for p, n in zip(paths, names)
-                if _export_rank(n) is not None
-            ],
+            "paths": self.exported_paths(),
             "arrays": [np.asarray(a) for a in arrays],
             "token": 0, "pos": 0, "remaining": 0, "done": True,
             "cache_index": 0, "seen": None,
@@ -974,10 +907,7 @@ class PagedSlotPool(SlotPool):
                 f"{len(page_ids)} were allocated"
             )
         paths, names, leaves, treedef = self._pool_flat()
-        want = [
-            p for p, n in zip(paths, names)
-            if _export_rank(n) is not None
-        ]
+        want = self.exported_paths()
         if list(state["paths"]) != want:
             raise ValueError(
                 "spill bundle leaf layout does not match this pool "
@@ -1044,15 +974,15 @@ class PagedSlotPool(SlotPool):
 
     @staticmethod
     def _scale_src(paths, names) -> Tuple[int, ...]:
-        """scale-leaf index -> its KV leaf's index (same path, name
-        minus the "_scale" suffix); -1 elsewhere."""
+        """scale-leaf index -> its KV leaf's index (same path, the
+        name its role says it scales); -1 elsewhere."""
         by_path = {p: i for i, p in enumerate(paths)}
         src = []
         for p, name in zip(paths, names):
-            if name.endswith("_scale"):
-                src.append(by_path[p.replace(name, name[: -len("_scale")])])
-            else:
-                src.append(-1)
+            r = role(name)
+            src.append(
+                by_path[p.replace(name, r.of)] if r.kind == SCALE else -1
+            )
         return tuple(src)
 
     def insert_paged(
@@ -1378,7 +1308,9 @@ class PagedSlotPool(SlotPool):
             self.done, self.remaining, slot
         )
         paths, names, leaves, treedef = self._pool_flat()
-        t_idx = [i for i, n in enumerate(names) if n == "page_table"]
+        t_idx = [
+            i for i, n in enumerate(names) if role(n).kind == TABLE
+        ]
         cleared = _clear_tables_jit(
             tuple(leaves[i] for i in t_idx), slot
         )
@@ -1395,10 +1327,7 @@ class PagedSlotPool(SlotPool):
         """Leaf paths that travel in a page bundle, in pool-flat order
         — the layout contract both ends of a migration must agree on."""
         paths, names, _, _ = self._pool_flat()
-        return [
-            p for p, n in zip(paths, names)
-            if _export_rank(n) is not None
-        ]
+        return [p for p, n in zip(paths, names) if role(n).in_arena]
 
     def export_slot(
         self, slot: int, page_ids: Optional[Sequence[int]] = None
@@ -1426,7 +1355,7 @@ class PagedSlotPool(SlotPool):
         )
         cache_index = 0
         for n, leaf in zip(names, leaves):
-            if n == "cache_index":
+            if role(n).kind == CURSOR:
                 # Every layer carries the same per-slot value.
                 cache_index = int(
                     np.asarray(leaf).reshape(-1, self.n_slots)[0, slot]
@@ -1439,10 +1368,7 @@ class PagedSlotPool(SlotPool):
             "page": self.page,
             "kv_quant": self.model.cfg.kv_quant or "",
             "n_pages": len(ids),
-            "paths": [
-                p for p, n in zip(paths, names)
-                if _export_rank(n) is not None
-            ],
+            "paths": self.exported_paths(),
             "arrays": [np.asarray(a) for a in arrays],
             "token": int(np.asarray(self.token)[slot]),
             "pos": int(np.asarray(self.pos)[slot]),
@@ -1481,10 +1407,7 @@ class PagedSlotPool(SlotPool):
                 f"{len(page_ids)} were allocated"
             )
         paths, names, leaves, treedef = self._pool_flat()
-        want = [
-            p for p, n in zip(paths, names)
-            if _export_rank(n) is not None
-        ]
+        want = self.exported_paths()
         if list(state["paths"]) != want:
             raise ValueError(
                 "bundle leaf layout does not match this pool "

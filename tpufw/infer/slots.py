@@ -21,10 +21,10 @@ move requests through it —
 
 Per-slot cache cursors ride the flax "cache" collection as a ``[S]``
 vector ``cache_index`` (trailing-slot-axis convention; the models'
-``_cached_attention`` branches on cursor rank). ``TRACE_COUNTS`` is
-bumped at TRACE time inside each op, so tests (and operators) can
-assert the shape-stability contract: inserts/retires at steady state
-add ZERO new traces.
+store, ``tpufw.ops.kv_store.append``, branches on cursor rank).
+``TRACE_COUNTS`` is bumped at TRACE time inside each op, so tests (and
+operators) can assert the shape-stability contract: inserts/retires at
+steady state add ZERO new traces.
 """
 
 from __future__ import annotations
@@ -40,23 +40,12 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from tpufw.infer.generate import _model_apply, _stream_prefill
 from tpufw.infer.sampling import SamplingConfig, sample_token
+from tpufw.ops.kv_store import STATE, STATE_LEAVES, path_role
 
 # Bumped INSIDE the jitted bodies, i.e. once per (re)trace, never per
 # call: the cheap, version-proof way to assert "occupancy changes do
 # not recompile" without reaching into jax internals.
 TRACE_COUNTS: Dict[str, int] = {"insert": 0, "decode_steps": 0, "retire": 0}
-
-#: Cache leaves that are per-slot STATE, by unstacked rank with the
-#: batch axis first ([*stack, B, *feat]): what a row keeps between steps
-#: that is neither a page of keys and values nor a table or cursor —
-#: a linear-attention layer's recurrent state and its convolution's
-#: tail (tpufw.models.solar_open2.KDALayer). The third role a cache
-#: leaf can have; every program that moves rows in or out of a pool
-#: decides what it does with one (tpufw.infer.pages). A prefix of
-#: pages does not determine it, a verify block cannot rewind it and a
-#: page bundle does not carry it, so the prefix trie, speculation and
-#: slot export decline a model that has any (``reject_state``).
-STATE_LEAVES = {"kda_state": 4, "conv_state": 3}
 
 
 def state_leaf_bytes(cache) -> int:
@@ -65,7 +54,7 @@ def state_leaf_bytes(cache) -> int:
     return sum(
         int(leaf.nbytes)
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
-        if str(getattr(path[-1], "key", path[-1])) in STATE_LEAVES
+        if path_role(path).kind == STATE
     )
 
 
@@ -199,7 +188,7 @@ def _decode_steps_jit(
     a row emits its token THEN burns budget, so the EOS/boundary token
     itself is delivered and the row freezes after. Done rows keep
     stepping (static shapes; masking, not control flow) but feed pad
-    back and emit pad out. Per-slot state (STATE_LEAVES) rides in
+    back and emit pad out. Per-slot state (kv_store role STATE) rides in
     ``cache`` and is updated in place by the model, a done row's too:
     it is junk from then on and the next insert overwrites all of it.
     """
@@ -304,7 +293,7 @@ class SlotPool:
         self.home = home
         for name in ("cache", "token", "pos", "done", "remaining", "seen"):
             setattr(self, name, jax.device_put(getattr(self, name), home))
-        #: Bytes of per-slot state (STATE_LEAVES) this pool holds.
+        #: Bytes of per-slot state (kv_store role STATE) this pool holds.
         self.state_bytes = state_leaf_bytes(self.cache)
 
     @classmethod

@@ -52,7 +52,7 @@ TPU-first shape discipline, mirroring ``generate``:
 - cache rollback is O(1) bookkeeping: rewind the scalar ``cache_index``
   and zero ``cached_segment_ids`` beyond it — never-valid slots are
   masked by segment 0 exactly like never-written ones
-  (tpufw.models.llama Attention._cached_attention), and the next
+  (tpufw.ops.kv_store), and the next
   iteration's write overwrites them.
 """
 
@@ -71,7 +71,9 @@ from tpufw.infer.generate import (
     prefill_cache,
 )
 from tpufw.infer.sampling import SamplingConfig, sample_token, transform_logits
-from tpufw.infer.slots import STATE_LEAVES
+from tpufw.ops.kv_store import (
+    CURSOR, SEGMENT, STATE, leaf_name, path_role,
+)
 
 # Trace-time counters for the CHUNKED slot-pool speculation below —
 # same contract as tpufw.infer.slots.TRACE_COUNTS: bumped once per
@@ -86,17 +88,17 @@ def _rollback(cache: dict, new_cursor: jax.Array) -> dict:
     lands on them. Keys/values stay — masking, not control flow."""
 
     def fix(path, leaf):
-        name = getattr(path[-1], "key", None)
-        if name in STATE_LEAVES:
+        kind = path_role(path).kind
+        if kind == STATE:
             raise ValueError(
-                f"speculative decoding: cache leaf {name!r} is per-slot "
-                "state, which a rejected draft has already advanced and "
-                "no cursor can rewind"
+                f"speculative decoding: cache leaf {leaf_name(path)!r} is "
+                "per-slot state, which a rejected draft has already "
+                "advanced and no cursor can rewind"
             )
-        if name == "cache_index":
+        if kind == CURSOR:
             # nn.scan stacks per-layer cursors into [L]; keep the shape.
             return jnp.full(leaf.shape, new_cursor, leaf.dtype)
-        if name == "cached_segment_ids":
+        if kind == SEGMENT:
             # [*stack, B, S]: mask the trailing slot axis.
             live = jnp.arange(leaf.shape[-1]) < new_cursor
             return jnp.where(live, leaf, 0)
@@ -109,7 +111,7 @@ def _cursor(cache: dict) -> jax.Array:
     """The shared cache_index of a decode cache pytree as a scalar
     (nn.scan stacks identical per-layer cursors into [L])."""
     for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-        if getattr(path[-1], "key", None) == "cache_index":
+        if path_role(path).kind == CURSOR:
             return jnp.max(leaf)
     raise ValueError("no cache_index in cache pytree")
 
@@ -606,7 +608,7 @@ def _pool_cursor(cache: dict, n_slots: int) -> jax.Array:
     cache_index leaf: [S] or nn.scan-stacked [L, S] — rows identical
     by construction)."""
     for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-        if getattr(path[-1], "key", None) == "cache_index":
+        if path_role(path).kind == CURSOR:
             return leaf.reshape(-1, n_slots)[0]
     raise ValueError("no cache_index in cache pytree")
 
@@ -617,7 +619,7 @@ def _set_pool_cursor(cache: dict, new: jax.Array) -> dict:
     see the module comment on mask-covered stale entries."""
 
     def fix(path, leaf):
-        if getattr(path[-1], "key", None) == "cache_index":
+        if path_role(path).kind == CURSOR:
             return jnp.broadcast_to(new.astype(leaf.dtype), leaf.shape)
         return leaf
 

@@ -61,8 +61,8 @@ from tpufw.models.llama import (
     projection,
 )
 from tpufw.models.mixtral import MoEMLP
-from tpufw.ops.attention import multi_head_attention
-from tpufw.ops.quant import dequantize_kv, quantize_kv
+from tpufw.ops import kv_store
+from tpufw.ops.attention import attention_mask, multi_head_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -486,144 +486,18 @@ class MLAttention(nn.Module):
     ):
         """Decode with the latent cache and absorbed up-projections.
 
-        Cache holds ``c_kv`` [B, S, kvr] + roped ``k_pe`` [B, S, dr]
-        (the MLA memory win). Scores: W_uk is folded into the query
-        (``q_lat = q_nope @ W_uk``), so nope-scores contract in latent
-        space; the output contracts attention-weighted latents with
-        W_uv once. Slot-ordered causality + segment masking follow
-        tpufw.models.llama Attention._cached_attention exactly.
+        The store (tpufw.ops.kv_store) holds ``c_kv`` [B, S, kvr] +
+        roped ``k_pe`` [B, S, dr] (the MLA memory win). Scores: W_uk is
+        folded into the query (``q_lat = q_nope @ W_uk``), so
+        nope-scores contract in latent space; the output contracts
+        attention-weighted latents with W_uv once.
         """
         cfg = self.cfg
-        b, t = q_nope.shape[:2]
-        kvr, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         dn = cfg.qk_nope_head_dim
-
-        seg = (
-            jnp.ones((b, t), jnp.int32) if segment_ids is None
-            else segment_ids.astype(jnp.int32)
+        views, seg, kv_seg, q_slots = kv_store.append(
+            self, cfg, {"cached_ckv": c_kv, "cached_kpe": k_pe}, segment_ids
         )
-        if getattr(cfg, "kv_page", 0):
-            # Paged latent arenas — layout/masking contract mirrors
-            # llama Attention._paged_cached_attention (page 0 reserved,
-            # gather reconstructs the logical row in slot order, junk
-            # beyond the cursor dies in the causal fill below; t > 1
-            # is the speculative verify block, same slot-ordered
-            # causality over the just-scattered tokens). Prefill runs
-            # contiguous and is paged at insert (tpufw.infer.pages).
-            page, n_pages = cfg.kv_page, cfg.kv_pages
-            if cfg.max_seq_len % page:
-                raise ValueError(
-                    f"kv_page={page} must divide "
-                    f"max_seq_len={cfg.max_seq_len}"
-                )
-            per_row = cfg.max_seq_len // page
-            quant = cfg.kv_quant == "int8"
-            kv_dtype = jnp.int8 if quant else cfg.dtype
-            cc = self.variable(
-                "cache", "cached_ckv",
-                jnp.zeros, (n_pages, page, kvr), kv_dtype,
-            )
-            cp = self.variable(
-                "cache", "cached_kpe",
-                jnp.zeros, (n_pages, page, dr), kv_dtype,
-            )
-            cseg = self.variable(
-                "cache", "cached_segment_ids",
-                jnp.zeros, (n_pages, page), jnp.int32,
-            )
-            table = self.variable(
-                "cache", "page_table", jnp.zeros, (b, per_row), jnp.int32
-            )
-            cursor = self.variable(
-                "cache", "cache_index", jnp.zeros, (b,), jnp.int32
-            )
-            if quant:
-                ccs = self.variable(
-                    "cache", "cached_ckv_scale",
-                    jnp.zeros, (n_pages, page), jnp.float32,
-                )
-                cps = self.variable(
-                    "cache", "cached_kpe_scale",
-                    jnp.zeros, (n_pages, page), jnp.float32,
-                )
-            cur = cursor.value
-            cur_w = jnp.minimum(cur, cfg.max_seq_len - t)
-            wslot = cur_w[:, None] + jnp.arange(t)[None, :]  # [B, t]
-            phys = table.value[jnp.arange(b)[:, None], wslot // page]
-            off = wslot % page
-            if quant:
-                qc, sc = quantize_kv(c_kv, n_feat=1)
-                qp, sp = quantize_kv(k_pe, n_feat=1)
-                cc.value = cc.value.at[phys, off].set(qc)
-                cp.value = cp.value.at[phys, off].set(qp)
-                ccs.value = ccs.value.at[phys, off].set(sc)
-                cps.value = cps.value.at[phys, off].set(sp)
-            else:
-                cc.value = cc.value.at[phys, off].set(
-                    c_kv.astype(cfg.dtype)
-                )
-                cp.value = cp.value.at[phys, off].set(
-                    k_pe.astype(cfg.dtype)
-                )
-            cseg.value = cseg.value.at[phys, off].set(seg)
-            cursor.value = cur + t
-            idx = table.value
-            s = cfg.max_seq_len
-            if quant:
-                ckv_all = dequantize_kv(
-                    cc.value[idx], ccs.value[idx], cfg.dtype
-                ).reshape(b, s, kvr)
-                kpe_all = dequantize_kv(
-                    cp.value[idx], cps.value[idx], cfg.dtype
-                ).reshape(b, s, dr)
-            else:
-                ckv_all = cc.value[idx].reshape(b, s, kvr)
-                kpe_all = cp.value[idx].reshape(b, s, dr)
-            cseg_all = cseg.value[idx].reshape(b, s)
-        else:
-            cc = self.variable(
-                "cache", "cached_ckv",
-                jnp.zeros, (b, cfg.max_seq_len, kvr), cfg.dtype,
-            )
-            cp = self.variable(
-                "cache", "cached_kpe",
-                jnp.zeros, (b, cfg.max_seq_len, dr), cfg.dtype,
-            )
-            cseg = self.variable(
-                "cache", "cached_segment_ids",
-                jnp.zeros, (b, cfg.max_seq_len), jnp.int32,
-            )
-            cursor = self.variable(
-                "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
-            )
-            cur = cursor.value
-            if cur.ndim == 0:
-                cc.value = jax.lax.dynamic_update_slice(
-                    cc.value, c_kv.astype(cfg.dtype), (0, cur, 0)
-                )
-                cp.value = jax.lax.dynamic_update_slice(
-                    cp.value, k_pe.astype(cfg.dtype), (0, cur, 0)
-                )
-                cseg.value = jax.lax.dynamic_update_slice(
-                    cseg.value, seg, (0, cur)
-                )
-                cur_w = cur
-            else:
-                # Per-row cursors [B] (tpufw.infer.slots pool decode) —
-                # see llama Attention._cached_attention for the clamp
-                # rationale.
-                cur_w = jnp.minimum(cur, cfg.max_seq_len - t)
-                rows = jnp.arange(b)[:, None]
-                cols = cur_w[:, None] + jnp.arange(t)[None, :]
-                cc.value = cc.value.at[rows, cols].set(
-                    c_kv.astype(cfg.dtype)
-                )
-                cp.value = cp.value.at[rows, cols].set(
-                    k_pe.astype(cfg.dtype)
-                )
-                cseg.value = cseg.value.at[rows, cols].set(seg)
-            cursor.value = cur + t
-            ckv_all, kpe_all, cseg_all = cc.value, cp.value, cseg.value
+        ckv_all, kpe_all = views["cached_ckv"], views["cached_kpe"]
 
         w_uk, w_uv = kv_b[..., :dn], kv_b[..., dn:]  # [kvr, H, dn/dv]
         # Absorb W_uk into the query: [B,T,H,dn] x [kvr,H,dn] -> latent
@@ -633,7 +507,6 @@ class MLAttention(nn.Module):
             q_nope.astype(cfg.dtype),
             w_uk.astype(cfg.dtype),
         )
-        s = cfg.max_seq_len
         logits = (
             jnp.einsum(
                 "bthr,bsr->bhts", q_lat, ckv_all,
@@ -644,17 +517,11 @@ class MLAttention(nn.Module):
                 preferred_element_type=jnp.float32,
             )
         ) * (float(cfg.qk_head_dim) ** -0.5)
-        # Causality over cache SLOTS (RoPE positions lag slots under
-        # left-padding); never-written slots keep segment 0. With
-        # per-row cursors this is [B,T,1] instead of [1,T,1].
-        slot_pos = (cur_w[..., None] + jnp.arange(t))[..., None]
-        mask = slot_pos >= jnp.arange(s)  # [.,T,S]
-        if mask.ndim == 2:
-            mask = mask[None]
-        seg_mask = seg[:, :, None] == cseg_all[:, None, :]  # [B,T,S]
-        logits = jnp.where(
-            (mask & seg_mask)[:, None, :, :], logits, -1e30
+        mask = attention_mask(
+            q_slots.shape[1], cfg.max_seq_len, segment_ids=seg,
+            kv_segment_ids=kv_seg, q_positions=q_slots,
         )
+        logits = jnp.where(mask, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
         # Attention-weighted latents, then ONE W_uv application.
         ctx_lat = jnp.einsum("bhts,bsr->bthr", probs, ckv_all)

@@ -29,8 +29,7 @@ import jax.numpy as jnp
 from jax import ad_checkpoint
 from flax import linen as nn
 
-from tpufw.ops import multi_head_attention, rms_norm
-from tpufw.ops.quant import dequantize_kv, quantize_kv
+from tpufw.ops import kv_store, multi_head_attention, rms_norm
 
 Dtype = Any
 
@@ -597,7 +596,7 @@ class Attention(nn.Module):
                     "causal construct — bidirectional models embed, "
                     "they don't autoregress"
                 )
-            out = self._cached_attention(q, k, v, segment_ids, positions)
+            out = self._cached_attention(q, k, v, segment_ids)
         else:
             out = multi_head_attention(
                 q,
@@ -623,186 +622,22 @@ class Attention(nn.Module):
             ("heads", "head_dim"), ("embed",), "o",
         )
 
-    def _cached_attention(self, q, k, v, segment_ids, positions):
-        """KV-cache step: append this call's k/v at the cache cursor, then
-        attend q (at ``positions``) over the whole cache. Static shapes —
-        the cache is always [B, max_seq_len] and masking does the rest:
-        never-written slots keep segment 0, so the segment mask hides them
-        (prompt pad slots stay 0 too, handled by the same mechanism).
-        """
+    def _cached_attention(self, q, k, v, segment_ids):
+        """KV-cache step: append this call's k/v to the store, then attend
+        q (at the slots it was written to) over the whole logical row
+        (tpufw.ops.kv_store: layouts, masking and clamp rationale)."""
         cfg = self.cfg
-        if getattr(cfg, "kv_page", 0):
-            return self._paged_cached_attention(q, k, v, segment_ids)
-        b, t = q.shape[:2]
-        shape = (b, cfg.max_seq_len, cfg.n_kv_heads, cfg.head_dim)
-        ck = self.variable("cache", "cached_key", jnp.zeros, shape, cfg.dtype)
-        cv = self.variable(
-            "cache", "cached_value", jnp.zeros, shape, cfg.dtype
+        views, seg, kv_seg, q_slots = kv_store.append(
+            self, cfg, {"cached_key": k, "cached_value": v}, segment_ids
         )
-        cseg = self.variable(
-            "cache", "cached_segment_ids",
-            jnp.zeros, (b, cfg.max_seq_len), jnp.int32,
-        )
-        cursor = self.variable(
-            "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
-        )
-        cur = cursor.value
-        seg = (
-            jnp.ones((b, t), jnp.int32) if segment_ids is None
-            else segment_ids.astype(jnp.int32)
-        )
-        if cur.ndim == 0:
-            ck.value = jax.lax.dynamic_update_slice(
-                ck.value, k.astype(cfg.dtype), (0, cur, 0, 0)
-            )
-            cv.value = jax.lax.dynamic_update_slice(
-                cv.value, v.astype(cfg.dtype), (0, cur, 0, 0)
-            )
-            cseg.value = jax.lax.dynamic_update_slice(
-                cseg.value, seg, (0, cur)
-            )
-            # Causality is over cache SLOTS, not RoPE positions — under
-            # left-padding a token's RoPE position lags its slot by pad_len
-            # and would wrongly mask valid recent slots.
-            slot_positions = jnp.broadcast_to(cur + jnp.arange(t), (b, t))
-        else:
-            # Per-row cursors [B] (tpufw.infer.slots pool decode): each
-            # slot writes at its own offset. Clamp the write window so a
-            # retired-but-still-stepped row scatters in bounds; its output
-            # is masked host-side, and the clamped slot is overwritten by
-            # the next insert's full-cache copy.
-            cur_w = jnp.minimum(cur, cfg.max_seq_len - t)
-            rows = jnp.arange(b)[:, None]
-            cols = cur_w[:, None] + jnp.arange(t)[None, :]
-            ck.value = ck.value.at[rows, cols].set(k.astype(cfg.dtype))
-            cv.value = cv.value.at[rows, cols].set(v.astype(cfg.dtype))
-            cseg.value = cseg.value.at[rows, cols].set(seg)
-            slot_positions = cur_w[:, None] + jnp.arange(t)[None, :]
-        cursor.value = cur + t
         return multi_head_attention(
             q,
-            ck.value,
-            cv.value,
+            views["cached_key"],
+            views["cached_value"],
             causal=True,
             segment_ids=seg,
-            kv_segment_ids=cseg.value,
-            q_positions=slot_positions,
-            logits_soft_cap=getattr(cfg, "attn_logit_soft_cap", None),
-            sliding_window=self.window,
-            backend="xla",
-        )
-
-    def _paged_cached_attention(self, q, k, v, segment_ids):
-        """Paged KV-cache decode step (cfg.kv_page > 0).
-
-        Storage is a global arena of ``kv_pages`` pages x ``kv_page``
-        slots shared by every row; ``page_table`` [B, S/page] maps each
-        row's logical slot j to physical page table[j // page], offset
-        j % page. The gather read reconstructs the logical [B, S] row
-        IN LOGICAL SLOT ORDER, so attention sees exactly what the
-        contiguous branch sees at every written slot and the output is
-        bit-equal at matching precision: unmapped table entries point at
-        reserved page 0, whose junk only ever surfaces at logical slots
-        strictly beyond the row's cursor, where the causal mask fills
-        the logit before softmax (exp underflows to exact 0.0, and
-        0.0 * finite-junk-V == 0.0). Occupancy, table churn, and cursor
-        motion are all DATA — one jitted program forever.
-
-        t == 1 is the plain decode step; t > 1 is the speculative
-        verify block (tpufw.infer.speculative chunked path): all t
-        tokens scatter into consecutive logical slots first, then the
-        gather reconstructs the row INCLUDING the block, so intra-block
-        causality falls out of the same slot-ordered mask. Prefill
-        still runs through a contiguous row cache and is scattered into
-        pages at insert (tpufw.infer.pages).
-        """
-        cfg = self.cfg
-        b, t = q.shape[:2]
-        page, n_pages = cfg.kv_page, cfg.kv_pages
-        if cfg.max_seq_len % page:
-            raise ValueError(
-                f"kv_page={page} must divide max_seq_len={cfg.max_seq_len}"
-            )
-        per_row = cfg.max_seq_len // page
-        quant = cfg.kv_quant == "int8"
-        kv_dtype = jnp.int8 if quant else cfg.dtype
-        shape = (n_pages, page, cfg.n_kv_heads, cfg.head_dim)
-        ck = self.variable("cache", "cached_key", jnp.zeros, shape, kv_dtype)
-        cv = self.variable(
-            "cache", "cached_value", jnp.zeros, shape, kv_dtype
-        )
-        cseg = self.variable(
-            "cache", "cached_segment_ids",
-            jnp.zeros, (n_pages, page), jnp.int32,
-        )
-        table = self.variable(
-            "cache", "page_table", jnp.zeros, (b, per_row), jnp.int32
-        )
-        # Per-row cursor from birth (the paged pool always decodes with
-        # one token per row) — no scalar branch to diverge on.
-        cursor = self.variable(
-            "cache", "cache_index", jnp.zeros, (b,), jnp.int32
-        )
-        if quant:
-            cks = self.variable(
-                "cache", "cached_key_scale",
-                jnp.zeros, (n_pages, page), jnp.float32,
-            )
-            cvs = self.variable(
-                "cache", "cached_value_scale",
-                jnp.zeros, (n_pages, page), jnp.float32,
-            )
-        cur = cursor.value
-        seg = (
-            jnp.ones((b, t), jnp.int32) if segment_ids is None
-            else segment_ids.astype(jnp.int32)
-        )
-        # Same write-window clamp as the contiguous per-row branch: a
-        # done-but-still-stepped row keeps scattering in bounds. Its
-        # writes land either in its own private last page (the
-        # allocator never shares a row's final page; speculative
-        # callers keep t <= page so the clamped window never leaves
-        # it) or, once retired (table zeroed), in reserved page 0.
-        wslot = (
-            jnp.minimum(cur, cfg.max_seq_len - t)[:, None]
-            + jnp.arange(t)[None, :]
-        )  # [B, t] logical write slots
-        phys = table.value[jnp.arange(b)[:, None], wslot // page]
-        off = wslot % page
-        if quant:
-            qk, sk = quantize_kv(k, n_feat=2)
-            qv, sv = quantize_kv(v, n_feat=2)
-            ck.value = ck.value.at[phys, off].set(qk)
-            cv.value = cv.value.at[phys, off].set(qv)
-            cks.value = cks.value.at[phys, off].set(sk)
-            cvs.value = cvs.value.at[phys, off].set(sv)
-        else:
-            ck.value = ck.value.at[phys, off].set(k.astype(cfg.dtype))
-            cv.value = cv.value.at[phys, off].set(v.astype(cfg.dtype))
-        cseg.value = cseg.value.at[phys, off].set(seg)
-        cursor.value = cur + t
-        # Gather the logical view: [B, per_row] table -> [B, S, ...].
-        idx = table.value
-        s = cfg.max_seq_len
-        feat = (cfg.n_kv_heads, cfg.head_dim)
-        if quant:
-            k_all = dequantize_kv(
-                ck.value[idx], cks.value[idx], cfg.dtype
-            ).reshape(b, s, *feat)
-            v_all = dequantize_kv(
-                cv.value[idx], cvs.value[idx], cfg.dtype
-            ).reshape(b, s, *feat)
-        else:
-            k_all = ck.value[idx].reshape(b, s, *feat)
-            v_all = cv.value[idx].reshape(b, s, *feat)
-        return multi_head_attention(
-            q,
-            k_all,
-            v_all,
-            causal=True,
-            segment_ids=seg,
-            kv_segment_ids=cseg.value[idx].reshape(b, s),
-            q_positions=wslot,
+            kv_segment_ids=kv_seg,
+            q_positions=q_slots,
             logits_soft_cap=getattr(cfg, "attn_logit_soft_cap", None),
             sliding_window=self.window,
             backend="xla",

@@ -14,7 +14,7 @@ followed by sigmoid-routed experts beside a shared one.
   calls is NOT keys and values: per head a [d_k, d_v] float32 state and
   the convolution's last ``kernel - 1`` inputs, cache leaves
   ``kda_state`` and ``conv_state`` with the batch axis first. The pools
-  carry them as per-slot state (``tpufw.infer.pages.STATE_LEAVES``).
+  carry them as per-slot state (``tpufw.ops.kv_store``: role STATE).
 - **Expert layer**: ``deepseek.DeepseekMoE`` with sigmoid scoring and a
   selection bias (``tpufw.ops.moe``), told which experts this chip
   holds (``experts_held``).
@@ -40,7 +40,7 @@ from tpufw.models.llama import (
     decoder_lm,
     projection,
 )
-from tpufw.ops import rms_norm
+from tpufw.ops import kv_store, rms_norm
 from tpufw.ops.kda import causal_conv, kda_chunk, kda_step
 
 LAYER_KINDS = ("gqa", "kda")
@@ -194,12 +194,11 @@ class KDALayer(nn.Module):
             axis=-1,
         )
         if cfg.decode:
-            tail = self.variable(
-                "cache", "conv_state", jnp.zeros, (b, km1, 3 * c), cfg.dtype
+            tail = kv_store.slot_state(
+                self, "conv_state", (b, km1, 3 * c), cfg.dtype
             )
-            state = self.variable(
-                "cache", "kda_state",
-                jnp.zeros, (b, h, dk, dk), KDA_STATE_DTYPE,
+            state = kv_store.slot_state(
+                self, "kda_state", (b, h, dk, dk), KDA_STATE_DTYPE
             )
             tail0, s0 = tail.value, state.value
         else:

@@ -54,6 +54,45 @@ def _repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     return x.reshape(b, s, k * n_rep, d)
 
 
+def attention_mask(
+    t: int,
+    s: int,
+    *,
+    causal: bool = True,
+    segment_ids: Optional[jax.Array] = None,
+    kv_segment_ids: Optional[jax.Array] = None,
+    q_positions: Optional[jax.Array] = None,
+    sliding_window: Optional[int] = None,
+) -> Optional[jax.Array]:
+    """Which of S keys each of T queries may attend: bool, broadcastable
+    to [B, 1, T, S], or None when nothing is masked. The ONE predicate —
+    ``xla_attention`` and MLA's absorbed decode (a different
+    contraction over the same cache slots) both fill with it. Arguments
+    as ``xla_attention``'s."""
+    mask = None
+    kpos = jnp.arange(s)[None, None, None, :]  # [1,1,1,S]
+    if causal or sliding_window is not None:
+        if q_positions is None:
+            # Align query i with absolute position s-t+i.
+            qpos = (jnp.arange(t) + (s - t))[None, None, :, None]
+        else:
+            qpos = q_positions[:, None, :, None]  # [B,1,T,1]
+        if causal:
+            mask = qpos >= kpos
+        if sliding_window is not None:
+            # Local attention (Gemma-style): only the last
+            # ``sliding_window`` positions are visible.
+            near = (qpos - kpos) < sliding_window
+            mask = near if mask is None else (mask & near)
+    if segment_ids is not None:
+        kv_seg = kv_segment_ids if kv_segment_ids is not None else segment_ids
+        seg_mask = (
+            segment_ids[:, None, :, None] == kv_seg[:, None, None, :]
+        )
+        mask = seg_mask if mask is None else (mask & seg_mask)
+    return mask
+
+
 def xla_attention(
     q: jax.Array,
     k: jax.Array,
@@ -71,7 +110,7 @@ def xla_attention(
     ``segment_ids`` ([B, T] int) masks cross-segment attention for packed
     sequences; ``kv_segment_ids`` ([B, S]) gives the key side its own ids
     when q and kv lengths differ (KV-cache decode — cached pad slots carry
-    segment 0 and are never attended). ``q_positions`` ([B, T] int) are the
+    segment 0 and are never attended). ``q_positions`` ([B or 1, T] int) are the
     queries' absolute positions in the S-long key axis for causal masking;
     default assumes queries are the final T positions. Softmax is computed
     in float32 regardless of input dtype — bf16 logits lose too much
@@ -97,27 +136,11 @@ def xla_attention(
     if logits_soft_cap is not None:
         logits = tanh_soft_cap(logits, logits_soft_cap)
 
-    mask = None
-    kpos = jnp.arange(s)[None, None, None, :]  # [1,1,1,S]
-    if causal or sliding_window is not None:
-        if q_positions is None:
-            # Align query i with absolute position s-t+i.
-            qpos = (jnp.arange(t) + (s - t))[None, None, :, None]
-        else:
-            qpos = q_positions[:, None, :, None]  # [B,1,T,1]
-        if causal:
-            mask = qpos >= kpos
-        if sliding_window is not None:
-            # Local attention (Gemma-style): only the last
-            # ``sliding_window`` positions are visible.
-            near = (qpos - kpos) < sliding_window
-            mask = near if mask is None else (mask & near)
-    if segment_ids is not None:
-        kv_seg = kv_segment_ids if kv_segment_ids is not None else segment_ids
-        seg_mask = (
-            segment_ids[:, None, :, None] == kv_seg[:, None, None, :]
-        )
-        mask = seg_mask if mask is None else (mask & seg_mask)
+    mask = attention_mask(
+        t, s, causal=causal, segment_ids=segment_ids,
+        kv_segment_ids=kv_segment_ids, q_positions=q_positions,
+        sliding_window=sliding_window,
+    )
     if mask is not None:
         logits = jnp.where(mask, logits, -1e30)
 

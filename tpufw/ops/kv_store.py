@@ -1,0 +1,226 @@
+"""The KV store: how a decode cache is laid out, written and read.
+
+A model says what it stores per token (``append``: llama's K/V heads,
+DeepSeek's MLA latents) or per row (``slot_state``: a linear-attention
+layer's recurrent state) and how it attends; this module owns the
+leaves of the flax "cache" collection, their names and their roles.
+``tpufw.infer`` moves rows in and out of pools by asking ``role()``
+what a leaf is, never by its name: a family that stores a new kind of
+page adds one row to ``_LEAVES`` and nothing to ``infer/``.
+
+Layouts (chosen from what the code can observe: ``cfg.kv_page``, the
+cursor's rank, ``cfg.kv_quant``):
+
+- CONTIGUOUS, ``[B, max_seq_len, *feat]`` rows. Static shapes: masking
+  does the rest. Never-written slots keep segment 0, so the segment
+  mask hides them (prompt pad slots stay 0 too). A scalar cursor
+  (``generate``, and every B=1 prefill: the row twin of a chunked
+  prefill is this store) appends with ``dynamic_update_slice``; a
+  ``[B]`` cursor (tpufw.infer.slots pool decode) scatters each row at
+  its own offset. The write window is clamped so a retired-but-still-
+  stepped row scatters in bounds: its output is masked host-side and
+  the clamped slots are overwritten by the next insert's whole-row
+  copy. This store is the tests' reference for the paged one.
+- PAGED (``cfg.kv_page > 0``), a global arena of ``kv_pages`` pages x
+  ``kv_page`` slots shared by every row; ``page_table`` [B, S/page]
+  maps a row's logical slot j to physical page table[j // page], offset
+  j % page. The view gathers the logical [B, S] row IN LOGICAL SLOT
+  ORDER, so attention sees exactly what the contiguous store shows at
+  every written slot and the output is bit-equal at matching
+  precision. Unmapped table entries point at reserved page 0, the junk
+  sink: its contents only ever surface at logical slots strictly
+  beyond the row's cursor, where the causal mask fills the logit
+  before softmax (exp underflows to exact 0.0, and 0.0 * finite junk
+  == 0.0). The same clamp keeps a done row's writes either in its own
+  private last page (the allocator never shares a row's final page;
+  speculative callers keep t <= page so the clamped window never leaves
+  it) or, once retired (table zeroed), in page 0 — never a neighbour's.
+  Occupancy, table churn and cursor motion are all DATA: one jitted
+  program forever. The cursor is ``[B]`` from birth. Prefill still runs
+  through a contiguous row and is scattered into pages at insert
+  (tpufw.infer.pages).
+- INT8 (``cfg.kv_quant == "int8"``, paged only): arenas hold int8
+  codes, quantized per token at append, with float32 scales stored
+  page-structured ``[kv_pages, kv_page]`` and applied at the view.
+
+t == 1 is the plain decode step; t > 1 is a prefill chunk (contiguous)
+or the speculative verify block (tpufw.infer.speculative): all t tokens
+land in consecutive logical slots first, then the view includes them,
+so intra-block causality falls out of the same slot-ordered mask.
+Causality is over cache SLOTS (``q_slots``), not RoPE positions: under
+left-padding a token's RoPE position lags its slot by the pad length
+and would wrongly mask valid recent slots.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from tpufw.ops.quant import dequantize_kv, quantize_kv
+
+PAGE, SCALE, SEGMENT, TABLE, CURSOR, STATE = (
+    "page", "scale", "segment", "table", "cursor", "state"
+)
+
+
+class Role(NamedTuple):
+    """What a cache leaf is. ``rank`` is its unstacked rank in a paged
+    pool (nn.scan stacks layer axes in front): ``(n_pages, page, *feat)``
+    for what lives in the arena — the trailing ``rank - 2`` dims of a
+    PAGE are the per-token feature block one int8 scale covers, and the
+    matching row leaf is ``(1, W, *feat)`` at the same rank —
+    ``(B, *feat)`` for per-slot STATE. ``of`` names the PAGE leaf a
+    SCALE belongs to."""
+
+    kind: str
+    rank: int
+    of: str = ""
+
+    @property
+    def in_arena(self) -> bool:
+        """Indexed by physical page: what a page bundle carries."""
+        return self.kind in (PAGE, SCALE, SEGMENT)
+
+
+_SEGMENT, _TABLE, _CURSOR = "cached_segment_ids", "page_table", "cache_index"
+_SCALE = "_scale"
+_LEAVES: Dict[str, Role] = {
+    "cached_key": Role(PAGE, 4), "cached_value": Role(PAGE, 4),  # K/V heads
+    "cached_ckv": Role(PAGE, 3), "cached_kpe": Role(PAGE, 3),  # MLA latents
+    _SEGMENT: Role(SEGMENT, 2),
+    _TABLE: Role(TABLE, 2),
+    _CURSOR: Role(CURSOR, 1),
+    # Per-slot STATE: what a row keeps between steps that is neither a
+    # page nor a table or cursor (tpufw.models.solar_open2.KDALayer: the
+    # recurrent state and its convolution's tail). A prefix of pages
+    # does not determine it, a verify block cannot rewind it and a page
+    # bundle does not carry it, so the prefix trie, speculation and slot
+    # export decline a model that has any (tpufw.infer.slots
+    # ``reject_state``).
+    "kda_state": Role(STATE, 4), "conv_state": Role(STATE, 3),
+}
+STATE_LEAVES = {n: r.rank for n, r in _LEAVES.items() if r.kind == STATE}
+
+
+def role(name: str) -> Role:
+    """The role of cache leaf ``name``. An unknown leaf raises: every
+    program that moves rows must know every leaf's role (an untouched
+    leaf would leak the previous occupant's state)."""
+    known = _LEAVES.get(name)
+    if known is not None:
+        return known
+    of = name[: -len(_SCALE)] if name.endswith(_SCALE) else ""
+    if of in _LEAVES and _LEAVES[of].kind == PAGE:
+        return Role(SCALE, 2, of)
+    raise ValueError(
+        f"unknown cache leaf {name!r}: tpufw.ops.kv_store must know "
+        "every leaf's role"
+    )
+
+
+def leaf_name(path) -> str:
+    """The name of the leaf at pytree ``path`` of a cache collection."""
+    last = path[-1]
+    return str(getattr(last, "key", last))
+
+
+def path_role(path) -> Role:
+    return role(leaf_name(path))
+
+
+def _declare(module, name, shape, dtype):
+    return module.variable("cache", name, jnp.zeros, shape, dtype)
+
+
+def slot_state(module, name: str, shape: Tuple[int, ...], dtype):
+    """Declare per-slot state ``name`` [B, *feat] on ``module`` (zero is
+    a row's empty past). Its update rule is the model's own."""
+    known = role(name)
+    if known.kind != STATE or known.rank != len(shape):
+        raise ValueError(f"{name!r} {shape} is not declared as {known}")
+    return _declare(module, name, shape, dtype)
+
+
+def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
+    """Append this call's tokens at the cache cursor and view the cache.
+
+    Called from inside flax ``module``. ``new`` maps PAGE leaf names to
+    ``[B, t, *feat]``; ``segment_ids`` [B, t] (None: all 1) are the
+    tokens' own. Returns ``(views, segment_ids, kv_segment_ids,
+    q_slots)``: each leaf's whole logical row ``[B, S, *feat]`` (the new
+    tokens included, in ``cfg.dtype``), the queries' segment ids as
+    stored, the slots' ``[B, S]`` (0 = never written) and the logical
+    slots the t queries sit at, ``[B, t]`` or ``[1, t]`` under a scalar
+    cursor. Query i may attend slot j iff ``j <= q_slots[., i]`` and the
+    segments match.
+    """
+    for name, x in new.items():
+        if role(name) != Role(PAGE, x.ndim):
+            raise ValueError(f"{name!r} rank {x.ndim} is not {role(name)}")
+    b, t = next(iter(new.values())).shape[:2]
+    s, page = cfg.max_seq_len, getattr(cfg, "kv_page", 0)
+    seg = (
+        jnp.ones((b, t), jnp.int32) if segment_ids is None
+        else segment_ids.astype(jnp.int32)
+    )
+    if page:
+        if s % page:
+            raise ValueError(f"kv_page={page} must divide max_seq_len={s}")
+        quant = cfg.kv_quant == "int8"
+        lead, dtype = (cfg.kv_pages, page), jnp.int8 if quant else cfg.dtype
+    else:
+        quant, lead, dtype = False, (b, s), cfg.dtype
+    store = {
+        n: _declare(module, n, lead + x.shape[2:], dtype)
+        for n, x in new.items()
+    }
+    cseg = _declare(module, _SEGMENT, lead, jnp.int32)
+    cursor = _declare(module, _CURSOR, (b,) if page else (), jnp.int32)
+    cur = cursor.value
+    if cur.ndim == 0:
+        at = (0, cur)
+        for n, x in new.items():
+            store[n].value = jax.lax.dynamic_update_slice(
+                store[n].value, x.astype(dtype), at + (0,) * (x.ndim - 2)
+            )
+        cseg.value = jax.lax.dynamic_update_slice(cseg.value, seg, at)
+        q_slots = (cur + jnp.arange(t))[None, :]
+    else:
+        q_slots = jnp.minimum(cur, s - t)[:, None] + jnp.arange(t)[None, :]
+        if page:
+            table = _declare(module, _TABLE, (b, s // page), jnp.int32)
+            at = (table.value[jnp.arange(b)[:, None], q_slots // page],
+                  q_slots % page)
+        else:
+            at = (jnp.arange(b)[:, None], q_slots)
+        if quant:
+            scales = {
+                n: _declare(module, n + _SCALE, lead, jnp.float32)
+                for n in new
+            }
+            coded = {
+                n: quantize_kv(x, n_feat=x.ndim - 2) for n, x in new.items()
+            }
+            for n in new:
+                store[n].value = store[n].value.at[at].set(coded[n][0])
+            for n in new:
+                scales[n].value = scales[n].value.at[at].set(coded[n][1])
+        else:
+            for n, x in new.items():
+                store[n].value = store[n].value.at[at].set(x.astype(dtype))
+        cseg.value = cseg.value.at[at].set(seg)
+    cursor.value = cur + t
+    if not page:
+        return {n: v.value for n, v in store.items()}, seg, cseg.value, q_slots
+
+    idx = table.value
+    views = {}
+    for n, x in new.items():
+        pages = store[n].value[idx]
+        if quant:
+            pages = dequantize_kv(pages, scales[n].value[idx], cfg.dtype)
+        views[n] = pages.reshape((b, s) + x.shape[2:])
+    return views, seg, cseg.value[idx].reshape(b, s), q_slots
