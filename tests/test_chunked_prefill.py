@@ -612,3 +612,53 @@ def test_scheduler_phases_and_request_chain(tiny_sched_model, tmp_path):
         assert ev["args"]["passes"] >= 3
         # Request-level records cross passes: no phase, no counter.
         assert ev["name"] not in phases
+
+
+# ------------------------------------- the live prefix of a row (PR 31)
+
+def test_chunks_across_two_rungs_serve_the_monolithic_tokens(monkeypatch):
+    """A 560-token prompt in 128-token chunks over a 1,024-slot row: the
+    first four chunks read 512 key slots, the tail and the decode steps
+    1,024 (tpufw.ops.kv_store's ladder, chosen inside the programs; its
+    floor of 2,048 slots is put at 512 here, for a test's cost, and no
+    other test builds a program over a 1,024-slot row). The
+    served tokens are the monolithic scheduler's, and the scheduler's two
+    counters say what the device read, by the rule the programs use."""
+    from tpufw.infer.speculative import _pool_cursor
+    from tpufw.ops import kv_store
+    from tpufw.workloads import serve as serve_mod
+
+    monkeypatch.setattr(kv_store, "MIN_RUNG", 512)
+
+    base = LLAMA_CONFIGS["llama3_tiny"].decode_config()
+    model = Llama(dataclasses.replace(base, max_seq_len=1024))
+    params = jax.jit(model.init)(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    prompt = np.random.default_rng(11).integers(1, 200, 560).tolist()
+    ref = _scheduler(model, params, 0).submit([prompt], 8)[0][0]
+
+    metrics = serve_mod._Metrics()
+    sched = serve_mod._SlotScheduler(
+        model, params, eos_id=None, default_sampling=GREEDY, seed_base=0,
+        page=PAGE, arena_pages=None, prefix_cache=True,
+        prefill_chunk_pages=8, metrics=metrics,
+    )
+    read = metrics.registry.counter("tpufw_serve_attended_key_slots_total")
+    whole = metrics.registry.counter("tpufw_serve_row_key_slots_total")
+    assert read.value() == 0 and whole.value() == 0  # exposed at 0
+    assert sched.submit([prompt], 8)[0][0] == ref
+
+    n = sched.n_slots
+    # Chunks end at 128, 256, 384, 512 (rung 512) and 560 (rung 1,024);
+    # then ONE chunk of 8 decode steps, n rows each: seven with the row
+    # live at 561..567 slots, and an eighth with no live row at all.
+    assert read.value() == 4 * 512 + 1024 + n * (7 * 1024 + 512)
+    assert whole.value() == 5 * 1024 + n * 8 * 1024
+    # The host's lengths are the device's cursors: the row's stands at
+    # prompt + 8 steps (a done row's keeps counting), an empty slot's at 8.
+    cursors = np.asarray(_pool_cursor(sched._pool.cache, n))
+    assert sorted(cursors.tolist()) == [8] * (n - 1) + [568]
+    text = metrics.registry.render()
+    for name in ("attended_key_slots_total", "row_key_slots_total"):
+        assert f"\ntpufw_serve_{name} " in text

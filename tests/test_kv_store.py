@@ -46,7 +46,12 @@ class Store(nn.Module):
 
     @nn.compact
     def __call__(self, new, segment_ids):
-        return kv_store.append(self, self.cfg, new, segment_ids)
+        read, seg, q_slots = kv_store.append(
+            self, self.cfg, new, segment_ids
+        )
+        # One rung at this size: ``read`` hands the whole row through.
+        views, kv_seg = read(lambda views, kv_seg: (views, kv_seg))
+        return views, seg, kv_seg, q_slots
 
 
 def tokens(layout, t, seed):
@@ -187,3 +192,202 @@ def test_an_unknown_leaf_has_no_role():
         Store(Cfg()).init(
             jax.random.key(0), {"cached_key": jnp.zeros((B, 1, 4))}, None
         )
+
+
+# ---- the live prefix: a ladder of key lengths chosen in the program ----
+
+S2, T2 = 8192, 3  # three rungs: 2048, 4096, 8192
+PER_ROW2 = S2 // PAGE
+RUNG = 4096
+STORES = ["scalar", "row_cursor", "paged", "paged_int8"]
+
+
+def config2(store):
+    if store.startswith("paged"):
+        return Cfg(
+            max_seq_len=S2, dtype=jnp.float32, kv_page=PAGE,
+            kv_pages=B * PER_ROW2 + 1,
+            kv_quant="int8" if store == "paged_int8" else "",
+        )
+    return Cfg(max_seq_len=S2, dtype=jnp.float32)
+
+
+def attention_over(layout, q):
+    """The family's contraction as a function of the L-long views: GQA's
+    through ``xla_attention``, the latent one MLA's absorbed scores."""
+    from tpufw.ops.attention import attention_mask, xla_attention
+
+    def attend(views, kv_seg, seg, q_slots):
+        if layout == "kv":
+            return xla_attention(
+                q, views["cached_key"], views["cached_value"],
+                segment_ids=seg, kv_segment_ids=kv_seg, q_positions=q_slots,
+            )
+        ckv, kpe = views["cached_ckv"], views["cached_kpe"]
+        logits = jnp.einsum("bthr,bsr->bhts", q[..., :6], ckv) + jnp.einsum(
+            "bthd,bsd->bhts", q[..., 6:], kpe
+        )
+        mask = attention_mask(
+            q.shape[1], ckv.shape[1], segment_ids=seg,
+            kv_segment_ids=kv_seg, q_positions=q_slots,
+        )
+        probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
+        return jnp.einsum("bhts,bsr->bthr", probs, ckv)
+
+    return attend
+
+
+class Reader(nn.Module):
+    """Appends, then runs ``attend`` under the store's bound. With
+    ``attend`` None it answers the rung the program took: the length of
+    the views it was handed, per query."""
+
+    cfg: Cfg
+    attend: Any = None
+
+    @nn.compact
+    def __call__(self, new, segment_ids):
+        read, seg, q_slots = kv_store.append(
+            self, self.cfg, new, segment_ids
+        )
+        b, t = seg.shape
+        if self.attend is None:
+            return read(
+                lambda views, kv_seg: jnp.full((b, t), kv_seg.shape[1])
+            )
+        return read(
+            lambda views, kv_seg: self.attend(views, kv_seg, seg, q_slots)
+        )
+
+
+def filled(cfg, layout, cursors):
+    """A cache whose rows hold random tokens below ``cursors`` (a scalar
+    for the scalar store, else one per row): every written slot carries
+    segment 1, every other 0; paged rows own private pages in row
+    order after reserved page 0."""
+    new = {n: x.astype(cfg.dtype) for n, x in tokens(layout, T2, 0).items()}
+    cache = Reader(cfg).init(jax.random.key(0), new, None)["cache"]
+    per_row = np.broadcast_to(np.asarray(cursors), (B,))
+    written = np.arange(S2)[None, :] < per_row[:, None]  # [B, S2]
+    out = {}
+    for i, (name, leaf) in enumerate(sorted(cache.items())):
+        kind = kv_store.role(name).kind
+        key = jax.random.fold_in(jax.random.key(7), i)
+        if kind == kv_store.PAGE and leaf.dtype == jnp.int8:
+            out[name] = jax.random.randint(key, leaf.shape, -127, 128, jnp.int8)
+        elif kind == kv_store.PAGE:
+            out[name] = jax.random.normal(key, leaf.shape, leaf.dtype)
+        elif kind == kv_store.SCALE:
+            out[name] = jax.random.uniform(
+                key, leaf.shape, leaf.dtype, 0.005, 0.02
+            )
+        elif kind == kv_store.SEGMENT and cfg.kv_page:
+            arena = np.zeros(leaf.shape, np.int32)
+            arena[1:] = written.reshape(B * PER_ROW2, PAGE)
+            out[name] = jnp.asarray(arena)
+        elif kind == kv_store.SEGMENT:
+            out[name] = jnp.asarray(written, jnp.int32)
+        elif kind == kv_store.TABLE:
+            out[name] = 1 + jnp.arange(
+                B * PER_ROW2, dtype=jnp.int32
+            ).reshape(B, PER_ROW2)
+        else:
+            assert kind == kv_store.CURSOR
+            out[name] = jnp.asarray(cursors, jnp.int32)
+    return out, new
+
+
+def cursors_for(store, live):
+    """Cursors whose longest row holds ``live`` slots after a T2 block;
+    the other rows of a per-row store sit lower."""
+    top = live - T2
+    return top if store == "scalar" else [max(top - 200, 0), top, 7]
+
+
+@pytest.mark.parametrize(
+    "live", [RUNG - 1, RUNG, RUNG + 1, S2], ids=["under", "at", "over", "end"]
+)
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+@pytest.mark.parametrize("store", STORES)
+def test_attention_over_the_live_prefix_is_attention_over_the_row(
+    store, layout, live, monkeypatch
+):
+    cfg = config2(store)
+    cache, new = filled(cfg, layout, cursors_for(store, live))
+    q = jax.random.normal(jax.random.key(3), (B, T2, 2, 10), jnp.float32)
+    if layout == "kv":
+        q = q[..., :4]
+    model = Reader(cfg, attention_over(layout, q))
+    probe = Reader(cfg)
+    took, _ = probe.apply({"cache": cache}, new, None, mutable=["cache"])
+    want_rung = {RUNG - 1: RUNG, RUNG: RUNG, RUNG + 1: S2, S2: S2}[live]
+    assert np.unique(np.asarray(took)).tolist() == [want_rung]
+    bounded, after = model.apply({"cache": cache}, new, None, mutable=["cache"])
+    # The whole row: the same store with a ladder of one rung.
+    monkeypatch.setattr(kv_store, "key_ladder", lambda s, page=0: (s,))
+    whole, after_whole = model.apply(
+        {"cache": cache}, new, None, mutable=["cache"]
+    )
+    assert bounded.shape == whole.shape and bool(jnp.isfinite(whole).all())
+    np.testing.assert_allclose(bounded, whole, rtol=2e-6, atol=2e-6)
+    for name in after["cache"]:
+        np.testing.assert_array_equal(
+            after["cache"][name], after_whole["cache"][name]
+        )
+
+
+@pytest.mark.parametrize("store", STORES[1:])
+def test_dead_rows_at_the_end_of_the_row_do_not_choose_the_rung(store):
+    """A pool's done rows keep stepping and their cursors run on to
+    ``max_seq_len``; they step with segment id 0 and the rung is the
+    live row's. With ids of 1 they would count, and it is the top one."""
+    cfg = config2(store)
+    cache, new = filled(cfg, "kv", [S2, 2600, S2])
+    dead = jnp.asarray([[0] * T2, [1] * T2, [0] * T2], jnp.int32)
+    took, _ = Reader(cfg).apply({"cache": cache}, new, dead, mutable=["cache"])
+    assert np.unique(np.asarray(took)).tolist() == [RUNG]
+    took, _ = Reader(cfg).apply({"cache": cache}, new, None, mutable=["cache"])
+    assert np.unique(np.asarray(took)).tolist() == [S2]
+    none = jnp.zeros((B, T2), jnp.int32)
+    took, _ = Reader(cfg).apply({"cache": cache}, new, none, mutable=["cache"])
+    assert np.unique(np.asarray(took)).tolist() == [2048]
+
+
+@pytest.mark.parametrize("store", STORES[:3])
+def test_the_hosts_rung_is_the_programs(store):
+    """``attended_keys``, by which the scheduler counts what the device
+    read, names the rung the program's switch took, at every live
+    length around every rung."""
+    cfg = config2(store)
+    assert kv_store.key_ladder(S2, cfg.kv_page) == (2048, RUNG, S2)
+    step = jax.jit(
+        lambda cache, new: Reader(cfg).apply(
+            {"cache": cache}, new, None, mutable=["cache"]
+        )[0]
+    )
+    lives = sorted(
+        {T2, S2} | {r + d for r in (2048, RUNG, RUNG + 300) for d in (-1, 0, 1)}
+    )
+    for live in lives:
+        cache, new = filled(cfg, "latent", cursors_for(store, live))
+        took = np.unique(np.asarray(step(cache, new))).tolist()
+        assert took == [kv_store.attended_keys(cfg, live)], live
+
+
+def test_the_ladder_is_a_rule_of_the_row_and_the_page():
+    assert kv_store.key_ladder(16384, 16) == (2048, 4096, 8192, 16384)
+    assert kv_store.key_ladder(8192, 16) == (2048, 4096, 8192)
+    assert kv_store.key_ladder(4096, 16) == (2048, 4096)
+    # One rung, and so no switch, under 4096 slots; no rung under 2048,
+    # none that is not whole pages or does not divide the row.
+    assert kv_store.key_ladder(2048, 16) == (2048,)
+    assert kv_store.key_ladder(S) == (S,)
+    assert kv_store.key_ladder(4096) == (2048, 4096)
+    assert kv_store.key_ladder(6144, 16) == (3072, 6144)
+    assert kv_store.key_ladder(8192, 768) == (8192,)
+    text = jax.jit(
+        lambda cache, new: Store(Cfg()).apply(
+            {"cache": cache}, new, None, mutable=["cache"]
+        )
+    ).lower(fresh(Cfg(), "kv"), tokens("kv", 1, 0)).as_text()
+    assert "case" not in text and "cond" not in text

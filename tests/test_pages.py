@@ -425,3 +425,97 @@ def test_every_cache_leaf_has_a_role_in_the_store(family):
             '"_scale"', "self.variable(",
         ):
             assert spelled not in text, (source, spelled)
+
+
+# ---- the live prefix of a row (tpufw.ops.kv_store's ladder of key lengths)
+
+LONG_S, LONG_NEW = 1024, 24  # two rungs under a floor of 512: 512, 1024
+
+
+def _long_family(family, monkeypatch):
+    from tpufw.ops import kv_store
+
+    # The ladder's floor is 2,048 slots. These tests put it at 512, so
+    # that a 1,024-slot row has two rungs at a test's cost; the rule at
+    # its own floor is tests/test_kv_store.py's. No other test builds a
+    # program at LONG_S, so no trace made under another floor is reused.
+    monkeypatch.setattr(kv_store, "MIN_RUNG", 512)
+    cls, cfg = _seam_family(family)
+    cfg = dataclasses.replace(cfg, max_seq_len=LONG_S)
+    assert kv_store.key_ladder(LONG_S, PAGE) == (512, LONG_S)
+    row_model = cls(cfg)
+    params = jax.jit(row_model.init)(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    pcfg = dataclasses.replace(
+        cfg, kv_page=PAGE, kv_pages=2 * (LONG_S // PAGE) + 1
+    )
+    pool = pages_mod.PagedSlotPool.create_paged(
+        cls(pcfg), row_model, params, 2, sampling=GREEDY, eos_id=None
+    )
+    return row_model, params, pool
+
+
+@pytest.mark.parametrize("family", ["llama", "deepseek"])
+def test_paged_decode_matches_contiguous_across_two_rungs(family, monkeypatch):
+    """A 500-token row decodes past slot 512 beside a 3-token one: the
+    paged pool's steps read 512 keys, then 1,024 (the longest LIVE row
+    chooses), the one-shot contiguous path likewise under its scalar
+    cursor, and the served tokens are equal."""
+    row_model, params, pool = _long_family(family, monkeypatch)
+    long_prompt = np.random.default_rng(5).integers(1, 200, 500).tolist()
+    prompts = [long_prompt, [1, 5, 9]]
+    want = generate_text(
+        row_model, params, prompts, max_new_tokens=LONG_NEW, sampling=GREEDY
+    )
+    firsts = {}
+    for i, p in enumerate(prompts):
+        firsts[i], _ = _admit(pool, i, p, i, max_new=LONG_NEW)
+    reads = {
+        pool.attended_keys([len(long_prompt) + n])[0]
+        for n in range(1, LONG_NEW)
+    }
+    assert reads == {512, LONG_S}
+    rows = _decode_all(pool, firsts, max_new=LONG_NEW, chunk=4)
+    assert [rows[0], rows[1]] == want
+
+
+def test_tpu_lowering_gathers_the_whole_row_only_in_the_top_rung(monkeypatch):
+    """Lowered for the chip, a multi-rung decode step holds the gather
+    of every row's whole table ([B, S/page] pages) only inside the top
+    branch of the store's switch: the lower branch gathers half of it
+    and nothing outside the switch gathers pages at all."""
+    import re
+
+    _, _, pool = _long_family("llama", monkeypatch)
+    text = (
+        slots_mod._decode_steps_jit.trace(
+            pool.model, pool.params, pool.cache, pool.token, pool.pos,
+            pool.done, pool.remaining, pool.seen,
+            jax.random.split(jax.random.key(1), 2),
+            sampling=GREEDY, pad_id=0, eos_id=None,
+        )
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+    case = re.search(
+        r'"stablehlo\.case"\(.*?^\s*\}\) : \(tensor<i32>\)', text, re.M | re.S
+    )
+    assert case and text.count('"stablehlo.case"') == 1
+    branches = re.split(r"^\s*\}, \{$", case.group(0), flags=re.M)
+    assert len(branches) == 2  # the ladder's rungs, 512 and 1,024
+    per_row = LONG_S // PAGE
+
+    def page_gathers(part, n_pages):
+        return re.findall(
+            rf'"stablehlo\.gather".*-> tensor<2x{n_pages}x{PAGE}[x>]', part
+        )
+
+    outside = text.replace(case.group(0), "")
+    for n in (per_row // 2, per_row):
+        assert not page_gathers(outside, n)
+    # K pages, V pages and their segment ids, at each rung's own length.
+    assert len(page_gathers(branches[0], per_row // 2)) == 3
+    assert not page_gathers(branches[0], per_row)
+    assert len(page_gathers(branches[1], per_row)) == 3
+    assert not page_gathers(branches[1], per_row // 2)

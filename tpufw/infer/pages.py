@@ -11,7 +11,11 @@ of ``kv_page`` slots each:
 - the STORE (``tpufw.ops.kv_store``, called by every model) owns the
   arena + table + gather/scatter reads and every leaf's role — the
   cache leaves just have a different shape, so ``_decode_steps_jit`` is
-  reused verbatim;
+  reused verbatim. A step gathers ``table[:, :L/page]``, the live prefix
+  of the rows and not their whole tables: L is a rung of the store's
+  ladder of key lengths, chosen inside the program from the cursors of
+  the rows that are not done (``PagedSlotPool.attended_keys`` is the
+  same rule for the scheduler's count);
 - this module owns moving rows in and out, and the allocator; it asks
   ``kv_store.role()`` what each leaf is: ``_paged_insert_jit``
   scatters a B=1 contiguous prefilled row into the slot's pages,
@@ -62,7 +66,7 @@ from tpufw.infer.slots import (
 )
 from tpufw.obs import trace as obs_trace
 from tpufw.ops.kv_store import (
-    CURSOR, PAGE, SCALE, SEGMENT, STATE, TABLE, role,
+    CURSOR, PAGE, SCALE, SEGMENT, STATE, TABLE, attended_keys, role,
 )
 from tpufw.ops.kv_store import leaf_name as _leaf_name
 from tpufw.ops.quant import dequantize_kv, quantize_kv
@@ -494,11 +498,16 @@ def _prefill_chunk_jit(
     churn never retrace (``start``/``n_real``/``is_final``/``rng`` are
     all traced).
 
-    Bit-parity with monolithic prefill holds per query: every apply
-    attends the full row cache under the causal + segment mask, padded
-    tail slots carry segment 0 (their logits weights underflow to an
-    exact 0.0), and the window scatter quantizes per token — identical
-    values to a whole-row insert. Sampling runs every chunk (one
+    Parity with monolithic prefill holds per query: every apply
+    attends the row cache's live prefix (``start + width`` slots, rounded
+    up to a rung of tpufw.ops.kv_store's ladder, chosen inside this
+    program from the row's cursor; the slots past it are the ones the
+    causal mask hides) under the causal + segment mask, padded tail
+    slots carry segment 0 (their logits weights underflow to an exact
+    0.0), and the window scatter quantizes per token — identical values
+    to a whole-row insert. A chunk at a lower rung than the monolithic
+    pass reduces over fewer (all-zero-weight) slots: equal up to
+    reduction order. Sampling runs every chunk (one
     program), but only the final chunk's draw is kept by the host; the
     key is ``split_prefill_keys``' first key, the exact key a cold
     ``prefill_row`` of the full prompt would use.
@@ -748,6 +757,16 @@ class PagedSlotPool(SlotPool):
         self._fresh_row = (
             jax.jit(row_zeros, out_shardings=self.home).lower().compile()
         )
+
+    def attended_keys(self, lives, chunk: bool = False) -> Tuple[int, int]:
+        """(key slots read, key slots of the whole row) a row, summed
+        over cached calls whose longest live rows hold ``lives`` slots:
+        decode steps of the pool's model or, with ``chunk``, prefill
+        chunks of the row twin. The store's own rule, so the host's
+        count names the rung each program took."""
+        cfg = (self.row_model if chunk else self.model).cfg
+        read = [attended_keys(cfg, n) for n in lives]
+        return sum(read), len(read) * int(cfg.max_seq_len)
 
     # ---- host-side page bookkeeping -------------------------------
 

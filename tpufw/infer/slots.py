@@ -76,6 +76,17 @@ def _track_seen(sampling: SamplingConfig) -> bool:
     )
 
 
+def live_segments(done, t: int):
+    """Segment ids [S, t] of one pool step: 1 for a live row, 0 for a
+    done or empty one. The store (tpufw.ops.kv_store) bounds the keys a
+    step reads by the longest row that carries an id > 0, and a done
+    row's cursor runs on to ``max_seq_len``; its output is pad either
+    way."""
+    return jnp.broadcast_to(
+        jnp.where(done, 0, 1).astype(jnp.int32)[:, None], (done.shape[0], t)
+    )
+
+
 def pool_cache(model, params, n_slots: int) -> Tuple[Any, Tuple]:
     """Allocate a zeroed S-slot cache for ``model`` + its batch axes.
 
@@ -188,7 +199,10 @@ def _decode_steps_jit(
     a row emits its token THEN burns budget, so the EOS/boundary token
     itself is delivered and the row freezes after. Done rows keep
     stepping (static shapes; masking, not control flow) but feed pad
-    back and emit pad out. Per-slot state (kv_store role STATE) rides in
+    back and emit pad out, and their cursors keep counting: they step
+    with segment id 0, which is how the store leaves them out of the
+    live length that bounds the keys it reads (``live_segments``).
+    Per-slot state (kv_store role STATE) rides in
     ``cache`` and is updated in place by the model, a done row's too:
     it is junk from then on and the next insert overwrites all of it.
     """
@@ -196,11 +210,12 @@ def _decode_steps_jit(
     apply = _model_apply(model, params)
     s = token.shape[0]
     track = _track_seen(sampling)
-    ones = jnp.ones((s, 1), jnp.int32)
 
     def step(carry, rng_step):
         cache, token, pos, done, remaining, seen = carry
-        logits, cache = apply(cache, token[:, None], pos[:, None], ones)
+        logits, cache = apply(
+            cache, token[:, None], pos[:, None], live_segments(done, 1)
+        )
         nxt = sample_token(logits[:, -1, :], sampling, rng_step, seen)
         if track:
             seen = seen.at[jnp.arange(s), nxt].set(True)

@@ -71,6 +71,7 @@ from tpufw.infer.generate import (
     prefill_cache,
 )
 from tpufw.infer.sampling import SamplingConfig, sample_token, transform_logits
+from tpufw.infer.slots import live_segments
 from tpufw.ops.kv_store import (
     CURSOR, SEGMENT, STATE, leaf_name, path_role,
 )
@@ -746,7 +747,7 @@ def _spec_verify_jit(
     block_in = jnp.concatenate([token[:, None], proposals], axis=1)
     positions = pos[:, None] + jnp.arange(k + 1)[None, :]
     logits, cache = apply(
-        cache, block_in, positions, jnp.ones((s, k + 1), jnp.int32)
+        cache, block_in, positions, live_segments(done, k + 1)
     )
     out, n_emit, accept, token, pos, done_new, remaining = _spec_advance(
         logits, proposals, None, key, token, pos, done, remaining,
@@ -786,7 +787,7 @@ def _spec_draft_verify_jit(
     cur0 = _pool_cursor(cache, s)
     d_cur0 = _pool_cursor(d_cache, s)
     stochastic = sampling.temperature != 0.0
-    ones = jnp.ones((s, 1), jnp.int32)
+    seg1 = live_segments(done, 1)
     draft_keys = (
         jax.random.split(jax.random.fold_in(key, 3), k)
         if stochastic else None
@@ -795,7 +796,7 @@ def _spec_draft_verify_jit(
     tok = token
     for i in range(k):
         d_logits, d_cache = d_apply(
-            d_cache, tok[:, None], (pos + i)[:, None], ones
+            d_cache, tok[:, None], (pos + i)[:, None], seg1
         )
         if stochastic:
             q_i = transform_logits(d_logits[:, -1, :], sampling)
@@ -813,7 +814,7 @@ def _spec_draft_verify_jit(
     block_in = jnp.concatenate([token[:, None], proposals], axis=1)
     positions = pos[:, None] + jnp.arange(k + 1)[None, :]
     logits, cache = apply(
-        cache, block_in, positions, jnp.ones((s, k + 1), jnp.int32)
+        cache, block_in, positions, live_segments(done, k + 1)
     )
     # tpulint: disable=TPU003 — _spec_advance folds key with constants
     # 1/2, disjoint from the fold_in(key, 3) draft split above.

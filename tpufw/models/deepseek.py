@@ -494,11 +494,9 @@ class MLAttention(nn.Module):
         """
         cfg = self.cfg
         dn = cfg.qk_nope_head_dim
-        views, seg, kv_seg, q_slots = kv_store.append(
+        read, seg, q_slots = kv_store.append(
             self, cfg, {"cached_ckv": c_kv, "cached_kpe": k_pe}, segment_ids
         )
-        ckv_all, kpe_all = views["cached_ckv"], views["cached_kpe"]
-
         w_uk, w_uv = kv_b[..., :dn], kv_b[..., dn:]  # [kvr, H, dn/dv]
         # Absorb W_uk into the query: [B,T,H,dn] x [kvr,H,dn] -> latent
         # queries [B,T,H,kvr].
@@ -507,26 +505,32 @@ class MLAttention(nn.Module):
             q_nope.astype(cfg.dtype),
             w_uk.astype(cfg.dtype),
         )
-        logits = (
-            jnp.einsum(
-                "bthr,bsr->bhts", q_lat, ckv_all,
-                preferred_element_type=jnp.float32,
+
+        def attend(views, kv_seg):
+            """Attention-weighted latents [B,T,H,kvr] over the L slots
+            the store shows (its live prefix)."""
+            ckv, kpe = views["cached_ckv"], views["cached_kpe"]
+            logits = (
+                jnp.einsum(
+                    "bthr,bsr->bhts", q_lat, ckv,
+                    preferred_element_type=jnp.float32,
+                )
+                + jnp.einsum(
+                    "bthd,bsd->bhts", q_pe.astype(cfg.dtype), kpe,
+                    preferred_element_type=jnp.float32,
+                )
+            ) * (float(cfg.qk_head_dim) ** -0.5)
+            mask = attention_mask(
+                q_slots.shape[1], ckv.shape[1], segment_ids=seg,
+                kv_segment_ids=kv_seg, q_positions=q_slots,
             )
-            + jnp.einsum(
-                "bthd,bsd->bhts", q_pe.astype(cfg.dtype), kpe_all,
-                preferred_element_type=jnp.float32,
-            )
-        ) * (float(cfg.qk_head_dim) ** -0.5)
-        mask = attention_mask(
-            q_slots.shape[1], cfg.max_seq_len, segment_ids=seg,
-            kv_segment_ids=kv_seg, q_positions=q_slots,
-        )
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-        # Attention-weighted latents, then ONE W_uv application.
-        ctx_lat = jnp.einsum("bhts,bsr->bthr", probs, ckv_all)
+            logits = jnp.where(mask, logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
+            return jnp.einsum("bhts,bsr->bthr", probs, ckv)
+
+        # ONE W_uv application, whatever the rung.
         return jnp.einsum(
-            "bthr,rhd->bthd", ctx_lat, w_uv.astype(cfg.dtype)
+            "bthr,rhd->bthd", read(attend), w_uv.astype(cfg.dtype)
         )
 
 

@@ -624,23 +624,27 @@ class Attention(nn.Module):
 
     def _cached_attention(self, q, k, v, segment_ids):
         """KV-cache step: append this call's k/v to the store, then attend
-        q (at the slots it was written to) over the whole logical row
-        (tpufw.ops.kv_store: layouts, masking and clamp rationale)."""
+        q (at the slots it was written to) over the live prefix of the
+        logical row, the rung of the store's ladder that holds every live
+        row (tpufw.ops.kv_store: layouts, the bound, masking and clamp
+        rationale)."""
         cfg = self.cfg
-        views, seg, kv_seg, q_slots = kv_store.append(
+        read, seg, q_slots = kv_store.append(
             self, cfg, {"cached_key": k, "cached_value": v}, segment_ids
         )
-        return multi_head_attention(
-            q,
-            views["cached_key"],
-            views["cached_value"],
-            causal=True,
-            segment_ids=seg,
-            kv_segment_ids=kv_seg,
-            q_positions=q_slots,
-            logits_soft_cap=getattr(cfg, "attn_logit_soft_cap", None),
-            sliding_window=self.window,
-            backend="xla",
+        return read(
+            lambda views, kv_seg: multi_head_attention(
+                q,
+                views["cached_key"],
+                views["cached_value"],
+                causal=True,
+                segment_ids=seg,
+                kv_segment_ids=kv_seg,
+                q_positions=q_slots,
+                logits_soft_cap=getattr(cfg, "attn_logit_soft_cap", None),
+                sliding_window=self.window,
+                backend="xla",
+            )
         )
 
 

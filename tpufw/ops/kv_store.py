@@ -43,6 +43,23 @@ cursor's rank, ``cfg.kv_quant``):
   codes, quantized per token at append, with float32 scales stored
   page-structured ``[kv_pages, kv_page]`` and applied at the view.
 
+THE LIVE PREFIX. A cached call reads the first L slots of the row, not
+all ``max_seq_len``: L is the rows' live length rounded up to a rung of
+``key_ladder`` (static lengths: S/8, S/4, S/2, S, none under 2048 slots,
+each a whole number of pages), and the rung is chosen INSIDE the program
+by a ``lax.switch`` on a scalar it computes from the cursors, so no
+program gains a key and no pool an argument. The live length is ``cur +
+t`` under the scalar cursor and, under ``[B]`` cursors, the largest
+``cursor + t`` over the rows whose tokens of this call carry a segment
+id > 0: a pool's done rows keep stepping and their cursors keep
+counting, so the pools step them with segment 0 (tpufw.infer.slots).
+The page table cannot bound it: a row is granted its whole budget up
+front. Slots past L are exactly those the causal mask fills with -1e30,
+whose weights underflow to an exact 0.0: the same mathematics at the
+same precision. A ladder of one rung (``max_seq_len`` < 4096) is the
+program without a switch. ``attended_keys`` is the same rule for the
+host, which counts what the device read (tpufw.workloads.serve).
+
 t == 1 is the plain decode step; t > 1 is a prefill chunk (contiguous)
 or the speculative verify block (tpufw.infer.speculative): all t tokens
 land in consecutive logical slots first, then the view includes them,
@@ -54,7 +71,7 @@ and would wrongly mask valid recent slots.
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Tuple
+from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -144,18 +161,64 @@ def slot_state(module, name: str, shape: Tuple[int, ...], dtype):
     return _declare(module, name, shape, dtype)
 
 
+#: No rung of the ladder is shorter. Each rung is one more copy of every
+#: attention layer in every cached program, and a warm start pays for it
+#: in executables to load (measured, PR 31: 3 s a rung for eight latent
+#: layers), while a 512-token chunk spends 13 us a key slot beside 34 ms
+#: that no rung shortens: under 2048 slots a rung saves less than it costs.
+MIN_RUNG = 2048
+
+
+def key_ladder(max_seq_len: int, page: int = 0) -> Tuple[int, ...]:
+    """The key lengths a cached call may read, ascending, the whole row
+    last: S/8, S/4, S/2 where they divide S, hold ``MIN_RUNG`` slots and
+    are whole pages. A function of the row and the page alone."""
+    s, unit = int(max_seq_len), max(int(page), 1)
+    return tuple(
+        s >> k for k in (3, 2, 1)
+        if s % (1 << k) == 0 and s >> k >= MIN_RUNG and (s >> k) % unit == 0
+    ) + (s,)
+
+
+def key_rung(ladder: Tuple[int, ...], live):
+    """Index of the shortest rung that holds ``live`` key slots; the
+    top rung for anything longer. ``live`` is a Python int (the host) or
+    a traced scalar (the program): one rule for both."""
+    return sum((live > rung) * 1 for rung in ladder[:-1])
+
+
+def attended_keys(cfg, live: int) -> int:
+    """Key slots of each row a cached call of ``cfg``'s model reads when
+    its longest live row holds ``live`` slots, this call's tokens
+    included: what the program's switch picks, for the host's count."""
+    ladder = key_ladder(cfg.max_seq_len, getattr(cfg, "kv_page", 0))
+    return ladder[key_rung(ladder, int(live))]
+
+
+def _head(x: jax.Array, n: int) -> jax.Array:
+    """``x[:, :n]``; ``x`` itself where that is all of it."""
+    return x if n == x.shape[1] else x[:, :n]
+
+
 def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
-    """Append this call's tokens at the cache cursor and view the cache.
+    """Append this call's tokens at the cache cursor; hand back how to
+    read the cache under the live-prefix bound.
 
     Called from inside flax ``module``. ``new`` maps PAGE leaf names to
     ``[B, t, *feat]``; ``segment_ids`` [B, t] (None: all 1) are the
-    tokens' own. Returns ``(views, segment_ids, kv_segment_ids,
-    q_slots)``: each leaf's whole logical row ``[B, S, *feat]`` (the new
-    tokens included, in ``cfg.dtype``), the queries' segment ids as
-    stored, the slots' ``[B, S]`` (0 = never written) and the logical
-    slots the t queries sit at, ``[B, t]`` or ``[1, t]`` under a scalar
-    cursor. Query i may attend slot j iff ``j <= q_slots[., i]`` and the
-    segments match.
+    tokens' own; a row whose ids are all 0 is not live (its slots are
+    written all the same). Returns ``(read, segment_ids, q_slots)``: the
+    queries' segment ids as stored, the logical slots the t queries sit
+    at (``[B, t]``, or ``[1, t]`` under a scalar cursor) and ``read``,
+    which runs the caller's contraction over the live prefix:
+    ``read(attend)`` is ``attend(views, kv_segment_ids)`` with each
+    leaf's first L logical slots ``[B, L, *feat]`` (the new tokens
+    included, in ``cfg.dtype``) and the slots' ids ``[B, L]`` (0 = never
+    written), L the rung of ``key_ladder`` that holds every live row
+    (module docstring). ``attend`` is traced once per rung and must
+    return the same shapes at each; it may not touch flax variables.
+    Query i may attend slot j iff ``j <= q_slots[., i]`` and the
+    segments match: ``attention_mask(t, L, ...)``.
     """
     for name, x in new.items():
         if role(name) != Role(PAGE, x.ndim):
@@ -213,14 +276,44 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
                 store[n].value = store[n].value.at[at].set(x.astype(dtype))
         cseg.value = cseg.value.at[at].set(seg)
     cursor.value = cur + t
-    if not page:
-        return {n: v.value for n, v in store.items()}, seg, cseg.value, q_slots
 
-    idx = table.value
-    views = {}
-    for n, x in new.items():
-        pages = store[n].value[idx]
-        if quant:
-            pages = dequantize_kv(pages, scales[n].value[idx], cfg.dtype)
-        views[n] = pages.reshape((b, s) + x.shape[2:])
-    return views, seg, cseg.value[idx].reshape(b, s), q_slots
+    # The writes are done; from here the store's VALUES are read, so a
+    # branch of the switch below touches no flax variable.
+    arenas = {n: v.value for n, v in store.items()}
+    ids = cseg.value
+    idx = table.value if page else None
+    scale_of = {n: v.value for n, v in scales.items()} if quant else {}
+
+    def view(length: int):
+        """(views, kv_segment_ids) of the first ``length`` slots."""
+        if not page:
+            return (
+                {n: _head(a, length) for n, a in arenas.items()},
+                _head(ids, length),
+            )
+        rows = _head(idx, length // page)
+        views = {}
+        for n, x in new.items():
+            pages = arenas[n][rows]
+            if quant:
+                pages = dequantize_kv(pages, scale_of[n][rows], cfg.dtype)
+            views[n] = pages.reshape((b, length) + x.shape[2:])
+        return views, ids[rows].reshape(b, length)
+
+    ladder = key_ladder(s, page)
+
+    def read(attend: Callable):
+        if len(ladder) == 1:
+            return attend(*view(s))
+        if cur.ndim == 0:
+            live = cur + t
+        else:
+            live = jnp.max(
+                jnp.where(jnp.any(seg > 0, axis=1), q_slots[:, -1] + 1, 0)
+            )
+        return jax.lax.switch(
+            key_rung(ladder, live),
+            [lambda n=n: attend(*view(n)) for n in ladder],
+        )
+
+    return read, seg, q_slots

@@ -856,7 +856,7 @@ class _SlotJob:
     its own EOS/max_new."""
 
     __slots__ = ("req", "prompt", "p_bucket", "max_new", "cache_len",
-                 "tokens", "unflushed", "cp", "t_grant", "pass0")
+                 "tokens", "unflushed", "cp", "t_grant", "pass0", "kv0")
 
     def __init__(self, req, prompt, p_bucket, max_new, cache_len):
         self.req = req
@@ -874,6 +874,17 @@ class _SlotJob:
         # where ``req_prefill`` / tpufw_serve_prefill_seconds start.
         self.t_grant = 0.0
         self.pass0 = 0
+        # Cache slots the row held when it was installed in its slot:
+        # the prompt's, left padding included. With the tokens sampled
+        # since, the row's cursor (``_row_keys``).
+        self.kv0 = 0
+
+
+def _row_keys(job: _SlotJob) -> int:
+    """Cache slots a decoding row holds once its next step's token is
+    written: its cursor + 1. The first sampled token is written by the
+    first decode step, so the cursor trails ``tokens`` by one."""
+    return job.kv0 + len(job.tokens)
 
 
 class _SlotReq:
@@ -1209,6 +1220,14 @@ class _SlotScheduler:
                     # reset after warm-up: the pool warm-up built is
                     # the one that serves, and its 1 is the evidence.
                     "row_shape_traces_total",
+                    # Key slots the device read, and key slots of the
+                    # whole rows, over dispatched decode steps,
+                    # speculative passes and prefill chunks: their ratio
+                    # is the share of ``max_seq_len`` the cached calls
+                    # attended (tpufw.ops.kv_store's ladder, by the
+                    # rule the programs use; ``_count_keys``).
+                    "attended_key_slots_total",
+                    "row_key_slots_total",
                 )
                 # Admissions whose prefix lookup the pool declined
                 # (a model with per-slot state gets no shared pages).
@@ -1710,6 +1729,18 @@ class _SlotScheduler:
                 "row_shape_traces_total", pool.row_shape_traces
             )
 
+    def _count_keys(self, lives, rows: int, chunk: bool = False) -> None:
+        """Book the key slots the device read in dispatched cached
+        calls of ``rows`` rows each: ``lives`` yields, per call, the
+        slots of its longest live row with the call's own tokens (0:
+        no row was live). The rung is the pool's to name, by the rule
+        its programs choose it with."""
+        if self._metrics is None or not self.page:
+            return
+        read, whole = self._pool.attended_keys(lives, chunk=chunk)
+        self._metrics.inc("attended_key_slots_total", rows * read)
+        self._metrics.inc("row_key_slots_total", rows * whole)
+
     def _admit(self) -> None:
         with self._cv:
             queue = list(self._queue)
@@ -2036,6 +2067,11 @@ class _SlotScheduler:
                 self._metrics.inc("retired_rows_total")
             req.rows_left -= 1
             return False
+        # A cold row comes left-padded to its bucket; a prefix hit's
+        # suffix prefill pads nothing.
+        job.kv0 = len(job.prompt) if grant is not None and shared_n else (
+            max(job.p_bucket or 0, len(job.prompt))
+        )
         if grant is not None:
             self._pool.insert_paged(
                 slot,
@@ -2284,6 +2320,10 @@ class _SlotScheduler:
         flush: list[_SlotReq] = []
         finished: list[_SlotReq] = []
         accept_frac = 0.0
+        # One verify call of k + 1 tokens a row, every active row live.
+        self._count_keys(
+            [max(_row_keys(job) + k for _, job in active)], self.n_slots
+        )
         for slot, job in active:
             req = job.req
             take = min(int(n_emit[slot]), job.max_new - len(job.tokens))
@@ -2379,6 +2419,7 @@ class _SlotScheduler:
             # The extent is for the span's arguments only; whether the
             # prefill ended is the pool's to say (``status`` below).
             width, _, final = self._pool.chunk_extent(cp)
+            live = cp.cursor + width
             t0 = time.perf_counter()
             # DISPATCH time: the chunk program is enqueued, not waited
             # for, and its device time is paid by whoever blocks next
@@ -2402,6 +2443,7 @@ class _SlotScheduler:
                 continue
             progressed = True
             with self._tracer.span("serve_emit", slot=slot):
+                self._count_keys([live], 1, chunk=True)
                 if self._metrics is not None:
                     self._metrics.registry.counter(
                         "tpufw_prefill_chunks_total"
@@ -2444,6 +2486,7 @@ class _SlotScheduler:
                 self._metrics.inc("retired_rows_total")
             req.rows_left -= 1
         else:
+            job.kv0 = len(job.prompt)
             self._pool.finalize_chunked(slot, cp, job.max_new - 1)
             if self._draft_pool is not None:
                 self._admit_draft(job, slot, cp.rng)
@@ -2527,12 +2570,14 @@ class _SlotScheduler:
         live_tokens = 0
         flush: list[_SlotReq] = []
         finished: list[_SlotReq] = []
+        spans = []  # per row: slots held at step 0, steps it was live
         for slot, job in active:
             req = job.req
             take = min(k, job.max_new - len(job.tokens))
             row = out[slot, :take].tolist()
             if self._eos is not None and self._eos in row:
                 row = row[: row.index(self._eos) + 1]
+            spans.append((_row_keys(job), len(row)))
             job.tokens.extend(row)
             job.unflushed.extend(row)
             live_tokens += len(row)
@@ -2557,6 +2602,15 @@ class _SlotScheduler:
                 req.rows_left -= 1
                 if req.rows_left == 0 and req.next_job == len(req.jobs):
                     finished.append(req)
+        # A row is live at step i while it still delivers a token there:
+        # the program's own ``done`` mask, read back from what it emitted.
+        self._count_keys(
+            (
+                max((at + i for at, n in spans if i < n), default=0)
+                for i in range(k)
+            ),
+            self.n_slots,
+        )
         if self._metrics is not None:
             self._metrics.inc("tokens_generated_total", live_tokens)
             # Capacity accounting: S * k device-steps ran; everything
@@ -2853,6 +2907,8 @@ class _Server:
                         "prefix_hits_total",
                         "prefix_misses_total",
                         "pages_freed_total",
+                        "attended_key_slots_total",
+                        "row_key_slots_total",
                     )
                     self.metrics.registry.counter(
                         "tpufw_serve_prefix_declined_total"
