@@ -42,7 +42,7 @@ sys.path.insert(0, ROOT)
 
 from benchmarks import harness, procs, stats, traffic  # noqa: E402
 
-BUCKETS = (512, 2048, 8192)
+BUCKETS = (512, 2048, 8192, 16384)
 QUEUE = "tpufw_serve_queue_depth"
 #: Host traces of the row model: a pool's one, none between two scrapes.
 ROW_TRACES = "tpufw_serve_row_shape_traces_total"

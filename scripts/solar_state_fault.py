@@ -46,9 +46,13 @@ def break_program(fault: str) -> None:
     solar_open2.kda_chunk = forgets
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--fault", required=True, choices=FAULTS)
+def through_harness(script: str, doc: str, faults, break_program) -> int:
+    """``benchmarks/run.py`` with each of its phases sent through
+    ``script``, whose ``break_program(fault)`` alters the served program
+    before the serve phase imports it (scripts/laguna_window_fault.py
+    calls this too)."""
+    ap = argparse.ArgumentParser(description=doc)
+    ap.add_argument("--fault", required=True, choices=faults)
     ap.add_argument("rest", nargs=argparse.REMAINDER)
     a = ap.parse_args()
     rest = a.rest[1:] if a.rest[:1] == ["--"] else a.rest
@@ -60,14 +64,14 @@ def main() -> int:
             break_program(a.fault)
         return run.main(rest)
     plain = runner._phase_cmd
-    # run.py's own phase command, with this file in front of its arguments.
+    # run.py's own phase command, with the script in front of its arguments.
     runner._phase_cmd = lambda args, phase: (
-        [sys.executable, os.path.abspath(__file__), "--fault", a.fault, "--"] + plain(args, phase)[2:]
+        [sys.executable, os.path.abspath(script), "--fault", a.fault, "--"] + plain(args, phase)[2:]
     )
-    print(f"bench: FAULT {a.fault} in the served program (scripts/solar_state_fault.py): this run has to be not correct",
+    print(f"bench: FAULT {a.fault} in the served program (scripts/{os.path.basename(script)}): this run has to be not correct",
           file=sys.stderr, flush=True)
     return run.main(rest)
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(through_harness(__file__, __doc__, FAULTS, break_program))

@@ -224,7 +224,8 @@ def test_zero_retrace_across_chunk_count(tiny_paged):
 
 def _row_family(kind):
     """(model class, decode config) of one cache kind the row twin
-    carries: K/V heads, MLA latents, per-slot state beside K/V."""
+    carries: K/V heads, MLA latents, per-slot state beside K/V, window
+    layers' rings beside K/V."""
     if kind == "gqa":
         from tpufw.models.mixtral import MIXTRAL_CONFIGS, Mixtral
 
@@ -233,12 +234,16 @@ def _row_family(kind):
         from tpufw.models.deepseek import DEEPSEEK_CONFIGS, Deepseek
 
         return Deepseek, DEEPSEEK_CONFIGS["deepseek_tiny"]
+    if kind == "window":
+        from tpufw.models.laguna import LAGUNA_CONFIGS, Laguna
+
+        return Laguna, LAGUNA_CONFIGS["laguna_tiny"]
     from tpufw.models.solar_open2 import SOLAR_OPEN2_CONFIGS, SolarOpen2
 
     return SolarOpen2, SOLAR_OPEN2_CONFIGS["solar_open2_tiny"]
 
 
-@pytest.fixture(scope="module", params=["gqa", "mla", "state"])
+@pytest.fixture(scope="module", params=["gqa", "mla", "state", "window"])
 def family(request):
     cls, base = _row_family(request.param)
     cfg = dataclasses.replace(base.decode_config(), max_seq_len=64)
@@ -334,6 +339,8 @@ def test_row_canvas_equals_the_per_call_tree(family):
         "gqa": {"cached_key", "cached_value"},
         "mla": {"cached_ckv", "cached_kpe"},
         "state": {"cached_key", "cached_value", "kda_state", "conv_state"},
+        "window": {"cached_key", "cached_value", "ring_key", "ring_value",
+                   "ring_slot", "ring_segment"},
     }[kind] <= names
     for g, w in zip(
         jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
@@ -364,8 +371,8 @@ def test_admissions_in_a_row_get_live_buffers_and_the_same_tokens(family):
     _, first = _monolithic(ref, pa, jax.random.key(2))
     assert cp_a.first_int == first
     assert got == _decode_all(ref, {0: first})[0]
-    if kind == "state":
-        assert pool.prefix is None  # no shared pages beside state
+    if kind in ("state", "window"):
+        assert pool.prefix is None  # no shared pages beside state or rings
         return
     # Two prefix hits in a row (the first admission's pages are in the
     # trie): each attach donates a canvas of its own.

@@ -391,3 +391,142 @@ def test_the_ladder_is_a_rule_of_the_row_and_the_page():
         )
     ).lower(fresh(Cfg(), "kv"), tokens("kv", 1, 0)).as_text()
     assert "case" not in text and "cond" not in text
+
+
+# ---- the ring: a window layer's last W keys, whatever the row holds ----
+
+W = 8
+RING_FEAT = {"ring_key": (2, 4), "ring_value": (2, 4)}
+
+
+class Ring(nn.Module):
+    cfg: Cfg
+
+    @nn.compact
+    def __call__(self, new, segment_ids):
+        read, seg, q_slots = kv_store.ring_append(
+            self, self.cfg, new, segment_ids, W
+        )
+        views, kv_seg, kv_slots = read(lambda v, s, p: (v, s, p))
+        return views, seg, kv_seg, kv_slots, q_slots
+
+
+def ring_tokens(t, seed):
+    return {
+        name: jax.random.normal(
+            jax.random.fold_in(jax.random.key(seed), i), (B, t, *feat)
+        ).astype(jnp.bfloat16)
+        for i, (name, feat) in enumerate(RING_FEAT.items())
+    }
+
+
+def visible(out, row, query):
+    """{logical slot: key} that query ``query`` of this call may attend
+    in ``row``: the store's own mask, ``attention_mask``'s rule."""
+    views, seg, kv_seg, kv_slots, q_slots = out
+    q = int(np.asarray(q_slots)[row if q_slots.shape[0] > 1 else 0, query])
+    behind = q - np.asarray(kv_slots)[row]
+    ok = (
+        (behind >= 0) & (behind < W)
+        & (np.asarray(kv_seg)[row] == int(np.asarray(seg)[row, query]))
+    )
+    keys = np.asarray(views["ring_key"].astype(jnp.float32))[row]
+    seen = {}
+    for j in np.flatnonzero(ok):
+        slot = int(np.asarray(kv_slots)[row, j])
+        assert slot not in seen, "a key shows once"
+        seen[slot] = keys[j]
+    return seen
+
+
+@pytest.mark.parametrize("cursor", ["scalar", "rows"])
+def test_the_ring_shows_each_query_its_window_and_nothing_else(cursor):
+    """Blocks of 5 (a prefill), single steps, a block of 4 (wider than
+    what is left of a lap), a block of 11 (wider than the ring) and more
+    steps: three laps of a ring of 8. Every query sees exactly the
+    tokens at the last 8 logical slots up to its own, with their values,
+    under a scalar cursor (a row twin) and under per-row cursors that
+    start apart (a pool)."""
+    cfg = Cfg(kv_page=PAGE if cursor == "rows" else 0, kv_pages=2)
+    cache = jax.tree_util.tree_map(
+        jnp.zeros_like,
+        Ring(cfg).init(jax.random.key(0), ring_tokens(1, 0), None)["cache"],
+    )
+    assert cache["ring_key"].shape == (B, W, 2, 4), "not max_seq_len, not pages"
+    assert cache["cache_index"].shape == ((B,) if cursor == "rows" else ())
+    start = np.array([0, 3, 9]) if cursor == "rows" else np.zeros(B, int)
+    if cursor == "rows":
+        cache["cache_index"] = jnp.asarray(start, jnp.int32)
+    written = [dict() for _ in range(B)]  # logical slot -> key, per row
+    at = start.copy()
+    for i, t in enumerate([5, 1, 1, 1, 4, 1, 11, 1, 1]):
+        new = ring_tokens(t, 10 + i)
+        out, mutated = Ring(cfg).apply({"cache": cache}, new, None, mutable=["cache"])
+        cache = mutated["cache"]
+        assert out[0]["ring_key"].shape[1] == kv_store.ring_keys(W, t)
+        keys = np.asarray(new["ring_key"].astype(jnp.float32))
+        for row in range(B):
+            for j in range(t):
+                written[row][int(at[row]) + j] = keys[row, j]
+            for j in range(t):
+                q = int(at[row]) + j
+                want = {s: k for s, k in written[row].items() if 0 <= q - s < W}
+                got = visible(out, row, j)
+                assert sorted(got) == sorted(want), (i, row, j)
+                for s in want:
+                    np.testing.assert_array_equal(got[s], want[s])
+        at += t
+    assert np.asarray(cache["cache_index"]).tolist() == (
+        at.tolist() if cursor == "rows" else int(at[0])
+    )
+
+
+def test_padding_and_done_rows_are_not_written_to_the_ring():
+    """A chunk padded on the right (segment 0) leaves the ring at the real
+    tokens; the cursor is then set back to them, as the chunk program
+    does, and the next chunk's queries see the real tokens alone. A row
+    that steps with segment 0 (a pool's done row) writes nothing."""
+    cfg = Cfg()
+    cache = jax.tree_util.tree_map(
+        jnp.zeros_like,
+        Ring(cfg).init(jax.random.key(0), ring_tokens(1, 0), None)["cache"],
+    )
+    first = ring_tokens(6, 1)
+    seg = jnp.asarray([[1, 1, 1, 1, 0, 0]] * B, jnp.int32)
+    _, mutated = Ring(cfg).apply({"cache": cache}, first, seg, mutable=["cache"])
+    cache = dict(mutated["cache"])
+    assert int(jnp.sum(cache["ring_segment"] > 0)) == 4 * B
+    cache["cache_index"] = jnp.asarray(4, jnp.int32)
+    out, mutated = Ring(cfg).apply({"cache": cache}, ring_tokens(3, 2), None, mutable=["cache"])
+    assert sorted(visible(out, 1, 2)) == [0, 1, 2, 3, 4, 5, 6]
+    done = jnp.asarray([[1], [0], [1]], jnp.int32)
+    before = mutated["cache"]
+    _, after = Ring(cfg).apply({"cache": before}, ring_tokens(1, 3), done, mutable=["cache"])
+    for name in ("ring_key", "ring_value", "ring_slot", "ring_segment"):
+        assert bool(jnp.all(after["cache"][name][1] == before[name][1])), name
+        assert not bool(jnp.all(after["cache"][name][0] == before[name][0])), name
+
+
+def test_ring_leaves_are_per_slot_and_the_hosts_count_is_the_programs():
+    for name in ("ring_key", "ring_value", "ring_slot", "ring_segment"):
+        r = kv_store.role(name)
+        assert r.kind == kv_store.RING and r.per_slot and not r.in_arena
+    assert kv_store.role("kda_state").per_slot and not kv_store.role("cached_key").per_slot
+    assert {k: d.reason for k, d in kv_store.DECLINES.items()} == {
+        kv_store.STATE: "state_layers", kv_store.RING: "window_layers"
+    }
+    assert (kv_store.ring_keys(512, 1), kv_store.ring_keys(512, 512)) == (512, 1024)
+    cache = Ring(Cfg()).init(jax.random.key(0), ring_tokens(1, 0), None)["cache"]
+    stacked = {"layers": jax.tree_util.tree_map(lambda x: jnp.stack([x] * 3), dict(cache))}
+    assert kv_store.ring_layers({"a": dict(cache), "b": dict(cache)}) == (2, W)
+    assert kv_store.ring_layers(stacked) == (3, W)
+    plain = Store(Cfg()).init(jax.random.key(0), tokens("kv", 1, 0), None)["cache"]
+    assert kv_store.ring_layers(plain) == (0, 0)
+    with pytest.raises(ValueError, match="rank 3"):
+        Ring(Cfg()).init(jax.random.key(0), {"ring_key": jnp.zeros((B, 1, 4))}, None)
+    # One static length a program: no switch, whatever max_seq_len is.
+    big = Cfg(max_seq_len=16384)
+    text = jax.jit(
+        lambda cache, new: Ring(big).apply({"cache": cache}, new, None, mutable=["cache"])
+    ).lower(cache, ring_tokens(1, 0)).as_text()
+    assert "case" not in text and "16384" not in text

@@ -373,19 +373,22 @@ def _seam_family(name):
     from benchmarks import harness
 
     keys = harness.model_keys(
-        harness.load_json("benchmarks/configs/rehearse/solar_open2.json")
+        harness.load_json(f"benchmarks/configs/rehearse/{name}.json")
     )
-    cls, cfg = harness.family_modules("solar_open2")[1].program_model(
+    cls, cfg = harness.family_modules(name)[1].program_model(
         keys, {"moe_dispatch": "sorted"}
     )
     return cls, cfg.decode_config()
 
 
-@pytest.mark.parametrize("family", ["llama", "deepseek", "solar_open2"])
+@pytest.mark.parametrize(
+    "family", ["llama", "deepseek", "solar_open2", "laguna"]
+)
 def test_every_cache_leaf_has_a_role_in_the_store(family):
     """The seam tpufw.ops.kv_store owns: whatever a pool or a row twin
     holds resolves through ``role()`` (the programs here switch on
-    nothing else), and the models spell no layout of their own."""
+    nothing else: no file of tpufw/infer spells a leaf's name), and the
+    models spell no layout of their own."""
     import pathlib
 
     from tpufw.ops import kv_store
@@ -417,14 +420,20 @@ def test_every_cache_leaf_has_a_role_in_the_store(family):
         kv_store.CURSOR,
     } <= kinds
     assert (kv_store.STATE in kinds) == (family == "solar_open2")
+    assert (kv_store.RING in kinds) == (family == "laguna")
     models = pathlib.Path(pages_mod.__file__).parents[1] / "models"
-    for source in ("llama.py", "deepseek.py"):
+    for source in ("llama.py", "deepseek.py", "laguna.py"):
         text = (models / source).read_text()
         for spelled in (
             '"page_table"', '"cache_index"', '"cached_segment_ids"',
-            '"_scale"', "self.variable(",
+            '"_scale"', "self.variable(", '"ring_slot"', '"ring_segment"',
         ):
             assert spelled not in text, (source, spelled)
+    for source in pathlib.Path(pages_mod.__file__).parent.glob("*.py"):
+        text = source.read_text()
+        # ("cache_index" is also a key of the slot bundle's wire format.)
+        for name in set(kv_store._LEAVES) - {"cache_index"}:
+            assert f'"{name}"' not in text, (source.name, name)
 
 
 # ---- the live prefix of a row (tpufw.ops.kv_store's ladder of key lengths)

@@ -1,0 +1,116 @@
+"""The serving programs of the benchmark's accepted families, as lowered
+text at their rehearsal widths, against digests recorded on the commit
+before window rings came to ``tpufw/ops/kv_store.py`` (PR 32): a change to
+the store, the attention module or the pools for ONE family's sake must
+leave the others' decode step and prefill chunk the programs they were
+(every static branch a program gains is paid in warm set-up, PERF.md §6,
+PR 31).
+
+Where a later change means to alter these programs, record the digests
+anew and say so: ``python tests/test_program_text.py > tests/data/program_digests.json``.
+The digests hold for the jax version they were recorded under; under
+another the test skips until they are recorded again.
+"""
+
+import dataclasses
+import hashlib
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+FAMILIES = ("deepseek_v2", "mixtral", "solar_open2")
+DIGESTS = os.path.join(ROOT, "tests", "data", "program_digests.json")
+PAGE, SLOTS, WIDTH = 16, 2, 32
+
+
+def program_texts(family: str) -> dict:
+    """{"decode", "chunk"}: StableHLO text of the paged pool's two-step
+    decode program and of a 32-token prefill chunk through the row twin,
+    over abstract bfloat16 parameters."""
+    from flax.linen import meta
+
+    from benchmarks import harness
+    from tpufw.infer import SamplingConfig, pages, slots
+
+    keys = harness.model_keys(harness.load_json(harness.rehearse_path(family)))
+    _, adapter = harness.family_modules(family)
+    cls, pc = adapter.program_model(keys, {"moe_dispatch": "sorted"})
+    cfg = pc.decode_config()
+    row_model = cls(cfg)
+    paged = cls(dataclasses.replace(
+        cfg, kv_page=PAGE, kv_pages=SLOTS * (cfg.max_seq_len // PAGE) + 1))
+    probe = jnp.zeros((1, 8), jnp.int32)
+    params = meta.unbox(jax.eval_shape(
+        lambda: row_model.init(jax.random.key(0), probe))["params"])
+    greedy = SamplingConfig(temperature=0.0)
+    abstract = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+    cache = abstract(jax.eval_shape(
+        lambda p: pages.paged_pool_cache(paged, p, SLOTS), params))
+    vec = lambda dtype: jax.ShapeDtypeStruct((SLOTS,), dtype)
+    step_keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 2))
+    decode = slots._decode_steps_jit.lower(
+        paged, params, cache, vec(jnp.int32), vec(jnp.int32), vec(bool),
+        vec(jnp.int32), None, step_keys,
+        sampling=greedy, pad_id=0, eos_id=None,
+    ).as_text()
+    paths, names, leaves, _ = pages._flatten_with_names(cache)
+    row_cache = abstract(pages._row_cache_shapes(row_model, params))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype)
+    chunk = pages._prefill_chunk_jit.lower(
+        tuple(leaves), row_cache, params,
+        jax.ShapeDtypeStruct((1, WIDTH), jnp.int32),
+        jax.ShapeDtypeStruct((WIDTH // PAGE,), jnp.int32),
+        scalar(jnp.int32), scalar(jnp.int32), scalar(bool),
+        jax.eval_shape(lambda: jax.random.key(0)), None,
+        row_model=row_model, sampling=greedy, eos_id=None,
+        paths=paths, names=names,
+        scale_src=pages.PagedSlotPool._scale_src(paths, names),
+        page=PAGE, quant=False,
+    ).as_text()
+    return {"decode": decode, "chunk": chunk}
+
+
+def digests() -> dict:
+    return {
+        family: {
+            name: hashlib.sha256(text.encode()).hexdigest()
+            for name, text in program_texts(family).items()
+        }
+        for family in FAMILIES
+    }
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_family_without_window_layers_lowers_to_the_program_it_was(family):
+    with open(DIGESTS) as f:
+        recorded = json.load(f)
+    if recorded["jax"] != jax.__version__:
+        # Another jax prints another text for the same program: that is
+        # no change of the store's. Record anew under the new version.
+        pytest.skip(f"digests recorded under jax {recorded['jax']}, this is {jax.__version__}")
+    got = {
+        name: hashlib.sha256(text.encode()).hexdigest()
+        for name, text in program_texts(family).items()
+    }
+    assert got == recorded["digests"][family], (
+        f"{family}'s lowered serving programs changed; if that is meant, "
+        "record them anew (this file's docstring)"
+    )
+
+
+if __name__ == "__main__":
+    import subprocess
+
+    commit = subprocess.run(
+        ["git", "rev-parse", "HEAD"], cwd=ROOT, capture_output=True, text=True
+    ).stdout.strip()
+    print(json.dumps({"recorded_on": commit, "jax": jax.__version__,
+                      "digests": digests()}, indent=1))

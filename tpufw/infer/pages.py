@@ -61,12 +61,12 @@ from tpufw.infer.slots import (
     SlotPool,
     _retire_jit,
     _track_seen,
+    per_slot_decline,
     reject_state,
-    state_leaf_bytes,
 )
 from tpufw.obs import trace as obs_trace
 from tpufw.ops.kv_store import (
-    CURSOR, PAGE, SCALE, SEGMENT, STATE, TABLE, attended_keys, role,
+    CURSOR, PAGE, SCALE, SEGMENT, TABLE, attended_keys, ring_keys, role,
 )
 from tpufw.ops.kv_store import leaf_name as _leaf_name
 from tpufw.ops.quant import dequantize_kv, quantize_kv
@@ -267,9 +267,10 @@ def _paged_insert_jit(
             out.append(leaf.at[..., slot, :].set(table_row))
         elif r.kind == CURSOR:
             out.append(leaf.at[..., slot].set(row_leaves[i]))
-        elif r.kind == STATE:
-            # Per-slot state: the row's, whole — nothing of the slot's
-            # previous occupant survives the insert.
+        elif r.per_slot:
+            # Per-slot state or a window layer's ring: the row's, whole
+            # — nothing of the slot's previous occupant survives the
+            # insert.
             a = _collapse_arena(leaf, r.rank)  # [stacks, n_slots, *feat]
             row = _collapse_arena(row_leaves[i], r.rank)[:, 0]
             out.append(
@@ -659,8 +660,9 @@ class PagedSlotPool(SlotPool):
     spill_pages_out: int = 0
     spill_pages_in: int = 0
     #: Why this pool has no prefix trie although one was asked for
-    #: ("state_layers": the model keeps per-slot state). The scheduler
-    #: counts the admissions declined for it, by this reason.
+    #: ("state_layers": the model keeps per-slot state; "window_layers":
+    #: rings). The scheduler counts the admissions declined for it, by
+    #: this reason.
     prefix_decline: str = ""
     #: The row twin's B=1 contiguous cache as ShapeDtypeStructs, found
     #: once by ``_find_row_shapes`` when the pool is built; ``_fresh_row``
@@ -698,9 +700,11 @@ class PagedSlotPool(SlotPool):
                 f"shared allocator covers {allocator.n_pages} pages but "
                 f"cfg.kv_pages={cfg.kv_pages}"
             )
-        # Shared pages are K/V alone: a row attached to them would start
-        # its state layers from zero, silently wrong. No trie, counted.
-        stateful = prefix_cache and state_leaf_bytes(cache) > 0
+        # Shared pages are the arena's K/V alone: a row attached to them
+        # would start its state layers from zero and its window layers
+        # from an empty ring, silently wrong. No trie, counted.
+        declined = per_slot_decline(cache) if prefix_cache else None
+        decline = declined.reason if declined else ""
         pool = cls(
             model=model,
             params=params,
@@ -723,9 +727,9 @@ class PagedSlotPool(SlotPool):
             ),
             prefix=(
                 PrefixCache(int(cfg.kv_page))
-                if prefix_cache and not stateful else None
+                if prefix_cache and not decline else None
             ),
-            prefix_decline="state_layers" if stateful else "",
+            prefix_decline=decline,
             slot_pages=[[] for _ in range(n_slots)],
         )
         pool._find_row_shapes()
@@ -767,6 +771,18 @@ class PagedSlotPool(SlotPool):
         cfg = (self.row_model if chunk else self.model).cfg
         read = [attended_keys(cfg, n) for n in lives]
         return sum(read), len(read) * int(cfg.max_seq_len)
+
+    def window_keys(self, calls: int, t: int = 1) -> Tuple[int, int]:
+        """(ring slots read, key slots of the whole row) a row, summed
+        over the window layers and ``calls`` cached calls of ``t``
+        tokens each (decode steps: 1; a prefill chunk: its width):
+        ``attended_keys``' twin for the layers that keep a ring. (0, 0)
+        for a model without one."""
+        layers, slots = self.ring_shape
+        return (
+            calls * layers * ring_keys(slots, t),
+            calls * layers * int(self.model.cfg.max_seq_len),
+        )
 
     # ---- host-side page bookkeeping -------------------------------
 
@@ -1067,6 +1083,7 @@ class PagedSlotPool(SlotPool):
         with self.tracer.span(
             "serve_row_alloc", shared_pages=len(shared_ids),
             state_bytes=self.state_bytes // self.n_slots,
+            window_bytes=self.window_bytes // self.n_slots,
         ):
             row_tree = self._fresh_row()
             if not len(shared_ids):

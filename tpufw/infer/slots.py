@@ -40,7 +40,9 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from tpufw.infer.generate import _model_apply, _stream_prefill
 from tpufw.infer.sampling import SamplingConfig, sample_token
-from tpufw.ops.kv_store import STATE, STATE_LEAVES, path_role
+from tpufw.ops.kv_store import (
+    DECLINES, RING, STATE, STATE_LEAVES, Decline, path_role, ring_layers,
+)
 
 # Bumped INSIDE the jitted bodies, i.e. once per (re)trace, never per
 # call: the cheap, version-proof way to assert "occupancy changes do
@@ -48,24 +50,35 @@ from tpufw.ops.kv_store import STATE, STATE_LEAVES, path_role
 TRACE_COUNTS: Dict[str, int] = {"insert": 0, "decode_steps": 0, "retire": 0}
 
 
-def state_leaf_bytes(cache) -> int:
-    """Bytes of per-slot state in a cache pytree (0: keys and values
-    only)."""
+def state_leaf_bytes(cache, kind: str = STATE) -> int:
+    """Bytes of the per-slot leaves of ``kind`` in a cache pytree:
+    recurrent STATE by default (0: keys and values only), a window
+    layer's RING."""
     return sum(
         int(leaf.nbytes)
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
-        if path_role(path).kind == STATE
+        if path_role(path).kind == kind
     )
 
 
+def per_slot_decline(cache) -> Optional[Decline]:
+    """Why a pool over ``cache`` shares no prefix page, exports no slot
+    and verifies no block (None where it may): by the kind of per-slot
+    leaf it holds that a page bundle does not carry and pages do not
+    determine (tpufw.ops.kv_store ``Role.per_slot``, ``DECLINES``)."""
+    for kind, decline in DECLINES.items():
+        if state_leaf_bytes(cache, kind):
+            return decline
+    return None
+
+
 def reject_state(pool, what: str) -> None:
-    """The one refusal of what per-slot state makes wrong today."""
-    if getattr(pool, "state_bytes", 0):
+    """The one refusal of what per-slot leaves make wrong today."""
+    decline = getattr(pool, "per_slot", None)
+    if decline is not None:
         raise ValueError(
-            f"{what}: {type(pool.model).__name__} keeps per-slot state "
-            f"({', '.join(STATE_LEAVES)}) beside its pages; snapshots of "
-            "state are not built yet, so this would continue from the "
-            "wrong state"
+            f"{what}: {type(pool.model).__name__} {decline.keeps}, so "
+            f"this would continue from the wrong {decline.wrong}"
         )
 
 
@@ -308,8 +321,16 @@ class SlotPool:
         self.home = home
         for name in ("cache", "token", "pos", "done", "remaining", "seen"):
             setattr(self, name, jax.device_put(getattr(self, name), home))
-        #: Bytes of per-slot state (kv_store role STATE) this pool holds.
+        #: Bytes of per-slot state (kv_store role STATE) this pool holds,
+        #: and of its window layers' rings (role RING), all slots.
         self.state_bytes = state_leaf_bytes(self.cache)
+        self.window_bytes = state_leaf_bytes(self.cache, RING)
+        #: What those leaves make this pool decline (None: nothing).
+        self.per_slot = per_slot_decline(self.cache)
+        #: (window layers, ring slots a row), and the ring slots the pool
+        #: holds: window layers x pool slots x window.
+        self.ring_shape = ring_layers(self.cache)
+        self.window_slots = self.ring_shape[0] * self.n_slots * self.ring_shape[1]
 
     @classmethod
     def create(

@@ -72,9 +72,7 @@ from tpufw.infer.generate import (
 )
 from tpufw.infer.sampling import SamplingConfig, sample_token, transform_logits
 from tpufw.infer.slots import live_segments
-from tpufw.ops.kv_store import (
-    CURSOR, SEGMENT, STATE, leaf_name, path_role,
-)
+from tpufw.ops.kv_store import CURSOR, SEGMENT, leaf_name, path_role
 
 # Trace-time counters for the CHUNKED slot-pool speculation below —
 # same contract as tpufw.infer.slots.TRACE_COUNTS: bumped once per
@@ -90,11 +88,11 @@ def _rollback(cache: dict, new_cursor: jax.Array) -> dict:
 
     def fix(path, leaf):
         kind = path_role(path).kind
-        if kind == STATE:
+        if path_role(path).per_slot:
             raise ValueError(
                 f"speculative decoding: cache leaf {leaf_name(path)!r} is "
-                "per-slot state, which a rejected draft has already "
-                "advanced and no cursor can rewind"
+                "per-slot state or a window layer's ring, which a rejected "
+                "draft has already advanced and no cursor can rewind"
             )
         if kind == CURSOR:
             # nn.scan stacks per-layer cursors into [L]; keep the shape.

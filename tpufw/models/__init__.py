@@ -8,6 +8,11 @@ from tpufw.models.gemma import (  # noqa: F401
     Gemma,
     GemmaConfig,
 )
+from tpufw.models.laguna import (  # noqa: F401
+    LAGUNA_CONFIGS,
+    Laguna,
+    LagunaConfig,
+)
 from tpufw.models.llama import (  # noqa: F401
     Llama,
     LlamaConfig,
@@ -57,6 +62,8 @@ def model_for_config(cfg):
         return Deepseek(cfg)
     if isinstance(cfg, SolarOpen2Config):  # a LlamaConfig too: first
         return SolarOpen2(cfg)
+    if isinstance(cfg, LagunaConfig):  # likewise
+        return Laguna(cfg)
     if isinstance(cfg, MixtralConfig):
         return Mixtral(cfg)
     if isinstance(cfg, GemmaConfig):

@@ -270,6 +270,11 @@ class YarnScaling:
     attention_factor: Optional[float] = None  # None = derive below
     truncate: bool = True
 
+    def inv_freqs(self, d: int, theta: float) -> jax.Array:
+        """[d/2] inverse frequencies over ``d`` rotated dimensions (what
+        tpufw.models.llama.apply_rope asks a scaling it does not own)."""
+        return _yarn_freqs(d, theta, self)
+
     def resolved_attention_factor(self) -> float:
         import math
 

@@ -76,6 +76,20 @@ class RopeScaling:
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerRope:
+    """One layer's rotary embedding, for a model whose layers differ in
+    it (tpufw.models.laguna: YaRN over the first half of each head on
+    global layers, plain rope over the whole head on window layers).
+    ``scaling`` is a ``RopeScaling`` or a ``deepseek.YarnScaling``;
+    ``rotary_dim`` the leading dimensions of each head that rotate
+    (None: all of them; HF ``partial_rotary_factor`` x head_dim)."""
+
+    theta: float
+    scaling: Optional[Any] = None
+    rotary_dim: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 128_256
     d_model: int = 4096
@@ -351,20 +365,38 @@ def apply_rope(
     x: jax.Array,
     positions: jax.Array,
     theta: float,
-    scaling: Optional[RopeScaling] = None,
+    scaling: Optional[Any] = None,
+    rotary_dim: Optional[int] = None,
 ) -> jax.Array:
-    """Rotary embeddings. x: [B, T, H, D], positions: [B, T] -> same shape."""
-    d = x.shape[-1]
+    """Rotary embeddings. x: [B, T, H, D], positions: [B, T] -> same shape.
+
+    ``rotary_dim`` < D rotates the first ``rotary_dim`` dimensions of
+    each head (split-half among themselves, HF's partial rotary) and
+    passes the rest through. ``scaling`` is a ``RopeScaling`` or a
+    ``tpufw.models.deepseek.YarnScaling``, whose attention factor
+    multiplies cos and sin: the rotated part alone."""
+    d = x.shape[-1] if rotary_dim is None else int(rotary_dim)
+    if d != x.shape[-1]:
+        rotated = apply_rope(x[..., :d], positions, theta, scaling)
+        return jnp.concatenate([rotated, x[..., d:]], axis=-1)
     freqs = 1.0 / (
         theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     )  # [D/2]
-    if scaling is not None:
+    att = 1.0
+    if isinstance(scaling, RopeScaling):
         freqs = _scale_rope_freqs(freqs, scaling)
+    elif scaling is not None:
+        # YarnScaling lives with the family that brought it, which
+        # imports this module.
+        freqs = scaling.inv_freqs(d, theta)
+        att = scaling.resolved_attention_factor()
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, T, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if att != 1.0:
+        out = out * att
     return out.astype(x.dtype)
 
 
@@ -540,13 +572,20 @@ class Attention(nn.Module):
     # Sliding-window size for this layer (None = global attention).
     # Gemma-2 alternates local/global layers, so this is per-block.
     window: Optional[int] = None
+    # Query heads of THIS layer over the model's ``n_kv_heads`` (None =
+    # cfg.n_heads; tpufw.models.laguna: 48 on global, 72 on window layers).
+    n_heads: Optional[int] = None
+    # This layer's rotary embedding (None = the model's one:
+    # cfg.rope_theta / cfg.rope_scaling over the whole head).
+    rope: Optional[LayerRope] = None
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None):
         cfg = self.cfg
+        n_heads = cfg.n_heads if self.n_heads is None else self.n_heads
         qkv_bias = getattr(cfg, "attention_qkv_bias", False)
         q = projection(
-            cfg, x, (cfg.n_heads, cfg.head_dim), -1,
+            cfg, x, (n_heads, cfg.head_dim), -1,
             ("embed",), ("q_heads", "head_dim"), "q", use_bias=qkv_bias,
         )
         k = projection(
@@ -557,7 +596,15 @@ class Attention(nn.Module):
             cfg, x, (cfg.n_kv_heads, cfg.head_dim), -1,
             ("embed",), ("kv_heads", "head_dim"), "v", use_bias=qkv_bias,
         )
-        if getattr(cfg, "use_rope", True):
+        if self.rope is not None:
+            rope = self.rope
+            q = apply_rope(
+                q, positions, rope.theta, rope.scaling, rope.rotary_dim
+            )
+            k = apply_rope(
+                k, positions, rope.theta, rope.scaling, rope.rotary_dim
+            )
+        elif getattr(cfg, "use_rope", True):
             rope_scaling = getattr(cfg, "rope_scaling", None)
             q = apply_rope(q, positions, cfg.rope_theta, rope_scaling)
             k = apply_rope(k, positions, cfg.rope_theta, rope_scaling)
@@ -608,12 +655,19 @@ class Attention(nn.Module):
                 sliding_window=self.window,
                 backend=cfg.attention_backend,
             )
-        if getattr(cfg, "attn_output_gate", False):
+        gated = getattr(cfg, "attn_output_gate", False)
+        if gated == "per_head":
+            # One sigmoid scalar a head, from the layer's input ([d, H]).
+            gate = projection(
+                cfg, x, n_heads, -1, ("embed",), ("q_heads",), "gate"
+            )
+            out = out * nn.sigmoid(gate)[..., None]
+        elif gated:
             # Elementwise sigmoid gate on the heads' output, taken from
             # the layer's input (a flat [d, H*hd] kernel, so the int8
             # path's table reads it like an MLP's ``gate``).
             gate = projection(
-                cfg, x, cfg.n_heads * cfg.head_dim, -1,
+                cfg, x, n_heads * cfg.head_dim, -1,
                 ("embed",), ("heads",), "gate",
             )
             out = out * nn.sigmoid(gate).reshape(out.shape)
@@ -629,6 +683,37 @@ class Attention(nn.Module):
         row (tpufw.ops.kv_store: layouts, the bound, masking and clamp
         rationale)."""
         cfg = self.cfg
+        soft_cap = getattr(cfg, "attn_logit_soft_cap", None)
+        if self.window is not None and getattr(cfg, "window_ring", False):
+            # A family whose window is a small part of its context in
+            # most layers (its config's constant ``window_ring``) keeps
+            # a RING of the last ``window`` keys per row in place of a
+            # row of max_seq_len it masks. The pools then share no
+            # prefix page, export no slot and verify no block (kv_store
+            # ``Role.per_slot``), which is why the one-window-everywhere
+            # presets (Mistral: 4096 of 32k) keep the mask: they would
+            # lose those for a cache 8x smaller. The ring is not in slot
+            # order: the mask reads each key's own logical slot
+            # (``kv_slots``).
+            read, seg, q_slots = kv_store.ring_append(
+                self, cfg, {"ring_key": k, "ring_value": v}, segment_ids,
+                self.window,
+            )
+            return read(
+                lambda views, kv_seg, kv_slots: multi_head_attention(
+                    q,
+                    views["ring_key"],
+                    views["ring_value"],
+                    causal=True,
+                    segment_ids=seg,
+                    kv_segment_ids=kv_seg,
+                    q_positions=q_slots,
+                    kv_positions=kv_slots,
+                    logits_soft_cap=soft_cap,
+                    sliding_window=self.window,
+                    backend="xla",
+                )
+            )
         read, seg, q_slots = kv_store.append(
             self, cfg, {"cached_key": k, "cached_value": v}, segment_ids
         )
@@ -641,7 +726,7 @@ class Attention(nn.Module):
                 segment_ids=seg,
                 kv_segment_ids=kv_seg,
                 q_positions=q_slots,
-                logits_soft_cap=getattr(cfg, "attn_logit_soft_cap", None),
+                logits_soft_cap=soft_cap,
                 sliding_window=self.window,
                 backend="xla",
             )

@@ -63,6 +63,7 @@ def attention_mask(
     kv_segment_ids: Optional[jax.Array] = None,
     q_positions: Optional[jax.Array] = None,
     sliding_window: Optional[int] = None,
+    kv_positions: Optional[jax.Array] = None,
 ) -> Optional[jax.Array]:
     """Which of S keys each of T queries may attend: bool, broadcastable
     to [B, 1, T, S], or None when nothing is masked. The ONE predicate —
@@ -70,7 +71,10 @@ def attention_mask(
     contraction over the same cache slots) both fill with it. Arguments
     as ``xla_attention``'s."""
     mask = None
-    kpos = jnp.arange(s)[None, None, None, :]  # [1,1,1,S]
+    if kv_positions is None:
+        kpos = jnp.arange(s)[None, None, None, :]  # [1,1,1,S]
+    else:
+        kpos = kv_positions[:, None, None, :]  # [B,1,1,S]
     if causal or sliding_window is not None:
         if q_positions is None:
             # Align query i with absolute position s-t+i.
@@ -104,6 +108,7 @@ def xla_attention(
     q_positions: Optional[jax.Array] = None,
     logits_soft_cap: Optional[float] = None,
     sliding_window: Optional[int] = None,
+    kv_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Reference softmax attention. q:[B,T,H,D], k/v:[B,S,K,D] -> [B,T,H,D].
 
@@ -115,7 +120,9 @@ def xla_attention(
     default assumes queries are the final T positions. Softmax is computed
     in float32 regardless of input dtype — bf16 logits lose too much
     precision at long T. ``sliding_window`` masks keys more than that
-    many positions behind the query (local attention).
+    many positions behind the query (local attention). ``kv_positions``
+    ([B, S] int) are the keys' own positions where they are not 0..S-1 in
+    order (a ring of the last S keys: tpufw.ops.kv_store).
     """
     b, t, h, d = q.shape
     _, s, kh, _ = k.shape
@@ -139,7 +146,7 @@ def xla_attention(
     mask = attention_mask(
         t, s, causal=causal, segment_ids=segment_ids,
         kv_segment_ids=kv_segment_ids, q_positions=q_positions,
-        sliding_window=sliding_window,
+        sliding_window=sliding_window, kv_positions=kv_positions,
     )
     if mask is not None:
         logits = jnp.where(mask, logits, -1e30)
@@ -208,6 +215,7 @@ def multi_head_attention(
     q_positions: Optional[jax.Array] = None,
     logits_soft_cap: Optional[float] = None,
     sliding_window: Optional[int] = None,
+    kv_positions: Optional[jax.Array] = None,
     backend: str = "xla",
 ) -> jax.Array:
     """Backend dispatcher — the single attention entry point for all models."""
@@ -222,8 +230,13 @@ def multi_head_attention(
             q_positions=q_positions,
             logits_soft_cap=logits_soft_cap,
             sliding_window=sliding_window,
+            kv_positions=kv_positions,
         )
-    if kv_segment_ids is not None or q_positions is not None:
+    if (
+        kv_segment_ids is not None
+        or q_positions is not None
+        or kv_positions is not None
+    ):
         raise NotImplementedError(
             f"KV-cache decode (kv_segment_ids/q_positions) requires "
             f"backend='xla', got {backend!r}"

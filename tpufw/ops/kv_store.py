@@ -42,6 +42,24 @@ cursor's rank, ``cfg.kv_quant``):
 - INT8 (``cfg.kv_quant == "int8"``, paged only): arenas hold int8
   codes, quantized per token at append, with float32 scales stored
   page-structured ``[kv_pages, kv_page]`` and applied at the view.
+- RING (``ring_append``: a layer that attends a window of W keys),
+  ``[B, W, *feat]`` rows whatever ``max_seq_len`` and ``kv_page`` are:
+  ring slot ``j % W`` holds the row's token at logical slot j, until
+  the token at j + W overwrites it. Keys are stored already rotated,
+  so the ring is never read in order: each ring slot carries its
+  logical slot (``ring_slot``) and segment id (``ring_segment``; 0 =
+  never written), and the mask is over those (query at logical slot q
+  may attend a ring slot iff ``0 <= q - its slot < W`` and the
+  segments match). Tokens with segment id 0 (prompt padding, a pool's
+  done rows) are NOT written: the ring is the last W real tokens. A
+  call of one token writes, then reads the W ring slots; a call of
+  t > 1 tokens reads the ring as it stood beside its own t tokens
+  (W + t keys, one static length), then keeps the last W of them. No
+  ladder and no switch: the view never grows with the row. In the
+  pools a ring is per-slot like STATE: written whole at insert, so a
+  reused slot holds nothing of the row before it; no page bundle
+  carries it, a prefix of pages does not determine it and a verify
+  block cannot un-write it (``Role.per_slot``).
 
 THE LIVE PREFIX. A cached call reads the first L slots of the row, not
 all ``max_seq_len``: L is the rows' live length rounded up to a rung of
@@ -71,6 +89,7 @@ and would wrongly mask valid recent slots.
 
 from __future__ import annotations
 
+import math
 from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax
@@ -78,8 +97,8 @@ import jax.numpy as jnp
 
 from tpufw.ops.quant import dequantize_kv, quantize_kv
 
-PAGE, SCALE, SEGMENT, TABLE, CURSOR, STATE = (
-    "page", "scale", "segment", "table", "cursor", "state"
+PAGE, SCALE, SEGMENT, TABLE, CURSOR, STATE, RING = (
+    "page", "scale", "segment", "table", "cursor", "state", "ring"
 )
 
 
@@ -89,8 +108,8 @@ class Role(NamedTuple):
     for what lives in the arena — the trailing ``rank - 2`` dims of a
     PAGE are the per-token feature block one int8 scale covers, and the
     matching row leaf is ``(1, W, *feat)`` at the same rank —
-    ``(B, *feat)`` for per-slot STATE. ``of`` names the PAGE leaf a
-    SCALE belongs to."""
+    ``(B, *feat)`` for per-slot STATE and RING. ``of`` names the PAGE
+    leaf a SCALE belongs to."""
 
     kind: str
     rank: int
@@ -100,6 +119,12 @@ class Role(NamedTuple):
     def in_arena(self) -> bool:
         """Indexed by physical page: what a page bundle carries."""
         return self.kind in (PAGE, SCALE, SEGMENT)
+
+    @property
+    def per_slot(self) -> bool:
+        """``[B, *feat]``, a slot's own and whole at every insert: what
+        no page bundle carries and no prefix of pages determines."""
+        return self.kind in (STATE, RING)
 
 
 _SEGMENT, _TABLE, _CURSOR = "cached_segment_ids", "page_table", "cache_index"
@@ -118,8 +143,43 @@ _LEAVES: Dict[str, Role] = {
     # export decline a model that has any (tpufw.infer.slots
     # ``reject_state``).
     "kda_state": Role(STATE, 4), "conv_state": Role(STATE, 3),
+    # A window layer's RING (``ring_append``): the last ``window`` keys
+    # and values of each row, the logical slot and the segment id of
+    # each ring slot. Per-slot like STATE, and declined where STATE is.
+    "ring_key": Role(RING, 4), "ring_value": Role(RING, 4),
+    "ring_slot": Role(RING, 2), "ring_segment": Role(RING, 2),
 }
 STATE_LEAVES = {n: r.rank for n, r in _LEAVES.items() if r.kind == STATE}
+
+
+class Decline(NamedTuple):
+    """What a pool that holds per-slot leaves of a kind declines prefix
+    sharing, slot export and speculation with: the scheduler's label,
+    what it keeps and what a continuation without it would start from
+    (the refusal's words)."""
+
+    reason: str
+    keeps: str
+    wrong: str
+
+
+#: The one rule for "this model has leaves a page bundle does not
+#: carry", by ``Role.kind`` (tpufw.infer.slots ``per_slot_decline``).
+DECLINES: Dict[str, Decline] = {
+    STATE: Decline(
+        "state_layers",
+        f"keeps per-slot state ({', '.join(STATE_LEAVES)}) beside its "
+        "pages; snapshots of state are not built yet",
+        "state",
+    ),
+    RING: Decline(
+        "window_layers",
+        "keeps a ring of its window layers' last keys per slot beside "
+        "its pages; a page bundle does not carry a ring, pages do not "
+        "determine it and a verify block cannot un-write it",
+        "keys",
+    ),
+}
 
 
 def role(name: str) -> Role:
@@ -315,5 +375,94 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
             key_rung(ladder, live),
             [lambda n=n: attend(*view(n)) for n in ladder],
         )
+
+    return read, seg, q_slots
+
+
+def ring_keys(window: int, t: int) -> int:
+    """Keys a call of ``t`` tokens reads of a window layer's ring, a row:
+    the ring after its own write (t == 1), or the ring as it stood beside
+    the call's own tokens. The one rule for ``ring_append`` and for the
+    host, which counts what the device read (tpufw.workloads.serve)."""
+    return int(window) if t == 1 else int(window) + int(t)
+
+
+def ring_layers(cache) -> Tuple[int, int]:
+    """(window layers, ring slots a row) of a cache pytree, by its
+    ``ring_slot`` leaves ``[*stack, B, window]``; (0, 0) without rings."""
+    layers = slots = 0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        if leaf_name(path) == "ring_slot":
+            layers += int(math.prod(leaf.shape[:-2]))
+            slots = int(leaf.shape[-1])
+    return layers, slots
+
+
+def ring_append(module, cfg, new: Dict[str, jax.Array], segment_ids, window):
+    """Append this call's tokens to a window layer's ring; hand back how
+    to read it (module docstring, RING).
+
+    ``new`` maps ``"ring_key"`` / ``"ring_value"`` to ``[B, t, *feat]``.
+    Returns ``(read, segment_ids, q_slots)`` as ``append`` does;
+    ``read(attend)`` is ``attend(views, kv_segment_ids, kv_slots)`` with
+    ``[B, L, *feat]`` views, the keys' segment ids and their logical
+    slots ``[B, L]``, L = ``window`` for t == 1 and ``window + t`` for a
+    wider call: one static length a program, so ``attend`` is traced
+    once. Query i may attend key j iff ``0 <= q_slots[., i] -
+    kv_slots[., j] < window`` and the segments match:
+    ``attention_mask(..., kv_positions=kv_slots, sliding_window=window)``.
+    The cursor is ``append``'s: a scalar (``generate``, a row twin), or
+    ``[B]`` in a pool, where it counts a done row's steps too."""
+    for name, x in new.items():
+        if role(name) != Role(RING, x.ndim):
+            raise ValueError(f"{name!r} rank {x.ndim} is not {role(name)}")
+    b, t = next(iter(new.values())).shape[:2]
+    r = int(window)
+    seg = (
+        jnp.ones((b, t), jnp.int32) if segment_ids is None
+        else segment_ids.astype(jnp.int32)
+    )
+    store = {
+        n: _declare(module, n, (b, r) + x.shape[2:], cfg.dtype)
+        for n, x in new.items()
+    }
+    rslot = _declare(module, "ring_slot", (b, r), jnp.int32)
+    rseg = _declare(module, "ring_segment", (b, r), jnp.int32)
+    paged = bool(getattr(cfg, "kv_page", 0))
+    cursor = _declare(module, _CURSOR, (b,) if paged else (), jnp.int32)
+    cur = cursor.value
+    q_slots = (cur[:, None] if cur.ndim else cur) + jnp.arange(t)[None, :]
+    slots = jnp.broadcast_to(q_slots, (b, t))
+    # The ring as it stood: what a call of t > 1 tokens reads.
+    old = {n: v.value for n, v in store.items()}
+    old_seg, old_slots = rseg.value, rslot.value
+    # The last ``r`` REAL tokens of the call are kept; what is not kept
+    # scatters out of bounds and is dropped.
+    real = seg > 0
+    after = jnp.cumsum(real[:, ::-1], axis=1)[:, ::-1] - real
+    at = (
+        jnp.arange(b)[:, None],
+        jnp.where(real & (after < r), slots % r, r),
+    )
+    for n, x in new.items():
+        store[n].value = store[n].value.at[at].set(
+            x.astype(cfg.dtype), mode="drop"
+        )
+    rslot.value = rslot.value.at[at].set(slots, mode="drop")
+    rseg.value = rseg.value.at[at].set(seg, mode="drop")
+    cursor.value = cur + t
+    if ring_keys(r, t) == r:
+        views = {n: v.value for n, v in store.items()}
+        kv_seg, kv_slots = rseg.value, rslot.value
+    else:
+        views = {
+            n: jnp.concatenate([old[n], x.astype(cfg.dtype)], axis=1)
+            for n, x in new.items()
+        }
+        kv_seg = jnp.concatenate([old_seg, seg], axis=1)
+        kv_slots = jnp.concatenate([old_slots, slots], axis=1)
+
+    def read(attend: Callable):
+        return attend(views, kv_seg, kv_slots)
 
     return read, seg, q_slots

@@ -1228,16 +1228,26 @@ class _SlotScheduler:
                     # rule the programs use; ``_count_keys``).
                     "attended_key_slots_total",
                     "row_key_slots_total",
+                    # The same pair for the layers that keep a ring of
+                    # their window (kv_store.ring_append): ring slots
+                    # read and the whole rows' slots, x window layers;
+                    # 0 for a model without one.
+                    "window_key_slots_total",
+                    "window_row_key_slots_total",
                 )
-                # Admissions whose prefix lookup the pool declined
-                # (a model with per-slot state gets no shared pages).
-                metrics.registry.counter(
-                    "tpufw_serve_prefix_declined_total"
-                ).inc(0.0, reason="state_layers")
-            # Per-slot state (linear-attention layers) the pool holds
-            # beside its K/V: 0 for a model that has none.
-            metrics.registry.gauge("tpufw_serve_state_bytes")
-            metrics.registry.gauge("tpufw_serve_state_slots")
+                # Admissions whose prefix lookup the pool declined (a
+                # model with per-slot state or window rings gets no
+                # shared pages).
+                for decline in slots_mod.DECLINES.values():
+                    metrics.registry.counter(
+                        "tpufw_serve_prefix_declined_total"
+                    ).inc(0.0, reason=decline.reason)
+            # Per-slot state (linear-attention layers) and window
+            # layers' rings the pool holds beside its K/V pages: 0 for a
+            # model that has none.
+            for name in ("state", "window"):
+                metrics.registry.gauge(f"tpufw_serve_{name}_bytes")
+                metrics.registry.gauge(f"tpufw_serve_{name}_slots")
             if self.prefill_chunk_pages:
                 # Chunked-prefill series live OUTSIDE the tpufw_serve_
                 # prefix (the disagg PrefillEngine reports the same
@@ -1651,11 +1661,17 @@ class _SlotScheduler:
                 self._pool, f"TPUFW_SERVE_SPEC_K={self.spec_k}"
             )
         if self._metrics is not None:
-            state = self._pool.state_bytes
             reg = self._metrics.registry
+            state = self._pool.state_bytes
             reg.gauge("tpufw_serve_state_bytes").set(float(state))
             reg.gauge("tpufw_serve_state_slots").set(
                 float(self.n_slots if state else 0)
+            )
+            reg.gauge("tpufw_serve_window_bytes").set(
+                float(self._pool.window_bytes)
+            )
+            reg.gauge("tpufw_serve_window_slots").set(
+                float(self._pool.window_slots)
             )
         if self._perf.enabled:
             # Mount the cost observatory on the pool (dynamic attr:
@@ -1729,17 +1745,25 @@ class _SlotScheduler:
                 "row_shape_traces_total", pool.row_shape_traces
             )
 
-    def _count_keys(self, lives, rows: int, chunk: bool = False) -> None:
+    def _count_keys(
+        self, lives, rows: int, chunk: bool = False, width: int = 1
+    ) -> None:
         """Book the key slots the device read in dispatched cached
-        calls of ``rows`` rows each: ``lives`` yields, per call, the
-        slots of its longest live row with the call's own tokens (0:
-        no row was live). The rung is the pool's to name, by the rule
-        its programs choose it with."""
+        calls of ``rows`` rows and ``width`` tokens each: ``lives``
+        yields, per call, the slots of its longest live row with the
+        call's own tokens (0: no row was live). The rung is the pool's
+        to name, by the rule its programs choose it with; layers that
+        keep a ring of their window are booked apart (x layers: they
+        read the ring whatever the rows hold)."""
         if self._metrics is None or not self.page:
             return
+        lives = list(lives)
         read, whole = self._pool.attended_keys(lives, chunk=chunk)
         self._metrics.inc("attended_key_slots_total", rows * read)
         self._metrics.inc("row_key_slots_total", rows * whole)
+        read, whole = self._pool.window_keys(len(lives), width)
+        self._metrics.inc("window_key_slots_total", rows * read)
+        self._metrics.inc("window_row_key_slots_total", rows * whole)
 
     def _admit(self) -> None:
         with self._cv:
@@ -2443,7 +2467,7 @@ class _SlotScheduler:
                 continue
             progressed = True
             with self._tracer.span("serve_emit", slot=slot):
-                self._count_keys([live], 1, chunk=True)
+                self._count_keys([live], 1, chunk=True, width=width)
                 if self._metrics is not None:
                     self._metrics.registry.counter(
                         "tpufw_prefill_chunks_total"
@@ -2909,10 +2933,15 @@ class _Server:
                         "pages_freed_total",
                         "attended_key_slots_total",
                         "row_key_slots_total",
+                        "window_key_slots_total",
+                        "window_row_key_slots_total",
                     )
-                    self.metrics.registry.counter(
-                        "tpufw_serve_prefix_declined_total"
-                    ).reset(reason="state_layers")
+                    from tpufw.ops.kv_store import DECLINES
+
+                    for decline in DECLINES.values():
+                        self.metrics.registry.counter(
+                            "tpufw_serve_prefix_declined_total"
+                        ).reset(reason=decline.reason)
                 if self._batcher.spec_k:
                     # Gated like the registration: the warmup request's
                     # speculative passes must stay invisible to scrapes.
