@@ -66,6 +66,16 @@ def metrics_of(bench: dict, cell_name: str, kind: str) -> list:
     ]
 
 
+def reader_module(metric_name: str) -> str:
+    """The module that reads a per-layer metric: ``benchmarks.metrics.<q>``,
+    q the name up to its first dot. One quantity whose cells report
+    different end-to-end metrics is several entries of ``per_layer``
+    (``ttft_max_ms`` moves ``ttft_p50_ms``; ``ttft_max_ms.tpot`` moves
+    ``tpot_p50_ms`` in a cell that does not report the former) and one
+    reader, so a split is entries and no new file."""
+    return "benchmarks.metrics." + metric_name.split(".", 1)[0]
+
+
 def family_modules(family: str):
     """(plain reference, adapter) of a family, found by name."""
     return (
@@ -97,8 +107,9 @@ def missing_parts(bench: dict, cell: dict, config: dict) -> list:
     except KeyError as e:
         out.append(e.args[0])
     for m in metrics_of(bench, cell["name"], "per_layer"):
-        if importlib.util.find_spec(f"benchmarks.metrics.{m['name']}") is None:
-            out.append(f"benchmarks/metrics/{m['name']}.py, the reader of per-layer metric {m['name']}, is missing")
+        module = reader_module(m["name"])
+        if importlib.util.find_spec(module) is None:
+            out.append(f"{module.replace('.', '/')}.py, the reader of per-layer metric {m['name']}, is missing")
     return out
 
 

@@ -4,9 +4,11 @@ device time the prefill-chunk program took in that stretch, at the chip's
 published bf16 peak. Both sides come from the same executions in the trace:
 tokens are each execution's chunk width, read off its operations. A chunk's
 place in its prompt is not in the trace, so its attention is counted at the
-mean number of keys a prompt token of this window's prompts attends."""
+mean number of keys a prompt token attends, over the prompts that were
+prefilling in the traced stretch (``_steps.prefilling_prompts``)."""
 
 from benchmarks import costs, harness
+from benchmarks.metrics import _steps
 
 
 def read(obs: dict):
@@ -18,7 +20,8 @@ def read(obs: dict):
     tokens = sum(p.get("tokens", 0) for p in progs)
     if secs <= 0 or tokens <= 0 or any(p.get("widths_unread") for p in progs):
         return None
-    t0, t1 = obs["t0"], obs["t0"] + obs["seconds"]
-    lens = [r["n_prompt"] for r in obs["records"] if t0 <= r["due"] < t1]
+    lens = _steps.prefilling_prompts(obs)
+    if not lens:
+        return None
     need = costs.prefill_chunk_flops(obs["family"], obs["config"], tokens, lens)
     return 100.0 * need / (secs * harness.peaks(obs["device"]["kind"])["bf16_flops"])

@@ -35,6 +35,9 @@ import time
 from benchmarks import harness, procs, stats, traffic
 
 TRACE_SECONDS = 6.0
+#: The profiler starts this long before the request the stretch is anchored
+#: to, and the stretch closes this long before the window does.
+TRACE_LEAD = 0.25
 
 
 def say(msg: str) -> None:
@@ -281,6 +284,31 @@ def serve_phase(args, bench: dict, cell: dict, config: dict) -> int:
     return 0
 
 
+def trace_offset(reqs, seconds: float):
+    """(seconds from the window's opening at which the profiler starts, the
+    request the stretch is anchored to or None). The stretch starts
+    ``TRACE_LEAD`` before an arrival and closes ``TRACE_LEAD`` before the
+    window does; of those starts, the one whose first half has the most
+    prompt tokens due in it, the earliest on a tie: the stretch then holds
+    an admission's prefill chunks and the decode steps beside and after
+    them however fast the server is, where a stretch at mid-window holds
+    whatever a slower tree was still doing then. It reads the schedule and nothing of the program,
+    so it is the same for a parent and a change and for every seed (the mix
+    fixes arrivals and lengths). A schedule with no such arrival is traced
+    at mid-window."""
+    best = None
+    for r in sorted(reqs, key=lambda r: r.t):
+        start = r.t - TRACE_LEAD
+        if not 0.0 <= start <= seconds - TRACE_SECONDS - TRACE_LEAD:
+            continue
+        due = sum(len(q.prompt) for q in reqs if start <= q.t <= start + TRACE_SECONDS / 2.0)
+        if best is None or due > best[0]:
+            best = (due, start, r)
+    if best is None:
+        return max(0.0, seconds / 2.0 - TRACE_SECONDS / 2.0), None
+    return best[1], best[2]
+
+
 def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
             compiles, setup_s, out_dir, client, jax) -> None:
     import asyncio
@@ -295,6 +323,7 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
 
     trace_dir = os.path.join(out_dir, "trace")
     traced = bool(args.trace) and not rehearse
+    traced_from = traced_s = None
     # How long after the window the client still waits for first tokens.
     drain = float(mix["drain_s"])
     rec_path = os.path.join(out_dir, "records.json")
@@ -313,11 +342,24 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
         scrape = lambda: asyncio.run(client.http_get(host, port, "/metrics"))
         until(0.0)
         prom0, c0 = scrape(), compiles.n
+        close = {}
+
+        def close_window():
+            until(seconds)
+            close["prom1"], close["c1"] = scrape(), compiles.n
+            close["device"] = _device_json(jax, rehearse)
+            close["late_s"] = time.time() - (t0 + seconds)
+
         if traced:
             import shutil
 
             shutil.rmtree(trace_dir, ignore_errors=True)
-            until(max(0.0, seconds / 2.0 - TRACE_SECONDS / 2.0))
+            offset, anchor = trace_offset(reqs, seconds)
+            say(f"traced stretch: {TRACE_SECONDS:.0f} s from {offset:.2f} s of the window, "
+                + (f"{TRACE_LEAD} s before the request due at {anchor.t:.2f} s ({len(anchor.prompt)} prompt tokens): "
+                   f"of the arrivals it can start at, the one with the most prompt tokens due in its first half"
+                   if anchor else "mid-window: no arrival of this schedule leaves the stretch room inside the window"))
+            until(offset)
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
@@ -325,10 +367,22 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
             traced_from = time.time()
             time.sleep(TRACE_SECONDS)
             traced_s = time.time() - traced_from
+            # On the chip stop_trace returns 4 to 30 s after it is called, by the cell,
+            # with the server running on meanwhile (PERF.md section 6, PR 35: on a thread
+            # of its own it took 22 to 74 s), and a stretch may close a lead before the
+            # window does: the window's close gets the thread, so that it is on time.
+            import threading
+
+            closing = threading.Thread(target=close_window, name="close_window")
+            closing.start()
             jax.profiler.stop_trace()
-        until(seconds)
-        prom1, c1 = scrape(), compiles.n
-        device = _device_json(jax, rehearse)
+            stopped_s = time.time() - traced_from - traced_s
+            closing.join()
+            say(f"the profiler took {stopped_s:.2f} s to stop; the window's second scrape was done "
+                f"{close['late_s']:.2f} s after its close")
+        else:
+            close_window()
+        prom1, c1, device = close["prom1"], close["c1"], close["device"]
         if gen.wait(timeout=drain + 120) != 0:
             raise RuntimeError("the load generator failed")
     finally:
@@ -368,8 +422,15 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
         f"medians are reported, the worst of each beside them; tokens in window {ws['tokens_in_window']}")
     from benchmarks.metrics import _steps
 
-    rows, cached = _steps.live_rows_and_tokens({"records": records, "t0": t0, "seconds": seconds})
-    say(f"occupancy at mid-window: {rows} of {mix['server_env']['TPUFW_SERVE_SLOTS']} slots decoding, "
+    obs = {
+        "records": records, "t0": t0, "seconds": seconds, "window": ws, "late_ms": late,
+        "prom0": _parse_prom(prom0), "prom1": _parse_prom(prom1),
+        "device": device, "family": config["family"], "config": keys,
+        "trace": None, "rehearse": rehearse, "traced_from": traced_from, "traced_s": traced_s,
+    }
+    rows, cached = _steps.live_rows_and_tokens(obs)
+    moment, what = _steps.sample_moment(obs)
+    say(f"occupancy at {what} ({moment - t0:.2f} s of the window): {rows} of {mix['server_env']['TPUFW_SERVE_SLOTS']} slots decoding, "
         f"{cached} tokens in their caches; backlog {ws['backlog_start']} -> {ws['backlog_end']}")
     counts = ("attempted", "failed", "tokens_in_window", "n_ttft", "n_tpot", "backlog_start", "backlog_end")
     say("window " + json.dumps({k: v for k, v in ws.items() if not rehearse or k in counts}))
@@ -386,12 +447,6 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
         elif m["name"] in ws:
             e2e[m["name"]] = {"value": ws[m["name"]], "unit": m["unit"]}
 
-    obs = {
-        "records": records, "t0": t0, "seconds": seconds, "window": ws, "late_ms": late,
-        "prom0": _parse_prom(prom0), "prom1": _parse_prom(prom1),
-        "device": device, "family": config["family"], "config": keys,
-        "trace": None, "rehearse": rehearse,
-    }
     from benchmarks.metrics import _phases
 
     phases = _phases.deltas(obs)
@@ -420,7 +475,7 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
 
         metrics = {}
         for m in harness.metrics_of(bench, cell["name"], "per_layer"):
-            reader = importlib.import_module(f"benchmarks.metrics.{m['name']}")
+            reader = importlib.import_module(harness.reader_module(m["name"]))
             value = reader.read(obs)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}

@@ -8,6 +8,7 @@ trace; only ``load_xplane`` touches jax.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -25,7 +26,9 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load_xplane(path: str) -> dict:
-    """{plane name: {line name: [(name, start_ns, dur_ns, {stat: value})]}}"""
+    """{plane name: {line name: [(name, start_ns, dur_ns, {})]}}. The
+    fourth place was each event's statistics; no reduction reads them and
+    a stretch holds a million operations, so they are not fetched."""
     from jax.profiler import ProfileData
 
     out = {}
@@ -34,8 +37,7 @@ def load_xplane(path: str) -> dict:
         for line in plane.lines:
             evs = lines.setdefault(line.name, [])
             for ev in line.events:
-                stats = {k: (v[:400] if isinstance(v, str) else v) for k, v in ev.stats}
-                evs.append((ev.name, float(ev.start_ns), float(ev.duration_ns), stats))
+                evs.append((ev.name, float(ev.start_ns), float(ev.duration_ns), {}))
     return out
 
 
@@ -167,14 +169,22 @@ def reduce_trace(planes: dict, chips: int = 1, traced_s: float = 0.0, hidden: in
     stretch's edges has been idle, and the span alone would not count it.
     ``hidden`` is the model's hidden size and ``widest`` the server's full
     prefill chunk, by which a prefill-chunk execution's width is read off
-    its operations."""
+    its operations.
+
+    A stretch in which nothing ran on the device (no device plane, or none
+    with an event) is a reading too: busy 0 s of ``traced_s``, no program,
+    one idle gap, the whole stretch. The readers that need a program then
+    find none and the run still ends with its result line."""
     devs = device_planes(planes)[:chips]
-    if not devs:
-        raise ValueError("the trace holds no TPU device plane")
     spans = [
         (s, s + d)
         for p in devs for evs in planes[p].values() for _, s, d, _ in evs
     ]
+    if not spans:
+        return {
+            "busy_s": 0.0, "window_s": traced_s, "span_s": 0.0, "device_ops": [], "programs": {},
+            "idle_gaps": [["no device operation in the traced stretch", traced_s]],
+        }
     lo, hi = min(s for s, _ in spans), max(e for _, e in spans)
     busy, op_time, programs = 0.0, {}, {}
     first_ops = []
@@ -191,18 +201,27 @@ def reduce_trace(planes: dict, chips: int = 1, traced_s: float = 0.0, hidden: in
             op_time[label] = op_time.get(label, 0.0) + d / 1e9
         for name, s, d, _ in lines.get(MODULES_LINE, []):
             programs.setdefault(module_base(name), []).append((s, d))
-    dev_ops = planes[devs[0]].get(OPS_LINE, [])
+    # An execution's operations are those that start inside it: found by
+    # bisection in the line sorted once, not by a pass over the whole line
+    # for every execution (80 executions x a million operations).
+    dev_ops = sorted(planes[devs[0]].get(OPS_LINE, []), key=lambda ev: ev[1])
+    starts = [ev[1] for ev in dev_ops]
+
+    def inside(s, d):
+        return dev_ops[bisect.bisect_left(starts, s):bisect.bisect_left(starts, s + d)]
+
     for name, prog in programs.items():
         if "decode_steps" in name:
-            prog_steps = [d / 1e6 / k for s, d in prog if (k := loop_steps(dev_ops, s, d))]
+            prog_steps = [d / 1e6 / k for s, d in prog if (k := loop_steps(inside(s, d), s, d))]
             programs[name] = {"runs": prog, "step_ms": prog_steps}
         elif "prefill_chunk" in name and hidden:
-            widths = [chunk_width(dev_ops, s, d, hidden, widest) for s, d in prog]
+            widths = [chunk_width(inside(s, d), s, d, hidden, widest) for s, d in prog]
             programs[name] = {"runs": prog, "tokens": sum(widths), "widths_unread": widths.count(0)}
     host = host_events(planes)
     span_s = (hi - lo) / 1e9
     window_s = max(span_s, traced_s)
-    idle = [[attribute_gap(g, host), g[1] / 1e9] for g in gaps([(s, d) for _, s, d, _ in first_ops], lo, hi)]
+    # Ten are reported, so the ten longest are looked up (gaps() sorts).
+    idle = [[attribute_gap(g, host), g[1] / 1e9] for g in gaps([(s, d) for _, s, d, _ in first_ops], lo, hi)[:10]]
     if window_s > span_s:
         # The trace's own clock is not the host's, so the idle time outside
         # the span cannot be split between the two edges.

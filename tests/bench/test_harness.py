@@ -91,7 +91,7 @@ def test_every_file_resolves_by_name(bench):
         mix = harness.load_json(harness.traffic_path(w["traffic"]))
         assert {"arrivals", "prompt", "output", "server_env", "rehearse", "ramp_s", "drain_s", "shape_seed"} <= set(mix)
     for m in bench["per_layer"]:
-        reader = importlib.import_module(f"benchmarks.metrics.{m['name']}")
+        reader = importlib.import_module(harness.reader_module(m["name"]))
         assert callable(reader.read)
 
 
@@ -135,6 +135,58 @@ def test_metrics_cover_cells(bench):
     for m in bench["per_layer"]:
         layers.setdefault(m["layer"].lower(), set()).add(m["layer"])
     assert all(len(v) == 1 for v in layers.values()), "one layer, two spellings"
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in harness.load_benchmark()["workloads"]])
+def test_a_cell_that_reports_ttft_reports_the_share_of_the_peak_that_moves_it(cell):
+    """A kernel's gain in the prefill can be claimed in ``ttft_p50_ms``
+    only while the whole step's share of the chip's peak bounds it: every
+    cell that reports the one lists ``prefill_mfu_share``, which moves it."""
+    bench = harness.load_benchmark()
+    e2e = {m["name"] for m in harness.metrics_of(bench, cell, "end_to_end")}
+    mfu = [m for m in harness.metrics_of(bench, cell, "per_layer") if m["name"] == "prefill_mfu_share"]
+    assert bool(mfu) == ("ttft_p50_ms" in e2e)
+    assert all(m["moves"] == "ttft_p50_ms" and m["source"] == "device_trace" for m in mfu)
+
+
+def test_a_split_quantity_has_one_reader(bench):
+    """``<quantity>.<suffix>`` is read by ``benchmarks/metrics/<quantity>.py``:
+    a quantity whose cells report different end-to-end metrics is entries
+    of ``per_layer`` and no new file. Each split entry keeps its
+    quantity's unit, sense, source and layer, moves another metric, and
+    shares no cell with it."""
+    assert harness.reader_module("ttft_max_ms.tpot") == harness.reader_module("ttft_max_ms") == "benchmarks.metrics.ttft_max_ms"
+    whole = {m["name"]: m for m in bench["per_layer"]}
+    split = [m for m in bench["per_layer"] if "." in m["name"]]
+    assert split, "solar2-longdoc-answers does not hold ttft_p50_ms, so what moved it there is split"
+    for m in split:
+        base = whole[m["name"].split(".", 1)[0]]
+        assert {k: m[k] for k in ("unit", "better", "source", "layer")} == {k: base[k] for k in ("unit", "better", "source", "layer")}
+        assert m["moves"] != base["moves"]
+        assert not set(m["workloads"]) & set(base["workloads"])
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in harness.load_benchmark()["workloads"]])
+def test_the_first_token_median_is_read_in_every_cell_with_long_prompts(cell):
+    """End to end (``ttft_p50_ms``) where it repeats, per layer
+    (``first_token_p50_ms``, the same statistic, no bound) where the
+    median is one request whose wait hangs on the phase of a decode
+    chunk; never both, and in neither only where no prompt is long
+    (``dsv2l-decode-long``)."""
+    bench = harness.load_benchmark()
+    held = "ttft_p50_ms" in {m["name"] for m in harness.metrics_of(bench, cell, "end_to_end")}
+    beside = "first_token_p50_ms" in {m["name"] for m in harness.metrics_of(bench, cell, "per_layer")}
+    assert not (held and beside)
+    known = {"dsv2l-decode-long": (False, False), "solar2-longdoc-answers": (False, True),
+             "mixtral-prefill-heavy": (True, False), "laguna-repo-context": (True, False)}
+    assert (held, beside) == known.get(cell, (held, beside)), "a later cell chooses for itself"
+
+
+def test_first_token_p50_ms_is_the_windows_median():
+    from benchmarks.metrics import first_token_p50_ms
+
+    assert first_token_p50_ms.read({"window": {"ttft_p50_ms": 230.5}}) == 230.5
+    assert first_token_p50_ms.read({"window": {}}) is None
 
 
 def test_run_fits_the_check(bench):

@@ -370,9 +370,11 @@ def test_the_ring_equals_the_masked_whole_row(built):
     ``window_ring``: what ``llama.Attention(window=W)`` does for every
     other family): a padded prefill chunk,
     a second chunk and decode steps past a lap of the ring give the same
-    output, to the last bit where XLA:CPU sums in the same order and to
-    float32 rounding of the sums otherwise (the ring's W + t or W keys
-    against the row's 128)."""
+    output to float32 rounding of the sums (the ring's W + t or W keys
+    against the row's 128). The order of the float32 sums is the backend's,
+    which picks its matmul by the shape it is handed: the test holds the
+    values, not the bits, so that a spelling of the contraction is not
+    chosen by what one backend's kernel choice makes bit-equal."""
     from tpufw.models.llama import Attention
 
     keys, _, params, _, _, pc32 = built
@@ -400,7 +402,6 @@ def test_the_ring_equals_the_masked_whole_row(built):
     real = np.asarray(seg > 0)
     diff = np.abs(np.asarray(outs[True]) - np.asarray(outs[False]))[real]
     assert diff.max() < 1e-6, "same keys, same weights: the order of the sums alone"
-    assert (diff == 0).mean() > 0.5
 
 
 # ---------------------------------------------------------- the declines
@@ -632,17 +633,22 @@ def test_the_reference_stands_alone_and_covers_every_answer():
     assert {"window_hbm_share", "window_keys_share", "attended_keys_share", "decode_roofline_share"} <= names
     assert "state_hbm_share" not in names
     # The first token of a 2k-15k prompt is what this mix's users wait for:
-    # the cell reports TTFT and what the whole window shows of the prefill
-    # half. The two readers of the traced 6 s (prefill_mfu_share,
-    # prefill_dev_ms_per_ktok) find no prefill chunk in it at this schedule
-    # (arrivals at 6.0, 7.7, 13.4 and 39.0 s; PERF.md section 7), and a
-    # metric lists the cells where its reader finds something to read.
+    # the cell reports TTFT, what the whole window shows of the prefill
+    # half, and the prefill's share of the chip's peak: the traced 6 s start
+    # a lead before the 15,232-token prompt is due (arrivals at 6.0, 7.7,
+    # 13.4 and 39.0 s; runners/serve.py::trace_offset), so its chunks are
+    # in the trace however fast the server is.
     assert {m["name"] for m in harness.metrics_of(bench, CELL, "end_to_end")} == {
         "tokens_per_s_per_chip", "tpot_p50_ms", "ttft_p50_ms", "setup_s"}
     assert {m["name"] for m in harness.metrics_of(bench, CELL, "per_layer") if m["moves"] == "ttft_p50_ms"} == {
-        "slo_good_share", "ttft_max_ms", "gen_late_max_ms", "join_wait_p50_ms", "queue_wait_p50_ms", "prefill_span_p50_ms"}
-    due = [r.t for r in traffic.schedule(mix, 1, bench["run_seconds"], 100) if r.t >= 0]
-    assert len(due) == 4 and not [t for t in due if 14.0 <= t <= 25.5], "no prompt is prefilling in the traced stretch"
+        "slo_good_share", "ttft_max_ms", "gen_late_max_ms", "join_wait_p50_ms", "queue_wait_p50_ms", "prefill_span_p50_ms",
+        "prefill_mfu_share"}
+    from benchmarks.runners import serve
+
+    reqs = traffic.schedule(mix, 1, bench["run_seconds"], 100)
+    start, anchor = serve.trace_offset(reqs, bench["run_seconds"])
+    assert sum(r.t >= 0 for r in reqs) == 4 and len(anchor.prompt) == mix["prompt"]["cap"], "the longest prompt prefills in the traced stretch"
+    assert [r.t for r in reqs if start <= r.t <= start + serve.TRACE_SECONDS] == [anchor.t]
 
 
 def test_new_readers_report_nothing_where_there_is_nothing_to_read():

@@ -64,8 +64,8 @@ def assert_nothing_left(seed: int, port: int, within: float = 10.0):
     while time.time() < deadline:
         try:
             socket.create_connection(("127.0.0.1", port), timeout=2).close()
-        except ConnectionRefusedError:
-            return
+        except (ConnectionRefusedError, ConnectionResetError):
+            return  # reset: a port in the act of closing, met once under the whole suite's load
         time.sleep(0.1)
     pytest.fail(f"port {port} still takes connections {within:.0f} s after the launcher ended")
 

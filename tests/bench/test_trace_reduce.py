@@ -8,7 +8,7 @@ import pytest
 
 from benchmarks import costs, harness, trace_reduce
 from benchmarks.metrics import (
-    decode_roofline_share, decode_step_dev_ms, device_idle_share, prefill_mfu_share,
+    decode_roofline_share, decode_step_dev_ms, device_idle_share, prefill_dev_ms_per_ktok, prefill_mfu_share,
 )
 
 
@@ -82,6 +82,43 @@ def test_traced_stretch_and_chunk_widths(planes):
     assert trace_reduce.chunk_width(ops, 1000, 4000, 2048, 256) == 0
 
 
+@pytest.mark.parametrize("empty", [
+    {},
+    {"/host:CPU": {"python": [("serve_wait", 0.0, 6e9, {})]}},
+    {"/device:TPU:0": {}, "/host:CPU": {"python": [("serve_wait", 0.0, 6e9, {})]}},
+    {"/device:TPU:0": {"XLA Ops": [], "XLA Modules": []}},
+], ids=["no-plane", "host-only", "device-plane-without-lines", "device-lines-without-events"])
+def test_an_empty_stretch_is_a_reading(empty):
+    """A server that has nothing in service for the whole traced stretch
+    leaves a trace with no device operation: busy 0 s of the stretch, one
+    idle gap, no program. The device reads idle, the readers that need a
+    program read nothing, and nothing raises."""
+    red = trace_reduce.reduce_trace(empty, 1, 6.001, 2048, 256)
+    assert red["busy_s"] == 0.0 and red["window_s"] == 6.001 and red["span_s"] == 0.0
+    assert red["programs"] == {} and red["device_ops"] == []
+    assert red["idle_gaps"] == [["no device operation in the traced stretch", 6.001]]
+    obs = {"trace": red, "family": "deepseek_v2", "config": {}, "device": {"kind": "TPU v5 lite"},
+           "t0": 0.0, "seconds": 10.0, "traced_from": 2.0, "traced_s": 6.001, "records": [
+               {"due": 1.0, "n_prompt": 256, "chunks": [(2.0, 1), (4.0, 16), (8.0, 16)], "done": 8.0}]}
+    assert device_idle_share.read(obs) == 100.0
+    for reader in (decode_step_dev_ms, decode_roofline_share, prefill_mfu_share, prefill_dev_ms_per_ktok):
+        assert reader.read(obs) is None
+
+
+def test_an_executions_operations_are_found_whatever_the_lines_order(planes):
+    """``reduce_trace`` finds the operations inside an execution by
+    bisection in the line sorted by start; a line handed over in another
+    order reads the same steps, widths, busy time and gaps."""
+    turned = {p: {line: evs[::-1] for line, evs in lines.items()} for p, lines in planes.items()}
+    a = trace_reduce.reduce_trace(planes, traced_s=20e-6, hidden=2048, widest=512)
+    b = trace_reduce.reduce_trace(turned, traced_s=20e-6, hidden=2048, widest=512)
+    for key in ("busy_s", "window_s", "span_s", "device_ops"):
+        assert a[key] == b[key], key
+    assert {k: (v["n"], v.get("tokens"), sorted(v.get("step_ms", []))) for k, v in a["programs"].items()} == {
+        k: (v["n"], v.get("tokens"), sorted(v.get("step_ms", []))) for k, v in b["programs"].items()}
+    assert sorted(g[1] for g in a["idle_gaps"]) == sorted(g[1] for g in b["idle_gaps"])
+
+
 def test_loop_steps(planes):
     ops = planes["/device:TPU:0"]["XLA Ops"]
     assert trace_reduce.loop_steps(ops, 1000, 4000) == 2
@@ -109,6 +146,16 @@ def test_readers_on_the_trace(planes):
     assert need == pytest.approx(128 * (2 * body + 2 * 16 * (128 + 64 + 128) * 8 * mean_keys))
     for reader in (device_idle_share, decode_step_dev_ms, decode_roofline_share, prefill_mfu_share):
         assert reader.read({**obs, "trace": None}) is None
+    # With the stretch's own moments the rows and the prompts are those inside it: at 7.0 + 1.0
+    # the second row decodes (its first token came at 7.0) and the first is done; the prompt
+    # prefilling in 7.0-9.0 is none, so the window's stand in.
+    there = {**obs, "traced_from": 7.0, "traced_s": 2.0}
+    floor_ms = costs.decode_step_bytes("deepseek_v2", keys, 1, 128 + 1) / 819e9 * 1e3
+    assert decode_roofline_share.read(there) == pytest.approx(100 * floor_ms / 0.002)
+    assert prefill_mfu_share.read(there) == pytest.approx(100 * need / (2000e-9 * 197e12))
+    only = costs.prefill_chunk_flops("deepseek_v2", keys, 128, [128])
+    assert prefill_mfu_share.read({**obs, "traced_from": 5.5, "traced_s": 2.0}) == pytest.approx(
+        100 * only / (2000e-9 * 197e12))
 
 
 def test_deepseek_counts_by_hand():
