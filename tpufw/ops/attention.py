@@ -4,8 +4,12 @@ The reference has no compute ops at all (its workload is ``nvidia-smi``,
 reference ``README.md:314``); attention exists here because BASELINE configs
 3-5 are Llama/Mixtral training. Backends:
 
-- ``"xla"``    — einsum softmax attention; XLA fuses it well and it runs
-                 anywhere (CPU tests, dryruns). The correctness reference.
+- ``"xla"``    — einsum softmax attention; a kv head is contracted with its
+                 group of query heads as the cache stores it (decode steps,
+                 long rows) or repeated to them where that is the cheaper
+                 (many queries over a short row). Runs anywhere: CPU tests,
+                 dryruns, the server's cached paths. The correctness
+                 reference.
 - ``"flash"``  — Pallas TPU flash-attention kernel (tpufw.ops.flash),
                  blockwise online-softmax in VMEM; long-seq memory O(T).
 - ``"ring"``   — sequence-parallel ring attention over the ``sequence`` mesh
@@ -46,12 +50,42 @@ def tanh_soft_cap(x: jax.Array, cap: float) -> jax.Array:
 
 
 def _repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
-    """[B, S, K, D] -> [B, S, K*n_rep, D] by repeating each kv head."""
+    """[B, S, K, D] -> [B, S, K*n_rep, D] by repeating each kv head.
+
+    For ``parallel/ring.py`` and ``parallel/ulysses.py``, whose per-shard
+    math is written over query heads, and for ``xla_attention`` where
+    ``_repeat_is_cheaper`` says so; everywhere else it contracts the group
+    where it lies."""
     if n_rep == 1:
         return x
     b, s, k, d = x.shape
     x = jnp.broadcast_to(x[:, :, :, None, :], (b, s, k, n_rep, d))
     return x.reshape(b, s, k * n_rep, d)
+
+
+#: The longest row over which XLA:TPU (v5e) computes max, exp and sum inside
+#: the per-head logits dot. Past it the per-head form of a 512-query call
+#: falls off a cliff: 72.8 ms at 8,192 keys where the grouped form takes 3.7
+#: (PERF.md section 5, PR 36).
+_FUSED_SOFTMAX_KEYS = 4096
+
+
+def _repeat_is_cheaper(t: int, s: int, d: int) -> bool:
+    """Whether a grouped-query call of ``t`` queries over ``s`` keys should
+    give every query head its own copy of its kv head; from static shapes
+    alone.
+
+    XLA:TPU fuses the softmax of the per-head spelling into its two dots
+    (the float32 logits are written once and read once) and reads the
+    grouped spelling's logits three times, so where the logits outweigh
+    the repeat the per-head form is ahead. Measured in the serving chunk
+    programs (PERF.md section 5, PR 36): at 64 queries the grouped form is
+    2% ahead, from 128 (a head's width) up the per-head form is 1-6% ahead,
+    as long as the row is short enough for that fusion. So prefill chunks
+    and training rows of up to 4,096 keys repeat K and V (in their own
+    dtype, a few tens of MB); decode steps, verify blocks and every longer
+    row contract the group in place, where a repeat costs 3-27x."""
+    return t >= d and s <= _FUSED_SOFTMAX_KEYS
 
 
 def attention_mask(
@@ -110,7 +144,22 @@ def xla_attention(
     sliding_window: Optional[int] = None,
     kv_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Reference softmax attention. q:[B,T,H,D], k/v:[B,S,K,D] -> [B,T,H,D].
+    """Reference softmax attention. q:[B,T,H,D], k:[B,S,K,D], v:[B,S,K,Dv]
+    -> [B,T,H,Dv].
+
+    Grouped-query heads (G = H // K query heads a kv head) are contracted
+    where the cache stores them: q is viewed as [B,T,K,G,D] and each kv
+    head's keys and values meet its G x T queries in one dot, in the
+    operands' own dtype with float32 accumulation. Nothing of [B,S,H,D]
+    exists then, in any dtype: a decode step that repeated K and V spent
+    most of its time writing them in float32. The queries are the dot's
+    rows ([.., G, T, S] logits): XLA:TPU then runs mask, softmax and the
+    value dot on the logits as the first dot left them, where with the
+    keys as rows it copies them in float32 wherever G x T outnumbers S.
+    MQA (K = 1) is the same code with a unit axis. Two cases keep the
+    per-head spelling, one kv head a query head: MHA (G = 1), which has
+    nothing to repeat, and ``_repeat_is_cheaper``'s many queries over a
+    short row, where the repeat is small and the logits are what costs.
 
     ``segment_ids`` ([B, T] int) masks cross-segment attention for packed
     sequences; ``kv_segment_ids`` ([B, S]) gives the key side its own ids
@@ -128,16 +177,19 @@ def xla_attention(
     _, s, kh, _ = k.shape
     if h % kh:
         raise ValueError(f"q heads {h} not divisible by kv heads {kh}")
-    k = _repeat_kv(k, h // kh)
-    v = _repeat_kv(v, h // kh)
+    g = h // kh
+    if g == 1 or _repeat_is_cheaper(t, s, d):
+        k, v = _repeat_kv(k, g), _repeat_kv(v, g)
+        logits_of, values_of = "bthd,bshd->bhts", "bhts,bshd->bthd"
+    else:
+        q = q.reshape(b, t, kh, g, d)
+        logits_of, values_of = "btkgd,bskd->bkgts", "bkgts,bskd->btkgd"
 
     scale = 1.0 / math.sqrt(d)
     # fp32 accumulation on the MXU: bf16 logits would already have lost the
     # precision the fp32 softmax is supposed to protect.
     logits = (
-        jnp.einsum(
-            "bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32
-        )
+        jnp.einsum(logits_of, q, k, preferred_element_type=jnp.float32)
         * scale
     )
     if logits_soft_cap is not None:
@@ -149,10 +201,15 @@ def xla_attention(
         sliding_window=sliding_window, kv_positions=kv_positions,
     )
     if mask is not None:
-        logits = jnp.where(mask, logits, -1e30)
+        # [B or 1, 1, T, S] over the heads, which take one axis or two.
+        logits = jnp.where(
+            mask if logits.ndim == 4 else mask[:, :, None], logits, -1e30
+        )
 
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bshd->bthd", probs, v)
+    out = jnp.einsum(values_of, probs, v)
+    # V's head width, not q's: MLA's uncached path has them differ.
+    return out.reshape(b, t, h, v.shape[-1])
 
 
 def _flash_over_mesh(q, k, v, *, segment_ids, **kwargs) -> jax.Array:

@@ -19,8 +19,10 @@ Trade-offs vs the ring (why tpufw ships both):
   numerics: no cross-chunk softmax merging).
 
 GQA: if the kv-head count doesn't divide by P, kv heads are repeated up
-to the query head count before the swap (costs bandwidth; exact same
-math — _repeat_kv is what single-device GQA attention does anyway).
+to the query head count before the swap (costs bandwidth and, unlike
+single-device ``xla_attention``, which contracts each kv head with its
+group in place, materialises K and V at the query heads' width; exact
+same math).
 """
 
 from __future__ import annotations
