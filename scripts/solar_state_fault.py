@@ -1,16 +1,18 @@
-"""A fault in the linear-attention state, put through the benchmark's own
-harness, which has to call the run not ``correct``:
+"""A fault in a family's per-slot recurrent state (Solar-Open2's linear
+attention, Falcon-H1's state-space mixer: the family is the workload's),
+put through the benchmark's own harness, which has to call the run not
+``correct``:
 
     python scripts/solar_state_fault.py --fault zero_carry|bf16_state -- \\
-        --workload solar2-longdoc-answers --seed <n> --seconds 45 --trace 0 [--rehearse-cpu]
+        --workload solar2-longdoc-answers|falconh1-instruct-burst --seed <n> --seconds 45 --trace 0 [--rehearse-cpu]
 
 Everything after ``--`` is ``benchmarks/run.py``'s own command line, and
 the launcher, the phases, the load, the reference check and the limits
 are its own: this script only sends each phase through itself, so that
 the PROGRAM is altered before the phase imports it.
 
-- ``zero_carry``: every call of the chunkwise delta rule starts from a
-  zero state, so what a prompt's earlier chunks wrote is lost at each
+- ``zero_carry``: every call of the chunkwise rule starts from a zero
+  state, so what a prompt's earlier chunks wrote is lost at each
   chunk boundary (the convolution tails and the softmax layer's keys and
   values are carried as ever). What a chunk-boundary bug, or a slot that
   kept another row's state, would look like to the served tokens.
@@ -30,20 +32,41 @@ sys.path.insert(0, ROOT)
 FAULTS = ("zero_carry", "bf16_state")
 
 
-def break_program(fault: str) -> None:
+#: family -> (the model's module, the chunkwise rule as that module binds
+#: it, which of its arguments is the carried state, the state's type).
+STATEFUL = {
+    "solar_open2": ("tpufw.models.solar_open2", "kda_chunk", 5, "KDA_STATE_DTYPE"),
+    "falcon_h1": ("tpufw.models.falcon_h1", "ssd_chunk", 6, "SSM_STATE_DTYPE"),
+}
+
+
+def family_of(argv) -> str:
+    """The family of the configuration the ``--workload`` of ``argv`` runs."""
+    from benchmarks import harness
+
+    bench = harness.load_benchmark()
+    cell = harness.cell(bench, argv[argv.index("--workload") + 1])
+    return harness.load_json(harness.config_entry(bench, cell["config"])["file"])["family"]
+
+
+def break_program(fault: str, family: str) -> None:
+    import importlib
+
     import jax.numpy as jnp
 
-    from tpufw.models import solar_open2
-
+    module, rule, state_at, state_dtype = STATEFUL[family]
+    model = importlib.import_module(module)
     if fault == "bf16_state":
-        solar_open2.KDA_STATE_DTYPE = jnp.bfloat16
+        setattr(model, state_dtype, jnp.bfloat16)
         return
-    sound = solar_open2.kda_chunk
+    sound = getattr(model, rule)
 
-    def forgets(q, k, v, g, beta, state, valid=None):
-        return sound(q, k, v, g, beta, jnp.zeros_like(state), valid)
+    def forgets(*args, **kwargs):
+        args = list(args)
+        args[state_at] = jnp.zeros_like(args[state_at])
+        return sound(*args, **kwargs)
 
-    solar_open2.kda_chunk = forgets
+    setattr(model, rule, forgets)
 
 
 def through_harness(script: str, doc: str, faults, break_program) -> int:
@@ -74,4 +97,5 @@ def through_harness(script: str, doc: str, faults, break_program) -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(through_harness(__file__, __doc__, FAULTS, break_program))
+    sys.exit(through_harness(
+        __file__, __doc__, FAULTS, lambda fault: break_program(fault, family_of(sys.argv))))

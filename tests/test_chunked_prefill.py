@@ -516,8 +516,10 @@ def _wait_idle(tracer, timeout=30.0):
 
 def test_scheduler_phases_and_request_chain(tiny_sched_model, tmp_path):
     """Paged chunked prefill with the pass on the record: the leaf
-    phases partition the scheduler thread's time (their self seconds sum
-    to >= 95% of it, in the trace and on the counter alike), the row
+    phases partition the scheduler thread's own spans (they nest, and
+    their self seconds sum to the outermost spans' durations, in the
+    trace and on the counter alike; nothing here is held to the wall
+    clock of a loaded machine), the row
     allocation is recorded once per admission, the request-chain
     histograms each take one observation per request, and one request's
     ``req_queue`` / ``req_prefill`` spans and ``serve_request`` event
@@ -563,12 +565,30 @@ def test_scheduler_phases_and_request_chain(tiny_sched_model, tmp_path):
     warm = [e for e in tracer._events[n0:] if e["ph"] == "X"]
     sched_evs = [e for e in warm if e["name"] in phases]
 
-    # The phases partition the thread's time over the warm batch.
+    # The phases partition the thread's own spans over the warm batch:
+    # they nest, the outermost ones follow one another without overlap,
+    # and the self seconds of all of them sum to the outermost ones'
+    # durations. What lies BETWEEN two outermost spans is the thread off
+    # the CPU or between two ``with`` blocks, which grows with the
+    # machine's load (six workers of a test run: the share this test
+    # used to bound from below), so it is bounded from above only.
     lo = min(e["ts"] for e in sched_evs)
     hi = max(e["ts"] + e["dur"] for e in sched_evs)
     covered = sum(e["self_dur"] for e in sched_evs)
     assert covered <= (hi - lo) * (1 + 1e-6)
-    assert covered >= 0.95 * (hi - lo), (covered, hi - lo)
+    assert len({e["tid"] for e in sched_evs}) == 1, "one scheduler thread"
+    outer, end = [], -1.0
+    for e in sorted(sched_evs, key=lambda e: (e["ts"], -e["dur"])):
+        if e["ts"] + e["dur"] <= end + 1e-2:
+            continue  # inside the outermost span before it: nested
+        assert e["ts"] >= end - 1e-2, (e["name"], e["ts"], end)
+        outer.append(e)
+        end = e["ts"] + e["dur"]
+    assert {e["name"] for e in outer} <= phases and len(outer) < len(sched_evs)
+    # Each event's times are rounded to a nanosecond.
+    assert covered == pytest.approx(
+        sum(e["dur"] for e in outer), abs=2e-3 * len(sched_evs)
+    )
     by_phase = {}
     for e in sched_evs:
         by_phase[e["name"]] = by_phase.get(e["name"], 0) + 1

@@ -378,11 +378,15 @@ def _seam_family(name):
     cls, cfg = harness.family_modules(name)[1].program_model(
         keys, {"moe_dispatch": "sorted"}
     )
+    if name == "falcon_h1":
+        # Its layers are alike, so its trunk scans: STATE and PAGE
+        # leaves of ONE layer, both stacked [L, ...] in front.
+        cfg = dataclasses.replace(cfg, scan_layers=True)
     return cls, cfg.decode_config()
 
 
 @pytest.mark.parametrize(
-    "family", ["llama", "deepseek", "solar_open2", "laguna"]
+    "family", ["llama", "deepseek", "solar_open2", "laguna", "falcon_h1"]
 )
 def test_every_cache_leaf_has_a_role_in_the_store(family):
     """The seam tpufw.ops.kv_store owns: whatever a pool or a row twin
@@ -419,10 +423,19 @@ def test_every_cache_leaf_has_a_role_in_the_store(family):
         kv_store.PAGE, kv_store.SCALE, kv_store.SEGMENT, kv_store.TABLE,
         kv_store.CURSOR,
     } <= kinds
-    assert (kv_store.STATE in kinds) == (family == "solar_open2")
+    assert (kv_store.STATE in kinds) == (
+        family in ("solar_open2", "falcon_h1")
+    )
     assert (kv_store.RING in kinds) == (family == "laguna")
+    if family == "falcon_h1":
+        # One scanned block holds a page pair AND per-slot state.
+        block = pages_mod.paged_pool_cache(paged, params, 2)["cache"]["layers"]
+        n_layers = cfg.n_layers
+        assert block["attn"]["cached_key"].shape[0] == n_layers
+        assert block["ssm"]["ssm_state"].shape[:2] == (n_layers, 2)
+        assert block["ssm"]["conv_state"].shape[:2] == (n_layers, 2)
     models = pathlib.Path(pages_mod.__file__).parents[1] / "models"
-    for source in ("llama.py", "deepseek.py", "laguna.py"):
+    for source in ("llama.py", "deepseek.py", "laguna.py", "falcon_h1.py"):
         text = (models / source).read_text()
         for spelled in (
             '"page_table"', '"cache_index"', '"cached_segment_ids"',

@@ -3,6 +3,11 @@ from tpufw.models.deepseek import (  # noqa: F401
     Deepseek,
     DeepseekConfig,
 )
+from tpufw.models.falcon_h1 import (  # noqa: F401
+    FALCON_H1_CONFIGS,
+    FalconH1,
+    FalconH1Config,
+)
 from tpufw.models.gemma import (  # noqa: F401
     GEMMA_CONFIGS,
     Gemma,
@@ -64,6 +69,8 @@ def model_for_config(cfg):
         return SolarOpen2(cfg)
     if isinstance(cfg, LagunaConfig):  # likewise
         return Laguna(cfg)
+    if isinstance(cfg, FalconH1Config):  # likewise
+        return FalconH1(cfg)
     if isinstance(cfg, MixtralConfig):
         return Mixtral(cfg)
     if isinstance(cfg, GemmaConfig):

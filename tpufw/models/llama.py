@@ -609,6 +609,11 @@ class Attention(nn.Module):
             q = apply_rope(q, positions, cfg.rope_theta, rope_scaling)
             k = apply_rope(k, positions, cfg.rope_theta, rope_scaling)
         # else NoPE: no position signal; causality alone orders tokens.
+        key_multiplier = getattr(cfg, "key_multiplier", None)
+        if key_multiplier is not None:
+            # A muP multiplier on the keys (tpufw.models.falcon_h1), the
+            # config's own number applied at run time.
+            k = k * jnp.asarray(key_multiplier, k.dtype)
         # Non-default query scaling (Gemma's query_pre_attn_scalar):
         # backends scale by head_dim**-0.5 internally, so pre-multiply q
         # by the ratio to the desired qpas**-0.5.
@@ -749,6 +754,13 @@ class MLP(nn.Module):
             cfg, x, d_ff, -1, ("embed",), ("mlp",), "gate"
         )
         up = projection(cfg, x, d_ff, -1, ("embed",), ("mlp",), "up")
+        # muP multipliers on the gate's pre-activation and on the output
+        # (tpufw.models.falcon_h1), None elsewhere.
+        gate_mult, down_mult = (
+            getattr(cfg, "mlp_multipliers", None) or (None, None)
+        )
+        if gate_mult is not None:
+            gate = gate * jnp.asarray(gate_mult, gate.dtype)
         act_name = getattr(cfg, "mlp_activation", "silu")
         if act_name == "silu":
             act = nn.silu(gate)
@@ -758,9 +770,12 @@ class MLP(nn.Module):
             raise ValueError(f"unknown mlp_activation {act_name!r}")
         h = act * up
         h = nn.with_logical_constraint(h, ("batch", "act_seq", "act_mlp"))
-        return projection(
+        out = projection(
             cfg, h, cfg.d_model, -1, ("mlp",), ("embed",), "down"
         )
+        if down_mult is not None:
+            out = out * jnp.asarray(down_mult, out.dtype)
+        return out
 
 
 class LlamaBlock(nn.Module):
@@ -868,6 +883,9 @@ def decoder_lm(
         x = x * jnp.asarray(
             math.sqrt(cfg.d_model), cfg.dtype
         ).astype(x.dtype)
+    embedding_multiplier = getattr(cfg, "embedding_multiplier", None)
+    if embedding_multiplier is not None:
+        x = x * jnp.asarray(embedding_multiplier, x.dtype)
     x = nn.with_logical_constraint(x, ("batch", "act_seq", "act_embed"))
 
     block_cls = block_base
@@ -939,6 +957,9 @@ def decoder_lm(
             ),
             name="lm_head",
         )(x)
+    lm_head_multiplier = getattr(cfg, "lm_head_multiplier", None)
+    if lm_head_multiplier is not None:
+        logits = logits * jnp.asarray(lm_head_multiplier, logits.dtype)
     logits = nn.with_logical_constraint(
         logits, ("batch", "act_seq", "act_vocab")
     )

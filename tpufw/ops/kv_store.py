@@ -143,6 +143,10 @@ _LEAVES: Dict[str, Role] = {
     # export decline a model that has any (tpufw.infer.slots
     # ``reject_state``).
     "kda_state": Role(STATE, 4), "conv_state": Role(STATE, 3),
+    # A state-space mixer's [B, H, P, N] state (tpufw.models.falcon_h1
+    # SSMMixer, beside the same ``conv_state``): there EVERY layer holds
+    # it beside a page pair.
+    "ssm_state": Role(STATE, 4),
     # A window layer's RING (``ring_append``): the last ``window`` keys
     # and values of each row, the logical slot and the segment id of
     # each ring slot. Per-slot like STATE, and declined where STATE is.

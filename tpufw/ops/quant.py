@@ -40,6 +40,9 @@ _PROJ_IN_DIMS = {
     # q/k/v/o are shaped like softmax attention's. The softmax layer's
     # output gate is a flat [d, H*hd] "gate".
     "f_a": 1, "f_b": 1, "g_a": 1, "g_b": 1, "beta": 1,
+    # A state-space mixer's two projections (tpufw.models.falcon_h1
+    # SSMMixer), flat [d, z + xBC + dt] and [inner, d].
+    "in_proj": 1, "out_proj": 1,
     # The dedicated LM head ([D, V]) is the largest single matmul a
     # decode step streams; tied (Gemma) embeddings stay fp — the gather
     # and the attend contraction want incompatible scale granularities.
@@ -51,6 +54,7 @@ _PROJ_RANK = {
     "q_a": 2, "q_b": 3, "kv_a": 2,
     "gate": 2, "up": 2, "down": 2,
     "f_a": 2, "f_b": 2, "g_a": 2, "g_b": 2, "beta": 2,
+    "in_proj": 2, "out_proj": 2,
     "lm_head": 2,
 }
 #: Mixtral expert stacks: RAW [E, in, out] arrays (not {kernel} modules)

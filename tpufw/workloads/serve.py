@@ -1667,6 +1667,13 @@ class _SlotScheduler:
             reg.gauge("tpufw_serve_state_slots").set(
                 float(self.n_slots if state else 0)
             )
+            if state:
+                # Only where the pool holds state: a model of keys and
+                # values alone keeps its /metrics as they were. Not
+                # reset after warm-up (readers take deltas).
+                self._metrics.register(
+                    "state_moved_bytes_total", "state_live_bytes_total"
+                )
             reg.gauge("tpufw_serve_window_bytes").set(
                 float(self._pool.window_bytes)
             )
@@ -1764,6 +1771,19 @@ class _SlotScheduler:
         read, whole = self._pool.window_keys(len(lives), width)
         self._metrics.inc("window_key_slots_total", rows * read)
         self._metrics.inc("window_row_key_slots_total", rows * whole)
+
+    def _count_state(self, moved: int, live: int) -> None:
+        """Book the per-slot state (kv_store role STATE) that dispatched
+        calls read and wrote, beside ``_count_keys``: ``moved`` slot-rows
+        (EVERY slot of the pool at each decode step, live or not; the
+        one row of a prefill chunk or of an insert), of which ``live``
+        were in service there (at a decode step: still delivering a
+        token), each x a slot's state bytes x 2 (read and written)."""
+        if self._metrics is None or not self._pool.state_bytes:
+            return
+        both = 2 * (self._pool.state_bytes // self.n_slots)
+        self._metrics.inc("state_moved_bytes_total", moved * both)
+        self._metrics.inc("state_live_bytes_total", live * both)
 
     def _admit(self) -> None:
         with self._cv:
@@ -2348,6 +2368,7 @@ class _SlotScheduler:
         self._count_keys(
             [max(_row_keys(job) + k for _, job in active)], self.n_slots
         )
+        self._count_state(self.n_slots, len(active))
         for slot, job in active:
             req = job.req
             take = min(int(n_emit[slot]), job.max_new - len(job.tokens))
@@ -2468,6 +2489,7 @@ class _SlotScheduler:
             progressed = True
             with self._tracer.span("serve_emit", slot=slot):
                 self._count_keys([live], 1, chunk=True, width=width)
+                self._count_state(1, 1)
                 if self._metrics is not None:
                     self._metrics.registry.counter(
                         "tpufw_prefill_chunks_total"
@@ -2512,6 +2534,7 @@ class _SlotScheduler:
         else:
             job.kv0 = len(job.prompt)
             self._pool.finalize_chunked(slot, cp, job.max_new - 1)
+            self._count_state(1, 1)  # the insert: the row's, whole
             if self._draft_pool is not None:
                 self._admit_draft(job, slot, cp.rng)
             if self._ema is not None:
@@ -2635,6 +2658,7 @@ class _SlotScheduler:
             ),
             self.n_slots,
         )
+        self._count_state(self.n_slots * k, sum(n for _, n in spans))
         if self._metrics is not None:
             self._metrics.inc("tokens_generated_total", live_tokens)
             # Capacity accounting: S * k device-steps ran; everything
