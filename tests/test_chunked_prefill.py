@@ -678,9 +678,13 @@ def test_chunks_across_two_rungs_serve_the_monolithic_tokens(monkeypatch):
 
     n = sched.n_slots
     # Chunks end at 128, 256, 384, 512 (rung 512) and 560 (rung 1,024);
-    # then ONE chunk of 8 decode steps, n rows each: seven with the row
-    # live at 561..567 slots, and an eighth with no live row at all.
-    assert read.value() == 4 * 512 + 1024 + n * (7 * 1024 + 512)
+    # then ONE chunk of 8 decode steps of a pool of n rows: seven with
+    # the one row live at 561..567 slots, and an eighth with no live row
+    # at all, each at the least row rung (n / 8 = 1 row of n, gathered
+    # whole: a row rung under the pool's width reads no key rung).
+    assert kv_store.row_ladder(n) == (1, n)
+    assert kv_store.attended_pair(model.cfg, n, 1, 561) == (1, 1024)
+    assert read.value() == 4 * 512 + 1024 + 1 * 8 * 1024
     assert whole.value() == 5 * 1024 + n * 8 * 1024
     # The host's lengths are the device's cursors: the row's stands at
     # prompt + 8 steps (a done row's keeps counting), an empty slot's at 8.

@@ -14,6 +14,7 @@ against the scalar-cursor contiguous store, the reference:
 """
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -49,16 +50,17 @@ class Store(nn.Module):
         read, seg, q_slots = kv_store.append(
             self, self.cfg, new, segment_ids
         )
-        # One rung at this size: ``read`` hands the whole row through.
-        views, kv_seg = read(lambda views, kv_seg: (views, kv_seg))
+        # One rung of each ladder at this size: ``read`` hands the whole
+        # rows through.
+        views, kv_seg = read(lambda views, kv_seg, _: (views, kv_seg))
         return views, seg, kv_seg, q_slots
 
 
-def tokens(layout, t, seed):
-    """[B, t, *feat] per leaf, already bf16 so a bf16 store is exact."""
+def tokens(layout, t, seed, b=B):
+    """[b, t, *feat] per leaf, already bf16 so a bf16 store is exact."""
     return {
         name: jax.random.normal(
-            jax.random.fold_in(jax.random.key(seed), i), (B, t, *feat)
+            jax.random.fold_in(jax.random.key(seed), i), (b, t, *feat)
         ).astype(jnp.bfloat16)
         for i, (name, feat) in enumerate(LAYOUTS[layout].items())
     }
@@ -202,22 +204,50 @@ RUNG = 4096
 STORES = ["scalar", "row_cursor", "paged", "paged_int8"]
 
 
-def config2(store):
+B8 = 16  # a pool with a row ladder: 2, 16
+#: Which rows of a pool of ``B8`` are live, by where the dead ones sit,
+#: and the row rung each takes.
+LIVE_ROWS = {
+    "start": ([0] * 15 + [1], 2),  # one dead row inside K
+    "middle": ([1] + [0] * 14 + [1], 2),
+    "interleaved": ([0, 1, 0, 1, 0, 1] + [0] * 10, B8),
+    "all": ([1] * B8, B8),
+    "none": ([0] * B8, 2),
+}
+
+
+def config2(store, b=B):
     if store.startswith("paged"):
         return Cfg(
             max_seq_len=S2, dtype=jnp.float32, kv_page=PAGE,
-            kv_pages=B * PER_ROW2 + 1,
+            kv_pages=b * PER_ROW2 + 1,
             kv_quant="int8" if store == "paged_int8" else "",
         )
     return Cfg(max_seq_len=S2, dtype=jnp.float32)
 
 
-def attention_over(layout, q):
-    """The family's contraction as a function of the L-long views: GQA's
-    through ``xla_attention``, the latent one MLA's absorbed scores."""
+def queries(layout, b, t):
+    q = jax.random.normal(jax.random.key(3), (b, t, 2, 10), jnp.float32)
+    return q[..., :4] if layout == "kv" else q
+
+
+def rows_read(alive):
+    """The rows a read under ``alive`` gathers: live first, stably, up to
+    the row rung. In numpy, beside the program's ``argsort``."""
+    alive = np.asarray(alive, bool)
+    k, _ = kv_store.attended_pair(
+        config2("paged", len(alive)), len(alive), int(alive.sum()), 1
+    )
+    return np.argsort(~alive, kind="stable")[:k]
+
+
+def attention_over(layout):
+    """The family's contraction as a function of the L-long views and the
+    rows' own queries: GQA's through ``xla_attention``, the latent one
+    MLA's absorbed scores."""
     from tpufw.ops.attention import attention_mask, xla_attention
 
-    def attend(views, kv_seg, seg, q_slots):
+    def attend(views, kv_seg, q, seg, q_slots):
         if layout == "kv":
             return xla_attention(
                 q, views["cached_key"], views["cached_value"],
@@ -238,36 +268,74 @@ def attention_over(layout, q):
 
 
 class Reader(nn.Module):
-    """Appends, then runs ``attend`` under the store's bound. With
-    ``attend`` None it answers the rung the program took: the length of
-    the views it was handed, per query."""
+    """Appends, then runs ``attend`` over ``q`` under the store's bounds.
+    With ``attend`` None it answers the rungs the program took: per
+    query of a row it read, the length and the row count of the views
+    it was handed (0 in a row it did not read)."""
 
     cfg: Cfg
     attend: Any = None
+    q: Any = None
 
     @nn.compact
     def __call__(self, new, segment_ids):
         read, seg, q_slots = kv_store.append(
             self, self.cfg, new, segment_ids
         )
-        b, t = seg.shape
+        t = seg.shape[1]
         if self.attend is None:
             return read(
-                lambda views, kv_seg: jnp.full((b, t), kv_seg.shape[1])
+                lambda views, kv_seg, _: jnp.broadcast_to(
+                    jnp.asarray(kv_seg.shape[::-1]), (kv_seg.shape[0], t, 2)
+                )
             )
         return read(
-            lambda views, kv_seg: self.attend(views, kv_seg, seg, q_slots)
+            lambda views, kv_seg, rows: self.attend(views, kv_seg, *rows),
+            (self.q, seg, q_slots),
         )
 
 
-def filled(cfg, layout, cursors):
-    """A cache whose rows hold random tokens below ``cursors`` (a scalar
-    for the scalar store, else one per row): every written slot carries
-    segment 1, every other 0; paged rows own private pages in row
-    order after reserved page 0."""
-    new = {n: x.astype(cfg.dtype) for n, x in tokens(layout, T2, 0).items()}
+@functools.lru_cache(maxsize=None)
+def reader(store, layout, b, t, whole=False, probe=False):
+    """One jitted program a shape, shared by the cases of a test:
+    ``Reader`` over ``config2(store, b)``, attending (``probe``:
+    answering its rungs). ``whole`` keeps apart the program that is only
+    ever traced under ``whole_rows``: jit does not see a patched
+    ladder."""
+    cfg = config2(store, b)
+    model = Reader(cfg) if probe else Reader(
+        cfg, attention_over(layout), queries(layout, b, t)
+    )
+    return jax.jit(
+        lambda cache, new, seg: model.apply(
+            {"cache": cache}, new, seg, mutable=["cache"]
+        )
+    )
+
+
+def took(probe, cache, new, segment_ids):
+    """([key rungs], [row rungs]) the program of ``probe`` took, over the
+    rows it read, and the rows it did not."""
+    out = np.asarray(probe(cache, new, segment_ids)[0])
+    unread = np.flatnonzero((out == 0).all(axis=(1, 2)))
+    read = np.delete(out, unread, axis=0)
+    return (
+        np.unique(read[..., 0]).tolist(),
+        np.unique(read[..., 1]).tolist(),
+        unread.tolist(),
+    )
+
+
+def filled(cfg, layout, cursors, b=B, t=T2):
+    """A cache whose ``b`` rows hold random tokens below ``cursors`` (a
+    scalar for the scalar store, else one per row): every written slot
+    carries segment 1, every other 0; paged rows own private pages in
+    row order after reserved page 0. And a call's ``t`` new tokens."""
+    new = {
+        n: x.astype(cfg.dtype) for n, x in tokens(layout, t, 0, b).items()
+    }
     cache = Reader(cfg).init(jax.random.key(0), new, None)["cache"]
-    per_row = np.broadcast_to(np.asarray(cursors), (B,))
+    per_row = np.broadcast_to(np.asarray(cursors), (b,))
     written = np.arange(S2)[None, :] < per_row[:, None]  # [B, S2]
     out = {}
     for i, (name, leaf) in enumerate(sorted(cache.items())):
@@ -283,14 +351,14 @@ def filled(cfg, layout, cursors):
             )
         elif kind == kv_store.SEGMENT and cfg.kv_page:
             arena = np.zeros(leaf.shape, np.int32)
-            arena[1:] = written.reshape(B * PER_ROW2, PAGE)
+            arena[1:] = written.reshape(b * PER_ROW2, PAGE)
             out[name] = jnp.asarray(arena)
         elif kind == kv_store.SEGMENT:
             out[name] = jnp.asarray(written, jnp.int32)
         elif kind == kv_store.TABLE:
             out[name] = 1 + jnp.arange(
-                B * PER_ROW2, dtype=jnp.int32
-            ).reshape(B, PER_ROW2)
+                b * PER_ROW2, dtype=jnp.int32
+            ).reshape(b, PER_ROW2)
         else:
             assert kind == kv_store.CURSOR
             out[name] = jnp.asarray(cursors, jnp.int32)
@@ -304,74 +372,216 @@ def cursors_for(store, live):
     return top if store == "scalar" else [max(top - 200, 0), top, 7]
 
 
-@pytest.mark.parametrize(
-    "live", [RUNG - 1, RUNG, RUNG + 1, S2], ids=["under", "at", "over", "end"]
-)
-@pytest.mark.parametrize("layout", list(LAYOUTS))
-@pytest.mark.parametrize("store", STORES)
-def test_attention_over_the_live_prefix_is_attention_over_the_row(
-    store, layout, live, monkeypatch
-):
-    cfg = config2(store)
-    cache, new = filled(cfg, layout, cursors_for(store, live))
-    q = jax.random.normal(jax.random.key(3), (B, T2, 2, 10), jnp.float32)
-    if layout == "kv":
-        q = q[..., :4]
-    model = Reader(cfg, attention_over(layout, q))
-    probe = Reader(cfg)
-    took, _ = probe.apply({"cache": cache}, new, None, mutable=["cache"])
-    want_rung = {RUNG - 1: RUNG, RUNG: RUNG, RUNG + 1: S2, S2: S2}[live]
-    assert np.unique(np.asarray(took)).tolist() == [want_rung]
-    bounded, after = model.apply({"cache": cache}, new, None, mutable=["cache"])
-    # The whole row: the same store with a ladder of one rung.
+def whole_rows(monkeypatch):
+    """Both ladders at one rung: every cached call reads every slot of
+    every row, what the bounds are held against."""
     monkeypatch.setattr(kv_store, "key_ladder", lambda s, page=0: (s,))
-    whole, after_whole = model.apply(
-        {"cache": cache}, new, None, mutable=["cache"]
-    )
+    monkeypatch.setattr(kv_store, "row_ladder", lambda b: (b,))
+
+
+@pytest.mark.parametrize(
+    "store,layout,live,rows,t",
+    [
+        pytest.param(store, layout, live, None, T2, id=f"{store}-{layout}-{name}")
+        for store in STORES
+        for layout in LAYOUTS
+        for name, live in zip(
+            ["under", "at", "over", "end"], [RUNG - 1, RUNG, RUNG + 1, S2]
+        )
+    ]
+    + [
+        # A pool with a row ladder: dead rows by where they sit.
+        pytest.param(store, layout, RUNG + 1, rows, t, id=f"{store}-{layout}-{rows}-t{t}")
+        for store in STORES[1:]
+        for layout in LAYOUTS
+        for rows in LIVE_ROWS
+        for t in (1, 4)
+    ],
+)
+def test_attention_over_the_live_prefix_is_attention_over_the_row(
+    store, layout, live, rows, t, monkeypatch
+):
+    if rows is None:  # every row live, a pool too narrow for a row rung
+        b, seg, read = B, None, np.arange(B)
+        cursors = cursors_for(store, live)
+        want_rung = {RUNG - 1: RUNG, RUNG: RUNG, RUNG + 1: S2, S2: S2}[live]
+        want = ([want_rung], [B], [])
+    else:
+        # Dead rows' cursors have run on to the end of the row; the live
+        # ones sit apart, the longest past the middle rung.
+        alive, k = LIVE_ROWS[rows]
+        b, read = B8, rows_read(alive)
+        seg = jnp.asarray(np.repeat(np.asarray(alive)[:, None], t, 1), jnp.int32)
+        cursors = [
+            live - t - 300 * sum(alive[:i]) if a else S2
+            for i, a in enumerate(alive)
+        ]
+        # Past the middle key rung, or an eighth of the pool whole.
+        want = ([S2], [k], sorted(set(range(b)) - set(read.tolist())))
+    cache, new = filled(config2(store, b), layout, cursors, b, t)
+    assert took(reader(store, layout, b, t, probe=True), cache, new, seg) == want
+    bounded, after = reader(store, layout, b, t)(cache, new, seg)
+    # Every row, whole: the same store with ladders of one rung.
+    whole_rows(monkeypatch)
+    whole, after_whole = reader(store, layout, b, t, whole=True)(cache, new, seg)
     assert bounded.shape == whole.shape and bool(jnp.isfinite(whole).all())
-    np.testing.assert_allclose(bounded, whole, rtol=2e-6, atol=2e-6)
+    # The live rows' outputs are the whole read's (a dead row inside the
+    # row rung averages the keys of whatever rung it is shown, as ever);
+    # rows that were not read come back zero.
+    alive = np.arange(b) if seg is None else np.flatnonzero(np.asarray(seg)[:, 0])
+    assert set(alive) <= set(read.tolist())
+    np.testing.assert_allclose(bounded[alive], whole[alive], rtol=2e-6, atol=2e-6)
+    assert bool(jnp.isfinite(bounded).all())
+    assert not np.asarray(bounded)[want[2]].any()
     for name in after["cache"]:
         np.testing.assert_array_equal(
             after["cache"][name], after_whole["cache"][name]
         )
 
 
-@pytest.mark.parametrize("store", STORES[1:])
-def test_dead_rows_at_the_end_of_the_row_do_not_choose_the_rung(store):
+@pytest.mark.parametrize(
+    "store,layout,rows,t",
+    [pytest.param(store, "kv", None, T2, id=store) for store in STORES[1:]]
+    + [
+        pytest.param(store, layout, rows, t, id=f"{store}-{layout}-{rows}-t{t}")
+        for store in STORES[1:]
+        for layout in LAYOUTS
+        for rows in LIVE_ROWS
+        for t in (1, 4)
+    ],
+)
+def test_dead_rows_at_the_end_of_the_row_do_not_choose_the_rung(
+    store, layout, rows, t
+):
     """A pool's done rows keep stepping and their cursors run on to
-    ``max_seq_len``; they step with segment id 0 and the rung is the
-    live row's. With ids of 1 they would count, and it is the top one."""
-    cfg = config2(store)
-    cache, new = filled(cfg, "kv", [S2, 2600, S2])
-    dead = jnp.asarray([[0] * T2, [1] * T2, [0] * T2], jnp.int32)
-    took, _ = Reader(cfg).apply({"cache": cache}, new, dead, mutable=["cache"])
-    assert np.unique(np.asarray(took)).tolist() == [RUNG]
-    took, _ = Reader(cfg).apply({"cache": cache}, new, None, mutable=["cache"])
-    assert np.unique(np.asarray(took)).tolist() == [S2]
-    none = jnp.zeros((B, T2), jnp.int32)
-    took, _ = Reader(cfg).apply({"cache": cache}, new, none, mutable=["cache"])
-    assert np.unique(np.asarray(took)).tolist() == [2048]
+    ``max_seq_len``; they step with segment id 0, the key rung is the
+    live rows' and the row rung holds the live rows and no more (an
+    eighth of the pool is read whole). With ids of 1 they would count:
+    the top rung of both ladders."""
+    if rows is None:
+        b, alive, k = B, [0, 1, 0], B
+    else:
+        b, (alive, k) = B8, LIVE_ROWS[rows]
+    cursors = [2600 - 100 * sum(alive[:i]) if a else S2 for i, a in enumerate(alive)]
+    cache, new = filled(config2(store, b), layout, cursors, b, t)
+    dead = jnp.asarray(np.repeat(np.asarray(alive)[:, None], t, 1), jnp.int32)
+    unread = sorted(set(range(b)) - set(rows_read(alive).tolist()))
+    probe = reader(store, layout, b, t, probe=True)
+    assert took(probe, cache, new, dead) == (
+        [S2 if k < b else RUNG if any(alive) else 2048], [k], unread
+    )
+    assert took(probe, cache, new, None) == (
+        [S2 if not all(alive) else RUNG], [b], []
+    )
+    least = kv_store.row_ladder(b)[0]
+    assert took(probe, cache, new, jnp.zeros((b, t), jnp.int32)) == (
+        [S2 if least < b else 2048], [least], list(range(least, b))
+    )
 
 
 @pytest.mark.parametrize("store", STORES[:3])
 def test_the_hosts_rung_is_the_programs(store):
-    """``attended_keys``, by which the scheduler counts what the device
-    read, names the rung the program's switch took, at every live
-    length around every rung."""
+    """``attended_pair``, by which the scheduler counts what the device
+    read, names the rungs the program's switch took: at every live
+    length around every key rung, and at every count of live rows of a
+    pool."""
     cfg = config2(store)
     assert kv_store.key_ladder(S2, cfg.kv_page) == (2048, RUNG, S2)
-    step = jax.jit(
-        lambda cache, new: Reader(cfg).apply(
-            {"cache": cache}, new, None, mutable=["cache"]
-        )[0]
-    )
+    step = reader(store, "latent", B, T2, probe=True)
     lives = sorted(
         {T2, S2} | {r + d for r in (2048, RUNG, RUNG + 300) for d in (-1, 0, 1)}
     )
     for live in lives:
         cache, new = filled(cfg, "latent", cursors_for(store, live))
-        took = np.unique(np.asarray(step(cache, new))).tolist()
-        assert took == [kv_store.attended_keys(cfg, live)], live
+        keys, rows, unread = took(step, cache, new, None)
+        k, length = kv_store.attended_pair(cfg, B, B, live)
+        assert (rows, keys, unread) == ([k], [length], []), live
+    if store == "scalar":
+        return  # one row rung: every row is read, above
+    cfg = config2(store, B8)
+    assert kv_store.row_ladder(B8) == (2, B8)
+    cache, new = filled(cfg, "latent", [2600] * B8, B8, 1)
+    pool_step = reader(store, "latent", B8, 1, probe=True)
+    for n in range(B8 + 1):
+        alive = np.random.default_rng(n).permutation(B8) < n
+        seg = jnp.asarray(alive[:, None], jnp.int32)
+        keys, rows, unread = took(pool_step, cache, new, seg)
+        k, length = kv_store.attended_pair(cfg, B8, n, 2601 if n else 0)
+        assert (rows, keys) == ([k], [length]), n
+        assert len(unread) == B8 - k and k == (2 if n <= 2 else B8)
+        assert length == (S2 if n <= 2 else RUNG)
+
+
+def case_branches(text):
+    """Branches of the one ``stablehlo.case`` of a lowered program, each
+    as its own text and that of every function it calls (a pool's
+    branches are calls: ``kv_store._read_rows``); [] without a case."""
+    import re
+
+    case = re.findall(
+        r'"stablehlo\.case"\(.*?^\s*\}\) : \(tensor<i32>\)', text, re.M | re.S
+    )
+    assert len(case) <= 1
+    funcs = dict(re.findall(
+        r"^  func\.func private @([\w.]+)(\(.*?^  \})$", text, re.M | re.S
+    ))
+
+    def inlined(part, seen=()):
+        called = set(re.findall(r"call @([\w.]+)\(", part)) - set(seen)
+        return part + "".join(
+            inlined(funcs[name], (*seen, *called)) for name in sorted(called)
+        )
+
+    if not case:
+        return []
+    return [inlined(b) for b in re.split(r"^\s*\}, \{$", case[0], flags=re.M)]
+
+
+@pytest.mark.parametrize("b", [1, 4, 8, 32, 64])
+def test_the_row_ladder_is_a_rule_of_the_pool(b):
+    """B/8 where it is whole, then B; the host's twin picks the shortest
+    rung that holds the live rows, and reads an eighth of the pool whole.
+    A program under ``[B]`` cursors holds a branch for the eighth and
+    one a key rung for the whole pool, the whole pool's last, and
+    those hold no ordering, no gather of the queries and no scatter (the
+    program it was before the row ladder); a scalar cursor's program has
+    the key rungs alone."""
+    want = {1: (1,), 4: (4,), 8: (1, 8), 32: (4, 32), 64: (8, 64)}[b]
+    assert kv_store.row_ladder(b) == want
+    keys = kv_store.key_ladder(S2, PAGE)
+    for n in range(b + 1):
+        k, length = kv_store.attended_pair(config2("paged", b), b, n, 2100)
+        assert k in want and k >= n and not any(n <= r < k for r in want)
+        assert length == (S2 if k < b else RUNG)
+    assert kv_store.row_ladder(3) == (3,) and kv_store.row_ladder(12) == (12,)
+    assert kv_store.row_ladder(16) == (2, 16) and kv_store.row_ladder(2) == (2,)
+    assert kv_store.branch_pairs((8, 64), (2048, 4096)) == [
+        (8, 4096), (64, 2048), (64, 4096)
+    ]
+    # A row of one key rung keeps one row rung, and so no switch.
+    assert kv_store.pool_ladders(S2, PAGE, b) == (want, keys)
+    assert kv_store.pool_ladders(2048, PAGE, b) == ((b,), (2048,))
+    for store in ("paged", "scalar"):
+        cfg = config2(store, b)
+        new = tokens("kv", 1, 0, b)
+        cache = jax.eval_shape(
+            lambda: Reader(cfg).init(jax.random.key(0), new, None)["cache"]
+        )
+        branches = case_branches(
+            jax.jit(
+                lambda cache, new: Reader(
+                    cfg, attention_over("kv"), queries("kv", b, 1)
+                ).apply({"cache": cache}, new, None, mutable=["cache"])
+            ).lower(cache, new).as_text()
+        )
+        rungs = want if store == "paged" else (b,)
+        assert len(branches) == len(rungs) - 1 + len(keys)
+        for i, part in enumerate(branches):
+            pool_wide = i >= len(rungs) - 1
+            # One ordering a call, outside the switch; the scatter back
+            # to the pool's width in the branches that read fewer rows.
+            assert "stablehlo.sort" not in part
+            assert ("stablehlo.scatter" not in part) == pool_wide, (store, i)
 
 
 def test_the_ladder_is_a_rule_of_the_row_and_the_page():

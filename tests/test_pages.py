@@ -493,11 +493,12 @@ def test_paged_decode_matches_contiguous_across_two_rungs(family, monkeypatch):
     firsts = {}
     for i, p in enumerate(prompts):
         firsts[i], _ = _admit(pool, i, p, i, max_new=LONG_NEW)
+    # A pool of two has one row rung: 2 rows x the key rung, of 2 x LONG_S.
     reads = {
-        pool.attended_keys([len(long_prompt) + n])[0]
+        pool.attended_keys([(len(long_prompt) + n, 1)])
         for n in range(1, LONG_NEW)
     }
-    assert reads == {512, LONG_S}
+    assert reads == {(2 * 512, 2 * LONG_S), (2 * LONG_S, 2 * LONG_S)}
     rows = _decode_all(pool, firsts, max_new=LONG_NEW, chunk=4)
     assert [rows[0], rows[1]] == want
 
@@ -506,7 +507,9 @@ def test_tpu_lowering_gathers_the_whole_row_only_in_the_top_rung(monkeypatch):
     """Lowered for the chip, a multi-rung decode step holds the gather
     of every row's whole table ([B, S/page] pages) only inside the top
     branch of the store's switch: the lower branch gathers half of it
-    and nothing outside the switch gathers pages at all."""
+    and nothing outside the switch gathers pages at all. (A pool of two
+    has no row rung under its width; tests/test_kv_store.py holds the
+    row ladder's branches.)"""
     import re
 
     _, _, pool = _long_family("llama", monkeypatch)
@@ -520,11 +523,15 @@ def test_tpu_lowering_gathers_the_whole_row_only_in_the_top_rung(monkeypatch):
         .lower(lowering_platforms=("tpu",))
         .as_text()
     )
+    from tests.test_kv_store import case_branches
+
     case = re.search(
         r'"stablehlo\.case"\(.*?^\s*\}\) : \(tensor<i32>\)', text, re.M | re.S
     )
     assert case and text.count('"stablehlo.case"') == 1
-    branches = re.split(r"^\s*\}, \{$", case.group(0), flags=re.M)
+    # Each branch with the text of the function it calls (the store's
+    # ``_read_rows``, lowered once a branch).
+    branches = case_branches(text)
     assert len(branches) == 2  # the ladder's rungs, 512 and 1,024
     per_row = LONG_S // PAGE
 
@@ -533,7 +540,8 @@ def test_tpu_lowering_gathers_the_whole_row_only_in_the_top_rung(monkeypatch):
             rf'"stablehlo\.gather".*-> tensor<2x{n_pages}x{PAGE}[x>]', part
         )
 
-    outside = text.replace(case.group(0), "")
+    main = text[text.index("func.func public @main"):]
+    outside = main[:main.index("\n  }\n")].replace(case.group(0), "")
     for n in (per_row // 2, per_row):
         assert not page_gathers(outside, n)
     # K pages, V pages and their segment ids, at each rung's own length.
@@ -541,3 +549,86 @@ def test_tpu_lowering_gathers_the_whole_row_only_in_the_top_rung(monkeypatch):
     assert not page_gathers(branches[0], per_row)
     assert len(page_gathers(branches[1], per_row)) == 3
     assert not page_gathers(branches[1], per_row // 2)
+
+
+# ---- the live rows of a pool (tpufw.ops.kv_store's ladder of row counts)
+
+@pytest.mark.parametrize("live", [1, 3, 8])
+@pytest.mark.parametrize("family", ["llama", "deepseek"])
+def test_a_pool_reads_its_live_rows_and_the_scheduler_counts_them(
+    family, live, monkeypatch
+):
+    """A paged pool of 8 slots (row rungs 1 and 8) with 1, 3 and 8 rows
+    live serves the tokens the one-shot path decodes, which reads every
+    row under its scalar cursor: a GQA store and DeepSeek's latent one.
+    The scheduler's ``attended_key_slots_total`` is K x L summed over
+    the steps it dispatched, the pair by the store's rule from the
+    DEVICE's ``done`` and ``remaining`` as each chunk started, and
+    ``row_key_slots_total`` every slot's whole row. (Rows of 96 slots
+    under a floor of 48 have two key rungs, and so a row rung: a row of
+    one key rung keeps no switch. No other test builds a program over a
+    96-slot row, so no trace made under another floor is reused.)"""
+    from tpufw.infer.speculative import _pool_cursor
+    from tpufw.ops import kv_store
+    from tpufw.workloads.serve import _Metrics, _SlotScheduler
+
+    monkeypatch.setattr(kv_store, "MIN_RUNG", 48)
+    cls, cfg = _seam_family(family)
+    cfg = dataclasses.replace(cfg, max_seq_len=96)
+    row_model = cls(cfg)
+    params = jax.jit(row_model.init)(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    rng = np.random.default_rng(live)
+    prompts = [
+        rng.integers(1, 200, int(n)).tolist()
+        for n in rng.integers(3, 30, live)
+    ]
+    want = generate_text(
+        row_model, params, prompts, max_new_tokens=11, sampling=GREEDY
+    )
+    metrics = _Metrics()
+    sched = _SlotScheduler(
+        row_model, params, eos_id=None, default_sampling=GREEDY,
+        seed_base=0, page=PAGE, arena_pages=None, prefix_cache=False,
+        prefill_chunk_pages=0, metrics=metrics,
+    )
+    n = sched.n_slots
+    assert kv_store.pool_ladders(96, PAGE, n) == ((1, 8), (48, 96))
+    assert kv_store.pool_ladders(64, PAGE, n) == ((8,), (64,))
+    assert sched.submit(prompts, 11)[0] == want
+
+    # What the device held as each decode chunk started. (The pool is
+    # built by the first admission, so its method is wrapped after one
+    # request and the counters are read from there.)
+    pool, seen = sched._pool, []
+    steps = pool.decode_steps
+
+    def recorded(keys):
+        seen.append((
+            len(keys), np.asarray(pool.done), np.asarray(pool.remaining),
+            np.asarray(_pool_cursor(pool.cache, n)),
+        ))
+        return steps(keys)
+
+    pool.decode_steps = recorded
+    read = metrics.registry.counter("tpufw_serve_attended_key_slots_total")
+    whole = metrics.registry.counter("tpufw_serve_row_key_slots_total")
+    read0, whole0 = read.value(), whole.value()
+    assert sched.submit(prompts, 11)[0] == want
+    expect = calls = 0
+    for k, done, remaining, cursor in seen:
+        for i in range(k):
+            alive = ~done & (remaining > i)
+            slots = int((cursor[alive] + i + 1).max()) if alive.any() else 0
+            k, length = kv_store.attended_pair(
+                pool.model.cfg, n, int(alive.sum()), slots
+            )
+            expect += k * length
+            calls += 1
+    assert calls >= 10 and read.value() - read0 == expect
+    assert whole.value() - whole0 == calls * n * 96
+    # One chunk of 16 steps: ten with the request's rows live (one row
+    # whole, or all 8 at the lower key rung: no row passes 48 slots),
+    # and six with none, one row whole.
+    assert expect == 10 * {1: 1 * 96, 3: 8 * 48, 8: 8 * 48}[live] + 6 * 96

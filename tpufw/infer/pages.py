@@ -11,11 +11,12 @@ of ``kv_page`` slots each:
 - the STORE (``tpufw.ops.kv_store``, called by every model) owns the
   arena + table + gather/scatter reads and every leaf's role — the
   cache leaves just have a different shape, so ``_decode_steps_jit`` is
-  reused verbatim. A step gathers ``table[:, :L/page]``, the live prefix
-  of the rows and not their whole tables: L is a rung of the store's
-  ladder of key lengths, chosen inside the program from the cursors of
-  the rows that are not done (``PagedSlotPool.attended_keys`` is the
-  same rule for the scheduler's count);
+  reused verbatim. A step gathers ``table[rows, :L/page]``, the live
+  prefix of the live rows and not every slot's whole table: L is a rung
+  of the store's ladder of key lengths and the K rows a rung of its
+  ladder of row counts, both chosen inside the program from the rows
+  that are not done (``PagedSlotPool.attended_keys`` is the same rule
+  for the scheduler's count);
 - this module owns moving rows in and out, and the allocator; it asks
   ``kv_store.role()`` what each leaf is: ``_paged_insert_jit``
   scatters a B=1 contiguous prefilled row into the slot's pages,
@@ -47,6 +48,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -66,7 +68,7 @@ from tpufw.infer.slots import (
 )
 from tpufw.obs import trace as obs_trace
 from tpufw.ops.kv_store import (
-    CURSOR, PAGE, SCALE, SEGMENT, TABLE, attended_keys, ring_keys, role,
+    CURSOR, PAGE, SCALE, SEGMENT, TABLE, attended_pair, ring_keys, role,
 )
 from tpufw.ops.kv_store import leaf_name as _leaf_name
 from tpufw.ops.quant import dequantize_kv, quantize_kv
@@ -762,21 +764,27 @@ class PagedSlotPool(SlotPool):
             jax.jit(row_zeros, out_shardings=self.home).lower().compile()
         )
 
-    def attended_keys(self, lives, chunk: bool = False) -> Tuple[int, int]:
-        """(key slots read, key slots of the whole row) a row, summed
-        over cached calls whose longest live rows hold ``lives`` slots:
-        decode steps of the pool's model or, with ``chunk``, prefill
-        chunks of the row twin. The store's own rule, so the host's
-        count names the rung each program took."""
+    def attended_keys(self, calls, chunk: bool = False) -> Tuple[int, int]:
+        """(key slots gathered, key slots of the whole rows) summed over
+        cached calls, each ``(slots of its longest live row, live
+        rows)``: decode steps of the pool's model, every slot a row, or,
+        with ``chunk``, prefill chunks of the row twin, one row under a
+        scalar cursor. Rows x slots a call: K x L read, the rungs of the
+        store's two ladders, of B x ``max_seq_len``. The store's own
+        rules, so the host's count names the branch each program took."""
         cfg = (self.row_model if chunk else self.model).cfg
-        read = [attended_keys(cfg, n) for n in lives]
-        return sum(read), len(read) * int(cfg.max_seq_len)
+        b = 1 if chunk else self.n_slots
+        read = [
+            math.prod(attended_pair(cfg, b, rows, slots))
+            for slots, rows in calls
+        ]
+        return sum(read), len(read) * b * int(cfg.max_seq_len)
 
     def window_keys(self, calls: int, t: int = 1) -> Tuple[int, int]:
         """(ring slots read, key slots of the whole row) a row, summed
         over the window layers and ``calls`` cached calls of ``t``
         tokens each (decode steps: 1; a prefill chunk: its width):
-        ``attended_keys``' twin for the layers that keep a ring. (0, 0)
+        this pool's ``attended_keys``' twin for the layers that keep a ring. (0, 0)
         for a model without one."""
         layers, slots = self.ring_shape
         return (

@@ -46,7 +46,7 @@ sparse ``moe_layer_freq``, and attention bias.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -511,32 +511,47 @@ class MLAttention(nn.Module):
             w_uk.astype(cfg.dtype),
         )
 
-        def attend(views, kv_seg):
-            """Attention-weighted latents [B,T,H,kvr] over the L slots
-            the store shows (its live prefix)."""
-            ckv, kpe = views["cached_ckv"], views["cached_kpe"]
-            logits = (
-                jnp.einsum(
-                    "bthr,bsr->bhts", q_lat, ckv,
-                    preferred_element_type=jnp.float32,
-                )
-                + jnp.einsum(
-                    "bthd,bsd->bhts", q_pe.astype(cfg.dtype), kpe,
-                    preferred_element_type=jnp.float32,
-                )
-            ) * (float(cfg.qk_head_dim) ** -0.5)
-            mask = attention_mask(
-                q_slots.shape[1], ckv.shape[1], segment_ids=seg,
-                kv_segment_ids=kv_seg, q_positions=q_slots,
-            )
-            logits = jnp.where(mask, logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            return jnp.einsum("bhts,bsr->bthr", probs, ckv)
-
-        # ONE W_uv application, whatever the rung.
+        # ONE W_uv application, whatever the rungs.
         return jnp.einsum(
-            "bthr,rhd->bthd", read(attend), w_uv.astype(cfg.dtype)
+            "bthr,rhd->bthd",
+            read(
+                _AttendLatents(float(cfg.qk_head_dim) ** -0.5, cfg.dtype),
+                (q_lat, q_pe.astype(cfg.dtype), seg, q_slots),
+            ),
+            w_uv.astype(cfg.dtype),
         )
+
+
+@dataclasses.dataclass(frozen=True)
+class _AttendLatents:
+    """``kv_store.append``'s ``attend`` for the latent cache: attention-
+    weighted latents [K,T,H,kvr] over the L slots of the K rows the store
+    shows (their live prefix), for the same rows' queries. Hashable by
+    value: the layers of a model share one trace of each branch."""
+
+    scale: float
+    dtype: Any
+
+    def __call__(self, views, kv_seg, rows):
+        ckv, kpe = views["cached_ckv"], views["cached_kpe"]
+        q_lat, q_pe, seg, q_slots = rows
+        logits = (
+            jnp.einsum(
+                "bthr,bsr->bhts", q_lat, ckv,
+                preferred_element_type=jnp.float32,
+            )
+            + jnp.einsum(
+                "bthd,bsd->bhts", q_pe, kpe,
+                preferred_element_type=jnp.float32,
+            )
+        ) * self.scale
+        mask = attention_mask(
+            q_slots.shape[1], ckv.shape[1], segment_ids=seg,
+            kv_segment_ids=kv_seg, q_positions=q_slots,
+        )
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(self.dtype)
+        return jnp.einsum("bhts,bsr->bthr", probs, ckv)
 
 
 class DeepseekMoE(nn.Module):

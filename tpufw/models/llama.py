@@ -685,8 +685,8 @@ class Attention(nn.Module):
         """KV-cache step: append this call's k/v to the store, then attend
         q (at the slots it was written to) over the live prefix of the
         logical row, the rung of the store's ladder that holds every live
-        row (tpufw.ops.kv_store: layouts, the bound, masking and clamp
-        rationale)."""
+        row, for the pool's live rows (tpufw.ops.kv_store: layouts, both
+        bounds, masking and clamp rationale)."""
         cfg = self.cfg
         soft_cap = getattr(cfg, "attn_logit_soft_cap", None)
         if self.window is not None and getattr(cfg, "window_ring", False):
@@ -722,19 +722,31 @@ class Attention(nn.Module):
         read, seg, q_slots = kv_store.append(
             self, cfg, {"cached_key": k, "cached_value": v}, segment_ids
         )
-        return read(
-            lambda views, kv_seg: multi_head_attention(
-                q,
-                views["cached_key"],
-                views["cached_value"],
-                causal=True,
-                segment_ids=seg,
-                kv_segment_ids=kv_seg,
-                q_positions=q_slots,
-                logits_soft_cap=soft_cap,
-                sliding_window=self.window,
-                backend="xla",
-            )
+        return read(_AttendHeads(soft_cap, self.window), (q, seg, q_slots))
+
+
+@dataclasses.dataclass(frozen=True)
+class _AttendHeads:
+    """``kv_store.append``'s ``attend`` for K/V heads: the K rows' queries
+    over the L slots of the same rows the store shows. Hashable by value:
+    the layers of a model share one trace of each branch."""
+
+    soft_cap: Optional[float]
+    window: Optional[int]
+
+    def __call__(self, views, kv_seg, rows):
+        q, seg, q_slots = rows
+        return multi_head_attention(
+            q,
+            views["cached_key"],
+            views["cached_value"],
+            causal=True,
+            segment_ids=seg,
+            kv_segment_ids=kv_seg,
+            q_positions=q_slots,
+            logits_soft_cap=self.soft_cap,
+            sliding_window=self.window,
+            backend="xla",
         )
 
 

@@ -75,8 +75,32 @@ The page table cannot bound it: a row is granted its whole budget up
 front. Slots past L are exactly those the causal mask fills with -1e30,
 whose weights underflow to an exact 0.0: the same mathematics at the
 same precision. A ladder of one rung (``max_seq_len`` < 4096) is the
-program without a switch. ``attended_keys`` is the same rule for the
+program without a switch. ``attended_pair`` is the same rule for the
 host, which counts what the device read (tpufw.workloads.serve).
+
+THE LIVE ROWS. Under ``[B]`` cursors a cached call reads K rows of the
+pool, not all B: a row is live in a call iff its tokens carry a segment
+id > 0 (the rule the live length is computed from), the rows are ordered
+live first, stably, and K is the shortest rung of ``row_ladder`` (B/8
+where that is whole, then B: a function of B alone, the key ladder's
+twin) that holds the live count. The view gathers the pages
+(contiguous: the rows) of the first K rows of that order only, ``read``
+hands ``attend`` the same K rows of its per-row operands, and the result
+is scattered back to ``[B, t, ...]`` with zeros in the rows not read.
+Zeros are safe there: a row that is not read is done or empty, the pools
+emit pad for it and the host masks it, and nothing a live row computes
+reads another row. Rows inside K beyond the live count are dead rows
+attending under segment 0, as every dead row did before. The branch of
+the one ``lax.switch`` is a pair (K, L) of ``branch_pairs``: the whole
+pool at each key rung, and the eighth of it at the whole row (B/8 x S
+key slots, no more than the whole pool reads at its lowest rung; a
+branch a pair of rungs is priced beside ``ROW_SHIFTS``). K == B is the
+program without a gather of queries or a scatter. A scalar cursor has
+ONE row rung: every row of such a call sits at the same cursor and is as
+live as the next (``generate``), and the row twin of a chunked prefill
+is one row, so the many prefill programs gain no branch. So has a pool
+over rows of one key rung (``pool_ladders``): its call keeps no switch.
+``attended_pair`` names the pair for the host.
 
 t == 1 is the plain decode step; t > 1 is a prefill chunk (contiguous)
 or the speculative verify block (tpufw.infer.speculative): all t tokens
@@ -90,6 +114,7 @@ and would wrongly mask valid recent slots.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax
@@ -251,17 +276,134 @@ def key_rung(ladder: Tuple[int, ...], live):
     return sum((live > rung) * 1 for rung in ladder[:-1])
 
 
-def attended_keys(cfg, live: int) -> int:
-    """Key slots of each row a cached call of ``cfg``'s model reads when
-    its longest live row holds ``live`` slots, this call's tokens
-    included: what the program's switch picks, for the host's count."""
-    ladder = key_ladder(cfg.max_seq_len, getattr(cfg, "kv_page", 0))
-    return ladder[key_rung(ladder, int(live))]
+#: The row rungs under the whole pool, as shifts of B: B/8 alone, which
+#: ``branch_pairs`` reads at the whole row alone. Every branch is one
+#: more copy of every attention layer in each of a pool's decode
+#: programs (one a chunk length: five in a 16-step pool), and a warm
+#: start loads them all. Measured on the chip (PR 38, DeepSeek-V2-Lite's
+#: eight latent layers, 64 slots, 2-6 rows live; warm ``setup_s`` 51.2-
+#: 56.2 s and ``tpot_p50_ms`` 20.1 before): B/8, B/4, B/2 by both key
+#: rungs, six more branches a layer, +23.7 s with each branch a closure
+#: and +11 s with each a cached program (``_read_rows``), TPOT 9.0 ms;
+#: B/8 by both key rungs, two more, +3.5 s (6%), TPOT 9.0; B/8 at the
+#: whole row, one more, level (53.7-56.1 s), TPOT 10.1. The gate on a
+#: warm start is 10% and two cells stand under their best already.
+ROW_SHIFTS = (3,)
+
+
+def row_ladder(n_rows: int) -> Tuple[int, ...]:
+    """The row counts a cached call under ``[B]`` cursors may read,
+    ascending, the whole pool last: B >> k for ``ROW_SHIFTS`` where that
+    is whole. A function of the pool's width alone, ``key_ladder``'s
+    twin; each rung multiplies the branches of the programs that step a
+    pool (a decode program a chunk length, not every cached program)."""
+    b = int(n_rows)
+    return tuple(
+        b >> k for k in ROW_SHIFTS if b % (1 << k) == 0 and b >> k >= 1
+    ) + (b,)
+
+
+def branch_pairs(rows: Tuple[int, ...], keys: Tuple[int, ...]):
+    """The (row count, key length) pairs a pool's cached call may read,
+    ascending in key slots read: the rungs of ``rows`` under the whole
+    pool at the whole row, then the whole pool at each rung of ``keys``."""
+    return [(k, keys[-1]) for k in rows[:-1]] + [(rows[-1], n) for n in keys]
+
+
+def branch_index(rows, keys, live_rows, live):
+    """Index into ``branch_pairs`` of the pair that holds ``live_rows``
+    live rows, the longest of ``live`` slots. Python ints (the host) or
+    traced scalars (the program): one rule for both."""
+    rung = key_rung(rows, live_rows)
+    return rung + (rung == len(rows) - 1) * key_rung(keys, live)
+
+
+def pool_ladders(max_seq_len: int, page: int, n_rows: int):
+    """(row ladder, key ladder) of a cached call under ``[B]`` cursors.
+    A row of ONE key rung keeps one row rung too: its call has no switch,
+    and putting one around the attention costs more than an eighth of so
+    short a row saves (measured, PR 38: Falcon-H1's 32 slots x 2,048,
+    six layers, 9 rows live: a step of 17.8 ms read 20.8 inside a switch
+    of two branches, its ``tpot_p50_ms`` 20.1 read 23.2)."""
+    keys = key_ladder(max_seq_len, page)
+    rows = row_ladder(n_rows) if len(keys) > 1 else (int(n_rows),)
+    return rows, keys
+
+
+def attended_pair(cfg, n_rows: int, live_rows: int, live: int):
+    """(rows, key slots of each) a cached call of ``cfg``'s model reads
+    of a pool of ``n_rows`` when ``live_rows`` rows are live and the
+    longest holds ``live`` slots, this call's tokens included: the
+    branch the program's switch takes, for the host's count. One row
+    (a prefill chunk, ``generate``: a scalar cursor) has the key rungs
+    alone."""
+    rows, keys = pool_ladders(
+        cfg.max_seq_len, getattr(cfg, "kv_page", 0), n_rows
+    )
+    return branch_pairs(rows, keys)[
+        branch_index(rows, keys, int(live_rows), int(live))
+    ]
 
 
 def _head(x: jax.Array, n: int) -> jax.Array:
     """``x[:, :n]``; ``x`` itself where that is all of it."""
     return x if n == x.shape[1] else x[:, :n]
+
+
+def _view(arenas, scales, ids, table, sel, *, length, page, dtype):
+    """(views, kv_segment_ids) of the first ``length`` logical slots of
+    the rows ``sel`` (None: every row, in place): ``arenas`` maps PAGE
+    leaf names to their values, ``ids`` is the SEGMENT leaf's, ``table``
+    the page table's (paged: ``page`` > 0) and ``scales`` the int8
+    arenas' scales by name (empty: not quantized)."""
+    def of(a):
+        a = _head(a, length // page if page else length)
+        return a if sel is None else a[sel]
+
+    if not page:
+        return {n: of(a) for n, a in arenas.items()}, of(ids)
+    rows = of(table)
+    shape = (rows.shape[0], length)
+    views = {}
+    for n, arena in arenas.items():
+        pages = arena[rows]
+        if scales:
+            pages = dequantize_kv(pages, scales[n][rows], dtype)
+        views[n] = pages.reshape(shape + arena.shape[2:])
+    return views, ids[rows].reshape(shape)
+
+
+# tpulint: disable=TPU006 — nothing handed in is updated: what comes back
+# is ``attend``'s output, scattered into zeros of the pool's width.
+@partial(jax.jit, static_argnames=("attend", "k", "length", "page", "dtype"))
+def _read_rows(
+    attend, arenas, scales, ids, table, order, per_row,
+    *, k, length, page, dtype,
+):
+    """One branch of a pool's ``read``: ``attend`` over the first
+    ``length`` slots of the first ``k`` rows of ``order`` [B], handed
+    back at ``[B, ...]`` with zeros in the rows not read; k == B reads
+    every row in place, whatever the order. A jitted function of its own, with
+    ``attend`` among its static arguments: the layers of a model whose
+    ``attend`` compare equal trace and lower each branch ONCE and call
+    it, where a closure would be traced again in every layer (measured,
+    PR 38: a warm start is mostly tracing, and four row rungs by two key
+    rungs in eight layers of five decode programs cost dsv2l 24 s of
+    it)."""
+    view = partial(
+        _view, arenas, scales, ids, table,
+        length=length, page=page, dtype=dtype,
+    )
+    b = order.shape[0]
+    if k == b:
+        return attend(*view(None), per_row)
+    sel = order[:k]
+    out = attend(*view(sel), jax.tree_util.tree_map(lambda x: x[sel], per_row))
+    return jax.tree_util.tree_map(
+        lambda y: jnp.zeros((b,) + y.shape[1:], y.dtype)
+        .at[sel].set(y, unique_indices=True),
+        out,
+    )
 
 
 def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
@@ -274,13 +416,23 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
     written all the same). Returns ``(read, segment_ids, q_slots)``: the
     queries' segment ids as stored, the logical slots the t queries sit
     at (``[B, t]``, or ``[1, t]`` under a scalar cursor) and ``read``,
-    which runs the caller's contraction over the live prefix:
-    ``read(attend)`` is ``attend(views, kv_segment_ids)`` with each
-    leaf's first L logical slots ``[B, L, *feat]`` (the new tokens
-    included, in ``cfg.dtype``) and the slots' ids ``[B, L]`` (0 = never
-    written), L the rung of ``key_ladder`` that holds every live row
-    (module docstring). ``attend`` is traced once per rung and must
-    return the same shapes at each; it may not touch flax variables.
+    which runs the caller's contraction over the live prefix of the live
+    rows: ``read(attend, per_row)`` is ``attend(views, kv_segment_ids,
+    per_row)`` over K rows of the pool: each leaf's first L logical
+    slots ``[K, L, *feat]`` (the new tokens included, in ``cfg.dtype``),
+    the slots' ids ``[K, L]`` (0 = never written) and the same K rows of
+    ``per_row``, a pytree of the caller's ``[B, ...]`` operands (the
+    queries, their segment ids, ``q_slots``): what ``attend`` may not
+    close over at width B. L is the rung of ``key_ladder`` that holds
+    every live row, K the rung of ``row_ladder`` that holds the live
+    rows, as ``branch_pairs`` pairs them (module docstring); under a
+    scalar cursor K is B and ``per_row`` comes through as given.
+    ``attend`` returns a pytree of ``[K, ...]`` arrays, which ``read``
+    hands back at ``[B, ...]``, zero in the rows not read. It is traced
+    at most once per (K, L), must return the same trailing shapes at
+    each and may not touch flax variables; where it is hashable and
+    compares equal across a model's layers (a frozen dataclass, not a
+    closure) the layers share one trace of each branch (``_read_rows``).
     Query i may attend slot j iff ``j <= q_slots[., i]`` and the
     segments match: ``attention_mask(t, L, ...)``.
     """
@@ -348,36 +500,38 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
     idx = table.value if page else None
     scale_of = {n: v.value for n, v in scales.items()} if quant else {}
 
-    def view(length: int):
-        """(views, kv_segment_ids) of the first ``length`` slots."""
-        if not page:
-            return (
-                {n: _head(a, length) for n, a in arenas.items()},
-                _head(ids, length),
-            )
-        rows = _head(idx, length // page)
-        views = {}
-        for n, x in new.items():
-            pages = arenas[n][rows]
-            if quant:
-                pages = dequantize_kv(pages, scale_of[n][rows], cfg.dtype)
-            views[n] = pages.reshape((b, length) + x.shape[2:])
-        return views, ids[rows].reshape(b, length)
+    state = (arenas, scale_of, ids, idx)
+    how = dict(page=page, dtype=cfg.dtype)
+    pool_rows, ladder = pool_ladders(s, page, b)
+    if not cur.ndim:
+        pool_rows = (b,)
 
-    ladder = key_ladder(s, page)
+    def read(attend: Callable, per_row=()):
+        def whole(length: int):
+            return attend(*_view(*state, None, length=length, **how), per_row)
 
-    def read(attend: Callable):
-        if len(ladder) == 1:
-            return attend(*view(s))
+        if len(ladder) == len(pool_rows) == 1:
+            return whole(s)
         if cur.ndim == 0:
-            live = cur + t
-        else:
-            live = jnp.max(
-                jnp.where(jnp.any(seg > 0, axis=1), q_slots[:, -1] + 1, 0)
+            return jax.lax.switch(
+                key_rung(ladder, cur + t), [partial(whole, n) for n in ladder]
             )
+        alive = jnp.any(seg > 0, axis=1)
+        live = jnp.max(jnp.where(alive, q_slots[:, -1] + 1, 0))
+        # Live rows first, each kind in the pool's own order. Outside
+        # the switch: the layers of a step sort the same ids, which is
+        # ONE sort to the compiler, where every branch of every layer
+        # would hold its own; the whole pool's branches do not read it.
+        order = jnp.argsort(~alive, stable=True)
         return jax.lax.switch(
-            key_rung(ladder, live),
-            [lambda n=n: attend(*view(n)) for n in ladder],
+            branch_index(pool_rows, ladder, jnp.sum(alive), live),
+            [
+                partial(
+                    _read_rows, attend, *state, order, per_row,
+                    k=k, length=n, **how,
+                )
+                for k, n in branch_pairs(pool_rows, ladder)
+            ],
         )
 
     return read, seg, q_slots

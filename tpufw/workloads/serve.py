@@ -1220,12 +1220,13 @@ class _SlotScheduler:
                     # reset after warm-up: the pool warm-up built is
                     # the one that serves, and its 1 is the evidence.
                     "row_shape_traces_total",
-                    # Key slots the device read, and key slots of the
-                    # whole rows, over dispatched decode steps,
+                    # Key slots the device gathered (rows read x the
+                    # slots of each), and key slots of the whole rows of
+                    # the pool, over dispatched decode steps,
                     # speculative passes and prefill chunks: their ratio
-                    # is the share of ``max_seq_len`` the cached calls
-                    # attended (tpufw.ops.kv_store's ladder, by the
-                    # rule the programs use; ``_count_keys``).
+                    # is the share of the pool's key slots the cached
+                    # calls attended (tpufw.ops.kv_store's two ladders,
+                    # by the rules the programs use; ``_count_keys``).
                     "attended_key_slots_total",
                     "row_key_slots_total",
                     # The same pair for the layers that keep a ring of
@@ -1752,23 +1753,27 @@ class _SlotScheduler:
                 "row_shape_traces_total", pool.row_shape_traces
             )
 
-    def _count_keys(
-        self, lives, rows: int, chunk: bool = False, width: int = 1
-    ) -> None:
-        """Book the key slots the device read in dispatched cached
-        calls of ``rows`` rows and ``width`` tokens each: ``lives``
-        yields, per call, the slots of its longest live row with the
-        call's own tokens (0: no row was live). The rung is the pool's
-        to name, by the rule its programs choose it with; layers that
-        keep a ring of their window are booked apart (x layers: they
-        read the ring whatever the rows hold)."""
+    def _count_keys(self, calls, chunk: bool = False, width: int = 1) -> None:
+        """Book the key slots the device gathered in dispatched cached
+        calls of ``width`` tokens a row: decode steps and verify blocks,
+        every slot of the pool a row, or (``chunk``) prefill chunks of
+        one row. ``calls`` yields, per call, the slots of its longest
+        live row with the call's own tokens (0: no row was live) and
+        how many rows were live. ``attended_key_slots_total`` grows by
+        K x L a call, the rows and the key slots of each the store read
+        (the pool names both rungs, by the rules its programs choose
+        them with), beside ``row_key_slots_total``'s B x ``max_seq_len``:
+        the share of the pool's key slots the device gathered. Layers
+        that keep a ring of their window are booked apart (x layers:
+        they read every row's ring whatever the rows hold)."""
         if self._metrics is None or not self.page:
             return
-        lives = list(lives)
-        read, whole = self._pool.attended_keys(lives, chunk=chunk)
-        self._metrics.inc("attended_key_slots_total", rows * read)
-        self._metrics.inc("row_key_slots_total", rows * whole)
-        read, whole = self._pool.window_keys(len(lives), width)
+        calls = list(calls)
+        read, whole = self._pool.attended_keys(calls, chunk=chunk)
+        self._metrics.inc("attended_key_slots_total", read)
+        self._metrics.inc("row_key_slots_total", whole)
+        rows = 1 if chunk else self.n_slots
+        read, whole = self._pool.window_keys(len(calls), width)
         self._metrics.inc("window_key_slots_total", rows * read)
         self._metrics.inc("window_row_key_slots_total", rows * whole)
 
@@ -2366,7 +2371,7 @@ class _SlotScheduler:
         accept_frac = 0.0
         # One verify call of k + 1 tokens a row, every active row live.
         self._count_keys(
-            [max(_row_keys(job) + k for _, job in active)], self.n_slots
+            [(max(_row_keys(job) + k for _, job in active), len(active))]
         )
         self._count_state(self.n_slots, len(active))
         for slot, job in active:
@@ -2488,7 +2493,7 @@ class _SlotScheduler:
                 continue
             progressed = True
             with self._tracer.span("serve_emit", slot=slot):
-                self._count_keys([live], 1, chunk=True, width=width)
+                self._count_keys([(live, 1)], chunk=True, width=width)
                 self._count_state(1, 1)
                 if self._metrics is not None:
                     self._metrics.registry.counter(
@@ -2651,13 +2656,8 @@ class _SlotScheduler:
                     finished.append(req)
         # A row is live at step i while it still delivers a token there:
         # the program's own ``done`` mask, read back from what it emitted.
-        self._count_keys(
-            (
-                max((at + i for at, n in spans if i < n), default=0)
-                for i in range(k)
-            ),
-            self.n_slots,
-        )
+        steps = ([at + i for at, n in spans if i < n] for i in range(k))
+        self._count_keys((max(at, default=0), len(at)) for at in steps)
         self._count_state(self.n_slots * k, sum(n for _, n in spans))
         if self._metrics is not None:
             self._metrics.inc("tokens_generated_total", live_tokens)
