@@ -641,6 +641,165 @@ def test_scheduler_phases_and_request_chain(tiny_sched_model, tmp_path):
         assert ev["name"] not in phases
 
 
+def _resting_since(tracer):
+    """When the ``serve_wait`` the idle scheduler sits in was opened, on
+    the tracer's clock (call after ``_wait_idle``)."""
+    ((frame,),) = tracer._live.values()
+    assert frame[0] == "serve_wait"
+    return frame[1]
+
+
+def test_pass_ledger_on_a_toy_server(tiny_sched_model, tmp_path):
+    """The scheduler books every pass (``serve._PassLedger``; its exact
+    identities are held on a hand-set clock in tests/test_pass_ledger.py).
+    A row decodes through a neighbour's admission: every pass has one
+    kind, the one whose decode chunk ran behind a chunk program or insert
+    is ``decode_behind_prefill`` and no other is, the steps of the kinds
+    are the chunks' k, passes and ``serve_wait`` tile the thread's time
+    between two moments of rest, starved seconds stay under each kind's
+    own, the wait is split from the fetch, and a request's three legs
+    (``req_queue``, ``req_prefill``, ``req_decode``) carry one ``rid``,
+    ``req_decode`` with the decode passes the row sat in."""
+    from tpufw.obs import trace as trace_mod
+    from tpufw.workloads import serve as serve_mod
+
+    model, params = tiny_sched_model
+    tracer = trace_mod.Tracer(str(tmp_path / "trace-serve.json"))
+    metrics = serve_mod._Metrics()
+    reg = metrics.registry
+    sched = serve_mod._SlotScheduler(
+        model, params, eos_id=None, default_sampling=GREEDY, seed_base=0,
+        page=PAGE, arena_pages=None, prefix_cache=True,
+        prefill_chunk_pages=1, metrics=metrics, tracer=tracer,
+    )
+    kinds = serve_mod.PASS_KINDS
+    seconds = reg.counter("tpufw_serve_pass_seconds_total")
+    steps = reg.counter("tpufw_serve_pass_steps_total")
+    starved = reg.counter("tpufw_serve_pass_starved_seconds_total")
+    phase_s = reg.counter("tpufw_serve_phase_seconds_total")
+    ticks = reg.counter("tpufw_serve_ticks_total")
+
+    def of(counter, kind, **labels):
+        return counter.value(**{"pass": kind}, **labels)
+
+    def starved_of(kind):
+        return sum(of(starved, kind, phase=p) for p in serve_mod.SCHED_PHASES)
+
+    # Before the first request: the new phase and every kind at 0.
+    text = reg.render()
+    assert 'tpufw_serve_phase_seconds_total{phase="serve_fetch"} 0' in text
+    for kind in kinds:
+        assert f'tpufw_serve_pass_seconds_total{{pass="{kind}"}} 0' in text
+        assert f'tpufw_serve_pass_steps_total{{pass="{kind}"}} 0' in text
+        assert (
+            f'tpufw_serve_pass_starved_seconds_total{{pass="{kind}",'
+            'phase="serve_fetch"} 0'
+        ) in text
+    _wait_idle(tracer)
+    assert sum(of(seconds, k) + starved_of(k) for k in kinds) == 0.0
+
+    def neighbours(first_a, first_b, new_a=161, new_b=9):
+        """A (40 tokens, 10 chunks of 16 steps) streams; B (40 tokens: 3
+        prefill chunks, 8 steps) is submitted at A's first token."""
+        q = queue.Queue()
+        sched.submit_stream([[first_a, 5, 9, 2, 6] * 8], new_a, None, q)
+        assert q.get(timeout=300)[0] == "chunk"
+        b = threading.Thread(
+            target=sched.submit, args=([[first_b, 5, 9, 2, 6] * 8], new_b)
+        )
+        b.start()
+        while q.get(timeout=300)[0] != "done":
+            pass
+        b.join(timeout=300)
+        assert not b.is_alive()
+        _wait_idle(tracer)
+
+    neighbours(1, 2)  # builds the pool and every program
+    before = {
+        "rest": _resting_since(tracer), "ticks": ticks.value(),
+        "wait": phase_s.value(phase="serve_wait"),
+        **{k: (of(seconds, k), of(steps, k), starved_of(k)) for k in kinds},
+    }
+    n0 = len(tracer._events)
+    neighbours(3, 4)
+    rest = _resting_since(tracer)
+    warm = [e for e in tracer._events[n0:] if e["ph"] == "X"]
+    grown = {
+        k: tuple(
+            now - was for now, was in
+            zip((of(seconds, k), of(steps, k), starved_of(k)), before[k])
+        )
+        for k in kinds
+    }
+
+    # One kind a pass, by what ran ahead of its decode chunk. A decodes
+    # from its own final chunk to the end, so every pass after that one
+    # has a decode chunk and "since the chunk before" is "in this pass".
+    chunks = [e for e in warm if e["name"] == "serve_decode_chunk"]
+    assert len(chunks) == ticks.value() - before["ticks"] >= 10
+    ahead, behind_k, plain_k, seen_chunk = 0, 0, 0, False
+    for e in warm:
+        if e["name"] == "serve_prefill_chunk":
+            ahead += 1 + e["args"]["final"]  # a final chunk brings its insert
+        elif e["name"] == "serve_decode_chunk":
+            if seen_chunk:
+                assert e["args"]["ahead"] == ahead, e
+            else:  # A's first: two passes without a chunk came before it
+                assert e["args"]["ahead"] == 2 and ahead == 4
+            assert (e["args"]["key_rung"], e["args"]["row_rung"]) == (
+                256, sched.n_slots
+            )
+            behind_k += e["args"]["k"] * (ahead > 0)
+            plain_k += e["args"]["k"] * (ahead == 0)
+            ahead, seen_chunk = 0, True
+    assert behind_k >= 4 * 16 and plain_k >= 16  # A's own, B's three
+    assert grown["decode_behind_prefill"][1] == behind_k
+    assert grown["decode"][1] == plain_k
+    assert grown["prefill_only"][1] == 0
+    # Every kind ran, and starved seconds stay under the kind's own.
+    for kind in kinds:
+        spent, _, idle = grown[kind]
+        assert 0.0 <= idle <= spent and spent > 0.0, (kind, grown[kind])
+    # Passes and serve_wait tile the thread's time from one rest to the
+    # next (the wait open at a scrape is booked whole when it closes):
+    # what they miss is the loop's own lines between a pass's end and the
+    # wait's span, never more than the time that went by.
+    booked = sum(grown[k][0] for k in kinds) + (
+        phase_s.value(phase="serve_wait") - before["wait"]
+    )
+    assert -0.05 <= booked - (rest - before["rest"]) <= 1e-6
+
+    # The wait ends when the results are ready; the copy is the fetch.
+    order = [e for e in warm if e["name"] in ("serve_device_wait", "serve_fetch")]
+    assert [e["name"] for e in order] == ["serve_device_wait", "serve_fetch"] * (
+        len(order) // 2
+    )
+    waited = [e["args"]["for"] for e in order[::2]]
+    assert waited.count("prefill_final") == 2
+    assert waited.count("decode") == len(chunks) == len(waited) - 2
+
+    # One request, three legs, one rid.
+    legs = {}
+    for i, e in enumerate(warm):
+        if e["name"] in ("req_queue", "req_prefill", "req_decode"):
+            legs.setdefault(e["args"]["rid"], {})[e["name"]] = (i, e)
+    assert sorted(legs) == [3, 4]
+    for rid, new in ((3, 161), (4, 9)):
+        assert set(legs[rid]) == {"req_queue", "req_prefill", "req_decode"}
+        (i0, _), (i1, last) = legs[rid]["req_prefill"], legs[rid]["req_decode"]
+        sat_in = [e for e in warm[i0:i1] if e["name"] == "serve_decode_chunk"]
+        assert last["args"]["passes"] == len(sat_in)
+        assert last["args"]["behind_prefill"] == sum(
+            e["args"]["ahead"] > 0 for e in sat_in
+        )
+        assert last["args"]["tokens"] == new
+        assert 0.0 < last["args"]["longest_pass_s"] <= last["dur"] / 1e6 + 1e-6
+    assert legs[3]["req_decode"][1]["args"]["passes"] == 10
+    assert legs[3]["req_decode"][1]["args"]["behind_prefill"] >= 4
+    assert legs[4]["req_decode"][1]["args"]["passes"] == 1
+    assert legs[4]["req_decode"][1]["args"]["behind_prefill"] == 1
+
+
 # ------------------------------------- the live prefix of a row (PR 31)
 
 def test_chunks_across_two_rungs_serve_the_monolithic_tokens(monkeypatch):

@@ -791,6 +791,49 @@ def test_warmup_invisible_to_metrics_and_seed_replay(
             assert line.endswith(" 0"), line
 
 
+def test_server_with_a_telemetry_dir_writes_the_request_chain(
+    tiny_env, monkeypatch, tmp_path
+):
+    """``TPUFW_TELEMETRY_DIR`` set: the server starts (it did not, from
+    PR 21 to PR 38: its run info read ``jax`` where nothing had imported
+    it), the scheduler's spans go to ``trace-serve.json``, and one
+    request's three legs there, ``req_queue``, ``req_prefill`` and
+    ``req_decode``, carry the ``rid`` of its ``serve_request`` event."""
+    import json
+
+    from tpufw.obs import events as events_mod
+    from tpufw.workloads import serve as serve_mod
+
+    tel = tmp_path / "tel"
+    monkeypatch.setenv("TPUFW_TELEMETRY_DIR", str(tel))
+    monkeypatch.setenv("TPUFW_SERVE_PAGE", "16")
+    monkeypatch.setenv("TPUFW_SERVE_PREFILL_CHUNK", "1")
+    srv = serve_mod._Server(port=0, max_new_tokens=4)
+    out, _ = srv._batcher.submit([[3, 5, 9, 2, 6] * 4], 12, None)
+    assert len(out[0]) == 12
+    srv._tel.close()
+    doc = json.loads((tel / "trace-serve.json").read_text())
+    legs = {}
+    for e in doc["traceEvents"]:
+        if e["name"] in ("req_queue", "req_prefill", "req_decode"):
+            legs.setdefault(e["args"]["rid"], {})[e["name"]] = e["args"]
+    # rid 1 was warm-up's request, rid 2 is this one.
+    assert set(legs[2]) == {"req_queue", "req_prefill", "req_decode"}
+    assert legs[2]["req_decode"]["tokens"] == 12
+    assert legs[2]["req_decode"]["passes"] >= 1
+    assert legs[2]["req_prefill"]["chunks"] == 2
+    waits = [
+        e["args"]["for"] for e in doc["traceEvents"]
+        if e["name"] == "serve_device_wait"
+    ]
+    assert {"decode", "prefill_final"} <= set(waits)
+    done = [
+        e for e in events_mod.read_events(str(tel / "events.jsonl"))
+        if e["kind"] == "serve_request"
+    ]
+    assert 2 in {e["rid"] for e in done}
+
+
 def test_paged_server_traces_its_row_model_once(tiny_env, monkeypatch):
     """The paged server's warm-up builds the pool that serves, and
     with it the row twin's shapes: ``row_shape_traces_total`` reads

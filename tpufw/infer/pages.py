@@ -651,8 +651,8 @@ class PagedSlotPool(SlotPool):
     trie_spill: Any = None
     trie_restore: Any = None
     #: Span sink for the host work done in here (``serve_row_alloc``,
-    #: the final chunk's ``serve_device_wait``); the serve scheduler
-    #: mounts its own tracer after building the pool.
+    #: the final chunk's ``serve_device_wait`` and ``serve_fetch``); the
+    #: serve scheduler mounts its own tracer after building the pool.
     tracer: Any = obs_trace.NULL
     # Admission-outcome counters for signals()/bench: requests whose
     # trie match (incl. spill restores) covered >= 1 page vs not, and
@@ -1075,6 +1075,7 @@ class PagedSlotPool(SlotPool):
                 names=names, scale_src=self._scale_src(paths, names),
                 page=self.page, quant=quant,
             )
+        self.dispatched("insert")
         self.cache = jax.tree_util.tree_unflatten(treedef, list(leaves))
         self.slot_pages[slot] = list(page_ids)
 
@@ -1094,6 +1095,7 @@ class PagedSlotPool(SlotPool):
             window_bytes=self.window_bytes // self.n_slots,
         ):
             row_tree = self._fresh_row()
+            self.dispatched("row")
             if not len(shared_ids):
                 return row_tree
             paths, names, leaves, _ = self._pool_flat()
@@ -1285,6 +1287,7 @@ class PagedSlotPool(SlotPool):
                     page=self.page, quant=quant,
                 )
             )
+            self.dispatched("chunk")
             if unlocked is not None:
                 # Dispatch is async — pin the device wall inside the
                 # lock-released window, not under some later holder.
@@ -1307,8 +1310,17 @@ class PagedSlotPool(SlotPool):
         if is_final:
             cp.first = first
             # The one read of a chunked prefill that blocks: it waits
-            # for every program queued before it, this chunk's last.
-            with self.tracer.span("serve_device_wait"):
+            # for every program queued before it, this chunk's last,
+            # until both results are ready (``serve_device_wait``: the
+            # device running), then copies them to the host
+            # (``serve_fetch``: two reads of one program's results).
+            with self.tracer.span(
+                "serve_device_wait", **{"for": "prefill_final"}
+            ):
+                first.copy_to_host_async()  # as np.asarray did: queued
+                done0.copy_to_host_async()  # behind the program
+                jax.block_until_ready((first, done0))
+            with self.tracer.span("serve_fetch"):
                 cp.first_int = int(np.asarray(first)[0])
                 cp.done0 = bool(np.asarray(done0)[0])
             return "done"

@@ -308,6 +308,12 @@ class SlotPool:
     remaining: jax.Array
     seen: Any
 
+    #: Called at the return of every program call made in here, with
+    #: what was dispatched ("decode", "chunk", "insert", "row"): the
+    #: device has work again. The serve scheduler mounts its pass
+    #: ledger's ``fed`` after building the pool (no dataclass field).
+    dispatched = staticmethod(lambda what: None)
+
     def __post_init__(self):
         # Pool state is born committed, where the weights live. To jit a
         # fresh uncommitted array and the committed output of an earlier
@@ -396,6 +402,7 @@ class SlotPool:
                 budget, self.token, self.pos, self.done, self.remaining,
                 self.seen, row_seen, axes=self.axes,
             )
+        self.dispatched("insert")
         self.cache = jax.tree_util.tree_unflatten(treedef, list(leaves))
 
     def decode_steps(self, keys) -> jax.Array:
@@ -424,6 +431,7 @@ class SlotPool:
             sampling=self.sampling, pad_id=self.pad_id,
             eos_id=self.eos_id,
         )
+        self.dispatched("decode")
         return out
 
     def spec_steps(self, proposals, key):
@@ -435,7 +443,9 @@ class SlotPool:
 
         reject_state(self, "speculative decoding")
 
-        return _spec.spec_verify_steps(self, proposals, key)
+        out = _spec.spec_verify_steps(self, proposals, key)
+        self.dispatched("decode")
+        return out
 
     def spec_draft_steps(self, draft_pool, key, k: int):
         """One fused draft+verify speculative pass against
@@ -446,7 +456,9 @@ class SlotPool:
         reject_state(self, "speculative decoding")
         reject_state(draft_pool, "speculative decoding (draft)")
 
-        return _spec.spec_draft_steps(self, draft_pool, key, k)
+        out = _spec.spec_draft_steps(self, draft_pool, key, k)
+        self.dispatched("decode")
+        return out
 
     def retire(self, slot: int) -> None:
         """Freeze ``slot`` (error paths — natural completions are

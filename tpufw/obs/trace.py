@@ -181,6 +181,15 @@ class Tracer:
                 for tid, stack in self._live.items()
             }
 
+    def open_name(self) -> Optional[str]:
+        """Name of the innermost span open on the CALLING thread, None
+        where it has none open: what a listener that is also told of
+        moments inside a span (the serve scheduler's pass ledger, at the
+        return of a dispatch) books that moment under. No clock read,
+        no lock: a thread's stack is only ever changed by that thread."""
+        stack = self._live.get(threading.get_ident())
+        return stack[-1][0] if stack else None
+
     def _buffer_complete(
         self, name: str, t0: float, t1: float, args, self_s: float
     ) -> None:
@@ -309,6 +318,9 @@ class NullTracer:
 
     def live_spans(self) -> dict:
         return {}
+
+    def open_name(self) -> None:
+        return None
 
     def close(self) -> None:
         pass

@@ -856,7 +856,8 @@ class _SlotJob:
     its own EOS/max_new."""
 
     __slots__ = ("req", "prompt", "p_bucket", "max_new", "cache_len",
-                 "tokens", "unflushed", "cp", "t_grant", "pass0", "kv0")
+                 "tokens", "unflushed", "cp", "t_grant", "pass0", "kv0",
+                 "t_first", "t_last", "longest_s", "decodes0", "behind0")
 
     def __init__(self, req, prompt, p_bucket, max_new, cache_len):
         self.req = req
@@ -874,6 +875,13 @@ class _SlotJob:
         # where ``req_prefill`` / tpufw_serve_prefill_seconds start.
         self.t_grant = 0.0
         self.pass0 = 0
+        # Where ``req_decode`` starts: the first token's moment, the
+        # newest delivery's, the longest stretch between two of them,
+        # and the pass ledger's counts of decode passes (and of those
+        # behind prefill) when the first token was sampled.
+        self.t_first = self.t_last = 0.0
+        self.longest_s = 0.0
+        self.decodes0 = self.behind0 = 0
         # Cache slots the row held when it was installed in its slot:
         # the prompt's, left padding included. With the tokens sampled
         # since, the row's cursor (``_row_keys``).
@@ -921,8 +929,10 @@ class _SlotReq:
 #: device, ``serve_wait`` the thread with nothing queued or running,
 #: ``serve_prefill_chunk`` / ``serve_decode_dispatch`` time to ENQUEUE a
 #: program (dispatch is asynchronous); the rest is host work. Request-
-#: level records (``req_queue``, ``req_prefill``) cross passes and stay
-#: out.
+#: level records (``req_queue``, ``req_prefill``, ``req_decode``) cross
+#: passes and stay out. ``serve_device_wait`` ends when the results it
+#: waits for are READY, so it is the device running what this thread
+#: enqueued and nothing else; ``serve_fetch`` is their copy to the host.
 SCHED_PHASES = (
     "serve_wait",
     "serve_pool_build",
@@ -934,8 +944,15 @@ SCHED_PHASES = (
     "serve_spec_chunk",
     "serve_decode_dispatch",
     "serve_device_wait",
+    "serve_fetch",
     "serve_emit",
 )
+
+#: What a scheduler pass was: a decode (or speculative) chunk with
+#: nothing enqueued ahead of it in the pass, one that ran behind at
+#: least one prefill program or insert of the same pass, or no decode
+#: chunk at all (``_PassLedger``).
+PASS_KINDS = ("decode", "decode_behind_prefill", "prefill_only")
 
 #: Buckets of the request-chain histograms: queue waits of a fraction
 #: of a second and chunked prefills of several seconds both need finer
@@ -945,6 +962,153 @@ _CHAIN_BUCKETS = (
     0.35, 0.4, 0.5, 0.6, 0.75, 1.0, 1.5, 2.0, 2.5, 3.0, 3.5, 4.0, 5.0,
     6.0, 8.0, 10.0, 15.0, 20.0, 30.0, 45.0, 60.0, 120.0,
 )
+
+
+class _PassLedger:
+    """The scheduler thread's passes on the books, always on: what each
+    pass was (``PASS_KINDS``), its wall seconds, the decode steps it
+    ran, and the seconds of it the device was STARVED.
+
+    A pass is one turn of ``_SlotScheduler._loop`` after ``serve_wait``:
+    it runs from the end of the pass before it (or of the wait) to
+    ``end_pass``, so passes and ``serve_wait`` tile the thread's time
+    and the passes' seconds sum to the time in service.
+
+    Starved seconds rest on the one bit the thread knows. After a
+    blocking read returns (``serve_device_wait`` or the blocking
+    ``serve_prefill`` closed), nothing this thread enqueued is left on
+    the device: it is *drained*. At the return of a dispatch (``fed``,
+    called by the pools where a program's call returns: a decode or
+    speculative chunk, a prefill program, an insert, a row's zero-fill)
+    it is *fed*; a pool just built leaves it fed too, and so does
+    start-up, until the first read says otherwise. Seconds of a pass
+    spent drained are seconds the device idled for this thread with
+    requests in service: a LOWER bound of the device's idle time (the
+    launch after an enqueue is the device's, not booked; the step keys'
+    split and a retired slot's table row are microseconds of device
+    work and feed nothing), over the whole of the server's life, no
+    profiler needed. They are booked by the leaf phase they fell in. **A
+    phase that straddles a dispatch is split at the dispatch's return**:
+    what came before is starved, under the span open on the thread at
+    that moment; what came after is not. Drained seconds that no span
+    covers (the loop's own lines between two ``with`` blocks) go to the
+    next phase that closes, and what is left at ``end_pass`` to the
+    last one that did, so a pass's starved seconds never pass its own.
+
+    Fed from what exists: it listens to the tracer's spans next to the
+    phase counter's ``on_span`` (a close of a phase while drained costs
+    one clock read), reads the innermost open span's name at a
+    dispatch, and counts the programs dispatched ahead of the pass's
+    decode chunk. ``clock`` is for tests that set the time by hand; it
+    has to be the tracer's."""
+
+    #: A dispatch of one of these ahead of the pass's decode chunk puts
+    #: the chunk behind prefill ("chunk": any prefill program, the
+    #: blocking whole-prompt one included).
+    AHEAD = ("chunk", "insert")
+
+    def __init__(self, tracer, registry=None, clock=time.perf_counter):
+        self._tracer = tracer
+        self._clock = clock
+        self._seconds = self._steps = self._starved = None
+        if registry is not None:
+            self._seconds = registry.counter(
+                "tpufw_serve_pass_seconds_total",
+                "Scheduler passes' wall seconds by kind of pass",
+            )
+            self._steps = registry.counter(
+                "tpufw_serve_pass_steps_total",
+                "Decode steps the scheduler's passes ran, by kind of pass",
+            )
+            self._starved = registry.counter(
+                "tpufw_serve_pass_starved_seconds_total",
+                "Seconds of a pass with nothing of this thread's on the "
+                "device, by kind of pass and phase",
+            )
+        self._lock = threading.Lock()  # counters: end_pass against reset
+        self.reset()  # every label exposed at 0
+        self._t_pass = self._mark = clock()
+        self._drained = False
+        self._last = "serve_emit"  # the phase that closed last
+        self._by_phase: dict[str, float] = {}
+        self._k = 0  # decode steps of the pass that is running
+        self._kind = "prefill_only"
+        #: Prefill programs and inserts dispatched so far in this pass.
+        self.ahead = 0
+        #: Passes that ran a decode chunk, and those of them that ran it
+        #: behind prefill, since start-up: a row's ``req_decode`` is the
+        #: difference between its first token and its last.
+        self.decodes = 0
+        self.behind = 0
+        tracer.listeners.append(self.on_span)
+
+    def _book(self, phase: str, now: float) -> None:
+        self._by_phase[phase] = (
+            self._by_phase.get(phase, 0.0) + now - self._mark
+        )
+        self._mark = now
+
+    def on_span(self, name, dur_s, args, self_s) -> None:
+        if name not in SCHED_PHASES:
+            return
+        if name == "serve_wait":
+            # Nothing in service: no pass's seconds, nobody starved.
+            self._t_pass = self._mark = self._clock()
+            return
+        if self._drained:
+            self._book(name, self._clock())
+        self._last = name
+        if name in ("serve_device_wait", "serve_prefill"):
+            if not self._drained:
+                self._drained, self._mark = True, self._clock()
+        elif name == "serve_pool_build":
+            self._drained = False
+        elif name == "serve_decode_chunk":
+            self._k += args["k"]
+        elif name == "serve_spec_chunk":
+            self._k += args["k"] + 1  # the verify block's positions
+
+    def fed(self, what: str) -> None:
+        """The call that dispatched a ``what`` ("decode", "chunk",
+        "insert", "row") just returned."""
+        if what == "decode":
+            self._kind = "decode_behind_prefill" if self.ahead else "decode"
+            self.decodes += 1
+            self.behind += bool(self.ahead)
+        elif what in self.AHEAD and self._kind == "prefill_only":
+            self.ahead += 1
+        if self._drained:
+            self._book(self._tracer.open_name() or self._last, self._clock())
+            self._drained = False
+
+    def end_pass(self) -> None:
+        now = self._clock()
+        if self._drained:
+            self._book(self._last, now)
+        kind, t0 = self._kind, self._t_pass
+        with self._lock:
+            if self._seconds is not None and t0 >= self._void_before:
+                self._seconds.inc(now - t0, **{"pass": kind})
+                self._steps.inc(self._k, **{"pass": kind})
+                for phase, s in self._by_phase.items():
+                    self._starved.inc(s, phase=phase, **{"pass": kind})
+        self._t_pass = now
+        self._by_phase.clear()
+        self._k, self._kind, self.ahead = 0, "prefill_only", 0
+
+    def reset(self) -> None:
+        """Zero the three families (warm-up's passes stay invisible to
+        scrapes), the pass that is running included: one that began
+        before this moment books nothing when it ends."""
+        with self._lock:
+            self._void_before = self._clock()
+            if self._seconds is None:
+                return
+            for kind in PASS_KINDS:
+                self._seconds.reset(**{"pass": kind})
+                self._steps.reset(**{"pass": kind})
+                for phase in SCHED_PHASES[1:]:  # serve_wait is no pass's
+                    self._starved.reset(phase=phase, **{"pass": kind})
 
 
 class _SlotScheduler:
@@ -1307,6 +1471,12 @@ class _SlotScheduler:
                     phase_s.inc(max(0.0, self_s), phase=name)
 
             self._tracer.listeners.append(on_span)
+        # The passes' own books, beside the phases': kind, seconds,
+        # steps and starved seconds of every pass (counters only where
+        # there is a registry; ``req_decode`` reads it either way).
+        self._ledger = _PassLedger(
+            self._tracer, metrics.registry if metrics is not None else None
+        )
         self._pool = None  # tpufw.infer.slots.SlotPool (lazy, keyed)
         self._pool_key: Optional[tuple] = None
         self._slots: list[Optional[_SlotJob]] = [None] * self.n_slots
@@ -1385,6 +1555,7 @@ class _SlotScheduler:
         with self._cv:
             self._job_index = 0
             self._chunk_index = 0
+        self._ledger.reset()
 
     def _enqueue(self, pend: _Pending) -> None:
         req = self._make_req(pend)  # raises ValueError -> HTTP 400
@@ -1488,6 +1659,7 @@ class _SlotScheduler:
                 self._fail_active(e)
             finally:
                 self._watchdog.disarm()
+                self._ledger.end_pass()
 
     def _row_model(self, cache_len: int):
         """CONTIGUOUS model variant with the pool's KV budget — built
@@ -1654,6 +1826,8 @@ class _SlotScheduler:
                     else None
                 ),
             )
+        # The pool reports the return of every dispatch it makes.
+        self._pool.dispatched = self._ledger.fed
         if self.page:
             self._pool.tracer = self._tracer
             self._count_row_shape_traces(self._pool)
@@ -1729,6 +1903,8 @@ class _SlotScheduler:
                             pad_id=0,
                             eos_id=None,
                         )
+                if self._draft_pool is not None:
+                    self._draft_pool.dispatched = self._ledger.fed
                 self._ema = self._spec_mod.AcceptEMA(
                     self.n_slots,
                     min_accept=self.spec_min_accept,
@@ -2073,6 +2249,11 @@ class _SlotScheduler:
                 )
         job.t_grant = time.perf_counter()
         job.pass0 = self._pass
+        # A blocking prefill: dispatched and read inside prefill_row /
+        # prefill_shared, so the ledger takes the device as fed from
+        # here (its starved seconds stay a lower bound) and as drained
+        # when the span closes.
+        self._ledger.fed("chunk")
         with self._tracer.span(
             "serve_prefill", prompt=len(job.prompt), width=job.p_bucket
         ):
@@ -2158,7 +2339,10 @@ class _SlotScheduler:
         """``job``'s first token was just sampled: close the request
         chain's prefill link, slot grant -> first token, on both
         admission paths."""
-        prefill_s = time.perf_counter() - job.t_grant
+        job.t_first = job.t_last = time.perf_counter()
+        job.decodes0 = self._ledger.decodes
+        job.behind0 = self._ledger.behind
+        prefill_s = job.t_first - job.t_grant
         self._tracer.complete(
             "req_prefill",
             prefill_s,
@@ -2171,6 +2355,29 @@ class _SlotScheduler:
             self._metrics.registry.histogram(
                 "tpufw_serve_prefill_seconds"
             ).observe(prefill_s)
+
+    def _delivered(self, job: _SlotJob, t: float, last: bool) -> None:
+        """``job`` was handed a chunk's tokens, read from the device at
+        ``t``. With its ``last`` token the request chain's third link is
+        written: ``req_decode``, first token -> last token, under the
+        ``rid`` of ``req_queue`` and ``req_prefill``, with the decode
+        passes the row sat in, how many of them ran behind a prefill
+        program or insert of the same pass, and the longest stretch
+        between two of its deliveries (one pass, read to read). It
+        feeds no counter: it answers "this stream stalled after its
+        first token: behind what"."""
+        job.longest_s = max(job.longest_s, t - job.t_last)
+        job.t_last = t
+        if last:
+            self._tracer.complete(
+                "req_decode",
+                t - job.t_first,
+                rid=job.req.rid,
+                tokens=len(job.tokens),
+                passes=self._ledger.decodes - job.decodes0,
+                behind_prefill=self._ledger.behind - job.behind0,
+                longest_pass_s=round(job.longest_s, 6),
+            )
 
     def _admit_draft(self, job: _SlotJob, slot: int, rng) -> None:
         """Prefill ``job``'s prompt through the draft model into the
@@ -2328,9 +2535,11 @@ class _SlotScheduler:
                 slot: list(self._pool.slot_pages[slot])
                 for slot, _ in active
             }
+        key_rung, row_rung = self._rungs(active, k)
         chunk_t0 = time.perf_counter()
         with self._tracer.span(
-            "serve_spec_chunk", k=k, rows=len(active)
+            "serve_spec_chunk", k=k, rows=len(active),
+            ahead=self._ledger.ahead, key_rung=key_rung, row_rung=row_rung,
         ):
             with self._tracer.span("serve_decode_dispatch"):
                 if self._draft_pool is not None:
@@ -2351,20 +2560,25 @@ class _SlotScheduler:
                     out, n_emit, accept = self._pool.spec_steps(
                         props, key
                     )
-            with self._tracer.span("serve_device_wait"):
+            with self._tracer.span("serve_device_wait", **{"for": "spec"}):
+                for a in (out, n_emit, accept):
+                    a.copy_to_host_async()  # as np.asarray did: see _run_chunk
+                self._jax.block_until_ready((out, n_emit, accept))
+            with self._tracer.span("serve_fetch"):
                 out = self._np.asarray(out)
                 n_emit = self._np.asarray(n_emit)
                 accept = self._np.asarray(accept)
-        chunk_s = time.perf_counter() - chunk_t0
+        t_read = time.perf_counter()
         with self._tracer.span("serve_emit", rows=len(active)):
-            self._emit_spec(active, k, out, n_emit, accept, chunk_s,
-                            page_snap)
+            self._emit_spec(active, k, out, n_emit, accept,
+                            t_read - chunk_t0, page_snap, t_read)
 
     def _emit_spec(
-        self, active, k, out, n_emit, accept, chunk_s, page_snap
+        self, active, k, out, n_emit, accept, chunk_s, page_snap, t_read
     ) -> None:
         """Host post-processing of one speculative pass: per-slot
-        accept bookkeeping, retires, stream flushes, completions."""
+        accept bookkeeping, retires, stream flushes, completions.
+        ``t_read``: when the pass's results reached the host."""
         live_tokens = 0
         flush: list[_SlotReq] = []
         finished: list[_SlotReq] = []
@@ -2389,9 +2603,11 @@ class _SlotScheduler:
             accept_frac += int(accept[slot]) / k
             if req.pend.stream_q is not None and req not in flush:
                 flush.append(req)
-            if len(job.tokens) >= job.max_new or (
+            last = len(job.tokens) >= job.max_new or (
                 self._eos is not None and row and row[-1] == self._eos
-            ):
+            )
+            self._delivered(job, t_read, last)
+            if last:
                 if self.page and self._page_export is not None:
                     self._page_export(
                         job,
@@ -2590,8 +2806,10 @@ class _SlotScheduler:
         # until the device has run everything queued before the read —
         # prefill chunks dispatched earlier in this pass included, so
         # the wait is NOT the decode program's own device time.
+        key_rung, row_rung = self._rungs(active)
         with self._tracer.span(
-            "serve_decode_chunk", k=k, rows=len(active)
+            "serve_decode_chunk", k=k, rows=len(active),
+            ahead=self._ledger.ahead, key_rung=key_rung, row_rung=row_rung,
         ):
             with self._tracer.span("serve_decode_dispatch"):
                 with self._cv:
@@ -2607,15 +2825,49 @@ class _SlotScheduler:
                 keys = self._jax.random.split(key, k)
                 chunk_t0 = time.perf_counter()
                 out = self._pool.decode_steps(keys)
-            with self._tracer.span("serve_device_wait"):
+            # The wait ends when the tokens are READY (the device ran
+            # everything queued before them); their copy to the host is
+            # the fetch. One read, as before, nothing merged or moved:
+            # the transfer is asked for where ``np.asarray`` on the
+            # pending array asked for it, queued behind the program, and
+            # not a host round trip later, once the wait has returned
+            # (that cost 0.23-0.30 ms a chunk on the chip: PERF.md §6).
+            with self._tracer.span(
+                "serve_device_wait", **{"for": "decode"}
+            ):
+                out.copy_to_host_async()
+                self._jax.block_until_ready(out)
+            with self._tracer.span("serve_fetch"):
                 out = self._np.asarray(out)
-        chunk_s = time.perf_counter() - chunk_t0
+        t_read = time.perf_counter()
         with self._tracer.span("serve_emit", rows=len(active)):
-            self._emit_chunk(active, k, out, chunk_s, page_snap)
+            self._emit_chunk(
+                active, k, out, t_read - chunk_t0, page_snap, t_read
+            )
 
-    def _emit_chunk(self, active, k, out, chunk_s, page_snap) -> None:
+    def _rungs(self, active, extra: int = 0):
+        """(key rung, row rung) the pool's next cached call reads, for
+        the decode span's arguments: the pair ``kv_store.attended_pair``
+        names (``_count_keys`` books the same, step by step, once the
+        chunk's tokens are known) at the chunk's first step, every
+        active row live and the longest holding its next token (and
+        ``extra`` more: a verify block)."""
+        from tpufw.ops.kv_store import attended_pair
+
+        rows, keys = attended_pair(
+            self._pool.model.cfg,
+            self.n_slots,
+            len(active),
+            max(_row_keys(job) for _, job in active) + extra,
+        )
+        return keys, rows
+
+    def _emit_chunk(
+        self, active, k, out, chunk_s, page_snap, t_read
+    ) -> None:
         """Host post-processing of one decode chunk: token
-        bookkeeping, retires, stream flushes, completions."""
+        bookkeeping, retires, stream flushes, completions. ``t_read``:
+        when the chunk's tokens reached the host."""
         if self._metrics is not None:
             self._metrics.inc("ticks_total")
             self._metrics.inc("tick_rows_total", len(active))
@@ -2635,9 +2887,11 @@ class _SlotScheduler:
             live_tokens += len(row)
             if req.pend.stream_q is not None and req not in flush:
                 flush.append(req)
-            if len(job.tokens) >= job.max_new or (
+            last = len(job.tokens) >= job.max_new or (
                 self._eos is not None and row and row[-1] == self._eos
-            ):
+            )
+            self._delivered(job, t_read, last)
+            if last:
                 # Retire: host-side in contiguous mode — the device
                 # row froze itself via the done/remaining masks. Paged
                 # mode also clears the page table and frees the pages.
@@ -2822,6 +3076,8 @@ class _Server:
         tdir = env_str("telemetry_dir", "")
         if tdir:
             import atexit
+
+            import jax
 
             self._tel = Telemetry.create(
                 telemetry_dir=tdir,
