@@ -410,6 +410,95 @@ def test_a_second_admission_builds_no_program(family):
     assert log.n == built, log.programs[built:]
 
 
+# ------------------- a frozen row through a whole further chunk (PR 40)
+
+def _arena(pool):
+    """The pool's arena leaves (pages, segment ids, scales) as
+    ``[stacks, n_pages, page, ...]`` host arrays, by leaf path."""
+    from tpufw.ops.kv_store import role
+
+    paths, names, leaves, _ = pool._pool_flat()
+    return {
+        path: np.asarray(pages_mod._collapse_arena(leaf, role(name).rank))
+        for path, name, leaf in zip(paths, names, leaves)
+        if role(name).in_arena
+    }
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+def test_a_frozen_row_rides_a_further_chunk_on_its_own_tail(tiny_paged, kv_quant):
+    """The slot scheduler's chained order steps a row that has ended
+    through one more whole chunk before its slot is released
+    (``serve._SlotScheduler._run_chunk``). Here the row ends on the last
+    step of a chunk with its cursor on the LAST key slot of its cache
+    (36 + 28 = 64 = max_seq_len: Mixtral's 8,064 + 128 = 8,192 at small
+    size), beside a live row. Through the further chunk it emits pad,
+    and of the arena it writes the junk page and its own last key slot,
+    which holds no token of it: every slot under its last cursor, every
+    other row's page and every free page stay byte for byte, and the
+    live row decodes the tokens it decodes once the frozen row's slot
+    was released."""
+    cfg, row_model, params = tiny_paged
+    s = cfg.max_seq_len
+    live_prompt = [2, 7, 1, 8, 2, 8]
+
+    def seated(release: bool):
+        pool = _paged_pool(cfg, row_model, params, kv_quant)
+        for slot, (prompt, max_new) in enumerate(
+            ((PROMPT, s - len(PROMPT)), (live_prompt, 50))
+        ):
+            cp = pool.start_chunked(
+                prompt, len(prompt) + max_new - 1,
+                jax.random.fold_in(jax.random.key(0), slot), 1,
+            )
+            while pool.chunk_step(cp) != "done":
+                pass
+            pool.finalize_chunked(slot, cp, max_new - 1)
+        # 27 steps: the long row's budget, spent on the chunk's last step.
+        outs = [
+            np.asarray(pool.decode_steps(jax.random.split(jax.random.key(i), 9)))
+            for i in range(3)
+        ]
+        assert bool(np.asarray(pool.done)[0]) and not bool(np.asarray(pool.done)[1])
+        assert int(np.asarray(pool.remaining)[0]) == 0
+        assert all((o[0] != 0).any() for o in outs)  # it was live to the end
+        own = list(pool.slot_pages[0])
+        if release:
+            pool.release_slot(0)
+        return pool, own
+
+    pool, own = seated(release=False)
+    assert len(own) == s // PAGE  # it holds its whole row
+    before = _arena(pool)
+    further = np.asarray(pool.decode_steps(jax.random.split(jax.random.key(9), 8)))
+    after = _arena(pool)
+    assert (further[0] == 0).all()  # frozen: pad, eight times
+    live_pages = set(pool.slot_pages[1])
+    for path, a in after.items():
+        b = before[path]
+        changed = {
+            int(p) for p in np.nonzero(
+                (a != b).reshape(a.shape[0], a.shape[1], -1).any(axis=(0, 2))
+            )[0]
+        }
+        # The junk page, the live row's own, the frozen row's last.
+        assert changed <= {0} | live_pages | {own[-1]}, (path, changed)
+        # Of its last page, the last key slot alone: no token of the
+        # row's lives there (its last one was emitted, never written).
+        assert np.array_equal(a[:, own[-1], : PAGE - 1], b[:, own[-1], : PAGE - 1]), path
+    # The live row's tokens are those it decodes beside a released slot.
+    ref, _ = seated(release=True)
+    ref_further = np.asarray(ref.decode_steps(jax.random.split(jax.random.key(9), 8)))
+    assert further[1].tolist() == ref_further[1].tolist()
+    assert (further[1] != 0).any()
+    # And the release that follows frees the row's pages, once (its
+    # prompt's two full pages stay with the prefix trie).
+    free0, kept = pool.allocator.n_free, len(PROMPT) // PAGE
+    assert pool.release_slot(0) == len(own) - kept
+    assert pool.allocator.n_free == free0 + len(own) - kept
+    assert len(set(pool.allocator.free)) == len(pool.allocator.free)
+
+
 # ---------------------------------------------- scheduler fungibility
 
 def _scheduler(model, params, prefill_chunk_pages):
@@ -678,6 +767,7 @@ def test_pass_ledger_on_a_toy_server(tiny_sched_model, tmp_path):
     starved = reg.counter("tpufw_serve_pass_starved_seconds_total")
     phase_s = reg.counter("tpufw_serve_phase_seconds_total")
     ticks = reg.counter("tpufw_serve_ticks_total")
+    chained = reg.counter("tpufw_serve_chunks_chained_total")
 
     def of(counter, kind, **labels):
         return counter.value(**{"pass": kind}, **labels)
@@ -717,7 +807,7 @@ def test_pass_ledger_on_a_toy_server(tiny_sched_model, tmp_path):
     neighbours(1, 2)  # builds the pool and every program
     before = {
         "rest": _resting_since(tracer), "ticks": ticks.value(),
-        "wait": phase_s.value(phase="serve_wait"),
+        "wait": phase_s.value(phase="serve_wait"), "chained": chained.value(),
         **{k: (of(seconds, k), of(steps, k), starved_of(k)) for k in kinds},
     }
     n0 = len(tracer._events)
@@ -753,6 +843,15 @@ def test_pass_ledger_on_a_toy_server(tiny_sched_model, tmp_path):
             plain_k += e["args"]["k"] * (ahead == 0)
             ahead, seen_chunk = 0, True
     assert behind_k >= 4 * 16 and plain_k >= 16  # A's own, B's three
+    # Where a boundary was quiet the successor went out before the read
+    # (the chained order): such a chunk ran behind nothing, was enqueued
+    # once like every other, and is counted; A's last chunks, B gone, are
+    # such, and no chunk beside B's prefill is.
+    links = [e for e in chunks if e["args"]["chained"]]
+    assert len(links) == chained.value() - before["chained"] >= 2
+    assert all(e["args"]["ahead"] == 0 for e in links)
+    assert chunks[-1] in links and not chunks[0]["args"]["chained"]
+    assert len(chunks) == sum(e["name"] == "serve_decode_dispatch" for e in warm)
     assert grown["decode_behind_prefill"][1] == behind_k
     assert grown["decode"][1] == plain_k
     assert grown["prefill_only"][1] == 0

@@ -78,13 +78,28 @@ class _Sched:
                 self.ledger.fed("insert")
                 self.tick(insert_after)
 
-    def decode(self, k, dispatch, wait, fetch, name="serve_decode_chunk"):
+    def decode(self, k, dispatch, wait, fetch, name="serve_decode_chunk", successor=None):
+        """One decode chunk as its pass sees it. ``dispatch=None``: the
+        pass found the chunk in flight and enqueues none of its own.
+        ``successor`` = (plan, glue, call): the chained order, the next
+        chunk planned and its keys made before the wait (``plan`` s, the
+        device running), then, once the wait returns, ``glue`` s of the
+        loop's own lines and ``call`` s up to the return of the
+        successor's dispatch, ahead of the fetch."""
         with self.tracer.span(name, k=k, rows=1, ahead=self.ledger.ahead):
-            with self.tracer.span("serve_decode_dispatch"):
-                self.tick(dispatch)
-                self.ledger.fed("decode")
+            if dispatch is not None:
+                with self.tracer.span("serve_decode_dispatch"):
+                    self.tick(dispatch)
+                    self.ledger.fed("decode")
+            if successor:
+                self.tick(successor[0])
             with self.tracer.span("serve_device_wait", **{"for": "decode"}):
                 self.tick(wait)
+            if successor:
+                self.tick(successor[1])
+                with self.tracer.span("serve_decode_dispatch"):
+                    self.tick(successor[2])
+                    self.ledger.fed("decode")
             with self.tracer.span("serve_fetch"):
                 self.tick(fetch)
 
@@ -203,6 +218,143 @@ def test_a_pass_has_one_kind_by_what_ran_ahead_of_its_chunk(ahead, kind):
     s.admit(0.5)
     s.ledger.end_pass()
     assert s.seconds("prefill_only") == 0.5 and s.ledger.ahead == 0
+
+
+def test_a_chain_of_passes_by_hand():
+    """The chained order (``_SlotScheduler._run_chunk``): a pass enqueues
+    its chunk's successor between the wait and the fetch, and the next
+    pass finds its chunk in flight. Of a chained boundary the device is
+    starved from the wait's return to the return of the successor's
+    dispatch, under ``serve_decode_dispatch``; the fetch and the emit
+    behind it book nothing. A pass still has one chunk: the successor is
+    the next pass's, which is a ``decode`` pass, counted where it starts,
+    with the steps of the chunk it reads. Passes and ``serve_wait`` tile
+    the thread's time as before."""
+    s = _Sched()
+    t0 = s.clock.now
+    s.wait(8.0)
+    seen = []  # (decodes at the pass's emit, steps booked once it ended)
+
+    def ends():
+        seen.append((s.ledger.decodes, s.steps("decode")))
+        s.ledger.end_pass()
+        seen[-1] = (seen[-1][0], s.steps("decode") - seen[-1][1])
+
+    # Pass 1: enqueues its own chunk (fed since start-up: not starved),
+    # then its successor.
+    s.admit(0.125)
+    s.decode(8, 0.5, 4.0, 0.25, successor=(0.03125, 0.0625, 0.25))
+    s.emit(1.0)
+    ends()
+    # Pass 2: its chunk is in flight; it enqueues the one after.
+    s.tick(0.0078125)
+    s.decode(8, None, 4.0, 0.25, successor=(0.03125, 0.0625, 0.25))
+    s.emit(1.0)
+    ends()
+    # Pass 3: in flight again, the last of the row's budget: no successor,
+    # so the plain order's boundary follows (fetch, emit, admit, dispatch).
+    s.tick(0.0078125)
+    s.decode(4, None, 2.0, 0.25)
+    s.emit(0.5)
+    ends()
+    # Pass 4: a plain pass.
+    s.admit(0.125)
+    s.decode(4, 0.5, 2.0, 0.25)
+    s.emit(0.5)
+    ends()
+    s.wait(16.0)
+    assert seen == [(1, 8), (2, 8), (3, 4), (4, 4)]  # one chunk a pass
+    assert s.ledger.behind == 0
+    assert s.seconds("decode") == (
+        (0.125 + 0.5 + 0.03125 + 4.0 + 0.0625 + 0.25 + 0.25 + 1.0)
+        + (0.0078125 + 0.03125 + 4.0 + 0.0625 + 0.25 + 0.25 + 1.0)
+        + (0.0078125 + 2.0 + 0.25 + 0.5)
+        + (0.125 + 0.5 + 2.0 + 0.25 + 0.5)
+    )
+    assert s.seconds("prefill_only") == s.seconds("decode_behind_prefill") == 0.0
+    assert sum(s.seconds(k) for k in KINDS) + s.phase_s.value(phase="serve_wait") == s.clock.now - t0
+    # The two chained boundaries: the glue and the call, and nothing else;
+    # then pass 4's own dispatch, starved as a plain one is.
+    assert s.starved("decode", "serve_decode_dispatch") == 2 * (0.0625 + 0.25) + 0.5
+    # Passes 3 and 4 alone: a fetch or an emit behind a successor books none.
+    assert s.starved("decode", "serve_fetch") == 0.25 + 0.25
+    assert s.starved("decode", "serve_emit") == 0.5 + 0.5
+    assert s.starved("decode", "serve_admit") == 0.125  # pass 4's
+    assert s.starved("decode", "serve_decode_chunk") == 0.0
+    assert s.starved("decode", "serve_device_wait") == 0.0
+    assert s.starved("decode") == 2 * 0.3125 + 0.5 + 0.5 + 1.0 + 0.125
+
+
+def test_a_successor_behind_a_prefill_pass_starts_a_plain_decode_pass():
+    """The pass that inserts a row runs its chunk behind prefill; where
+    its boundary is quiet the successor goes out all the same, and the
+    starved seconds of that boundary are that pass's. The pass that reads
+    the successor ran nothing ahead of it."""
+    s = _Sched()
+    s.wait(1.0)
+    s.chunk(0.25, 0.125, final=(2.0, 0.5))
+    s.emit(0.25, insert_after=0.0625)
+    s.decode(8, 0.5, 4.0, 0.25, successor=(0.0, 0.0625, 0.25))
+    s.emit(1.0)
+    s.ledger.end_pass()
+    assert (s.steps("decode_behind_prefill"), s.steps("decode")) == (8, 0)
+    assert s.starved("decode_behind_prefill", "serve_decode_dispatch") == 0.3125
+    assert s.starved("decode_behind_prefill", "serve_emit") == 0.25  # up to the insert
+    assert (s.ledger.decodes, s.ledger.behind) == (2, 1)
+    s.decode(8, None, 4.0, 0.25)
+    s.emit(1.0)
+    s.ledger.fed("insert")  # after the chunk it read: not ahead of it
+    s.ledger.end_pass()
+    assert (s.steps("decode_behind_prefill"), s.steps("decode")) == (8, 8)
+    assert s.seconds("decode") == 4.0 + 0.25 + 1.0
+    assert s.starved("decode") == 0.25 + 1.0
+    assert (s.ledger.decodes, s.ledger.behind, s.ledger.ahead) == (2, 1, 0)
+    # The chain ended: the next pass starts with no chunk of its own.
+    s.admit(0.5)
+    s.ledger.end_pass()
+    assert s.seconds("prefill_only") == 0.5
+
+
+def test_reset_after_warmup_drops_the_keys_made_ahead_and_zeroes_the_count():
+    """Warm-up chains like any traffic: its passes, its count of chained
+    chunks and the step keys it left made for its next chunk index are
+    gone once the caller resets, so the first live chunk makes index 0's
+    own keys."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpufw.infer import SamplingConfig
+    from tpufw.models import LLAMA_CONFIGS, Llama
+
+    model = Llama(LLAMA_CONFIGS["llama3_tiny"].decode_config())
+    params = jax.jit(model.init)(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    metrics = serve_mod._Metrics()
+    sched = serve_mod._SlotScheduler(
+        model, params, eos_id=None, seed_base=0, metrics=metrics,
+        default_sampling=SamplingConfig(temperature=0.0),
+    )
+    chained = metrics.registry.counter("tpufw_serve_chunks_chained_total")
+    steps = metrics.registry.counter("tpufw_serve_pass_steps_total")
+    assert "tpufw_serve_chunks_chained_total 0" in metrics.registry.render()
+    def booked():
+        return sum(steps.value(**{"pass": k}) for k in KINDS)
+
+    warm = sched.submit([[1, 2, 3]], 49, None)[0]  # 48 steps: 16, 16, 16
+    assert chained.value() == 2 and sched._chunk_index == 3
+    deadline = time.monotonic() + 60  # the reply leaves before the pass ends
+    while booked() < 48 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert booked() == 48
+    sched._keys_ahead = ((3, 16), "made for an index the reset takes back")
+    sched.reset_after_warmup()
+    assert sched._keys_ahead is None and sched._chunk_index == 0
+    assert chained.value() == 0
+    assert booked() == 0
+    # Seed replay: the same request again draws chunk indices 0, 1, 2.
+    assert sched.submit([[1, 2, 3]], 49, None)[0] == warm
+    assert chained.value() == 2 and sched._chunk_index == 3
 
 
 def test_a_stalled_pass_without_a_chunk_is_prefill_only():

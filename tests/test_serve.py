@@ -785,7 +785,10 @@ def test_warmup_invisible_to_metrics_and_seed_replay(
         assert isinstance(srv._batcher, serve_mod._SlotScheduler)
         assert srv._batcher._job_index == 0
         assert srv._batcher._chunk_index == 0
+        assert srv._batcher._keys_ahead is None
     rendered = srv.metrics.render({})
+    if backend == "slots":
+        assert "\ntpufw_serve_chunks_chained_total 0\n" in rendered
     for line in rendered.splitlines():
         if line.startswith("tpufw_serve_") and not line.startswith("#"):
             assert line.endswith(" 0"), line

@@ -895,6 +895,27 @@ def _row_keys(job: _SlotJob) -> int:
     return job.kv0 + len(job.tokens)
 
 
+class _DecodeChunk:
+    """One decode chunk from its launch to its read: the rows it was
+    launched for, its length, what the emit needs of the moment of its
+    launch (the page-table snapshot, the span's arguments) and, once it
+    is enqueued, its pending tokens. ``chained``: it was enqueued before
+    its predecessor was read (``_SlotScheduler._run_chunk``)."""
+
+    __slots__ = ("active", "k", "page_snap", "ahead", "key_rung",
+                 "row_rung", "chained", "out", "t0")
+
+    def __init__(self, active, k, page_snap, ahead, rungs, chained):
+        self.active = active
+        self.k = k
+        self.page_snap = page_snap
+        self.ahead = ahead
+        self.key_rung, self.row_rung = rungs
+        self.chained = chained
+        self.out = None  # [S, k] tokens, pending on the device
+        self.t0 = 0.0
+
+
 class _SlotReq:
     """Request-level bookkeeping around a _Pending: the per-row jobs,
     the admission cursor (``next_job``), and completion accounting."""
@@ -933,6 +954,14 @@ class _SlotReq:
 #: passes and stay out. ``serve_device_wait`` ends when the results it
 #: waits for are READY, so it is the device running what this thread
 #: enqueued and nothing else; ``serve_fetch`` is their copy to the host.
+#: A pass runs them in one of two orders (``_SlotScheduler._run_chunk``).
+#: The plain one: admit, prefill chunks, ``serve_decode_chunk`` (dispatch,
+#: wait, fetch), emit. The CHAINED one, where nothing is queued and
+#: nobody prefills when the wait returns: the SUCCESSOR's
+#: ``serve_decode_dispatch`` comes between the wait and the fetch, so the
+#: fetch, the emit and the next pass's scan run beside the device, and
+#: the next pass is its ``serve_decode_chunk`` alone: no admit, no
+#: dispatch of its own, the wait for the chunk it found in flight.
 SCHED_PHASES = (
     "serve_wait",
     "serve_pool_build",
@@ -995,6 +1024,16 @@ class _PassLedger:
     next phase that closes, and what is left at ``end_pass`` to the
     last one that did, so a pass's starved seconds never pass its own.
 
+    A pass has ONE decode chunk: the one it waits for and reads. Where
+    the scheduler enqueues that chunk's successor before it reads it
+    (``_run_chunk``'s chained order), the second ``fed("decode")`` of
+    the pass feeds the device like any dispatch (the starved seconds of
+    such a boundary run from the wait's return to it, under
+    ``serve_decode_dispatch``; the fetch and the emit behind it book
+    none) but is the NEXT pass's chunk: that pass starts as a ``decode``
+    pass (a successor runs behind no prefill), is counted in ``decodes``
+    where it starts, and books the chunk's steps where its span closes.
+
     Fed from what exists: it listens to the tracer's spans next to the
     phase counter's ``on_span`` (a close of a phase while drained costs
     one clock read), reads the innermost open span's name at a
@@ -1033,6 +1072,7 @@ class _PassLedger:
         self._by_phase: dict[str, float] = {}
         self._k = 0  # decode steps of the pass that is running
         self._kind = "prefill_only"
+        self._chained = False  # this pass enqueued the next one's chunk
         #: Prefill programs and inserts dispatched so far in this pass.
         self.ahead = 0
         #: Passes that ran a decode chunk, and those of them that ran it
@@ -1071,7 +1111,9 @@ class _PassLedger:
     def fed(self, what: str) -> None:
         """The call that dispatched a ``what`` ("decode", "chunk",
         "insert", "row") just returned."""
-        if what == "decode":
+        if what == "decode" and self._kind != "prefill_only":
+            self._chained = True  # the next pass's chunk, enqueued early
+        elif what == "decode":
             self._kind = "decode_behind_prefill" if self.ahead else "decode"
             self.decodes += 1
             self.behind += bool(self.ahead)
@@ -1094,7 +1136,10 @@ class _PassLedger:
                     self._starved.inc(s, phase=phase, **{"pass": kind})
         self._t_pass = now
         self._by_phase.clear()
-        self._k, self._kind, self.ahead = 0, "prefill_only", 0
+        self._k, self.ahead = 0, 0
+        self._kind = "decode" if self._chained else "prefill_only"
+        self.decodes += self._chained
+        self._chained = False
 
     def reset(self) -> None:
         """Zero the three families (warm-up's passes stay invisible to
@@ -1370,6 +1415,10 @@ class _SlotScheduler:
                 "retired_rows_total",
                 "wasted_slot_steps_total",
                 "pool_switches_total",
+                # Decode chunks enqueued before their predecessor was
+                # read (``_run_chunk``'s chained order), beside
+                # ``ticks_total``, the chunks read.
+                "chunks_chained_total",
             )
             if self.page:
                 # Feature-gated (register = expose at 0): legacy-mode
@@ -1487,6 +1536,14 @@ class _SlotScheduler:
         # invisible to seed replay.
         self._job_index = 0
         self._chunk_index = 0
+        # Step keys made while a chunk ran, for the chunk after it:
+        # ((chunk index, k), keys), taken only by the dispatch that has
+        # that index and that length (``_dispatch_chunk``).
+        self._keys_ahead = None
+        # The decode chunk enqueued and not yet read, where a pass ended
+        # with one in flight (at most one: ``_run_chunk``). The
+        # scheduler thread's alone.
+        self._inflight: Optional[_DecodeChunk] = None
         self._rid = 0  # request ids handed out (rid of the newest)
         self._pass = 0  # scheduler passes begun (req_prefill counts them)
         self._queue: list[_SlotReq] = []
@@ -1551,11 +1608,16 @@ class _SlotScheduler:
     def reset_after_warmup(self) -> None:
         """Restore the rng-stream indices so warmup prefills/chunks
         are invisible to seed replay (the compiled programs and the
-        warm pool itself stay)."""
+        warm pool itself stay), drop the step keys made ahead under
+        warm-up's indices, and zero the scheduler's own books: the
+        passes' and the count of chained chunks."""
         with self._cv:
             self._job_index = 0
             self._chunk_index = 0
+            self._keys_ahead = None
         self._ledger.reset()
+        if self._metrics is not None:
+            self._metrics.reset("chunks_chained_total")
 
     def _enqueue(self, pend: _Pending) -> None:
         req = self._make_req(pend)  # raises ValueError -> HTTP 400
@@ -1632,12 +1694,16 @@ class _SlotScheduler:
         # No span encloses the pass: in a profiler capture it would
         # cover every idle gap of the device and hide the phase.
         while True:
+            # A chunk in flight is service too (with an EOS every row of
+            # it may have retired at its predecessor's emit): it is read
+            # before the thread rests.
+            inflight = self._inflight is not None
             with self._cv:
-                if not self._queue and not self._n_active:
+                if not self._queue and not self._n_active and not inflight:
                     with self._tracer.span("serve_wait"):
                         while not self._queue and not self._n_active:
                             self._cv.wait()
-                idle = self._n_active == 0
+                idle = self._n_active == 0 and not inflight
             if idle and self.wait_s > 0:
                 # Coalescing window: near-simultaneous arrivals land
                 # in the same first admission round. Never slept while
@@ -1652,8 +1718,13 @@ class _SlotScheduler:
             # dump shows which. Idle waiting above stays disarmed.
             self._watchdog.arm()
             try:
-                self._admit()
-                if self._n_active:
+                if not inflight:
+                    # Behind a chunk in flight nothing is admitted: what
+                    # arrived since it was enqueued waits for its read,
+                    # as it would have behind the same chunk enqueued a
+                    # pass later.
+                    self._admit()
+                if self._n_active or inflight:
                     self._run_chunk()
             except Exception as e:  # noqa: BLE001 — serving loop
                 self._fail_active(e)
@@ -2766,28 +2837,117 @@ class _SlotScheduler:
             self._finish(req)
 
     def _run_chunk(self) -> None:
-        progressed = self._run_prefill_chunks()
-        active = [
-            (i, j)
-            for i, j in enumerate(self._slots)
-            if j is not None and j.cp is None
-        ]
-        if not active:
-            if self._n_active and not progressed:
-                # Every occupied slot is a prefill stalled on pages
-                # and nothing is decoding: yield briefly so the loop
-                # doesn't spin hot waiting for a release/eviction.
-                time.sleep(0.001)
-            return
-        if self._use_spec(active):
-            self._run_spec_chunk(active)
-            return
+        """The pass's device work: one prefill chunk for every
+        prefilling slot, then ONE decode chunk, waited for, read and
+        emitted. A pass runs in one of two orders.
+
+        The plain order: prefill chunks, then the chunk's step keys and
+        program are enqueued (``serve_decode_dispatch``), the thread
+        blocks until its tokens are ready (``serve_device_wait``), copies
+        them (``serve_fetch``) and emits them (``serve_emit``: retires
+        free slots and pages), and the next pass admits, prefills and
+        enqueues the next chunk, the device idle from the wait's return
+        to that enqueue.
+
+        The CHAINED order, taken when the wait returns on a boundary
+        where nothing could come between this chunk and the next: no
+        request queued, no slot prefilling, no speculation for this
+        pool, and a row with budget left. Then the SUCCESSOR is enqueued
+        first, for the same rows, with the step keys that were made
+        while this chunk ran (``_plan_successor``), and only then this
+        chunk is fetched and emitted, beside the device. The next pass
+        finds its chunk in flight (``_inflight``): it admits nothing
+        (``_loop``: whoever arrived since waits for this chunk's read,
+        as behind the same chunk enqueued a pass later), enqueues none
+        of its own, and blocks. At most one chunk is ever in flight
+        unread. The successor is the chunk the plain order would have
+        enqueued: same rows, same chunk index and so the same keys, the
+        length the budgets give once this chunk's tokens are counted.
+        A row that ends in this chunk rides the successor frozen, as it
+        rides the rest of a chunk it ends in the middle of (the step's
+        ``done`` mask: segment id 0, writes past its cursor in its own
+        last page or the junk page): the emit drops what the successor
+        emitted for it (``_emit_chunk``), its ``release_slot`` is
+        enqueued behind the successor and ahead of any insert or
+        prefill chunk that could be granted its pages, and its export
+        reads the pages of the snapshot taken at its own chunk's launch.
+        (Where an EOS ends a row, the successor's length is the one the
+        budgets gave, and a chunk whose rows all ended runs for
+        nobody.)"""
+        chunk, self._inflight = self._inflight, None
+        if chunk is None:
+            progressed = self._run_prefill_chunks()
+            active = [
+                (i, j)
+                for i, j in enumerate(self._slots)
+                if j is not None and j.cp is None
+            ]
+            if not active:
+                if self._n_active and not progressed:
+                    # Every occupied slot is a prefill stalled on pages
+                    # and nothing is decoding: yield briefly so the loop
+                    # doesn't spin hot waiting for a release/eviction.
+                    time.sleep(0.001)
+                return
+            if self._use_spec(active):
+                self._run_spec_chunk(active)
+                return
+            chunk = self._decode_chunk(active)
+        # An asynchronous pass, recorded as it is: the time to enqueue
+        # the step keys and the decode program, then the time blocked
+        # until the device has run everything queued before the read —
+        # prefill chunks dispatched earlier in this pass included, so
+        # the wait is NOT the decode program's own device time.
+        with self._tracer.span(
+            "serve_decode_chunk", k=chunk.k, rows=len(chunk.active),
+            ahead=chunk.ahead, key_rung=chunk.key_rung,
+            row_rung=chunk.row_rung, chained=int(chunk.chained),
+        ):
+            if chunk.out is None:
+                with self._tracer.span("serve_decode_dispatch"):
+                    self._dispatch_chunk(chunk)
+            # While the device runs the chunk: its successor planned and
+            # the successor's step keys made (three eager calls, queued
+            # behind the running program).
+            successor = self._plan_successor(chunk)
+            # The wait ends when the tokens are READY (the device ran
+            # everything queued before them); their copy to the host is
+            # the fetch. One read, as before, nothing merged or moved:
+            # the transfer is asked for where ``np.asarray`` on the
+            # pending array asked for it, queued behind the program, and
+            # not a host round trip later, once the wait has returned
+            # (that cost 0.23-0.30 ms a chunk on the chip: PERF.md §6).
+            with self._tracer.span(
+                "serve_device_wait", **{"for": "decode"}
+            ):
+                chunk.out.copy_to_host_async()
+                self._jax.block_until_ready(chunk.out)
+            if successor is not None and self._boundary_is_quiet():
+                # The device is idle from the wait's return to this
+                # call's: the whole of a chained boundary.
+                with self._tracer.span("serve_decode_dispatch"):
+                    self._dispatch_chunk(successor)
+                self._inflight = successor
+                if self._metrics is not None:
+                    self._metrics.inc("chunks_chained_total")
+            with self._tracer.span("serve_fetch"):
+                out = self._np.asarray(chunk.out)
+        t_read = time.perf_counter()
+        with self._tracer.span("serve_emit", rows=len(chunk.active)):
+            self._emit_chunk(
+                chunk.active, chunk.k, out, t_read - chunk.t0,
+                chunk.page_snap, t_read,
+            )
+
+    def _decode_chunk(self, active, ran: int = 0) -> _DecodeChunk:
+        """The chunk to launch for ``active``, ``ran`` steps of theirs
+        being enqueued and not yet emitted (a successor's: the length of
+        the chunk it follows)."""
         # Pow-2 ladder on the chunk length: the scan length is a
         # compiled-shape dimension, so the tail of a nearly-done pool
         # shrinks k in big steps (at most log2(chunk) programs), never
         # per-value.
-        max_left = max(j.max_new - len(j.tokens) for _, j in active)
-        k = min(self.chunk, _pow2_ceil(max_left))
+        max_left = max(j.max_new - len(j.tokens) for _, j in active) - ran
         # Chunk-boundary page-table snapshot for the export hook: a
         # row that finishes mid-chunk keeps absorbing the junk-sink
         # (page 0) writes for the chunk's remaining steps, and once it
@@ -2801,49 +2961,79 @@ class _SlotScheduler:
                 slot: list(self._pool.slot_pages[slot])
                 for slot, _ in active
             }
-        # An asynchronous pass, recorded as it is: the time to enqueue
-        # the step keys and the decode program, then the time blocked
-        # until the device has run everything queued before the read —
-        # prefill chunks dispatched earlier in this pass included, so
-        # the wait is NOT the decode program's own device time.
-        key_rung, row_rung = self._rungs(active)
-        with self._tracer.span(
-            "serve_decode_chunk", k=k, rows=len(active),
-            ahead=self._ledger.ahead, key_rung=key_rung, row_rung=row_rung,
-        ):
-            with self._tracer.span("serve_decode_dispatch"):
-                with self._cv:
-                    # Reset from the caller side in reset_after_warmup;
-                    # bump under the monitor so neither side loses an
-                    # update.
-                    chunk_index = self._chunk_index
-                    self._chunk_index += 1
-                key = self._jax.random.fold_in(
-                    self._jax.random.key(self._seed_base + 1),
-                    chunk_index,
-                )
-                keys = self._jax.random.split(key, k)
-                chunk_t0 = time.perf_counter()
-                out = self._pool.decode_steps(keys)
-            # The wait ends when the tokens are READY (the device ran
-            # everything queued before them); their copy to the host is
-            # the fetch. One read, as before, nothing merged or moved:
-            # the transfer is asked for where ``np.asarray`` on the
-            # pending array asked for it, queued behind the program, and
-            # not a host round trip later, once the wait has returned
-            # (that cost 0.23-0.30 ms a chunk on the chip: PERF.md §6).
-            with self._tracer.span(
-                "serve_device_wait", **{"for": "decode"}
-            ):
-                out.copy_to_host_async()
-                self._jax.block_until_ready(out)
-            with self._tracer.span("serve_fetch"):
-                out = self._np.asarray(out)
-        t_read = time.perf_counter()
-        with self._tracer.span("serve_emit", rows=len(active)):
-            self._emit_chunk(
-                active, k, out, t_read - chunk_t0, page_snap, t_read
-            )
+        return _DecodeChunk(
+            active, min(self.chunk, _pow2_ceil(max_left)), page_snap,
+            0 if ran else self._ledger.ahead, self._rungs(active, ran),
+            chained=ran > 0,
+        )
+
+    def _step_keys(self, chunk_index: int, k: int):
+        """The ``k`` step keys of decode chunk ``chunk_index``: three
+        eager calls, a function of the seed, the index and the length."""
+        return self._jax.random.split(
+            self._jax.random.fold_in(
+                self._jax.random.key(self._seed_base + 1), chunk_index
+            ),
+            k,
+        )
+
+    def _dispatch_chunk(self, chunk: _DecodeChunk) -> None:
+        """Enqueue ``chunk``: the next chunk index, its step keys (those
+        made ahead, where they are this index's and this length's), the
+        pool's decode program."""
+        with self._cv:
+            # Reset from the caller side in reset_after_warmup; bump
+            # under the monitor so neither side loses an update.
+            at = (self._chunk_index, chunk.k)
+            self._chunk_index += 1
+            ahead, self._keys_ahead = self._keys_ahead, None
+        keys = (
+            ahead[1] if ahead is not None and ahead[0] == at
+            else self._step_keys(*at)
+        )
+        chunk.t0 = time.perf_counter()
+        chunk.out = self._pool.decode_steps(keys)
+
+    def _plan_successor(self, chunk: _DecodeChunk) -> Optional[_DecodeChunk]:
+        """While ``chunk`` runs: the chunk that follows it if no row
+        joins (None: no row has budget left after it), and that chunk's
+        step keys, made ahead under the next chunk index. The rows are
+        those that stay, the length what the parent's rule gives from
+        the budgets as they will stand (``tokens`` still holds what was
+        emitted before ``chunk``). Whichever order enqueues the next
+        chunk takes the keys if it has that index and that length, and
+        makes its own otherwise; no index is consumed here. A pool that
+        speculates plans nothing: its passes choose their kind anew."""
+        if self._ema is not None:
+            return None
+        staying = [
+            (slot, job) for slot, job in chunk.active
+            if self._slots[slot] is job
+            and job.max_new - len(job.tokens) > chunk.k
+        ]
+        if not staying:
+            return None
+        successor = self._decode_chunk(staying, ran=chunk.k)
+        with self._cv:
+            at = (self._chunk_index, successor.k)
+        keys = self._step_keys(*at)
+        with self._cv:
+            # Named by index and length, so keys that a reset overtook
+            # are never taken for another chunk's.
+            self._keys_ahead = (at, keys)
+        return successor
+
+    def _boundary_is_quiet(self) -> bool:
+        """At a decode chunk's boundary, by what the thread can see and
+        nothing else: may the successor go ahead of the read? Not with a
+        request queued or a slot prefilling: an admission or a prefill
+        chunk belongs between the two chunks (the plain order)."""
+        with self._cv:
+            if self._queue:
+                return False
+        return not any(
+            j is not None and j.cp is not None for j in self._slots
+        )
 
     def _rungs(self, active, extra: int = 0):
         """(key rung, row rung) the pool's next cached call reads, for
@@ -2867,7 +3057,11 @@ class _SlotScheduler:
     ) -> None:
         """Host post-processing of one decode chunk: token
         bookkeeping, retires, stream flushes, completions. ``t_read``:
-        when the chunk's tokens reached the host."""
+        when the chunk's tokens reached the host. A row that retired
+        since the chunk was launched (it ended in the chunk before, and
+        rode this one frozen: ``_run_chunk``'s chained order) is given
+        nothing of it."""
+        active = [(s, j) for s, j in active if self._slots[s] is j]
         if self._metrics is not None:
             self._metrics.inc("ticks_total")
             self._metrics.inc("tick_rows_total", len(active))
@@ -2994,6 +3188,7 @@ class _SlotScheduler:
         }
         self._slots = [None] * self.n_slots
         self._n_active = 0
+        self._inflight = None  # its rows fail with the rest
         self._pool = None  # donated buffers are suspect after a failure
         self._pool_key = None
         self._draft_pool = None  # rides the pool's allocator — same fate
