@@ -1,6 +1,8 @@
 """The gated delta rule (tpufw.ops.kda): the chunkwise form against the
 one-step form against a token-by-token recurrence written out here, the
-identity under ``valid``, and the short convolution's carried tail."""
+identity under ``valid``, and the short convolution's carried tail. Each
+under both shapes of decay: one a channel with a square state (KDA), and
+one a head with d_k != d_v, neither a power of two (Gated DeltaNet)."""
 
 import jax
 import jax.numpy as jnp
@@ -10,10 +12,19 @@ import pytest
 from tpufw.ops.kda import BLOCK, causal_conv, kda_chunk, kda_step
 
 F32 = jnp.float32
+#: form -> (d_k, d_v, one decay a head).
+FORMS = {"channel": (16, 16, False), "head": (12, 20, True)}
+both_forms = pytest.mark.parametrize("form", sorted(FORMS))
+
+
+def wide(g, k):
+    """A decay [.., H] or [.., H, dk] as [.., H, dk]."""
+    return g if g.ndim == k.ndim else jnp.broadcast_to(g[..., None], k.shape)
 
 
 def token_by_token(q, k, v, g, beta, s):
     outs = []
+    g = wide(g, k)
     for t in range(q.shape[1]):
         s = s * jnp.exp(g[:, t])[..., None]
         delta = v[:, t] - jnp.einsum("bhkv,bhk->bhv", s, k[:, t], precision="highest")
@@ -22,23 +33,29 @@ def token_by_token(q, k, v, g, beta, s):
     return jnp.stack(outs, 1), s
 
 
-def inputs(t, b=2, h=3, d=16, seed=0, decay=5.0):
+def inputs(t, b=2, h=3, form="channel", seed=0, decay=5.0):
+    d, dv, per_head = FORMS[form]
     ks = jax.random.split(jax.random.key(seed), 7)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (b, t, h, d))) * d ** -0.5
     k = unit(jax.random.normal(ks[1], (b, t, h, d)))
-    v = jax.random.normal(ks[2], (b, t, h, d))
+    v = jax.random.normal(ks[2], (b, t, h, dv))
     # The seeded weights' spread: -exp(N(0,1)) * softplus(N(0,2)), and stronger.
     g = -jnp.exp(jax.random.normal(ks[3], (h,)))[:, None] * jax.nn.softplus(
         jax.random.normal(ks[4], (b, t, h, d)) * 2 ** 0.5) * decay
-    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (b, t, h)))
-    s0 = jax.random.normal(ks[6], (b, h, d, d))
+    if per_head:
+        g = g[..., 0]
+    # (0, 2): past 1 the transition reflects along k (a negative eigenvalue).
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (b, t, h)) * 2)
+    s0 = jax.random.normal(ks[6], (b, h, d, dv))
     return q, k, v, g, beta, s0
 
 
+@both_forms
 @pytest.mark.parametrize("t", [1, BLOCK - 1, BLOCK, 2 * BLOCK + 22])
-def test_chunk_step_and_token_by_token_agree(t):
-    q, k, v, g, beta, s0 = inputs(t)
+def test_chunk_step_and_token_by_token_agree(t, form):
+    q, k, v, g, beta, s0 = inputs(t, form=form)
+    assert t < BLOCK or float(jnp.max(beta)) > 1.9
     want_o, want_s = token_by_token(q, k, v, g, beta, s0)
     got_o, got_s = kda_chunk(q, k, v, g, beta, s0)
     # float32 against float32: the order of sums only.
@@ -52,12 +69,29 @@ def test_chunk_step_and_token_by_token_agree(t):
     np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=1e-6)
 
 
-@pytest.mark.parametrize("pad", [5, BLOCK + 9])
-def test_a_padded_tail_leaves_the_state_bit_equal(pad):
+@both_forms
+def test_one_decay_a_head_is_the_per_channel_rule_under_a_broadcast_decay(form):
+    """The scalar form builds no [C, C, d_k] product; handed the same
+    decay on every channel, the per-channel form answers the same."""
     t = BLOCK + 22
-    q, k, v, g, beta, s0 = inputs(t, seed=1)
+    q, k, v, g, beta, s0 = inputs(t, form=form, seed=7)
+    g = g if g.ndim == beta.ndim else g[..., 0]
+    got_o, got_s = kda_chunk(q, k, v, g, beta, s0)
+    want_o, want_s = kda_chunk(q, k, v, wide(g, k), beta, s0)
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s), atol=2e-5)
+    o1, s1 = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
+    o2, s2 = kda_step(q[:, 0], k[:, 0], v[:, 0], wide(g, k)[:, 0], beta[:, 0], s0)
+    assert bool(jnp.all(o1 == o2)) and bool(jnp.all(s1 == s2))
+
+
+@both_forms
+@pytest.mark.parametrize("pad", [5, BLOCK + 9])
+def test_a_padded_tail_leaves_the_state_bit_equal(pad, form):
+    t = BLOCK + 22
+    q, k, v, g, beta, s0 = inputs(t, form=form, seed=1)
     _, want = kda_chunk(q, k, v, g, beta, s0)
-    junk = inputs(pad, seed=2)
+    junk = inputs(pad, form=form, seed=2)
     cat = lambda a, j: jnp.concatenate([a, j], axis=1)
     valid = jnp.broadcast_to(jnp.arange(t + pad) < t, (2, t + pad))
     o, got = kda_chunk(cat(q, junk[0]), cat(k, junk[1]), cat(v, junk[2]), cat(g, junk[3]),
@@ -72,12 +106,13 @@ def test_a_padded_tail_leaves_the_state_bit_equal(pad):
                                  jnp.zeros_like(junk[4][:, 0]), s0)[1] == s0))
 
 
-def test_left_padding_is_the_rows_empty_past():
+@both_forms
+def test_left_padding_is_the_rows_empty_past(form):
     t, pad = 40, 24
-    q, k, v, g, beta, _ = inputs(t, seed=3)
-    s0 = jnp.zeros((2, 3, 16, 16))
+    q, k, v, g, beta, s0 = inputs(t, form=form, seed=3)
+    s0 = jnp.zeros_like(s0)
     want_o, want_s = kda_chunk(q, k, v, g, beta, s0)
-    junk = inputs(pad, seed=4)
+    junk = inputs(pad, form=form, seed=4)
     cat = lambda j, a: jnp.concatenate([j, a], axis=1)
     valid = jnp.broadcast_to(jnp.arange(t + pad) >= pad, (2, t + pad))
     o, s = kda_chunk(cat(junk[0], q), cat(junk[1], k), cat(junk[2], v), cat(junk[3], g),
@@ -87,25 +122,30 @@ def test_left_padding_is_the_rows_empty_past():
     np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=1e-4)
 
 
-def test_no_decay_however_strong_overflows_and_the_state_stays_finite():
+@both_forms
+def test_no_decay_however_strong_overflows_and_the_state_stays_finite(form):
     """8,192 positions under the seeded weights' spread of decays, and
     under decays a hundred times stronger (exp(-G) would overflow float32
-    within a block; no exponent here is positive)."""
+    within a block and exp(G) underflows to 0 in it; no exponent here is
+    positive)."""
     for decay in (1.0, 100.0):
-        q, k, v, g, beta, _ = inputs(8192, b=1, h=2, seed=5, decay=decay)
-        o, s = jax.jit(kda_chunk)(q, k, v, g, beta, jnp.zeros((1, 2, 16, 16)))
+        q, k, v, g, beta, s0 = inputs(8192, b=1, h=2, form=form, seed=5, decay=decay)
+        if decay > 1:
+            assert float(jnp.min(jnp.sum(g[:, :BLOCK], axis=1))) < -200  # exp underflows
+        o, s = jax.jit(kda_chunk)(q, k, v, g, beta, jnp.zeros_like(s0))
         assert bool(jnp.all(jnp.isfinite(o))) and bool(jnp.all(jnp.isfinite(s)))
         assert float(jnp.max(jnp.abs(s))) < 1e3
 
 
-def test_bfloat16_state_is_told_apart():
+@both_forms
+def test_bfloat16_state_is_told_apart(form):
     """Keeping the state in bfloat16 between steps moves the outputs by
     far more than float32 rounding: what the chip run's tolerance is held
     against."""
     t = 256
-    q, k, v, g, beta, _ = inputs(t, b=1, decay=0.05, seed=6)
-    want, _ = token_by_token(q, k, v, g, beta, jnp.zeros((1, 3, 16, 16)))
-    s, outs = jnp.zeros((1, 3, 16, 16), jnp.bfloat16), []
+    q, k, v, g, beta, s0 = inputs(t, b=1, form=form, decay=0.05, seed=6)
+    want, _ = token_by_token(q, k, v, g, beta, jnp.zeros_like(s0))
+    s, outs = jnp.zeros(s0.shape, jnp.bfloat16), []
     for i in range(t):
         o, s = kda_step(q[:, i], k[:, i], v[:, i], g[:, i], beta[:, i], s)
         outs.append(o)

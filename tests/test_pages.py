@@ -378,15 +378,17 @@ def _seam_family(name):
     cls, cfg = harness.family_modules(name)[1].program_model(
         keys, {"moe_dispatch": "sorted"}
     )
-    if name == "falcon_h1":
-        # Its layers are alike, so its trunk scans: STATE and PAGE
-        # leaves of ONE layer, both stacked [L, ...] in front.
+    if name in ("falcon_h1", "olmo_hybrid"):
+        # Its layers (olmo_hybrid: its PERIODS of four layers) are
+        # alike, so its trunk scans: STATE and PAGE leaves of ONE unit,
+        # both stacked [L, ...] in front.
         cfg = dataclasses.replace(cfg, scan_layers=True)
     return cls, cfg.decode_config()
 
 
 @pytest.mark.parametrize(
-    "family", ["llama", "deepseek", "solar_open2", "laguna", "falcon_h1"]
+    "family",
+    ["llama", "deepseek", "solar_open2", "laguna", "falcon_h1", "olmo_hybrid"],
 )
 def test_every_cache_leaf_has_a_role_in_the_store(family):
     """The seam tpufw.ops.kv_store owns: whatever a pool or a row twin
@@ -424,7 +426,7 @@ def test_every_cache_leaf_has_a_role_in_the_store(family):
         kv_store.CURSOR,
     } <= kinds
     assert (kv_store.STATE in kinds) == (
-        family in ("solar_open2", "falcon_h1")
+        family in ("solar_open2", "falcon_h1", "olmo_hybrid")
     )
     assert (kv_store.RING in kinds) == (family == "laguna")
     if family == "falcon_h1":
@@ -434,8 +436,21 @@ def test_every_cache_leaf_has_a_role_in_the_store(family):
         assert block["attn"]["cached_key"].shape[0] == n_layers
         assert block["ssm"]["ssm_state"].shape[:2] == (n_layers, 2)
         assert block["ssm"]["conv_state"].shape[:2] == (n_layers, 2)
+    if family == "olmo_hybrid":
+        # One scanned PERIOD: per-slot state in three of its blocks, a
+        # page pair in the fourth, each stacked by period.
+        period = pages_mod.paged_pool_cache(paged, params, 2)["cache"]["layers"]
+        n_periods = cfg.n_layers // len(cfg.period)
+        for j in range(3):
+            gdn = period[f"linear_{j}"]["gdn"]
+            assert gdn["gdn_state"].shape[:2] == (n_periods, 2)
+            assert gdn["conv_state"].shape[:2] == (n_periods, 2)
+        assert period["full_3"]["attn"]["cached_key"].shape[0] == n_periods
     models = pathlib.Path(pages_mod.__file__).parents[1] / "models"
-    for source in ("llama.py", "deepseek.py", "laguna.py", "falcon_h1.py"):
+    for source in (
+        "llama.py", "deepseek.py", "laguna.py", "falcon_h1.py",
+        "olmo_hybrid.py",
+    ):
         text = (models / source).read_text()
         for spelled in (
             '"page_table"', '"cache_index"', '"cached_segment_ids"',

@@ -31,6 +31,11 @@ from tpufw.models.mixtral import (  # noqa: F401
     MixtralConfig,
     MoEMLP,
 )
+from tpufw.models.olmo_hybrid import (  # noqa: F401
+    OLMO_HYBRID_CONFIGS,
+    OlmoHybrid,
+    OlmoHybridConfig,
+)
 from tpufw.models.resnet import ResNet, ResNetConfig, resnet50  # noqa: F401
 from tpufw.models.solar_open2 import (  # noqa: F401
     SOLAR_OPEN2_CONFIGS,
@@ -71,6 +76,8 @@ def model_for_config(cfg):
         return Laguna(cfg)
     if isinstance(cfg, FalconH1Config):  # likewise
         return FalconH1(cfg)
+    if isinstance(cfg, OlmoHybridConfig):  # likewise
+        return OlmoHybrid(cfg)
     if isinstance(cfg, MixtralConfig):
         return Mixtral(cfg)
     if isinstance(cfg, GemmaConfig):

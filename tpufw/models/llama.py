@@ -596,6 +596,17 @@ class Attention(nn.Module):
             cfg, x, (cfg.n_kv_heads, cfg.head_dim), -1,
             ("embed",), ("kv_heads", "head_dim"), "v", use_bias=qkv_bias,
         )
+        if getattr(cfg, "qk_norm", None):
+            # QK-norm over the WHOLE projected width, before the split
+            # into heads means anything (OLMo 2's placement; tpufw.models.
+            # olmo_hybrid): one learned scale of H * hd for q, one of
+            # Hk * hd for k.
+            q, k = (
+                RMSNorm(cfg.rms_eps, name=name)(
+                    a.reshape(*a.shape[:-2], -1)
+                ).reshape(a.shape)
+                for name, a in (("q_norm", q), ("k_norm", k))
+            )
         if self.rope is not None:
             rope = self.rope
             q = apply_rope(

@@ -41,7 +41,13 @@ from tpufw.models.llama import (
     projection,
 )
 from tpufw.ops import kv_store, rms_norm
-from tpufw.ops.kda import causal_conv, kda_chunk, kda_step
+from tpufw.ops.kda import (
+    causal_conv,
+    decay_rate,
+    kda_chunk,
+    kda_step,
+    unit_qk,
+)
 
 LAYER_KINDS = ("gqa", "kda")
 #: The recurrent state's type. Not a setting: a probe that wants to see
@@ -144,6 +150,52 @@ class SolarOpen2Config(LlamaConfig):
         return total
 
 
+def raw_param(module, cfg, name, shape, init):
+    """A parameter that is no projection's kernel: unpartitioned, in the
+    parameters' type."""
+    return module.param(
+        name,
+        nn.with_logical_partitioning(init, (None,) * len(shape)),
+        shape,
+        cfg.param_dtype,
+    )
+
+
+def short_conv(module, cfg, parts, kernel: int, valid):
+    """The depthwise causal convolution (no bias) and SiLU in front of a
+    delta-rule layer's q, k and v. ``parts`` [B,T,c_i] each, in that
+    order; one kernel a part (``q_conv``, ``k_conv``, ``v_conv``), one
+    ``conv_state`` leaf for the tail of all three, which with
+    ``cfg.decode`` this call continues from and writes. Returns the
+    three convolved parts."""
+    mixed = jnp.concatenate(parts, -1)
+    b, _, c = mixed.shape
+    conv_w = jnp.concatenate(
+        [
+            raw_param(
+                module, cfg, f"{n}_conv", (kernel, part.shape[-1]),
+                nn.initializers.lecun_normal(),
+            )
+            for n, part in zip("qkv", parts)
+        ],
+        axis=-1,
+    )
+    if cfg.decode:
+        tail = kv_store.slot_state(
+            module, "conv_state", (b, kernel - 1, c), cfg.dtype
+        )
+        tail0 = tail.value
+    else:
+        tail0 = jnp.zeros((b, kernel - 1, c), cfg.dtype)
+    mixed, tail1 = causal_conv(mixed, conv_w, tail0, valid)
+    if cfg.decode:
+        tail.value = tail1
+    k_at = parts[0].shape[-1]
+    return jnp.split(
+        nn.silu(mixed), [k_at, k_at + parts[1].shape[-1]], axis=-1
+    )
+
+
 class KDALayer(nn.Module):
     """One linear-attention mixer. x [B,T,d] -> [B,T,d]; positions play
     no part. With ``cfg.decode`` the state and the convolution's tail
@@ -158,7 +210,7 @@ class KDALayer(nn.Module):
     def __call__(self, x, segment_ids=None):
         cfg = self.cfg
         b, t, _ = x.shape
-        h, dk, km1 = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv - 1
+        h, dk = cfg.kda_heads, cfg.kda_head_dim
         c = h * dk
         f32 = jnp.float32
         valid = None if segment_ids is None else segment_ids > 0
@@ -177,46 +229,26 @@ class KDALayer(nn.Module):
                 cfg, mid, c, -1, ("lora",), (out_name,), bb
             )
 
-        def raw(name, shape, init):
-            return self.param(
-                name,
-                nn.with_logical_partitioning(init, (None,) * len(shape)),
-                shape,
-                cfg.param_dtype,
+        q, k, v = (
+            a.reshape(b, t, h, dk).astype(f32)
+            for a in short_conv(
+                self, cfg, [heads("q"), heads("k"), heads("v")],
+                cfg.kda_conv, valid,
             )
-
-        mixed = jnp.concatenate([heads("q"), heads("k"), heads("v")], -1)
-        conv_w = jnp.concatenate(
-            [
-                raw(f"{n}_conv", (km1 + 1, c), nn.initializers.lecun_normal())
-                for n in "qkv"
-            ],
-            axis=-1,
         )
         if cfg.decode:
-            tail = kv_store.slot_state(
-                self, "conv_state", (b, km1, 3 * c), cfg.dtype
-            )
             state = kv_store.slot_state(
                 self, "kda_state", (b, h, dk, dk), KDA_STATE_DTYPE
             )
-            tail0, s0 = tail.value, state.value
+            s0 = state.value
         else:
-            tail0 = jnp.zeros((b, km1, 3 * c), cfg.dtype)
             s0 = jnp.zeros((b, h, dk, dk), KDA_STATE_DTYPE)
-        mixed, tail1 = causal_conv(mixed, conv_w, tail0, valid)
-        q, k, v = (
-            a.reshape(b, t, h, dk).astype(f32)
-            for a in jnp.split(nn.silu(mixed), 3, axis=-1)
-        )
-        unit = lambda a: a * jax.lax.rsqrt(
-            jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6
-        )
-        q, k = unit(q) * dk ** -0.5, unit(k)
+        q, k = unit_qk(q, k)
 
-        a_log = raw("A_log", (h,), nn.initializers.zeros_init()).astype(f32)
-        dt_bias = raw("dt_bias", (c,), nn.initializers.zeros_init())
-        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+        zeros = nn.initializers.zeros_init()
+        a_log = raw_param(self, cfg, "A_log", (h,), zeros).astype(f32)
+        dt_bias = raw_param(self, cfg, "dt_bias", (c,), zeros)
+        g = decay_rate(a_log, True) * jax.nn.softplus(
             (low_rank("f_a", "f_b", "heads").astype(f32)
              + dt_bias.astype(f32)).reshape(b, t, h, dk)
         )
@@ -240,7 +272,7 @@ class KDALayer(nn.Module):
             with jax.named_scope("kda_chunk"):
                 o, s1 = kda_chunk(q, k, v, g, beta, s0, valid)
         if cfg.decode:
-            tail.value, state.value = tail1, s1
+            state.value = s1
 
         scale = self.param(
             "o_norm",

@@ -1,6 +1,8 @@
-"""Gated delta rule with a per-channel decay (Kimi Delta Attention,
-arXiv:2510.26692), in the two forms serving needs. Per head the state
-``S`` is a [d_k, d_v] float32 matrix and one token does
+"""Gated delta rule in the two forms serving needs, with a decay per
+channel (Kimi Delta Attention, arXiv:2510.26692: ``g`` [.., H, d_k]) or
+one a head (Gated DeltaNet, arXiv:2412.06464: ``g`` [.., H]). Per head
+the state ``S`` is a [d_k, d_v] float32 matrix, d_k and d_v any sizes,
+and one token does
 
     S' = Diag(alpha_t) S_{t-1}            alpha_t = exp(g_t) in (0, 1]
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
@@ -19,6 +21,10 @@ arXiv:2510.26692), in the two forms serving needs. Per head the state
   is carried block to block by ``lax.scan``. Every exponent is a
   difference G_t - G_s with s <= t, so never positive: no decay, however
   strong, overflows (the factored exp(G_t) * exp(-G_s) form would).
+  With one decay a head the sum over c leaves the exponent,
+  A = (K K^T) * exp(G_t - G_s): a matmul times a [C, C] matrix, and no
+  [C, C, d_k] product is built; the system, its solve, the carry and
+  the rule for padding are the same lines either way.
 
 A position under ``valid == False`` is the identity on the state
 (alpha = 1, beta = 0): a right-padded tail leaves ``S`` bit-equal to
@@ -45,11 +51,13 @@ _HI = jax.lax.Precision.HIGHEST
 
 
 def kda_step(q, k, v, g, beta, state):
-    """One token a row. q, k, g [B,H,dk]; v [B,H,dv]; beta [B,H];
-    state [B,H,dk,dv]. Returns (o [B,H,dv] float32, new state in the
-    state's dtype)."""
+    """One token a row. q, k [B,H,dk]; g [B,H,dk] or [B,H]; v [B,H,dv];
+    beta [B,H]; state [B,H,dk,dv]. Returns (o [B,H,dv] float32, new
+    state in the state's dtype)."""
     f32 = jnp.float32
     q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    if g.ndim == beta.ndim:
+        g = g[..., None]  # one decay a head, over every channel
     s = state.astype(f32) * jnp.exp(g)[..., None]
     w = v - jnp.einsum("bhkv,bhk->bhv", s, k, precision=_HI)
     s = s + (beta[..., None] * k)[..., None] * w[..., None, :]
@@ -59,8 +67,8 @@ def kda_step(q, k, v, g, beta, state):
 
 def _block(carry, xs):
     """One block of ``BLOCK`` positions, all rows and heads at once.
-    carry S [B,H,dk,dv]; xs q,k,g [B,C,H,dk], v [B,C,H,dv], beta
-    [B,C,H]."""
+    carry S [B,H,dk,dv]; xs q,k [B,C,H,dk], g [B,C,H,dk] or (one decay
+    a head) [B,C,H,1], v [B,C,H,dv], beta [B,C,H]."""
     s0 = carry
     q, k, v, g, beta = xs
     c = q.shape[1]
@@ -74,11 +82,16 @@ def _block(carry, xs):
     t_idx = jnp.arange(c)
     lower = t_idx[:, None] >= t_idx[None, :]  # s <= t
     decay = jnp.where(lower[..., None], jnp.exp(jnp.minimum(diff, 0.0)), 0.0)
-    kk = jnp.sum(k[:, :, :, None, :] * k[:, :, None, :, :] * decay, axis=-1)
-    qk = jnp.sum(q[:, :, :, None, :] * k[:, :, None, :, :] * decay, axis=-1)
+    if g.shape[-1] == 1:
+        # One decay a head: it leaves the sum over channels.
+        kk = jnp.einsum("bhtk,bhsk->bhts", k, k, precision=_HI) * decay[..., 0]
+        qk = jnp.einsum("bhtk,bhsk->bhts", q, k, precision=_HI) * decay[..., 0]
+    else:
+        kk = jnp.sum(k[:, :, :, None, :] * k[:, :, None, :, :] * decay, axis=-1)
+        qk = jnp.sum(q[:, :, :, None, :] * k[:, :, None, :, :] * decay, axis=-1)
     strict = (t_idx[:, None] > t_idx[None, :]).astype(kk.dtype)
     system = jnp.eye(c, dtype=kk.dtype) + kk * strict * beta[:, :, None, :]
-    gamma = jnp.exp(cum)  # [B,H,C,dk]
+    gamma = jnp.exp(cum)  # [B,H,C,dk or 1]
     rhs = v - jnp.einsum("bhtk,bhkv->bhtv", k * gamma, s0, precision=_HI)
     w = jax.scipy.linalg.solve_triangular(
         system, rhs, lower=True, unit_diagonal=True
@@ -94,14 +107,16 @@ def _block(carry, xs):
 
 
 def kda_chunk(q, k, v, g, beta, state, valid: Optional[jax.Array] = None):
-    """``T`` tokens a row, in blocks of ``BLOCK``. q, k, g [B,T,H,dk];
-    v [B,T,H,dv]; beta [B,T,H]; state [B,H,dk,dv]; valid [B,T] bool
-    (None = all). Returns (o [B,T,H,dv] float32, new state in the
-    state's dtype). T is padded up to a whole number of blocks with
-    positions that are not valid."""
+    """``T`` tokens a row, in blocks of ``BLOCK``. q, k [B,T,H,dk]; g
+    [B,T,H,dk] or [B,T,H]; v [B,T,H,dv]; beta [B,T,H]; state
+    [B,H,dk,dv]; valid [B,T] bool (None = all). Returns (o [B,T,H,dv]
+    float32, new state in the state's dtype). T is padded up to a whole
+    number of blocks with positions that are not valid."""
     f32 = jnp.float32
     b, t = q.shape[:2]
     q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    if g.ndim == beta.ndim:
+        g = g[..., None]  # one decay a head, over every channel
     if valid is not None:
         g = jnp.where(valid[:, :, None, None], g, 0.0)
         beta = jnp.where(valid[:, :, None], beta, 0.0)
@@ -122,6 +137,25 @@ def kda_chunk(q, k, v, g, beta, state, valid: Optional[jax.Array] = None):
     )
     o = jnp.moveaxis(o, 0, 1).reshape(b, n * BLOCK, *o.shape[3:])
     return o[:, :t], s.astype(state.dtype)
+
+
+def unit_qk(q, k, eps: float = 1e-6):
+    """q and k [.., d_k] float32, each L2-normalised over its head's
+    channels, q scaled by d_k ** -0.5: what both delta-rule layers feed
+    the rule."""
+    unit = lambda a: a * jax.lax.rsqrt(
+        jnp.sum(a * a, axis=-1, keepdims=True) + eps
+    )
+    return unit(q) * q.shape[-1] ** -0.5, unit(k)
+
+
+def decay_rate(a_log, per_channel: bool):
+    """-exp(A_log), float32: what multiplies softplus(its input + bias)
+    into g <= 0. [H] for one decay a head, [H, 1] over a head's channels.
+    (A function of ``a_log`` alone, so that a caller's own operations
+    keep their order around it.)"""
+    rate = jnp.exp(a_log)
+    return -(rate[:, None] if per_channel else rate)
 
 
 def causal_conv(x, weight, tail, valid: Optional[jax.Array] = None):

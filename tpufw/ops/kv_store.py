@@ -172,6 +172,10 @@ _LEAVES: Dict[str, Role] = {
     # SSMMixer, beside the same ``conv_state``): there EVERY layer holds
     # it beside a page pair.
     "ssm_state": Role(STATE, 4),
+    # Gated DeltaNet's [B, H, d_k, d_v] state (tpufw.models.olmo_hybrid
+    # GatedDeltaNetLayer, beside the same ``conv_state``), in three of a
+    # period's four layers; the fourth holds a page pair.
+    "gdn_state": Role(STATE, 4),
     # A window layer's RING (``ring_append``): the last ``window`` keys
     # and values of each row, the logical slot and the segment id of
     # each ring slot. Per-slot like STATE, and declined where STATE is.
@@ -350,18 +354,23 @@ def _head(x: jax.Array, n: int) -> jax.Array:
     return x if n == x.shape[1] else x[:, :n]
 
 
-def _view(arenas, scales, ids, table, sel, *, length, page, dtype):
+def _view(arenas, scales, ids, table, sel, *, length, page, dtype, heads=None):
     """(views, kv_segment_ids) of the first ``length`` logical slots of
     the rows ``sel`` (None: every row, in place): ``arenas`` maps PAGE
     leaf names to their values, ``ids`` is the SEGMENT leaf's, ``table``
     the page table's (paged: ``page`` > 0) and ``scales`` the int8
-    arenas' scales by name (empty: not quantized)."""
+    arenas' scales by name (empty: not quantized). ``heads``: the
+    model's heads where a slot holds more (``_stored_heads``); the view
+    drops the rest."""
     def of(a):
         a = _head(a, length // page if page else length)
         return a if sel is None else a[sel]
 
+    def shown(v):
+        return v if heads is None else v[:, :, :heads]
+
     if not page:
-        return {n: of(a) for n, a in arenas.items()}, of(ids)
+        return {n: shown(of(a)) for n, a in arenas.items()}, of(ids)
     rows = of(table)
     shape = (rows.shape[0], length)
     views = {}
@@ -369,16 +378,41 @@ def _view(arenas, scales, ids, table, sel, *, length, page, dtype):
         pages = arena[rows]
         if scales:
             pages = dequantize_kv(pages, scales[n][rows], dtype)
-        views[n] = pages.reshape(shape + arena.shape[2:])
+        views[n] = shown(pages.reshape(shape + arena.shape[2:]))
     return views, ids[rows].reshape(shape)
+
+
+def _stored_heads(cfg, new: Dict[str, jax.Array]):
+    """(``new`` as a slot stores it, the model's head count for the view
+    to cut back to, or None). A family whose config derives
+    ``kv_store_heads`` (its K/V heads rounded up to whole tiles of 8
+    sublanes: tpufw.models.olmo_hybrid) stores ``[B, t, H, hd]`` leaves
+    with zero heads up to that count. The tiling pads 30 heads to 32 in
+    HBM anyway, so the bytes are the same; what goes is a second copy of
+    every arena leaf, which XLA:TPU otherwise makes and undoes in every
+    decode call, keeping the arena in another dimension order inside the
+    step loop than at the program's edge (measured at 30 heads, PERF.md
+    section 7 (ap); Falcon-H1's 4 heads carry no such copy, so "does not
+    fill a tile" is not the whole rule, and no family gets this unasked)."""
+    held = getattr(cfg, "kv_store_heads", None)
+    if not held:
+        return new, None
+    heads = next(iter(new.values())).shape[2]
+    if held == heads:
+        return new, None
+    widen = ((0, 0), (0, 0), (0, held - heads), (0, 0))
+    return {n: jnp.pad(x, widen) for n, x in new.items()}, heads
 
 
 # tpulint: disable=TPU006 — nothing handed in is updated: what comes back
 # is ``attend``'s output, scattered into zeros of the pool's width.
-@partial(jax.jit, static_argnames=("attend", "k", "length", "page", "dtype"))
+@partial(
+    jax.jit,
+    static_argnames=("attend", "k", "length", "page", "dtype", "heads"),
+)
 def _read_rows(
     attend, arenas, scales, ids, table, order, per_row,
-    *, k, length, page, dtype,
+    *, k, length, page, dtype, heads=None,
 ):
     """One branch of a pool's ``read``: ``attend`` over the first
     ``length`` slots of the first ``k`` rows of ``order`` [B], handed
@@ -392,7 +426,7 @@ def _read_rows(
     it)."""
     view = partial(
         _view, arenas, scales, ids, table,
-        length=length, page=page, dtype=dtype,
+        length=length, page=page, dtype=dtype, heads=heads,
     )
     b = order.shape[0]
     if k == b:
@@ -439,6 +473,7 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
     for name, x in new.items():
         if role(name) != Role(PAGE, x.ndim):
             raise ValueError(f"{name!r} rank {x.ndim} is not {role(name)}")
+    new, heads = _stored_heads(cfg, new)
     b, t = next(iter(new.values())).shape[:2]
     s, page = cfg.max_seq_len, getattr(cfg, "kv_page", 0)
     seg = (
@@ -501,7 +536,7 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
     scale_of = {n: v.value for n, v in scales.items()} if quant else {}
 
     state = (arenas, scale_of, ids, idx)
-    how = dict(page=page, dtype=cfg.dtype)
+    how = dict(page=page, dtype=cfg.dtype, heads=heads)
     pool_rows, ladder = pool_ladders(s, page, b)
     if not cur.ndim:
         pool_rows = (b,)

@@ -43,6 +43,10 @@ _PROJ_IN_DIMS = {
     # A state-space mixer's two projections (tpufw.models.falcon_h1
     # SSMMixer), flat [d, z + xBC + dt] and [inner, d].
     "in_proj": 1, "out_proj": 1,
+    # Gated DeltaNet (tpufw.models.olmo_hybrid): the decay's [d, H]
+    # projection; its q/k/v/o, ``gate`` and ``beta`` are shaped like the
+    # names above.
+    "decay": 1,
     # The dedicated LM head ([D, V]) is the largest single matmul a
     # decode step streams; tied (Gemma) embeddings stay fp — the gather
     # and the attend contraction want incompatible scale granularities.
@@ -55,6 +59,7 @@ _PROJ_RANK = {
     "gate": 2, "up": 2, "down": 2,
     "f_a": 2, "f_b": 2, "g_a": 2, "g_b": 2, "beta": 2,
     "in_proj": 2, "out_proj": 2,
+    "decay": 2,
     "lm_head": 2,
 }
 #: Mixtral expert stacks: RAW [E, in, out] arrays (not {kernel} modules)
