@@ -419,7 +419,11 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
         say(f"generator lateness (sent - due): median {statistics.median(late):.3f} ms, "
             f"max {max(late):.3f} ms over {len(late)}")
     say(f"samples: ttft {ws['n_ttft']}, tpot {ws['n_tpot']} (requests with >= 16 tokens in the window): "
-        f"medians are reported, the worst of each beside them; tokens in window {ws['tokens_in_window']}")
+        f"medians are reported, the worst of each beside them")
+    say(f"tokens: the requests due in the window asked for {ws['offered_tokens']} (offered_tokens) and had been sent "
+        f"{ws['tokens_of_due']} of them when it closed (tokens_of_due: tokens_per_s_per_chip is this count over the window's "
+        f"seconds); {ws['tokens_in_window']} tokens of every request, the ramp's too, arrived inside the window "
+        f"(tokens_in_window: the rate's numerator until PR 43, in no metric now)")
     from benchmarks.metrics import _steps
 
     obs = {
@@ -432,7 +436,8 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
     moment, what = _steps.sample_moment(obs)
     say(f"occupancy at {what} ({moment - t0:.2f} s of the window): {rows} of {mix['server_env']['TPUFW_SERVE_SLOTS']} slots decoding, "
         f"{cached} tokens in their caches; backlog {ws['backlog_start']} -> {ws['backlog_end']}")
-    counts = ("attempted", "failed", "tokens_in_window", "n_ttft", "n_tpot", "backlog_start", "backlog_end")
+    counts = ("attempted", "failed", "offered_tokens", "tokens_of_due", "tokens_in_window",
+              "n_ttft", "n_tpot", "backlog_start", "backlog_end")
     say("window " + json.dumps({k: v for k, v in ws.items() if not rehearse or k in counts}))
     compared = {"requests_failed": ws["failed"], "replies_malformed": bad, "compiled_in_window": compiled_in_window}
     for name, value in compared.items():
@@ -501,6 +506,7 @@ def _window(args, bench, cell, config, mix, keys, check, reqs, host, port,
             "attempted": ws["attempted"], "failed": ws["failed"], "metrics": metrics,
             "device": device, "breakdown": breakdown, "replies_ok": bad == 0 and ws["attempted"] > 0,
             "compiled_in_window": compiled_in_window, "digest": digest,
+            **{k: ws[k] for k in ("offered_tokens", "tokens_of_due", "tokens_in_window")},
             "compared": {k: {"value": v, "limit": 0} for k, v in compared.items()},
         }, f)
 

@@ -99,10 +99,24 @@ def window_stats(records, t0: float, seconds: float, cutoff: float, limits: dict
     first token when the client stopped waiting (``cutoff``: the mix's
     ``drain_s`` after the window at the latest), counts as attempted and
     failed and as missing every limit; its TTFT is the time it had waited
-    at cutoff."""
+    at cutoff.
+
+    Three counts of tokens. ``offered_tokens``: what the requests due in
+    the window ask for. ``tokens_of_due``: what those requests had been
+    sent when the window closed, a failed one what it got;
+    ``tokens_per_s_per_chip`` is this one over the window's seconds, so
+    tokens and seconds are of one span: a stall inside the window lowers
+    it, and since each request's count can only grow with the server's
+    speed, a faster server never reads lower. The ramp's requests load the
+    server and count for nothing. ``tokens_in_window``: the chunks of every
+    record, the ramp's too, that arrived inside the window; it was the
+    rate's numerator until PR 43, FALLS where a faster server ends the
+    ramp's answers before the window opens, and is kept as a count so that
+    earlier readings can be compared with a run of today."""
     t1 = t0 + seconds
     due_in = [r for r in records if t0 <= r["due"] < t1]
-    tokens = sum(n for r in records for t, n in r["chunks"] if t0 <= t < t1)
+    tokens_in_window = sum(n for r in records for t, n in r["chunks"] if t0 <= t < t1)
+    tokens_of_due = sum(n for r in due_in for t, n in r["chunks"] if t < t1)
     ttft, good, failed = [], 0, 0
     for r in due_in:
         first = r["chunks"][0][0] if r["chunks"] else None
@@ -133,8 +147,10 @@ def window_stats(records, t0: float, seconds: float, cutoff: float, limits: dict
         "backlog_end": open_at(t1),
         "attempted": len(due_in),
         "failed": failed,
-        "tokens_in_window": tokens,
-        "tokens_per_s_per_chip": tokens / seconds / chips,
+        "offered_tokens": sum(r["max_new"] for r in due_in),
+        "tokens_of_due": tokens_of_due,
+        "tokens_in_window": tokens_in_window,
+        "tokens_per_s_per_chip": tokens_of_due / seconds / chips,
         "n_ttft": len(ttft),
         "n_tpot": len(tpots),
         "late_ms": [(r["sent"] - r["due"]) * 1e3 for r in due_in if r["sent"] is not None],

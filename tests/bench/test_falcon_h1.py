@@ -599,7 +599,7 @@ def test_new_readers_report_nothing_where_there_is_nothing_to_read():
         assert CELL not in per_layer[name]["workloads"], name
     for name in ("attended_keys_share", "state_hbm_share", "first_token_p50_ms", "slo_good_share.tpot"):
         assert CELL in per_layer[name]["workloads"], name
-    assert per_layer["state_live_share"]["workloads"] == ["solar2-longdoc-answers", CELL]
+    assert CELL in per_layer["state_live_share"]["workloads"]  # ``in``: later cells are appended by PRs that may not edit this file
     assert per_layer["state_live_share"]["moves"] == "tpot_p50_ms" and per_layer["state_live_share"]["better"] == "higher"
 
 

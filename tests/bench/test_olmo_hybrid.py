@@ -586,11 +586,10 @@ def test_the_cell_reports_the_state_and_first_token_metrics():
     per_layer = {m["name"]: m for m in harness.load_benchmark()["per_layer"]}
     # ``in``, not a position or a whole list: the next cell is appended to
     # these lists by a PR that may not edit this file.
-    for name in ("state_hbm_share", "attended_keys_share", "first_token_p50_ms", "gen_late_max_ms.tokens", "prefill_dev_ms_per_ktok",
+    for name in ("state_hbm_share", "state_live_share", "attended_keys_share", "first_token_p50_ms", "gen_late_max_ms.tokens", "prefill_dev_ms_per_ktok",
                  "prefill_mfu_share.tpot", "slo_good_share.tpot", "ttft_max_ms.tpot", "join_wait_p50_ms.tpot",
                  "queue_wait_p50_ms.tpot", "prefill_span_p50_ms.tpot"):
         assert CELL in per_layer[name]["workloads"], name
     # Nothing to read here: the cell holds no ``ttft_p50_ms`` end to end and has no window layer.
-    # (``state_live_share`` has something to read and is not pinned either way: PERF.md section 7 (aq).)
     for name in ("slo_good_share", "ttft_max_ms", "prefill_mfu_share", "window_keys_share", "window_hbm_share"):
         assert CELL not in per_layer[name]["workloads"], name

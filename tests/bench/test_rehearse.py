@@ -46,6 +46,8 @@ def test_rehearsal_of_each_cell(cell):
     last = [ln for ln in proc.stderr.splitlines() if ln.strip()][-len(shown):]
     assert [ln.split()[2].split("=")[0] for ln in last] == list(result["compared"])
     assert "self seconds by phase" in proc.stdout and '"serve_wait"' in proc.stdout
+    # The three counts of tokens, as counts (no rate on a CPU): which is which is ``stats.window_stats``'s to say.
+    assert all(f'"{k}": ' in proc.stdout for k in ("offered_tokens", "tokens_of_due", "tokens_in_window"))
     for name in ("tokens_per_s_per_chip", "tpot_p50_ms", "ttft_p50_ms", "setup_s "):
         assert f'"{name}' not in proc.stdout.replace("setup_s ", "")
 

@@ -136,14 +136,89 @@ def test_window_stats_counts_failures_as_misses():
                             t0=10.0, seconds=10.0, cutoff=21.0, limits=limits, chips=1)
     assert ws["attempted"] == 6 and ws["failed"] == 2  # the error, and the one with no token by the cutoff
     assert ws["slo_good_share"] == pytest.approx(100.0 * 2 / 6)  # good and long_prompt
+    # The ramp's 32 tokens inside the window stay in their own count and leave the rate.
     assert ws["tokens_in_window"] == 4 * 25 + 32
-    assert ws["tokens_per_s_per_chip"] == pytest.approx(13.2)
+    assert ws["tokens_of_due"] == 4 * 25 and ws["offered_tokens"] == 6 * 40
+    assert ws["tokens_per_s_per_chip"] == pytest.approx(10.0)
     assert ws["n_ttft"] == 6 and ws["ttft_max_ms"] == pytest.approx(7000.0)  # the failed one waited to the cutoff
     assert ws["ttft_p50_ms"] == pytest.approx(500.0)  # 100, 200, 500, 500, 6000, 7000
     assert ws["n_tpot"] == 5  # four due in the window and the ramp's, inside the window only
     assert ws["tpot_p50_ms"] == pytest.approx(50.0) and ws["tpot_max_ms"] == pytest.approx(125.0)
     assert stats.ttft_limit_ms(limits["ttft_ms"], 513) == 900.0
     assert ws["backlog_start"] == 2 and ws["backlog_end"] == 2  # the ramp's and the one due at t0; the failed and the unanswered
+
+
+@pytest.mark.parametrize("case, records, want", [
+    # Still streaming when the window closes: counted to there, the chunk after it not, however long the client waits.
+    ("cut at the window's close", [rec(12.0, 12.0, [(12.5, 1), (16.0, 8), (19.9, 8), (20.0, 8), (20.9, 8)], status="cut")],
+     {"tokens_of_due": 17, "tokens_in_window": 17, "offered_tokens": 40, "attempted": 1, "failed": 0}),
+    # An error after some tokens: failed, and what it was sent still counts.
+    ("failed with chunks", [rec(12.0, 12.0, [(12.5, 1), (13.0, 8)], status="error")],
+     {"tokens_of_due": 9, "tokens_in_window": 9, "offered_tokens": 40, "attempted": 1, "failed": 1}),
+    # Only the ramp's request is there: its tokens inside the window are no rate of this window.
+    ("no request due", [rec(5.0, 5.0, [(9.0, 8), (10.5, 16), (11.5, 16)])],
+     {"tokens_of_due": 0, "tokens_in_window": 32, "offered_tokens": 0, "attempted": 0, "failed": 0}),
+    # The server stalls from 13 s past the window's close and then sends the rest: whole answer, half the rate.
+    ("a stall inside the window", [rec(12.0, 12.0, [(12.5, 1), (13.0, 19), (20.5, 20)])],
+     {"tokens_of_due": 20, "tokens_in_window": 20, "offered_tokens": 40, "attempted": 1, "failed": 0}),
+])
+def test_window_stats_counts_the_tokens_of_the_requests_due(case, records, want):
+    ws = stats.window_stats(records, t0=10.0, seconds=10.0, cutoff=21.0, limits={}, chips=1)
+    assert {k: ws[k] for k in want} == want, case
+    assert ws["tokens_per_s_per_chip"] == pytest.approx(want["tokens_of_due"] / 10.0)
+
+
+def replayed(name, tpot_ms, first_s=0.15, chunk=8, stall=(0.0, 0.0)):
+    """The mix's own schedule as the records of a server that sends every
+    request its first token ``first_s`` after it is due and ``chunk``
+    tokens every ``chunk x tpot_ms`` from then on, cut where the client
+    stops waiting, through ``window_stats``. ``stall`` = (second of the
+    window, seconds): every chunk due from then on comes that much later."""
+    m = mix(name)
+    t0, seconds = 1000.0, 45.0
+    cutoff = t0 + seconds + m["drain_s"]
+    records = []
+    for r in traffic.schedule(m, 1, seconds, 1000):
+        first = t0 + r.t + first_s
+        sizes = [1] + [min(chunk, r.max_new - 1 - k) for k in range(0, r.max_new - 1, chunk)]
+        chunks = [(first + i * chunk * tpot_ms / 1e3, n) for i, n in enumerate(sizes)]
+        chunks = [(t + stall[1] if t >= t0 + stall[0] else t, n) for t, n in chunks]
+        chunks = [c for c in chunks if c[0] <= cutoff]
+        records.append(rec(t0 + r.t, t0 + r.t, chunks, status="ok" if len(chunks) == len(sizes) else "cut",
+                           n_prompt=len(r.prompt), max_new=r.max_new))
+    return stats.window_stats(records, t0, seconds, cutoff, {}, 1)
+
+
+@pytest.mark.parametrize("slower, faster, fell", [
+    (27.2, 20.0, 0.0), (20.0, 15.0, 0.0), (15.0, 13.2, 0.0), (13.2, 10.0, 0.0),
+    (27.2, 13.2, 0.08),  # PR 42's pair: the driver read -12.9% against a bound of 6%
+])
+def test_a_faster_server_never_reads_fewer_tokens_a_second(slower, faster, fell):
+    """What refused PR 42, kept: ``reason-pool``'s ramp holds answers of
+    2,048, 1,536 and 922 tokens, which a server at 27 ms a token streams
+    far into the window and one at 13 ms ends before it; the chunks inside
+    the window fell with the server's speed, the tokens that the requests
+    due in it had been sent when it closed cannot."""
+    slow, fast = replayed("reason-pool", slower), replayed("reason-pool", faster)
+    assert fast["offered_tokens"] == slow["offered_tokens"] == 11_035
+    assert fast["tokens_per_s_per_chip"] > slow["tokens_per_s_per_chip"]
+    assert fast["tokens_in_window"] < (1.0 - fell) * slow["tokens_in_window"]
+    assert slow["tokens_of_due"] < fast["tokens_of_due"] < fast["offered_tokens"]  # the answer due at 44.9 s has 0.1 s
+
+
+@pytest.mark.parametrize("name, tpot_ms", [
+    ("reason-long", 9.7), ("longprompt-steady", 6.1), ("longdoc-answers", 6.1), ("repo-context", 5.0), ("instruct-burst", 19.7),
+    ("reason-pool", 27.2),
+])
+def test_a_stall_inside_the_window_lowers_the_rate(name, tpot_ms):
+    """Tokens and seconds are of one span. At their cells' TPOT (the
+    ledger's, PR 41) a server that sends nothing from second 20 to second
+    35 of the window reads lower in every mix; one at twice the TPOT
+    never reads higher; and no reading passes the offered load."""
+    sound, stalled, slow = replayed(name, tpot_ms), replayed(name, tpot_ms, stall=(20.0, 15.0)), replayed(name, 2 * tpot_ms)
+    assert 0 < stalled["tokens_of_due"] < sound["tokens_of_due"] <= sound["offered_tokens"]
+    assert slow["tokens_of_due"] <= sound["tokens_of_due"]
+    assert sound["tokens_per_s_per_chip"] == pytest.approx(sound["tokens_of_due"] / 45.0)
 
 
 def test_gap_numbers_leave_out_near_tie_routing():
