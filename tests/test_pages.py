@@ -510,7 +510,7 @@ def test_paged_decode_matches_contiguous_across_two_rungs(family, monkeypatch):
         firsts[i], _ = _admit(pool, i, p, i, max_new=LONG_NEW)
     # A pool of two has one row rung: 2 rows x the key rung, of 2 x LONG_S.
     reads = {
-        pool.attended_keys([(len(long_prompt) + n, 1)])
+        pool.attended_keys([[len(long_prompt) + n]])
         for n in range(1, LONG_NEW)
     }
     assert reads == {(2 * 512, 2 * LONG_S), (2 * LONG_S, 2 * LONG_S)}
@@ -524,10 +524,12 @@ def test_tpu_lowering_gathers_the_whole_row_only_in_the_top_rung(monkeypatch):
     branch of the store's switch: the lower branch gathers half of it
     and nothing outside the switch gathers pages at all. (A pool of two
     has no row rung under its width; tests/test_kv_store.py holds the
-    row ladder's branches.)"""
+    row ladder's branches.) The latent cache's pool: on the chip a K/V
+    pool's decode step has no switch, it reads the arena in place
+    (tests/test_program_text.py holds that program)."""
     import re
 
-    _, _, pool = _long_family("llama", monkeypatch)
+    _, _, pool = _long_family("deepseek", monkeypatch)
     text = (
         slots_mod._decode_steps_jit.trace(
             pool.model, pool.params, pool.cache, pool.token, pool.pos,
@@ -559,7 +561,8 @@ def test_tpu_lowering_gathers_the_whole_row_only_in_the_top_rung(monkeypatch):
     outside = main[:main.index("\n  }\n")].replace(case.group(0), "")
     for n in (per_row // 2, per_row):
         assert not page_gathers(outside, n)
-    # K pages, V pages and their segment ids, at each rung's own length.
+    # Both latent leaves' pages and their segment ids, at each rung's own
+    # length.
     assert len(page_gathers(branches[0], per_row // 2)) == 3
     assert not page_gathers(branches[0], per_row)
     assert len(page_gathers(branches[1], per_row)) == 3

@@ -30,10 +30,11 @@ DIGESTS = os.path.join(ROOT, "tests", "data", "program_digests.json")
 PAGE, SLOTS, WIDTH = 16, 2, 32
 
 
-def program_texts(family: str) -> dict:
+def program_texts(family: str, platforms=None) -> dict:
     """{"decode", "chunk"}: StableHLO text of the paged pool's two-step
     decode program and of a 32-token prefill chunk through the row twin,
-    over abstract bfloat16 parameters."""
+    over abstract bfloat16 parameters; lowered for this backend, or for
+    ``platforms`` (("tpu",): jax's TPU rules, without a TPU)."""
     from flax.linen import meta
 
     from benchmarks import harness
@@ -56,15 +57,19 @@ def program_texts(family: str) -> dict:
         lambda p: pages.paged_pool_cache(paged, p, SLOTS), params))
     vec = lambda dtype: jax.ShapeDtypeStruct((SLOTS,), dtype)
     step_keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), 2))
-    decode = slots._decode_steps_jit.lower(
+    lower = lambda jitted, *a, **k: jitted.trace(*a, **k).lower(
+        lowering_platforms=platforms).as_text()
+    decode = lower(
+        slots._decode_steps_jit,
         paged, params, cache, vec(jnp.int32), vec(jnp.int32), vec(bool),
         vec(jnp.int32), None, step_keys,
         sampling=greedy, pad_id=0, eos_id=None,
-    ).as_text()
+    )
     paths, names, leaves, _ = pages._flatten_with_names(cache)
     row_cache = abstract(pages._row_cache_shapes(row_model, params))
     scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype)
-    chunk = pages._prefill_chunk_jit.lower(
+    chunk = lower(
+        pages._prefill_chunk_jit,
         tuple(leaves), row_cache, params,
         jax.ShapeDtypeStruct((1, WIDTH), jnp.int32),
         jax.ShapeDtypeStruct((WIDTH // PAGE,), jnp.int32),
@@ -74,7 +79,7 @@ def program_texts(family: str) -> dict:
         paths=paths, names=names,
         scale_src=pages.PagedSlotPool._scale_src(paths, names),
         page=PAGE, quant=False,
-    ).as_text()
+    )
     return {"decode": decode, "chunk": chunk}
 
 
@@ -104,6 +109,44 @@ def test_a_family_without_window_layers_lowers_to_the_program_it_was(family):
         f"{family}'s lowered serving programs changed; if that is meant, "
         "record them anew (this file's docstring)"
     )
+
+
+def test_on_the_chip_a_kv_pool_decodes_through_one_kernel_a_layer(monkeypatch):
+    """Lowered for the TPU with the store's kernel rule steered on (here
+    the backend is the CPU and the rehearsal's heads are 16 wide), the
+    Olmo-Hybrid rehearsal pool's decode program calls the Mosaic kernel
+    ONCE an attention layer, no ``lax.switch`` of ladder branches and no gather
+    of the K/V arena ``[n_pages, page, heads, hd]``: the pages are read
+    in place. Its prefill chunk, a row under a scalar cursor, is the
+    ladder's program to the letter."""
+    import re
+
+    from benchmarks import harness
+    from tpufw.ops import paged_attend
+
+    family = "olmo_hybrid"
+    plain = program_texts(family, ("tpu",))
+    monkeypatch.setattr(paged_attend, "serves", lambda *a: True)
+    jax.clear_caches()  # the trace above is this one's to jit, else
+    try:
+        steered = program_texts(family, ("tpu",))
+    except Exception as e:  # noqa: BLE001 — whatever this jax raises
+        pytest.skip(f"this jax cannot lower a Mosaic kernel off the chip: {e!r}")
+    layers = harness.model_keys(
+        harness.load_json(harness.rehearse_path(family)))["layer_types"]
+    full = layers.count("full_attention")
+    arena = re.compile(r"stablehlo\.gather.*: \(tensor<\d+x%dx\d+x\d+xbf16>" % PAGE)
+    # The kernel is a jitted function of its own, lowered once for the
+    # layers' one shape and called from each.
+    calls = lambda text: len(re.findall(r"call @paged_attention\w*\(", text))
+    assert (calls(plain["decode"]), calls(steered["decode"])) == (0, full)
+    assert "tpu_custom_call" in steered["decode"]
+    assert "tpu_custom_call" not in plain["decode"]
+    assert len(arena.findall(plain["decode"])) == 2 * full
+    assert not arena.findall(steered["decode"])
+    assert "stablehlo.case" not in steered["decode"]
+    assert steered["chunk"] == plain["chunk"]
+    jax.clear_caches()  # nor is the steered trace a later test's
 
 
 if __name__ == "__main__":

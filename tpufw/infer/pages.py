@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import math
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -68,7 +67,7 @@ from tpufw.infer.slots import (
 )
 from tpufw.obs import trace as obs_trace
 from tpufw.ops.kv_store import (
-    CURSOR, PAGE, SCALE, SEGMENT, TABLE, attended_pair, ring_keys, role,
+    CURSOR, PAGE, SCALE, SEGMENT, TABLE, attended_slots, ring_keys, role,
 )
 from tpufw.ops.kv_store import leaf_name as _leaf_name
 from tpufw.ops.quant import dequantize_kv, quantize_kv
@@ -764,20 +763,23 @@ class PagedSlotPool(SlotPool):
             jax.jit(row_zeros, out_shardings=self.home).lower().compile()
         )
 
-    def attended_keys(self, calls, chunk: bool = False) -> Tuple[int, int]:
-        """(key slots gathered, key slots of the whole rows) summed over
-        cached calls, each ``(slots of its longest live row, live
-        rows)``: decode steps of the pool's model, every slot a row, or,
-        with ``chunk``, prefill chunks of the row twin, one row under a
-        scalar cursor. Rows x slots a call: K x L read, the rungs of the
-        store's two ladders, of B x ``max_seq_len``. The store's own
-        rules, so the host's count names the branch each program took."""
+    def attended_keys(
+        self, calls, chunk: bool = False, width: int = 1
+    ) -> Tuple[int, int]:
+        """(key slots read, key slots of the whole rows) summed over
+        cached calls of ``width`` tokens a row, each the slots its live
+        rows hold, the call's own tokens included (none: no row was
+        live): decode steps and verify blocks of the pool's model, every
+        slot a row, or, with ``chunk``, prefill chunks of the row twin,
+        one row under a scalar cursor. The store's own rule
+        (``kv_store.attended_slots``), so the host's count is what each
+        program read: every live row's own pages where the step reads
+        the arena in place, else K x L, the rungs of the store's two
+        ladders; of B x ``max_seq_len``."""
         cfg = (self.row_model if chunk else self.model).cfg
         b = 1 if chunk else self.n_slots
-        read = [
-            math.prod(attended_pair(cfg, b, rows, slots))
-            for slots, rows in calls
-        ]
+        leaves = () if chunk else self.page_leaves
+        read = [attended_slots(cfg, leaves, b, lens, width) for lens in calls]
         return sum(read), len(read) * b * int(cfg.max_seq_len)
 
     def window_keys(self, calls: int, t: int = 1) -> Tuple[int, int]:

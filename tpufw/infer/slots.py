@@ -41,7 +41,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 from tpufw.infer.generate import _model_apply, _stream_prefill
 from tpufw.infer.sampling import SamplingConfig, sample_token
 from tpufw.ops.kv_store import (
-    DECLINES, RING, STATE, STATE_LEAVES, Decline, path_role, ring_layers,
+    DECLINES, PAGE, RING, STATE, STATE_LEAVES, Decline, leaf_name, path_role,
+    ring_layers,
 )
 
 # Bumped INSIDE the jitted bodies, i.e. once per (re)trace, never per
@@ -337,6 +338,13 @@ class SlotPool:
         #: holds: window layers x pool slots x window.
         self.ring_shape = ring_layers(self.cache)
         self.window_slots = self.ring_shape[0] * self.n_slots * self.ring_shape[1]
+        #: The PAGE leaves a cached call appends to, by name: what the
+        #: store chooses its read by (``kv_store.in_place``).
+        self.page_leaves = frozenset(
+            leaf_name(path)
+            for path, _ in jax.tree_util.tree_leaves_with_path(self.cache)
+            if path_role(path).kind == PAGE
+        )
 
     @classmethod
     def create(

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import ad_checkpoint
 from flax import linen as nn
 
-from tpufw.ops import kv_store, multi_head_attention, rms_norm
+from tpufw.ops import kv_store, multi_head_attention, paged_attend, rms_norm
 
 Dtype = Any
 
@@ -759,6 +759,32 @@ class _AttendHeads:
             sliding_window=self.window,
             backend="xla",
         )
+
+    @property
+    def paged(self):
+        """The same contraction over the rows' pages IN PLACE, for a
+        call of one token a row (``kv_store.append``'s ``read`` asks):
+        None with a window, whose mask the kernel does not carry."""
+        return None if self.window is not None else self._paged
+
+    def _paged(self, arenas, kv_seg, table, lens, rows, *, heads=None):
+        """``arenas`` the K and V page arenas ``[n_pages, page, K, hd]``
+        as stored (the first ``heads`` of K the model's: None, all),
+        ``kv_seg`` [B, S] the logical slots' segment ids, ``table`` the
+        page table, ``lens`` [B] the slots each row attends (0: not
+        live), ``rows`` the ``per_row`` of ``__call__`` at t == 1."""
+        q, seg, _ = rows
+        out = paged_attend.paged_attention(
+            q[:, 0],
+            arenas["cached_key"],
+            arenas["cached_value"],
+            table,
+            lens,
+            kv_seg == seg,
+            kv_heads=heads,
+            logits_soft_cap=self.soft_cap,
+        )
+        return out[:, None]
 
 
 class MLP(nn.Module):

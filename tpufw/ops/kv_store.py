@@ -61,7 +61,28 @@ cursor's rank, ``cfg.kv_quant``):
   carries it, a prefix of pages does not determine it and a verify
   block cannot un-write it (``Role.per_slot``).
 
-THE LIVE PREFIX. A cached call reads the first L slots of the row, not
+WHO READS HOW. A pool's DECODE STEP over K/V heads reads the arena IN
+PLACE (``in_place``: ``[B]`` cursors, one token a row, a paged arena that
+is not int8, an ``attend`` that offers a paged form, on the chip): one
+Pallas kernel (tpufw.ops.paged_attend) walks each live row's OWN pages
+through its table row as far as its own cursor, a row that is not live
+reads nothing, and nothing is gathered or copied, so neither ladder
+below has a say. Every other cached call reads through the two ladders,
+and why each stays there: a scalar cursor (``generate``, every prefill
+chunk's row twin) is a contiguous row of many query tokens, where a
+static slice is already in place and the chunk programs are the
+heaviest to trace; a call of t > 1 tokens under ``[B]`` cursors (the
+speculative verify block) wants a kernel with a query-block axis; the
+contiguous pool is the paged one's reference in the tests; an int8 arena
+wants its scales dequantised in the kernel; the latent cache's absorbed
+contraction (tpufw.models.deepseek) is another kernel; a window masked
+over the arena is not in this kernel's mask (rings are ``ring_append``'s
+and have no ladder); and off the chip the ladder read is what runs, the
+kernel's reference, so that the suite does not step every pool through
+the Pallas interpreter. The ladders go when those have kernels (D12).
+
+THE LIVE PREFIX (the ladders' calls). A cached call reads the first L
+slots of the row, not
 all ``max_seq_len``: L is the rows' live length rounded up to a rung of
 ``key_ladder`` (static lengths: S/8, S/4, S/2, S, none under 2048 slots,
 each a whole number of pages), and the rung is chosen INSIDE the program
@@ -76,9 +97,14 @@ front. Slots past L are exactly those the causal mask fills with -1e30,
 whose weights underflow to an exact 0.0: the same mathematics at the
 same precision. A ladder of one rung (``max_seq_len`` < 4096) is the
 program without a switch. ``attended_pair`` is the same rule for the
-host, which counts what the device read (tpufw.workloads.serve).
+host, which counts what the device read (tpufw.workloads.serve:
+``attended_slots``, which books a call read in place at each live row's
+own pages).
 
-THE LIVE ROWS. Under ``[B]`` cursors a cached call reads K rows of the
+THE LIVE ROWS (the ladders' calls under ``[B]`` cursors: since the
+decode step of a K/V pool reads in place, the latent cache's decode step,
+verify blocks, int8 arenas and every pool off the chip). Under ``[B]``
+cursors a cached call reads K rows of the
 pool, not all B: a row is live in a call iff its tokens carry a segment
 id > 0 (the rule the live length is computed from), the rows are ordered
 live first, stably, and K is the shortest rung of ``row_ladder`` (B/8
@@ -120,6 +146,7 @@ from typing import Callable, Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from tpufw.ops import paged_attend
 from tpufw.ops.quant import dequantize_kv, quantize_kv
 
 PAGE, SCALE, SEGMENT, TABLE, CURSOR, STATE, RING = (
@@ -281,7 +308,9 @@ def key_rung(ladder: Tuple[int, ...], live):
 
 
 #: The row rungs under the whole pool, as shifts of B: B/8 alone, which
-#: ``branch_pairs`` reads at the whole row alone. Every branch is one
+#: ``branch_pairs`` reads at the whole row alone. They serve the pools
+#: whose decode step the kernel does not (``in_place``): the latent cache
+#: (dsv2l: the measurements below), int8 arenas, verify blocks. Every branch is one
 #: more copy of every attention layer in each of a pool's decode
 #: programs (one a chunk length: five in a 16-step pool), and a warm
 #: start loads them all. Measured on the chip (PR 38, DeepSeek-V2-Lite's
@@ -323,7 +352,10 @@ def branch_index(rows, keys, live_rows, live):
 
 
 def pool_ladders(max_seq_len: int, page: int, n_rows: int):
-    """(row ladder, key ladder) of a cached call under ``[B]`` cursors.
+    """(row ladder, key ladder) of a cached call under ``[B]`` cursors
+    that reads through the ladders (not ``in_place``: there the kernel
+    reads each row by its own length, and Falcon-H1's pool, measured
+    below, is served by it on the chip).
     A row of ONE key rung keeps one row rung too: its call has no switch,
     and putting one around the attention costs more than an eighth of so
     short a row saves (measured, PR 38: Falcon-H1's 32 slots x 2,048,
@@ -347,6 +379,50 @@ def attended_pair(cfg, n_rows: int, live_rows: int, live: int):
     return branch_pairs(rows, keys)[
         branch_index(rows, keys, int(live_rows), int(live))
     ]
+
+
+def in_place(cfg, leaves, width: int = 1) -> bool:
+    """Whether a pool's cached call of ``width`` tokens a row reads the
+    arena IN PLACE (tpufw.ops.paged_attend) where its ``attend`` offers
+    that, and not through the ladders: from what the store can observe
+    and nothing a caller sets. ``leaves`` are the PAGE leaves the call
+    appends to (the host: the names in the pool's cache). One token a
+    row; K/V heads in a paged arena that is not int8; no window over the
+    arena (rings are ``ring_append``'s; a model that masks a window on
+    its arena keeps the ladder in every layer, so that the host's count
+    is one rule a call); and the chip, at the kernel's widths
+    (``paged_attend.serves``): off it the ladder read runs."""
+    page = getattr(cfg, "kv_page", 0)
+    if width != 1 or not page or cfg.kv_quant == "int8":
+        return False
+    if set(leaves) != {"cached_key", "cached_value"}:
+        return False
+    if getattr(cfg, "sliding_window", None) is not None and not getattr(
+        cfg, "window_ring", False
+    ):
+        return False
+    stored = getattr(cfg, "kv_store_heads", None) or getattr(
+        cfg, "n_kv_heads", 0
+    )
+    head_dim = getattr(cfg, "head_dim", 0)
+    return bool(stored and head_dim) and paged_attend.serves(
+        head_dim, page, stored, cfg.dtype
+    )
+
+
+def attended_slots(cfg, leaves, n_rows: int, lens, width: int = 1) -> int:
+    """Key slots a cached call of ``cfg``'s model reads of a pool of
+    ``n_rows`` whose live rows hold ``lens`` slots each, this call's
+    tokens included: each live row's own pages where the call reads in
+    place (``in_place``), else the pair of ``attended_pair``: the rule
+    ``append``'s ``read`` follows, for the host's count."""
+    lens = [int(n) for n in lens]
+    if in_place(cfg, leaves, width):
+        page = cfg.kv_page
+        return sum(-(-n // page) * page for n in lens)
+    return math.prod(
+        attended_pair(cfg, n_rows, len(lens), max(lens, default=0))
+    )
 
 
 def _head(x: jax.Array, n: int) -> jax.Array:
@@ -442,7 +518,9 @@ def _read_rows(
 
 def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
     """Append this call's tokens at the cache cursor; hand back how to
-    read the cache under the live-prefix bound.
+    read the cache: in place where ``in_place`` says so and ``attend``
+    offers ``attend.paged`` (module docstring, WHO READS HOW), else under
+    the live-prefix bound, as follows.
 
     Called from inside flax ``module``. ``new`` maps PAGE leaf names to
     ``[B, t, *feat]``; ``segment_ids`` [B, t] (None: all 1) are the
@@ -541,7 +619,20 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
     if not cur.ndim:
         pool_rows = (b,)
 
+    offer = cur.ndim == 1 and in_place(cfg, new, t)
+
     def read(attend: Callable, per_row=()):
+        paged = getattr(attend, "paged", None) if offer else None
+        if paged is not None:
+            # One kernel over each live row's own pages, by its own
+            # length (tpufw.ops.paged_attend): no rung, no gather.
+            alive = jnp.any(seg > 0, axis=1)
+            return paged(
+                arenas, ids[idx].reshape(b, s), idx,
+                jnp.where(alive, q_slots[:, -1] + 1, 0), per_row,
+                heads=heads,
+            )
+
         def whole(length: int):
             return attend(*_view(*state, None, length=length, **how), per_row)
 

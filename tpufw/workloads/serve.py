@@ -2001,22 +2001,25 @@ class _SlotScheduler:
             )
 
     def _count_keys(self, calls, chunk: bool = False, width: int = 1) -> None:
-        """Book the key slots the device gathered in dispatched cached
+        """Book the key slots the device read in dispatched cached
         calls of ``width`` tokens a row: decode steps and verify blocks,
         every slot of the pool a row, or (``chunk``) prefill chunks of
-        one row. ``calls`` yields, per call, the slots of its longest
-        live row with the call's own tokens (0: no row was live) and
-        how many rows were live. ``attended_key_slots_total`` grows by
-        K x L a call, the rows and the key slots of each the store read
-        (the pool names both rungs, by the rules its programs choose
-        them with), beside ``row_key_slots_total``'s B x ``max_seq_len``:
-        the share of the pool's key slots the device gathered. Layers
-        that keep a ring of their window are booked apart (x layers:
-        they read every row's ring whatever the rows hold)."""
+        one row. ``calls`` yields, per call, the slots each of its live
+        rows holds with the call's own tokens (none: no row was live).
+        ``attended_key_slots_total`` grows by what the store read of
+        them (the pool names it, by the rule its programs choose their
+        read with: each live row's own pages where a step reads the
+        arena in place, else K x L, the rows and the key slots of each
+        the two ladders' rungs hold), beside ``row_key_slots_total``'s B
+        x ``max_seq_len``: the share of the pool's key slots the device
+        read. Layers that keep a ring of their window are booked apart
+        (x layers: they read every row's ring whatever the rows hold)."""
         if self._metrics is None or not self.page:
             return
         calls = list(calls)
-        read, whole = self._pool.attended_keys(calls, chunk=chunk)
+        read, whole = self._pool.attended_keys(
+            calls, chunk=chunk, width=width
+        )
         self._metrics.inc("attended_key_slots_total", read)
         self._metrics.inc("row_key_slots_total", whole)
         rows = 1 if chunk else self.n_slots
@@ -2606,7 +2609,7 @@ class _SlotScheduler:
                 slot: list(self._pool.slot_pages[slot])
                 for slot, _ in active
             }
-        key_rung, row_rung = self._rungs(active, k)
+        key_rung, row_rung = self._rungs(active, k, width=k + 1)
         chunk_t0 = time.perf_counter()
         with self._tracer.span(
             "serve_spec_chunk", k=k, rows=len(active),
@@ -2656,7 +2659,7 @@ class _SlotScheduler:
         accept_frac = 0.0
         # One verify call of k + 1 tokens a row, every active row live.
         self._count_keys(
-            [(max(_row_keys(job) + k for _, job in active), len(active))]
+            [[_row_keys(job) + k for _, job in active]], width=k + 1
         )
         self._count_state(self.n_slots, len(active))
         for slot, job in active:
@@ -2780,7 +2783,7 @@ class _SlotScheduler:
                 continue
             progressed = True
             with self._tracer.span("serve_emit", slot=slot):
-                self._count_keys([(live, 1)], chunk=True, width=width)
+                self._count_keys([[live]], chunk=True, width=width)
                 self._count_state(1, 1)
                 if self._metrics is not None:
                     self._metrics.registry.counter(
@@ -3035,21 +3038,23 @@ class _SlotScheduler:
             j is not None and j.cp is not None for j in self._slots
         )
 
-    def _rungs(self, active, extra: int = 0):
+    def _rungs(self, active, extra: int = 0, width: int = 1):
         """(key rung, row rung) the pool's next cached call reads, for
         the decode span's arguments: the pair ``kv_store.attended_pair``
         names (``_count_keys`` books the same, step by step, once the
         chunk's tokens are known) at the chunk's first step, every
         active row live and the longest holding its next token (and
-        ``extra`` more: a verify block)."""
-        from tpufw.ops.kv_store import attended_pair
+        ``extra`` more: a verify block of ``width``, or the steps of the
+        chunk still in flight). Where the step reads the arena
+        in place there is no rung: the longest row's own pages, and the
+        rows that are live."""
+        from tpufw.ops.kv_store import attended_pair, in_place
 
-        rows, keys = attended_pair(
-            self._pool.model.cfg,
-            self.n_slots,
-            len(active),
-            max(_row_keys(job) for _, job in active) + extra,
-        )
+        cfg = self._pool.model.cfg
+        longest = max(_row_keys(job) for _, job in active) + extra
+        if in_place(cfg, self._pool.page_leaves, width):
+            return -(-longest // self.page) * self.page, len(active)
+        rows, keys = attended_pair(cfg, self.n_slots, len(active), longest)
         return keys, rows
 
     def _emit_chunk(
@@ -3105,7 +3110,7 @@ class _SlotScheduler:
         # A row is live at step i while it still delivers a token there:
         # the program's own ``done`` mask, read back from what it emitted.
         steps = ([at + i for at, n in spans if i < n] for i in range(k))
-        self._count_keys((max(at, default=0), len(at)) for at in steps)
+        self._count_keys(steps)
         self._count_state(self.n_slots * k, sum(n for _, n in spans))
         if self._metrics is not None:
             self._metrics.inc("tokens_generated_total", live_tokens)
