@@ -1,11 +1,11 @@
 """A fault in a family's per-slot recurrent state (Solar-Open2's linear
-attention, Falcon-H1's state-space mixer, Olmo-Hybrid's Gated DeltaNet:
-the family is the workload's),
+attention, Falcon-H1's state-space mixer, Olmo-Hybrid's Gated DeltaNet,
+Phi-4-mini-flash's Mamba-1 scan: the family is the workload's),
 put through the benchmark's own harness, which has to call the run not
 ``correct``:
 
     python scripts/solar_state_fault.py --fault zero_carry|bf16_state -- \\
-        --workload solar2-longdoc-answers|falconh1-instruct-burst|olmoh-reason-pool --seed <n> --seconds 45 --trace 0 [--rehearse-cpu]
+        --workload solar2-longdoc-answers|falconh1-instruct-burst|olmoh-reason-pool|phi4flash-reason-longctx --seed <n> --seconds 45 --trace 0 [--rehearse-cpu]
 
 Everything after ``--`` is ``benchmarks/run.py``'s own command line, and
 the launcher, the phases, the load, the reference check and the limits
@@ -39,6 +39,7 @@ STATEFUL = {
     "solar_open2": ("tpufw.models.solar_open2", "kda_chunk", 5, "KDA_STATE_DTYPE"),
     "falcon_h1": ("tpufw.models.falcon_h1", "ssd_chunk", 6, "SSM_STATE_DTYPE"),
     "olmo_hybrid": ("tpufw.models.olmo_hybrid", "kda_chunk", 5, "GDN_STATE_DTYPE"),
+    "phi4flash": ("tpufw.models.phi4flash", "selective_chunk", 6, "MAMBA_STATE_DTYPE"),
 }
 
 

@@ -38,6 +38,9 @@ class Case:
 CASES = {
     "g1_30_heads_stored_32": Case(30, 30, stored=32, block_rows=512),
     "g4": Case(8, 2),
+    # tpufw.models.phi4flash: 40 zero-padded query heads over 10 K/V
+    # pairs, a page of 16 stored heads (six of zeros).
+    "g4_10_pairs_stored_16": Case(40, 10, stored=16, block_rows=256),
     "g5": Case(20, 4, block_rows=128),
     "g9": Case(18, 2),
     "soft_cap": Case(8, 2, soft_cap=3.0),

@@ -111,20 +111,34 @@ def test_a_family_without_window_layers_lowers_to_the_program_it_was(family):
     )
 
 
-def test_on_the_chip_a_kv_pool_decodes_through_one_kernel_a_layer(monkeypatch):
+def _arena_readers(family: str) -> int:
+    """Layers of the family's rehearsal model that read a page pair in a
+    decode step: its full-attention layers, or, where later layers attend
+    pages an earlier one wrote, the writer and every such reader."""
+    from benchmarks import costs, harness
+
+    keys = harness.model_keys(harness.load_json(harness.rehearse_path(family)))
+    if "layer_types" in keys:
+        return keys["layer_types"].count("full_attention")
+    return costs.of(family).readers(keys)
+
+
+@pytest.mark.parametrize("family", ["olmo_hybrid", "phi4flash"])
+def test_on_the_chip_a_kv_pool_decodes_through_one_kernel_a_layer(family, monkeypatch):
     """Lowered for the TPU with the store's kernel rule steered on (here
     the backend is the CPU and the rehearsal's heads are 16 wide), the
-    Olmo-Hybrid rehearsal pool's decode program calls the Mosaic kernel
-    ONCE an attention layer, no ``lax.switch`` of ladder branches and no gather
+    rehearsal pool's decode program calls the Mosaic kernel ONCE a layer
+    that reads the arena (Olmo-Hybrid: its full-attention layers;
+    Phi-4-mini-flash: the layer that writes the one page pair AND each
+    cross layer that reads it with its own queries), no ``lax.switch`` of
+    ladder branches and no gather
     of the K/V arena ``[n_pages, page, heads, hd]``: the pages are read
     in place. Its prefill chunk, a row under a scalar cursor, is the
     ladder's program to the letter."""
     import re
 
-    from benchmarks import harness
     from tpufw.ops import paged_attend
 
-    family = "olmo_hybrid"
     plain = program_texts(family, ("tpu",))
     monkeypatch.setattr(paged_attend, "serves", lambda *a: True)
     jax.clear_caches()  # the trace above is this one's to jit, else
@@ -132,9 +146,7 @@ def test_on_the_chip_a_kv_pool_decodes_through_one_kernel_a_layer(monkeypatch):
         steered = program_texts(family, ("tpu",))
     except Exception as e:  # noqa: BLE001 — whatever this jax raises
         pytest.skip(f"this jax cannot lower a Mosaic kernel off the chip: {e!r}")
-    layers = harness.model_keys(
-        harness.load_json(harness.rehearse_path(family)))["layer_types"]
-    full = layers.count("full_attention")
+    full = _arena_readers(family)
     arena = re.compile(r"stablehlo\.gather.*: \(tensor<\d+x%dx\d+x\d+xbf16>" % PAGE)
     # The kernel is a jitted function of its own, lowered once for the
     # layers' one shape and called from each.

@@ -42,6 +42,7 @@ from tpufw.infer.generate import _model_apply, _stream_prefill
 from tpufw.infer.sampling import SamplingConfig, sample_token
 from tpufw.ops.kv_store import (
     DECLINES, PAGE, RING, STATE, STATE_LEAVES, Decline, leaf_name, path_role,
+    page_readers,
     ring_layers,
 )
 
@@ -338,6 +339,9 @@ class SlotPool:
         #: holds: window layers x pool slots x window.
         self.ring_shape = ring_layers(self.cache)
         self.window_slots = self.ring_shape[0] * self.n_slots * self.ring_shape[1]
+        #: Layers that read each page pair in a cached call, its writer
+        #: included (``kv_store.page_readers``): 1 for most models.
+        self.page_readers = page_readers(self.model.cfg)
         #: The PAGE leaves a cached call appends to, by name: what the
         #: store chooses its read by (``kv_store.in_place``).
         self.page_leaves = frozenset(

@@ -36,6 +36,11 @@ from tpufw.models.olmo_hybrid import (  # noqa: F401
     OlmoHybrid,
     OlmoHybridConfig,
 )
+from tpufw.models.phi4flash import (  # noqa: F401
+    PHI4FLASH_CONFIGS,
+    Phi4Flash,
+    Phi4FlashConfig,
+)
 from tpufw.models.resnet import ResNet, ResNetConfig, resnet50  # noqa: F401
 from tpufw.models.solar_open2 import (  # noqa: F401
     SOLAR_OPEN2_CONFIGS,
@@ -78,6 +83,8 @@ def model_for_config(cfg):
         return FalconH1(cfg)
     if isinstance(cfg, OlmoHybridConfig):  # likewise
         return OlmoHybrid(cfg)
+    if isinstance(cfg, Phi4FlashConfig):  # likewise
+        return Phi4Flash(cfg)
     if isinstance(cfg, MixtralConfig):
         return Mixtral(cfg)
     if isinstance(cfg, GemmaConfig):

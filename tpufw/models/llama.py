@@ -698,42 +698,60 @@ class Attention(nn.Module):
         logical row, the rung of the store's ladder that holds every live
         row, for the pool's live rows (tpufw.ops.kv_store: layouts, both
         bounds, masking and clamp rationale)."""
-        cfg = self.cfg
-        soft_cap = getattr(cfg, "attn_logit_soft_cap", None)
-        if self.window is not None and getattr(cfg, "window_ring", False):
-            # A family whose window is a small part of its context in
-            # most layers (its config's constant ``window_ring``) keeps
-            # a RING of the last ``window`` keys per row in place of a
-            # row of max_seq_len it masks. The pools then share no
-            # prefix page, export no slot and verify no block (kv_store
-            # ``Role.per_slot``), which is why the one-window-everywhere
-            # presets (Mistral: 4096 of 32k) keep the mask: they would
-            # lose those for a cache 8x smaller. The ring is not in slot
-            # order: the mask reads each key's own logical slot
-            # (``kv_slots``).
-            read, seg, q_slots = kv_store.ring_append(
-                self, cfg, {"ring_key": k, "ring_value": v}, segment_ids,
-                self.window,
-            )
-            return read(
-                lambda views, kv_seg, kv_slots: multi_head_attention(
-                    q,
-                    views["ring_key"],
-                    views["ring_value"],
-                    causal=True,
-                    segment_ids=seg,
-                    kv_segment_ids=kv_seg,
-                    q_positions=q_slots,
-                    kv_positions=kv_slots,
-                    logits_soft_cap=soft_cap,
-                    sliding_window=self.window,
-                    backend="xla",
-                )
-            )
-        read, seg, q_slots = kv_store.append(
-            self, cfg, {"cached_key": k, "cached_value": v}, segment_ids
+        return cached_attention(
+            self, self.cfg, q, k, v, segment_ids, self.window,
+            getattr(self.cfg, "attn_logit_soft_cap", None),
+        )[0]
+
+
+def cached_attention(
+    module, cfg, q, k, v, segment_ids, window=None, soft_cap=None
+):
+    """``Attention``'s cached step, from inside flax ``module`` (which
+    declares the cache leaves). Returns ``(out, reader)``: ``reader(q2)``
+    attends other queries, at the slots this call's sat at, over the same
+    cache, for a later layer that stores nothing of its own
+    (tpufw.ops.kv_store, READERS THAT ARE NOT THE WRITER); None for a
+    ring, which only its own layer reads."""
+    if window is not None and getattr(cfg, "window_ring", False):
+        # A family whose window is a small part of its context in
+        # most layers (its config's constant ``window_ring``) keeps
+        # a RING of the last ``window`` keys per row in place of a
+        # row of max_seq_len it masks. The pools then share no
+        # prefix page, export no slot and verify no block (kv_store
+        # ``Role.per_slot``), which is why the one-window-everywhere
+        # presets (Mistral: 4096 of 32k) keep the mask: they would
+        # lose those for a cache 8x smaller. The ring is not in slot
+        # order: the mask reads each key's own logical slot
+        # (``kv_slots``).
+        read, seg, q_slots = kv_store.ring_append(
+            module, cfg, {"ring_key": k, "ring_value": v}, segment_ids,
+            window,
         )
-        return read(_AttendHeads(soft_cap, self.window), (q, seg, q_slots))
+        return read(
+            lambda views, kv_seg, kv_slots: multi_head_attention(
+                q,
+                views["ring_key"],
+                views["ring_value"],
+                causal=True,
+                segment_ids=seg,
+                kv_segment_ids=kv_seg,
+                q_positions=q_slots,
+                kv_positions=kv_slots,
+                logits_soft_cap=soft_cap,
+                sliding_window=window,
+                backend="xla",
+            )
+        ), None
+    read, seg, q_slots = kv_store.append(
+        module, cfg, {"cached_key": k, "cached_value": v}, segment_ids
+    )
+    attend = _AttendHeads(soft_cap, window)
+
+    def reader(q2):
+        return read(attend, (q2, seg, q_slots))
+
+    return reader(q), reader
 
 
 @dataclasses.dataclass(frozen=True)
@@ -856,8 +874,9 @@ def unstack_layer_params(params: dict, donate: bool = False) -> dict:
     (``dataclasses.replace(cfg, scan_layers=False)``), which skips the
     per-step per-layer weight slicing of the decode scan. Works for
     every decoder_lm family (Llama/Qwen/Mistral/Mixtral/Deepseek and
-    Gemma, whose scanned unit is a PAIR). A tree with no "layers" key
-    (already unscanned) is returned unchanged.
+    Gemma, whose scanned unit is a PAIR), and for a trunk of several
+    stacks, each under a key that ends in "_layers". A tree with no such
+    key (already unscanned) is returned unchanged.
 
     With ``donate=True`` each stacked leaf is explicitly DELETED once
     its per-layer slices exist, so peak device memory is the weights
@@ -869,24 +888,29 @@ def unstack_layer_params(params: dict, donate: bool = False) -> dict:
     the input tree's "layers" leaves are INVALID afterwards — only
     enable when the caller drops the old tree immediately (the serve
     paths do); the default keeps the input usable."""
-    if "layers" not in params:
+    stacks = [k for k in params if k == "layers" or k.endswith("_layers")]
+    if not stacks:
         return params
-    leaves, treedef = jax.tree_util.tree_flatten(params["layers"])
-    n = leaves[0].shape[0]
-    split = jax.jit(lambda a: tuple(a[i] for i in range(n)))
-    per_leaf = []
-    for leaf in leaves:
-        out = split(leaf)
-        if donate and isinstance(leaf, jax.Array):
-            # The slices must exist on device before the source dies.
-            jax.block_until_ready(out)
-            leaf.delete()
-        per_leaf.append(out)
-    out = {k: v for k, v in params.items() if k != "layers"}
-    for i in range(n):
-        out[f"layer_{i}"] = jax.tree_util.tree_unflatten(
-            treedef, [pl[i] for pl in per_leaf]
-        )
+    out = {k: v for k, v in params.items() if k not in stacks}
+    for stack in stacks:
+        # "layers" -> "layer_{i}"; a trunk of several stacks
+        # (tpufw.models.phi4flash: "self_layers", "cross_layers") names
+        # each the same way: "self_layer_{i}".
+        leaves, treedef = jax.tree_util.tree_flatten(params[stack])
+        n = leaves[0].shape[0]
+        split = jax.jit(lambda a, n=n: tuple(a[i] for i in range(n)))
+        per_leaf = []
+        for leaf in leaves:
+            cut = split(leaf)
+            if donate and isinstance(leaf, jax.Array):
+                # The slices must exist on device before the source dies.
+                jax.block_until_ready(cut)
+                leaf.delete()
+            per_leaf.append(cut)
+        for i in range(n):
+            out[f"{stack[:-1]}_{i}"] = jax.tree_util.tree_unflatten(
+                treedef, [pl[i] for pl in per_leaf]
+            )
     return out
 
 

@@ -81,6 +81,22 @@ and have no ladder); and off the chip the ladder read is what runs, the
 kernel's reference, so that the suite does not step every pool through
 the Pallas interpreter. The ladders go when those have kernels (D12).
 
+READERS THAT ARE NOT THE WRITER. ``append`` writes the call's tokens
+and hands back ``read``; WHO calls ``read`` is the model's business. A
+family whose later layers attend an earlier layer's keys and values
+(tpufw.models.phi4flash: one full-attention layer writes the model's one
+page pair, seven cross-attention layers after it project queries only)
+hands that layer's ``read``, with the ``seg`` and ``q_slots`` it came
+with, down its trunk, and each reader calls it with an ``attend`` and
+queries of its own. A reader declares no cache leaf and writes nothing;
+its queries sit at the slots the writer's did, so the mask is the
+writer's (a key is seen up to and including the query's own position);
+every rule above and below holds a reader as it holds the writer: in
+place through the kernel where the writer's call is, through the ladders
+elsewhere, one more read of the same arena and no copy of it. Readers
+whose ``attend`` compares equal to the writer's share its trace of each
+branch. ``page_readers`` is the count for the host's books.
+
 THE LIVE PREFIX (the ladders' calls). A cached call reads the first L
 slots of the row, not
 all ``max_seq_len``: L is the rows' live length rounded up to a rung of
@@ -203,6 +219,10 @@ _LEAVES: Dict[str, Role] = {
     # GatedDeltaNetLayer, beside the same ``conv_state``), in three of a
     # period's four layers; the fourth holds a page pair.
     "gdn_state": Role(STATE, 4),
+    # A Mamba-1 mixer's [B, N, D] state (tpufw.models.phi4flash
+    # MambaMixer, beside the same ``conv_state``): a decay a channel AND
+    # a state, channels on the lanes.
+    "mamba_state": Role(STATE, 3),
     # A window layer's RING (``ring_append``): the last ``window`` keys
     # and values of each row, the logical slot and the segment id of
     # each ring slot. Per-slot like STATE, and declined where STATE is.
@@ -404,7 +424,11 @@ def in_place(cfg, leaves, width: int = 1) -> bool:
     stored = getattr(cfg, "kv_store_heads", None) or getattr(
         cfg, "n_kv_heads", 0
     )
-    head_dim = getattr(cfg, "head_dim", 0)
+    # A family that stores its heads under another view (paired heads:
+    # tpufw.models.phi4flash) says how wide a stored head is.
+    head_dim = getattr(cfg, "kv_store_head_dim", None) or getattr(
+        cfg, "head_dim", 0
+    )
     return bool(stored and head_dim) and paged_attend.serves(
         head_dim, page, stored, cfg.dtype
     )
@@ -423,6 +447,16 @@ def attended_slots(cfg, leaves, n_rows: int, lens, width: int = 1) -> int:
     return math.prod(
         attended_pair(cfg, n_rows, len(lens), max(lens, default=0))
     )
+
+
+def page_readers(cfg) -> int:
+    """Layers that read each PAGE pair of ``cfg``'s model in a cached
+    call, its writer included: 1 wherever a layer reads only what it
+    wrote; a family whose later layers attend pages an earlier one wrote
+    (module docstring, READERS THAT ARE NOT THE WRITER) derives the
+    count in its config (``kv_page_readers``). For the host, which books
+    what the readers beside the writer read (tpufw.workloads.serve)."""
+    return int(getattr(cfg, "kv_page_readers", 1))
 
 
 def _head(x: jax.Array, n: int) -> jax.Array:
@@ -542,7 +576,11 @@ def append(module, cfg, new: Dict[str, jax.Array], segment_ids):
     ``attend`` returns a pytree of ``[K, ...]`` arrays, which ``read``
     hands back at ``[B, ...]``, zero in the rows not read. It is traced
     at most once per (K, L), must return the same trailing shapes at
-    each and may not touch flax variables; where it is hashable and
+    each and may not touch flax variables; ``read`` touches none either
+    (the writes are done when ``append`` returns), so it may be called
+    again, by this module or by a later one that stores nothing of its
+    own, with other queries at the same slots (module docstring, READERS
+    THAT ARE NOT THE WRITER); where ``attend`` is hashable and
     compares equal across a model's layers (a frozen dataclass, not a
     closure) the layers share one trace of each branch (``_read_rows``).
     Query i may attend slot j iff ``j <= q_slots[., i]`` and the

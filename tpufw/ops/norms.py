@@ -1,4 +1,5 @@
-"""Normalization ops. RMSNorm is the Llama/Mixtral norm; computed in fp32."""
+"""Normalization ops. RMSNorm is the Llama/Mixtral norm, LayerNorm the
+Phi-4-flash family's; computed in fp32."""
 
 from __future__ import annotations
 
@@ -17,3 +18,18 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     y = x32 * jax.lax.rsqrt(var + eps)
     return (y * weight.astype(jnp.float32)).astype(dtype)
+
+
+def layer_norm(
+    x: jax.Array, weight: jax.Array, bias: jax.Array, eps: float = 1e-5
+) -> jax.Array:
+    """LayerNorm (mean and variance over the last axis, a scale and a
+    bias) with fp32 accumulation, output cast back to x.dtype."""
+    dtype = x.dtype
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    y = (x32 - mean) * jax.lax.rsqrt(var + eps)
+    return (
+        y * weight.astype(jnp.float32) + bias.astype(jnp.float32)
+    ).astype(dtype)

@@ -47,6 +47,11 @@ _PROJ_IN_DIMS = {
     # projection; its q/k/v/o, ``gate`` and ``beta`` are shaped like the
     # names above.
     "decay": 1,
+    # A Mamba-1 mixer's two small projections (tpufw.models.phi4flash
+    # MambaMixer): [inner, dt_rank + 2 N] and [dt_rank, inner] with the
+    # time step's bias; its ``in_proj`` / ``out_proj`` and the Gated
+    # Memory Unit's are shaped like the names above.
+    "x_proj": 1, "dt_proj": 1,
     # The dedicated LM head ([D, V]) is the largest single matmul a
     # decode step streams; tied (Gemma) embeddings stay fp — the gather
     # and the attend contraction want incompatible scale granularities.
@@ -60,6 +65,7 @@ _PROJ_RANK = {
     "f_a": 2, "f_b": 2, "g_a": 2, "g_b": 2, "beta": 2,
     "in_proj": 2, "out_proj": 2,
     "decay": 2,
+    "x_proj": 2, "dt_proj": 2,
     "lm_head": 2,
 }
 #: Mixtral expert stacks: RAW [E, in, out] arrays (not {kernel} modules)

@@ -1449,6 +1449,15 @@ class _SlotScheduler:
                     "window_key_slots_total",
                     "window_row_key_slots_total",
                 )
+                # Key slots read by layers through pages ANOTHER layer
+                # wrote (tpufw.ops.kv_store, readers that are not the
+                # writer): ``attended_key_slots_total``'s count once for
+                # each such reader, by the kind of call; 0 for a model
+                # whose layers read only what they wrote.
+                for call in ("decode", "chunk"):
+                    metrics.registry.counter(
+                        "tpufw_serve_shared_key_slots_total"
+                    ).inc(0.0, call=call)
                 # Admissions whose prefix lookup the pool declined (a
                 # model with per-slot state or window rings gets no
                 # shared pages).
@@ -2013,7 +2022,10 @@ class _SlotScheduler:
         the two ladders' rungs hold), beside ``row_key_slots_total``'s B
         x ``max_seq_len``: the share of the pool's key slots the device
         read. Layers that keep a ring of their window are booked apart
-        (x layers: they read every row's ring whatever the rows hold)."""
+        (x layers: they read every row's ring whatever the rows hold);
+        ``shared_key_slots_total`` grows by the same read once for each
+        layer that attends the pages beside the one that wrote them (the
+        pool reads the count off the store's rule for its model)."""
         if self._metrics is None or not self.page:
             return
         calls = list(calls)
@@ -2022,6 +2034,12 @@ class _SlotScheduler:
         )
         self._metrics.inc("attended_key_slots_total", read)
         self._metrics.inc("row_key_slots_total", whole)
+        self._metrics.registry.counter(
+            "tpufw_serve_shared_key_slots_total"
+        ).inc(
+            (self._pool.page_readers - 1) * read,
+            call="chunk" if chunk else "decode",
+        )
         rows = 1 if chunk else self.n_slots
         read, whole = self._pool.window_keys(len(calls), width)
         self._metrics.inc("window_key_slots_total", rows * read)
