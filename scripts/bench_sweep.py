@@ -41,11 +41,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmarks import harness, procs, stats, traffic  # noqa: E402
+from benchmarks.metrics import _prom  # noqa: E402
 
 BUCKETS = (512, 2048, 8192, 16384)
 QUEUE = "tpufw_serve_queue_depth"
 #: Host traces of the row model: a pool's one, none between two scrapes.
 ROW_TRACES = "tpufw_serve_row_shape_traces_total"
+#: Decode steps of a pool with routed experts, and those of them that ran
+#: the experts over the live rows' assignments alone (tpufw.ops.moe_live).
+EXPERT_STEPS = "tpufw_serve_expert_steps_total"
+EXPERT_LIVE = "tpufw_serve_expert_live_steps_total"
 
 
 def limits_from(records_t0, seconds):
@@ -156,11 +161,14 @@ def sweep_windows(a, rates, shapes, out):
                              "compiled_in_window": c1 - c0,
                              "row_shape_traces": [prom0.get(ROW_TRACES), prom1.get(ROW_TRACES)]})
                 ws = stats.window_stats(run["records"], t0, seconds, run["cutoff"], {}, cell["chips"])
+                # None where the server has no such counter (an older tree).
+                grew = lambda name: _prom.delta({"prom0": prom0, "prom1": prom1}, name)
                 runner.say(f"sweep: window {n} rate {rate} shape {shape}: attempted {ws['attempted']} failed {ws['failed']} "
                            f"tokens/s {ws['tokens_per_s_per_chip']:.2f} tpot_p50 {ws.get('tpot_p50_ms', 0):.2f} "
                            f"ttft_p50 {ws.get('ttft_p50_ms', 0):.0f} queue {rows[-1]['queue_start']:.0f} -> "
                            f"{rows[-1]['queue_end']:.0f} in service {ws['backlog_start']} -> {ws['backlog_end']} "
-                           f"programs built {c1 - c0} row shape traces {prom0.get(ROW_TRACES)} -> {prom1.get(ROW_TRACES)}; "
+                           f"programs built {c1 - c0} row shape traces {prom0.get(ROW_TRACES)} -> {prom1.get(ROW_TRACES)} "
+                           f"expert steps on the live rows alone {grew(EXPERT_LIVE)} of {grew(EXPERT_STEPS)}; "
                            f"{time.time() - began:.0f} s so far")
         summarise(a, cell, seconds, setup_s, rows, out)
 

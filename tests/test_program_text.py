@@ -161,6 +161,57 @@ def test_on_the_chip_a_kv_pool_decodes_through_one_kernel_a_layer(family, monkey
     jax.clear_caches()  # nor is the steered trace a later test's
 
 
+def _expert_layers(family: str) -> int:
+    """Layers of the family's rehearsal model that run routed experts."""
+    from benchmarks import harness
+
+    keys = harness.model_keys(harness.load_json(harness.rehearse_path(family)))
+    return keys["num_hidden_layers"] - keys.get("first_k_dense_replace", 0)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_on_the_chip_a_pool_of_eight_routes_its_live_rows_through_the_kernel(
+    family, monkeypatch
+):
+    """Lowered for the TPU with the expert kernel's rule steered on (here
+    the backend is the CPU and the rehearsal's widths are toys), the
+    decode program of a pool of EIGHT slots (the shortest with a rung
+    under it) calls the Mosaic kernel twice an expert layer (gate and up
+    fused, down), in the one branch of a ``case`` whose other branch
+    holds the parent's three ``ragged_dot``s; its prefill chunk (a row
+    twin of ``t > 1`` under a scalar cursor) is the parent's to the
+    letter, and the two-slot pool of the digests above, with no rung
+    under it, has no kernel in its decode program either."""
+    import re
+
+    from tpufw.ops import moe_live
+
+    monkeypatch.setattr(sys.modules[__name__], "SLOTS", 8)
+    plain = program_texts(family, ("tpu",))
+    monkeypatch.setattr(moe_live, "serves", lambda *a: True)
+    jax.clear_caches()  # the trace above is this one's to jit, else
+    try:
+        steered = program_texts(family, ("tpu",))
+    except Exception as e:  # noqa: BLE001 — whatever this jax raises
+        pytest.skip(f"this jax cannot lower a Mosaic kernel off the chip: {e!r}")
+    layers = _expert_layers(family)
+    calls = lambda text: len(re.findall(r"call @live_experts\w*\(", text))
+    ragged = lambda text: text.count('"chlo.ragged_dot"(')
+    assert (calls(plain["decode"]), calls(steered["decode"])) == (0, 2 * layers)
+    assert "tpu_custom_call" in steered["decode"]
+    assert "tpu_custom_call" not in plain["decode"]
+    assert ragged(steered["decode"]) == ragged(plain["decode"]) == 3 * layers
+    assert steered["decode"].count("stablehlo.case") == (
+        plain["decode"].count("stablehlo.case") + layers
+    )
+    assert steered["chunk"] == plain["chunk"]
+    # Two slots have no rung under them: the parent's decode program.
+    monkeypatch.setattr(sys.modules[__name__], "SLOTS", 2)
+    two = program_texts(family, ("tpu",))
+    assert calls(two["decode"]) == 0 and "tpu_custom_call" not in two["decode"]
+    jax.clear_caches()  # nor is the steered trace a later test's
+
+
 if __name__ == "__main__":
     import subprocess
 

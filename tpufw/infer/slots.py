@@ -40,6 +40,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from tpufw.infer.generate import _model_apply, _stream_prefill
 from tpufw.infer.sampling import SamplingConfig, sample_token
+from tpufw.ops import moe_live
 from tpufw.ops.kv_store import (
     DECLINES, PAGE, RING, STATE, STATE_LEAVES, Decline, leaf_name, path_role,
     page_readers,
@@ -348,6 +349,13 @@ class SlotPool:
             leaf_name(path)
             for path, _ in jax.tree_util.tree_leaves_with_path(self.cache)
             if path_role(path).kind == PAGE
+        )
+        #: R of the routed experts' decode step (``moe_live.pool_rows``,
+        #: the rule the program branches by: 0 where it never runs the
+        #: kernel); None for a model without routed experts.
+        widths = moe_live.expert_widths(self.params)
+        self.expert_rows = None if widths is None else moe_live.pool_rows(
+            self.model.cfg, self.n_slots, *widths
         )
 
     @classmethod

@@ -27,10 +27,11 @@ from tpufw.models.llama import (
     decoder_lm,
     reject_quant_lora,
 )
+from tpufw.ops import moe_live
 from tpufw.ops.moe import (
     expert_capacity,
     route_topk_capacity,
-    route_topk_sorted,
+    sorted_route,
 )
 
 
@@ -300,22 +301,39 @@ class MoEMLP(nn.Module):
         Single-device / data-sharded only: the expert weight stacks
         stay whole. Sharding the ``expert`` mesh axis needs the einsum
         path, whose dispatch tensors ARE the all-to-all (module doc of
-        tpufw.ops.moe)."""
+        tpufw.ops.moe).
+
+        A POOL'S DECODE STEP (one token a row, ``valid`` given: some
+        rows dead) runs the experts over its live rows' assignments
+        alone while no more than ``moe_live.pool_rows`` rows are live
+        (B/8; 0: never, and off the chip): the first ``k x R`` sorted
+        assignments and their count go to ``tpufw.ops.moe_live``, which
+        fetches an expert's weights only where a live assignment names
+        it. Same selection, gates and precision; what is left out are
+        the products a zero gate multiplied. Above R the ``ragged_dot``s
+        run as everywhere else, under a ``lax.cond`` on the live count."""
         cfg = self.cfg
         b, t, d = x.shape
         k = cfg.experts_per_token
         e = self._n_held()
         g = b * t
-        token, group_sizes, gates, aux, z = route_topk_sorted(
+        route = sorted_route(
             router_logits, k, capacity,
             valid=None if valid is None else valid.reshape(g),
             dtype=x.dtype,
             **self._routing(),
         )
-        xs = x.reshape(g, d).astype(cfg.dtype)[token]  # [k*G, d]
+        token, group_sizes, gates = route.token, route.group_sizes, route.gates
+        xf = x.reshape(g, d).astype(cfg.dtype)
+        names = (
+            ("w_gate", (e, d, d_ff), ("expert", "embed", "expert_mlp")),
+            ("w_up", (e, d, d_ff), ("expert", "embed", "expert_mlp")),
+            ("w_down", (e, d_ff, d), ("expert", "expert_mlp", "embed")),
+        )
+        stacks = [self._expert_weights(*n) for n in names]
 
-        def grouped(name, shape, names, inp):
-            w, a, bw = self._expert_weights(name, shape, names)
+        def grouped(stack, inp):
+            w, a, bw = stack
             y = jax.lax.ragged_dot(inp, w.astype(cfg.dtype), group_sizes)
             if a is not None:
                 lo = jax.lax.ragged_dot(
@@ -329,24 +347,37 @@ class MoEMLP(nn.Module):
                 )
             return y
 
-        gate_out = grouped(
-            "w_gate", (e, d, d_ff),
-            ("expert", "embed", "expert_mlp"), xs,
+        def combine(ys, token, gates):
+            yw = ys * gates[:, None].astype(cfg.dtype)
+            return jnp.zeros((g, d), cfg.dtype).at[token].add(yw)
+
+        def every_row():
+            xs = xf[token]  # [k*G, d]
+            gate_out, up_out = grouped(stacks[0], xs), grouped(stacks[1], xs)
+            h = nn.silu(gate_out) * up_out
+            return combine(grouped(stacks[2], h), token, gates)
+
+        def live_rows_alone():
+            gate, up, down = (w.astype(cfg.dtype) for w, _, _ in stacks)
+            at, ids = token[:n_live], route.eids[:n_live]
+            n = k * g - route.counts[e]  # the sentinel's sort last
+            h = moe_live.live_experts(xf[at], ids, n, gate, up)
+            ys = moe_live.live_experts(h, ids, n, down)
+            return combine(ys, at, gates[:n_live])
+
+        rows = (
+            moe_live.pool_rows(cfg, b, d, d_ff)
+            if t == 1 and valid is not None else 0
         )
-        up_out = grouped(
-            "w_up", (e, d, d_ff),
-            ("expert", "embed", "expert_mlp"), xs,
-        )
-        h = nn.silu(gate_out) * up_out
-        ys = grouped(
-            "w_down", (e, d_ff, d),
-            ("expert", "expert_mlp", "embed"), h,
-        )
-        yw = ys * gates[:, None].astype(cfg.dtype)
-        y = (
-            jnp.zeros((g, d), cfg.dtype).at[token].add(yw)
-        ).reshape(b, t, d)
-        return y, aux, z
+        n_live = k * rows
+        if rows:
+            y = jax.lax.cond(
+                moe_live.takes(rows, jnp.sum(valid)),
+                live_rows_alone, every_row,
+            )
+        else:
+            y = every_row()
+        return y.reshape(b, t, d), route.aux_lb, route.z
 
     @nn.compact
     def __call__(self, x, valid=None):

@@ -18,7 +18,7 @@ unchanged).
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -105,7 +105,29 @@ def _topk_select(
     return probs, topk_probs, topk_idx
 
 
-def route_topk_sorted(
+class SortedRoute(NamedTuple):
+    """``sorted_route``'s assignments. ``route_topk_sorted`` hands on
+    what ``ragged_dot`` over all k*G rows takes; ``eids`` and ``counts``
+    are what it folds away: the assignments before the sentinel's are
+    the live rows' held ones, sorted by expert, which is what
+    ``tpufw.ops.moe_live`` is handed."""
+
+    token: jax.Array  # [k*G] source token of each sorted assignment
+    group_sizes: jax.Array  # [E] rows a group, the sentinel's in E-1
+    gates: jax.Array  # [k*G] combine weight of each
+    aux_lb: jax.Array
+    z: jax.Array
+    eids: jax.Array  # [k*G] its (local) expert id; the sentinel E last
+    counts: jax.Array  # [E + 1] assignments a group, the sentinel's last
+
+
+def route_topk_sorted(*args, **kwargs):
+    """``sorted_route`` as ``ragged_dot`` takes it: (token [k*G],
+    group_sizes [E], gates [k*G], aux_lb, z)."""
+    return sorted_route(*args, **kwargs)[:5]
+
+
+def sorted_route(
     router_logits: jax.Array,
     k: int,
     capacity: int,
@@ -116,7 +138,7 @@ def route_topk_sorted(
     scoring: str = "softmax",
     select_bias: Optional[jax.Array] = None,
     held: Optional[tuple[int, int]] = None,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> SortedRoute:
     """Sorted-dispatch twin of ``route_topk_capacity``: identical
     selection, priority, capacity-drop, and aux-statistic semantics,
     but instead of materializing [G, E, C] one-hot dispatch/combine
@@ -147,12 +169,13 @@ def route_topk_sorted(
     expert ids become local and ``group_sizes`` has length n, matching
     stacks of n experts. None = all E are here.
 
-    Returns (token [k*G], group_sizes [E], gates [k*G], aux_lb, z):
-    ``token[i]`` is the source token id of the i-th SORTED assignment
-    (gather ``x[token]`` to build the grouped input), ``group_sizes``
-    counts sorted rows per expert and always sums to k*G (every row
-    of ragged_dot's output is defined), ``gates`` is the combine
-    weight per sorted assignment.
+    Returns a ``SortedRoute``: ``token[i]`` is the source token id of
+    the i-th SORTED assignment (gather ``x[token]`` to build the
+    grouped input), ``group_sizes`` counts sorted rows per expert and
+    always sums to k*G (every row of ragged_dot's output is defined),
+    ``gates`` is the combine weight per sorted assignment; ``eids``
+    and ``counts`` are the same assignments before the sentinel's were
+    folded into group E-1.
     """
     g, e_all = router_logits.shape
     probs, topk_probs, topk_idx = _topk_select(
@@ -193,7 +216,9 @@ def route_topk_sorted(
     if validf is not None:
         top1_mask = top1_mask * validf[:, None]
     aux_lb, z = _router_stats(router_logits, probs, top1_mask, validf, g)
-    return token[order], group_sizes, gates, aux_lb, z
+    return SortedRoute(
+        token[order], group_sizes, gates, aux_lb, z, sorted_eids, counts
+    )
 
 
 def _router_stats(router_logits, probs, top1_mask, validf, g):

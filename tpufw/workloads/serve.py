@@ -903,14 +903,15 @@ class _DecodeChunk:
     its predecessor was read (``_SlotScheduler._run_chunk``)."""
 
     __slots__ = ("active", "k", "page_snap", "ahead", "key_rung",
-                 "row_rung", "chained", "out", "t0")
+                 "row_rung", "live", "chained", "out", "t0")
 
-    def __init__(self, active, k, page_snap, ahead, rungs, chained):
+    def __init__(self, active, k, page_snap, ahead, rungs, live, chained):
         self.active = active
         self.k = k
         self.page_snap = page_snap
         self.ahead = ahead
         self.key_rung, self.row_rung = rungs
+        self.live = live
         self.chained = chained
         self.out = None  # [S, k] tokens, pending on the device
         self.t0 = 0.0
@@ -1419,6 +1420,14 @@ class _SlotScheduler:
                 # read (``_run_chunk``'s chained order), beside
                 # ``ticks_total``, the chunks read.
                 "chunks_chained_total",
+                # Decode steps dispatched on a pool whose model has
+                # routed experts, and those of them with no more live
+                # rows than the pool's ``expert_rows``: the steps whose
+                # expert matmuls ran over the live rows' assignments
+                # alone (tpufw.ops.moe_live; ``_count_experts``). 0 for
+                # a model without routed experts.
+                "expert_steps_total",
+                "expert_live_steps_total",
             )
             if self.page:
                 # Feature-gated (register = expose at 0): legacy-mode
@@ -1627,6 +1636,8 @@ class _SlotScheduler:
         self._ledger.reset()
         if self._metrics is not None:
             self._metrics.reset("chunks_chained_total")
+            self._metrics.reset("expert_steps_total")
+            self._metrics.reset("expert_live_steps_total")
 
     def _enqueue(self, pend: _Pending) -> None:
         req = self._make_req(pend)  # raises ValueError -> HTTP 400
@@ -2057,6 +2068,31 @@ class _SlotScheduler:
         both = 2 * (self._pool.state_bytes // self.n_slots)
         self._metrics.inc("state_moved_bytes_total", moved * both)
         self._metrics.inc("state_live_bytes_total", live * both)
+
+    def _experts_live(self, live: int) -> int:
+        """1 where a decode step of the pool with ``live`` live rows runs
+        its routed experts over those rows' assignments alone: the
+        program's own rule (``moe_live.takes`` of the pool's
+        ``expert_rows``); 0 above that, off the chip, and for a model
+        without routed experts."""
+        from tpufw.ops.moe_live import takes
+
+        rows = self._pool.expert_rows
+        return int(rows is not None and bool(takes(rows, live)))
+
+    def _count_experts(self, lives) -> None:
+        """Book dispatched decode steps of a pool whose model has
+        routed experts, beside ``_count_keys``: ``lives`` yields each
+        step's live rows, and a step counts as live where
+        ``_experts_live`` says so."""
+        if self._metrics is None or self._pool.expert_rows is None:
+            return
+        lives = list(lives)
+        self._metrics.inc("expert_steps_total", len(lives))
+        self._metrics.inc(
+            "expert_live_steps_total",
+            sum(self._experts_live(n) for n in lives),
+        )
 
     def _admit(self) -> None:
         with self._cv:
@@ -2922,7 +2958,8 @@ class _SlotScheduler:
         with self._tracer.span(
             "serve_decode_chunk", k=chunk.k, rows=len(chunk.active),
             ahead=chunk.ahead, key_rung=chunk.key_rung,
-            row_rung=chunk.row_rung, chained=int(chunk.chained),
+            row_rung=chunk.row_rung, live=chunk.live,
+            chained=int(chunk.chained),
         ):
             if chunk.out is None:
                 with self._tracer.span("serve_decode_dispatch"):
@@ -2985,7 +3022,7 @@ class _SlotScheduler:
         return _DecodeChunk(
             active, min(self.chunk, _pow2_ceil(max_left)), page_snap,
             0 if ran else self._ledger.ahead, self._rungs(active, ran),
-            chained=ran > 0,
+            self._experts_live(len(active)), chained=ran > 0,
         )
 
     def _step_keys(self, chunk_index: int, k: int):
@@ -3130,6 +3167,9 @@ class _SlotScheduler:
         steps = ([at + i for at, n in spans if i < n] for i in range(k))
         self._count_keys(steps)
         self._count_state(self.n_slots * k, sum(n for _, n in spans))
+        self._count_experts(
+            sum(i < n for _, n in spans) for i in range(k)
+        )
         if self._metrics is not None:
             self._metrics.inc("tokens_generated_total", live_tokens)
             # Capacity accounting: S * k device-steps ran; everything
